@@ -25,8 +25,7 @@ import numpy as np
 from ..core.snapshot import GraphView
 from ..obs import ledger as _ledger
 from ..obs.trace import TRACER, block_steps
-from ..ops.segment import (partition_segment_reduce, segment_combine,
-                           segment_sum_sorted_csr)
+from ..ops.segment import partition_segment_reduce, segment_combine
 from .program import Context, Edges, VertexProgram
 
 _elem = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
@@ -116,15 +115,11 @@ def make_mask_runner(program: VertexProgram, n: int, m: int, k: int,
                 (k * m,) + a.shape[1:])
 
         def combine_flat(tree_flat, ids, sorted_):
-            # the segmented-scan combine beats XLA's scatter lowering ~3x
-            # per element on TPU but is a multi-pass loser on CPU (whose
-            # native scatter-add is one pass) — pick per backend at trace
-            # time; per-window blocks keep results bitwise equal to k=1 runs
-            use_scan = (program.combiner == "sum" and sorted_
-                        and jax.default_backend() == "tpu")
             # the binned route owns the DESTINATION direction (the layout
-            # bins by dst); the reverse direction keeps the flat scatter
-            use_pcpm = pcpm is not None and sorted_ and not use_scan
+            # bins by dst); the reverse direction keeps the flat scatter.
+            # No branch here may depend on the backend's name: the chip
+            # must run the combine the CPU tests run.
+            use_pcpm = pcpm is not None and sorted_
 
             def leaf(x):
                 if use_pcpm:
@@ -138,12 +133,8 @@ def make_mask_runner(program: VertexProgram, n: int, m: int, k: int,
                             b_local, pcpm.n_per, n, program.combiner,
                             mw.reshape(P, cap)))(xb, mb)
                     return out                       # [k, n, ...]
-                if use_scan:
-                    out = segment_sum_sorted_csr(x, ids, k * n, em_flat,
-                                                 block_size=m)
-                else:
-                    out = segment_combine(x, ids, k * n, program.combiner,
-                                          em_flat, indices_are_sorted=sorted_)
+                out = segment_combine(x, ids, k * n, program.combiner,
+                                      em_flat, indices_are_sorted=sorted_)
                 return out.reshape((k, n) + x.shape[1:])
             return jax.tree_util.tree_map(leaf, tree_flat)
 
@@ -344,13 +335,10 @@ def run_async(
 
     # build the layout only when the binned route can actually engage:
     # custom exchanges and in-only programs never take the sorted-combine
-    # path, and on TPU the sum combine lowers through the segmented scan
-    # (combine_flat's use_scan) — paying an O(m log m) build + upload per
-    # fresh view for a route that won't run would be pure overhead
+    # path — paying an O(m log m) build + upload per fresh view for a
+    # route that won't run would be pure overhead
     binnable = (program.combiner != "custom"
-                and program.direction in ("out", "both")
-                and not (program.combiner == "sum"
-                         and jax.default_backend() == "tpu"))
+                and program.direction in ("out", "both"))
     layout = _view_layout(view, e_src, e_dst,
                           program.needs_occurrences) if binnable else None
     extra = ()

@@ -42,6 +42,7 @@ class IngestionPipeline:
         self.batch_size = batch_size
         self._threads: list[threading.Thread] = []
         self._stop = threading.Event()
+        # sources added and not yet handed to run()/start()
         self._feeds: list[tuple[Source, Parser]] = []
         self.counts: dict[str, int] = {}
         self.errors: dict[str, str] = {}
@@ -94,18 +95,27 @@ class IngestionPipeline:
 
     # ---- synchronous mode (tests, file replay, benchmarks) ----
 
+    def _take_feeds(self) -> list[tuple[Source, Parser]]:
+        """The feeds no earlier ``run``/``start`` consumed: a source added
+        to a running node joins without replaying the drained ones."""
+        feeds, self._feeds = self._feeds, []
+        return feeds
+
     def run(self) -> None:
-        """Drain every source to exhaustion on the calling thread."""
+        """Drain every not-yet-consumed source to exhaustion on the
+        calling thread."""
         self._ensure_writer()
-        for source, parser in self._feeds:
+        for source, parser in self._take_feeds():
             self._consume(source, parser)
         self._finish_writer()
 
     # ---- live mode (threads; SpoutTrait self-scheduling analogue) ----
 
     def start(self) -> None:
+        """One consumer thread per not-yet-consumed source; call again
+        after ``add_source`` to start the late joiner alone."""
         self._ensure_writer()
-        for source, parser in self._feeds:
+        for source, parser in self._take_feeds():
             t = threading.Thread(
                 target=self._consume, args=(source, parser),
                 name=f"ingest-{source.name}", daemon=True)

@@ -20,8 +20,9 @@ tick ("epoch") by:
   never (a weight update can raise distances).
 
 Every epoch falls back to the legacy full re-sweep (``Job._run_at``)
-when the incremental path cannot serve — non-columnar program, engine
-construction/dispatch failure, memory guards — so the fallback IS the
+when the incremental path cannot serve — non-columnar program, memory
+guards, a transport failure or OOM mid-dispatch (any other error fails
+the job, ``jobs/manager.declinable``) — so the fallback IS the
 correctness oracle: both paths emit through ``Job._emit`` with
 identical row shapes. Every ``RTPU_LIVE_RESYNC`` epochs the engine
 drops device residency and the warm seed ("resync"): the next epoch
@@ -227,14 +228,17 @@ class LiveEpochState:
                 self.job.ledger.add_phase("device_wait",
                                           _time.perf_counter() - b0)
         except Exception as e:
-            # ANY incremental failure (fold, dispatch, device) falls
+            # a transport failure or OOM on the incremental path falls
             # back to the oracle path for THIS epoch and rebuilds the
-            # engine on the next — a live job must keep serving
+            # engine on the next — a live job must keep serving. Any
+            # other error (jobs/manager.declinable) fails the job.
+            self.hb = None
+            self.last_out = None
+            if not _manager().declinable(e):
+                raise
             _live_log.warning("live epoch failed (%s: %s) — falling "
                               "back to full re-sweep",
                               type(e).__name__, e)
-            self.hb = None
-            self.last_out = None
             return self._resweep(q, t, alg, t0)
 
         ship = int(hb.ship_bytes)

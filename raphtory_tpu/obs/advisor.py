@@ -567,10 +567,12 @@ def rule_device_pressure(sig: dict) -> dict | None:
             f"sigs) inside the last {comp.get('window_seconds')}s — "
             "request traffic is shape-diverse enough to recompile "
             "faster than programs amortise",
-            "RTPU_COMPILE_CACHE_DIR",
-            "set RTPU_COMPILE_CACHE_DIR (persistent compile cache), "
-            "and bucket/pad request shapes upstream so distinct sigs "
-            "collapse; /devicez lists the recent compile events",
+            "JAX_COMPILATION_CACHE_DIR",
+            "bucket/pad request shapes upstream so distinct sigs "
+            "collapse; the persistent compile cache "
+            "(JAX_COMPILATION_CACHE_DIR, default <checkout>/.jax_cache) "
+            "only spares the recompiles a restart would repeat; "
+            "/devicez lists the recent compile events",
             {"compile": comp},
             severity="warning")
     return None

@@ -142,13 +142,11 @@ def _metrics():
 
 
 def _peaks():
-    """(peak FLOP/s, peak B/s) for the probed platform — the ledger's
-    roofline anchors (order-of-magnitude, not calibration; that gap is
-    exactly what the divergence ratio renders visible)."""
+    """(peak FLOP/s, peak B/s) of the probed device kind — the ledger's
+    one table; an unknown kind raises there."""
     from . import ledger as _ledger
 
-    platform = _ledger.xla_analysis_caps().get("platform", "cpu")
-    return _ledger._PEAKS.get(platform, _ledger._PEAKS["cpu"])
+    return _ledger.device_peaks()
 
 
 def estimated_seconds(flops, hbm_bytes) -> float | None:
@@ -382,7 +380,7 @@ def measured_table() -> list[dict]:
 
 def memory_snapshot() -> dict:
     """``memory_stats()`` of the first device, degrade-tolerant: backends
-    that return None or raise (CPU rigs, older jaxlibs) yield
+    that return None or raise (the CPU backend) yield
     ``{"available": False}`` — the ``/devicez`` memory block and every
     sampler must keep serving through that, never crash or 500."""
     try:

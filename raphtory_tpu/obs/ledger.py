@@ -65,16 +65,36 @@ from ..analysis.sanitizer import (note_shared as _san_note,
 from . import device as _device
 from .trace import TRACER
 
-#: (peak FLOP/s, peak memory bandwidth B/s) operating points per backend —
-#: order-of-magnitude roofline anchors, not measured calibration (the
-#: TPU row matches bench.py's v5e-class constants). Override the derived
+#: (peak FLOP/s, peak memory bandwidth B/s) per jax ``device_kind`` — the
+#: ONE table (bench.py reads it too). "TPU v5 lite" is one v5e chip:
+#: 197 TFLOP/s bf16, 819 GB/s HBM (Google Cloud documentation, "TPU
+#: v5e"). "cpu" is an order-of-magnitude anchor for the CPU backend the
+#: tests run on, not a calibration. A device that is not in the table is
+#: an error, never a default (``device_peaks``). Override the derived
 #: ridge with RTPU_LEDGER_RIDGE.
-_PEAKS = {
-    "tpu": (197e12, 819e9),     # v5e-class bf16 peak / HBM bandwidth
-    "gpu": (1e14, 2e12),
-    "cpu": (1e11, 2e10),        # few-core container class
+DEVICE_PEAKS = {
+    "TPU v5 lite": (197e12, 819e9),
+    "cpu": (1e11, 2e10),
 }
-_DEFAULT_PLATFORM = "cpu"
+
+
+def device_peaks(device_kind: str | None = None) -> tuple[float, float]:
+    """(peak FLOP/s, peak B/s) of ``device_kind`` (default: the probed
+    device 0). Raises for a kind the table does not know — a roofline
+    share against some other chip's peaks is worse than none."""
+    if device_kind is None:
+        device_kind = xla_analysis_caps().get("device_kind")
+    if device_kind is None:   # harvest off (RTPU_LEDGER_XLA=0): ask jax
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peak rates for device kind {device_kind!r}: add it to "
+            f"obs/ledger.DEVICE_PEAKS with its source (known: "
+            f"{sorted(DEVICE_PEAKS)})") from None
 
 
 def _enabled() -> bool:
@@ -111,11 +131,9 @@ _CAPS_LOCK = threading.Lock()
 
 
 def _cost_dict(compiled):
-    """Tolerant ``cost_analysis()`` extraction: older jaxlibs return a
-    one-element list of dicts, newer ones a dict; either may be None."""
+    """``cost_analysis()`` of a compiled executable: a dict, or None on
+    backends that report nothing."""
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
     return ca if isinstance(ca, dict) else None
 
 
@@ -127,13 +145,15 @@ def xla_analysis_caps() -> dict:
     with _CAPS_LOCK:
         if _CAPS:
             return dict(_CAPS)
-    caps = {"cost": False, "memory": False,
-            "platform": _DEFAULT_PLATFORM, "probed": True}
+    caps = {"cost": False, "memory": False, "platform": None,
+            "device_kind": None, "probed": True}
     if _xla_enabled():
         try:
             import jax
 
-            caps["platform"] = jax.devices()[0].platform
+            dev = jax.devices()[0]
+            caps["platform"] = dev.platform
+            caps["device_kind"] = dev.device_kind
             fn = jax.jit(lambda x: x * 2.0 + 1.0)
             comp = fn.lower(
                 jax.ShapeDtypeStruct((8,), "float32")).compile()
@@ -158,22 +178,21 @@ def reset_xla_caps() -> None:
         _CAPS.clear()
 
 
-def ridge_flops_per_byte(platform: str | None = None) -> float:
-    """Roofline ridge point for ``platform`` (default: the probed one)."""
+def ridge_flops_per_byte(device_kind: str | None = None) -> float:
+    """Roofline ridge point for ``device_kind`` (default: the probed
+    one)."""
     v = os.environ.get("RTPU_LEDGER_RIDGE")
     if v is not None:
         try:
             return max(1e-6, float(v))
         except ValueError:
             pass
-    if platform is None:
-        platform = xla_analysis_caps().get("platform", _DEFAULT_PLATFORM)
-    flops, bw = _PEAKS.get(platform, _PEAKS[_DEFAULT_PLATFORM])
+    flops, bw = device_peaks(device_kind)
     return flops / bw
 
 
 def classify_roofline(flops, bytes_accessed,
-                      platform: str | None = None) -> str:
+                      device_kind: str | None = None) -> str:
     """``hbm_bound`` | ``compute_bound`` | ``unknown`` from harvested
     cost-analysis numbers — the ONE place the classification rule lives
     (docs/OBSERVABILITY.md documents it verbatim)."""
@@ -181,7 +200,7 @@ def classify_roofline(flops, bytes_accessed,
         return "unknown"
     intensity = float(flops) / float(bytes_accessed)
     return ("compute_bound"
-            if intensity >= ridge_flops_per_byte(platform) else "hbm_bound")
+            if intensity >= ridge_flops_per_byte(device_kind) else "hbm_bound")
 
 
 # --------------------------------------------------------- kernel registry
@@ -362,13 +381,13 @@ class KernelRegistry:
             if flops and nbytes:
                 updates["intensity"] = round(flops / nbytes, 4)
             updates["bound"] = classify_roofline(flops, nbytes,
-                                                 caps.get("platform"))
+                                                 caps.get("device_kind"))
             hbm = (rec.get("est_hbm_bytes") if traffic
                    else (int(nbytes) if nbytes else None))
             if not traffic:
                 updates["est_hbm_bytes"] = hbm
             updates["bound_refined"] = classify_roofline(
-                flops, hbm, caps.get("platform"))
+                flops, hbm, caps.get("device_kind"))
             if flops and hbm:
                 updates["intensity_refined"] = round(flops / hbm, 4)
             with self._lock:
@@ -936,7 +955,7 @@ def costz() -> dict:
         "enabled": _enabled(),
         "xla": caps,
         "ridge_flops_per_byte": round(
-            ridge_flops_per_byte(caps.get("platform")), 3),
+            ridge_flops_per_byte(caps.get("device_kind")), 3),
         "classification_rule": (
             "intensity = flops / bytes_accessed; hbm_bound if intensity "
             "< ridge else compute_bound; unknown without harvested "

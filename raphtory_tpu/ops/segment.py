@@ -58,61 +58,6 @@ def segment_combine(
     )
 
 
-def segment_sum_sorted_csr(
-    data: jnp.ndarray,
-    segment_ids_sorted: jnp.ndarray,
-    num_segments: int,
-    mask: jnp.ndarray | None = None,
-    block_size: int | None = None,
-):
-    """Sum combine over SORTED segment ids via a SEGMENTED prefix scan +
-    boundary gathers — the TPU-native replacement for scatter-add on the
-    hot path (XLA's scatter lowering costs ~3x a scan per element on TPU;
-    measured, tools/tpu_physics.py).
-
-    The scan carries (started, running_sum) and RESETS at every segment
-    start, so sums accumulate at each segment's own magnitude — unlike a
-    global cumsum-and-difference, whose absolute error floor is
-    ulp(running total) and which would drown per-vertex sums at
-    multi-million-segment scale. The per-segment result is the scanned
-    value at the segment's last row (one small gather at indptr[j+1]-1).
-
-    ``block_size``: when the flat array is a stack of independent blocks
-    (the engine's window-major layout, segments never spanning blocks), the
-    scan runs per block along axis 1 — the scan tree over a block is then
-    identical to a single-block run, keeping batched results bitwise equal
-    to unbatched ones."""
-    if mask is not None:
-        mk = mask.reshape(mask.shape + (1,) * (data.ndim - mask.ndim))
-        data = jnp.where(mk, data, jnp.zeros((), data.dtype))
-    m = len(data)
-    b = block_size if block_size is not None else m
-    k = m // b
-    ids2 = segment_ids_sorted.reshape(k, b)
-    starts = jnp.concatenate(
-        [jnp.ones((k, 1), bool), ids2[:, 1:] != ids2[:, :-1]], axis=1)
-
-    def op(a, c):
-        af, av = a
-        cf, cv = c
-        return (af | cf, jnp.where(
-            cf.reshape(cf.shape + (1,) * (av.ndim - cf.ndim)), cv, av + cv))
-
-    data2 = data.reshape((k, b) + data.shape[1:])
-    _, scanned = jax.lax.associative_scan(op, (starts, data2), axis=1)
-    scanned = scanned.reshape((m,) + data.shape[1:])
-    # CSR boundaries from the sorted ids themselves (one vectorised
-    # searchsorted — no host-built indptr to ship); empty segments -> 0
-    indptr = jnp.searchsorted(
-        segment_ids_sorted, jnp.arange(num_segments + 1, dtype=jnp.int32))
-    last = jnp.clip(indptr[1:] - 1, 0, m - 1)
-    out = scanned[last]
-    nonempty = indptr[1:] > indptr[:-1]
-    return jnp.where(
-        nonempty.reshape(nonempty.shape + (1,) * (out.ndim - 1)),
-        out, jnp.zeros((), out.dtype))
-
-
 def partition_segment_reduce(
     data: jnp.ndarray,
     local_ids: jnp.ndarray,

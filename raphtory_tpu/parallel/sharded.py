@@ -435,18 +435,10 @@ def _partition_floor() -> int:
 
 
 def _shard_map(fn, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with vma checking on jax >= 0.6; the experimental
-    ``shard_map`` (no vma system — the explicit ``vary()``/vma-seeding
-    promotions are no-ops there, and ``check_rep`` is off because the
-    halting psums intentionally mix replicated and varying operands) on
-    older jax. One shim so both parallel runners track the API move."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=True)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with vma checking on — one spelling for both
+    parallel runners."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=True)
 
 
 def make_mesh(n_vertex_shards: int | None = None, n_window_shards: int = 1,
@@ -831,10 +823,7 @@ def _sharded_runner(program: VertexProgram, mesh: Mesh, n_loc: int,
         def vary(x):
             """Promote x to varying over exactly the mesh axes it is missing
             (no-op when already fully varying) — shard_map's check_vma
-            requires explicit promotion of shard-invariant values. Pre-vma
-            jax (< 0.6) has no typeof/pcast and needs no promotion."""
-            if not hasattr(jax, "typeof") or not hasattr(jax.lax, "pcast"):
-                return x
+            requires explicit promotion of shard-invariant values."""
             missing = tuple(a for a in (W_AXIS, V_AXIS)
                             if a not in jax.typeof(x).vma)
             return jax.lax.pcast(x, missing, to="varying") if missing else x

@@ -169,35 +169,3 @@ def test_lpa_reduce_shape():
     assert out["vertices"] > 0
     assert out["communities"] >= 1
     assert sum(out["top5"]) <= out["vertices"]
-
-
-def test_segment_sum_sorted_csr_matches_scatter():
-    """The prefix-scan CSR combine must equal segment_sum exactly for ints
-    and to f32 rounding for floats, in flat and blocked layouts, with masks,
-    empty segments and trailing feature dims."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    from raphtory_tpu.ops.segment import (
-        segment_combine, segment_sum_sorted_csr)
-
-    rng = np.random.default_rng(0)
-    n, m, k = 17, 64, 3
-    ids1 = np.sort(rng.integers(0, n, m))
-    ids = np.concatenate([ids1 + kk * n for kk in range(k)]).astype(np.int32)
-    mask = rng.random(k * m) < 0.8
-
-    for data in (rng.integers(0, 100, (k * m,)).astype(np.int32),
-                 rng.random((k * m,)).astype(np.float32),
-                 rng.random((k * m, 5)).astype(np.float32)):
-        want = np.asarray(segment_combine(
-            jnp.asarray(data), jnp.asarray(ids), k * n, "sum",
-            jnp.asarray(mask)))
-        got_flat = np.asarray(segment_sum_sorted_csr(
-            jnp.asarray(data), jnp.asarray(ids), k * n, jnp.asarray(mask)))
-        got_blk = np.asarray(segment_sum_sorted_csr(
-            jnp.asarray(data), jnp.asarray(ids), k * n, jnp.asarray(mask),
-            block_size=m))
-        atol = 0 if data.dtype == np.int32 else 1e-4
-        np.testing.assert_allclose(got_flat, want, atol=atol)
-        np.testing.assert_allclose(got_blk, want, atol=atol)

@@ -377,7 +377,7 @@ def test_rule_device_pressure_memory_and_storm():
                                   "threshold": 16,
                                   "window_seconds": 60.0}}}
     f = advisor_mod.rule_device_pressure(sig)
-    assert f is not None and f["knob"] == "RTPU_COMPILE_CACHE_DIR"
+    assert f is not None and f["knob"] == "JAX_COMPILATION_CACHE_DIR"
 
     # healthy: memory unavailable + a few warm-up compiles
     sig = {"device": {"memory": {"available": False},

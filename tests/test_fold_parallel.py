@@ -411,25 +411,6 @@ def test_fold_cache_locks_clean_under_sanitizer(monkeypatch):
         san.uninstall()
 
 
-# ------------------------------------------------- compile cache knob
-
-
-def test_compile_cache_knob(monkeypatch, tmp_path):
-    import jax
-
-    from raphtory_tpu.utils.config import configure_compile_cache
-
-    monkeypatch.delenv("RTPU_COMPILE_CACHE_DIR", raising=False)
-    assert configure_compile_cache() is None
-    old = jax.config.jax_compilation_cache_dir
-    try:
-        monkeypatch.setenv("RTPU_COMPILE_CACHE_DIR", str(tmp_path))
-        assert configure_compile_cache() == str(tmp_path)
-        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
-    finally:
-        jax.config.update("jax_compilation_cache_dir", old)
-
-
 # ------------------------------------------------------------- metrics
 
 

@@ -109,7 +109,17 @@ def test_roofline_classifier_rule():
 
 def test_ridge_override_knob(monkeypatch):
     monkeypatch.setenv("RTPU_LEDGER_RIDGE", "2.5")
-    assert ledger.ridge_flops_per_byte("tpu") == 2.5
+    assert ledger.ridge_flops_per_byte("TPU v5 lite") == 2.5
+
+
+def test_device_peaks_keyed_by_kind_unknown_raises():
+    # v5e: Google Cloud "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s
+    assert ledger.device_peaks("TPU v5 lite") == (197e12, 819e9)
+    assert ledger.device_peaks() == ledger.DEVICE_PEAKS["cpu"]
+    with pytest.raises(KeyError, match="TPU v9"):
+        ledger.device_peaks("TPU v9")
+    with pytest.raises(KeyError):
+        ledger.classify_roofline(1e9, 1e6, "tpu")   # a platform, not a kind
 
 
 # -------------------------------------------------- instrument + registry
@@ -167,7 +177,7 @@ def test_capability_probe_degrades_to_host_accounting(monkeypatch):
 
 
 def test_harvest_failure_never_fails_the_dispatch(monkeypatch):
-    """cost_analysis raising mid-harvest (older jaxlib / exotic backend)
+    """cost_analysis raising mid-harvest (an exotic backend)
     leaves an error note on the record; the sweep's dispatch result is
     unaffected."""
     monkeypatch.setattr(ledger, "REGISTRY", ledger.KernelRegistry())

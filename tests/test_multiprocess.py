@@ -23,12 +23,7 @@ import jax
 
 # configure BEFORE any backend use: CPU platform, 2 local devices
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 2)
-except AttributeError:   # older jax: the XLA flag spells the same thing
-    import os
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=2")
+jax.config.update("jax_num_cpu_devices", 2)
 
 pid, port = int(sys.argv[1]), sys.argv[2]
 

@@ -131,8 +131,9 @@ def test_small_time_acquire_does_not_mask_staleness(spy):
 
 
 def test_failed_resident_dispatch_discards_sweep(spy, monkeypatch):
-    """A device failure mid-dispatch drops the resident sweep (partially
-    applied deltas must never be reused) and the job still completes."""
+    """A transport failure mid-dispatch drops the resident sweep
+    (partially applied deltas must never be reused) and the job still
+    completes on the cold path."""
     from raphtory_tpu.engine.device_sweep import DeviceSweep
 
     g = _graph()
@@ -142,7 +143,7 @@ def test_failed_resident_dispatch_discards_sweep(spy, monkeypatch):
     assert g._resident is not None
 
     def boom(self, *a, **k):
-        raise RuntimeError("injected device loss")
+        raise RuntimeError("UNAVAILABLE: injected device loss")
 
     monkeypatch.setattr(DeviceSweep, "run", boom)
     j1 = mgr.submit(registry.resolve("DegreeBasic"), ViewQuery(60))
@@ -152,6 +153,25 @@ def test_failed_resident_dispatch_discards_sweep(spy, monkeypatch):
     j2 = mgr.submit(registry.resolve("DegreeBasic"), ViewQuery(70))
     assert j2.wait(60) and j2.status == "done", j2.error
     assert g._resident is not None                          # re-pinned fresh
+
+
+def test_device_error_on_resident_route_fails_the_job(spy, monkeypatch):
+    """An error that is neither transport nor OOM — a program the
+    compiler refuses, a runtime INTERNAL — must fail the job with the
+    error, not decline to the cold path and end ``done``."""
+    from raphtory_tpu.engine.device_sweep import DeviceSweep
+
+    g = _graph()
+    mgr = AnalysisManager(g)
+
+    def boom(self, *a, **k):
+        raise RuntimeError("INTERNAL: compiler refused the program")
+
+    monkeypatch.setattr(DeviceSweep, "run", boom)
+    j = mgr.submit(registry.resolve("DegreeBasic"), ViewQuery(60))
+    assert j.wait(60) and j.status == "failed"
+    assert "compiler refused" in j.error
+    assert g._resident is None      # inconsistent state still discarded
 
 
 def test_ingestion_after_pin_invalidates(spy):

@@ -357,12 +357,7 @@ import sys
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 2)
-except AttributeError:
-    import os
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=2")
+jax.config.update("jax_num_cpu_devices", 2)
 
 pid, port = int(sys.argv[1]), sys.argv[2]
 

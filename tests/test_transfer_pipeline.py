@@ -119,8 +119,13 @@ def test_transient_classifier():
     class XlaRuntimeError(Exception):
         pass
 
-    assert _is_transient(XlaRuntimeError("INTERNAL: stream failed"))
+    # a runtime/compile error the device reports is its answer, not a
+    # transport flap: it must surface on the first attempt
+    assert not _is_transient(XlaRuntimeError("INTERNAL: stream failed"))
+    assert not _is_transient(XlaRuntimeError(
+        "INTERNAL: Mosaic failed to compile TPU kernel"))
     assert not _is_transient(XlaRuntimeError("RESOURCE_EXHAUSTED: OOM"))
+    assert _is_transient(XlaRuntimeError("UNAVAILABLE: connection lost"))
 
 
 def test_metrics_mirror():

@@ -116,12 +116,7 @@ def worker(idx: int, n: int, coord_port: int, rest_base: int, tmpdir: str,
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 2)
-    except AttributeError:
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=2")
+    jax.config.update("jax_num_cpu_devices", 2)
 
     import numpy as np
 
