@@ -169,8 +169,12 @@ class TemporalGraph:
             if sweep is None:
                 from ..engine.device_sweep import DeviceSweep
 
+                from ..obs import ledger as _ledger
+
                 pinned = self.log.pin()   # (n, version) atomic with rows
-                sweep = DeviceSweep(pinned)
+                with _ledger.engine_build("pin", pinned) as sp:
+                    sweep = DeviceSweep(pinned)
+                    sp.set(**_ledger.built(sweep))
                 self._resident = sweep
                 self._resident_version = pinned.version
                 self._resident_n = pinned.n

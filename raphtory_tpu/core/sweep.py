@@ -764,6 +764,16 @@ def log_fingerprint(log) -> tuple:
     fp = getattr(log, "_rtpu_fold_fp", None)
     if fp is not None:
         return fp
+    tr = _tracer()
+    if tr is None:
+        return _fingerprint(log)
+    # O(events) on the caller's thread, once per pin: every engine pins
+    # anew, so a Range request pays it per request
+    with tr.span("fold.fingerprint", events=int(log.n)):
+        return _fingerprint(log)
+
+
+def _fingerprint(log) -> tuple:
     t = log.column("time")
     idx = np.arange(len(t), dtype=np.uint64)
     gold = np.uint64(0x9E3779B97F4A7C15)

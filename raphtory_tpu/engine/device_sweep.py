@@ -56,18 +56,24 @@ from .program import VertexProgram
 
 
 def sweep_phase_summary(sp, elapsed, fold_seconds, fold_stall_seconds,
-                        ship_delta, ship_bytes, n_hops, fold_modes=None):
+                        ship_delta, ship_bytes, n_hops, fold_modes=None,
+                        fold_inline_seconds=0.0):
     """Per-sweep fold/stage/ship/compute phase breakdown, attached to the
     sweep span AND observed into ``raphtory_sweep_phase_seconds{phase}``
-    — shared by both sweep engines. ``fold`` is host fold+staging time
-    (worker-thread time under the lookahead prefetcher), ``stage``/
-    ``ship`` are the transfer engine's staging-copy and wire-wait stalls
-    accumulated during THIS sweep (``TransferStats.delta_since``), and
-    ``compute`` is the dispatch-loop wall residual (device compute plus
-    Python driving) — elapsed minus the fold stall and transfer stalls
-    the loop actually waited on. Per-hop numbers are these divided by
-    ``n_hops``. Returns the phase dict (engines keep it as
-    ``last_phase_seconds``).
+    — shared by both sweep engines. The four phases PARTITION the
+    dispatch loop's wall (``elapsed``), all of it seconds of the thread
+    that drove the sweep: ``fold`` is what that thread folded inline
+    (``fold_inline_seconds``: one group, or prefetch off) plus what it
+    stalled waiting on a worker's fold (``fold_stall_seconds``);
+    ``stage``/``ship`` are the transfer engine's staging-copy and
+    wire-wait stalls accumulated during THIS sweep
+    (``TransferStats.delta_since``); ``compute`` is the rest — device
+    compute plus Python driving. ``fold_seconds`` is the fold's COST
+    (worker-thread time under the lookahead prefetcher or the parallel
+    pool, overlapped with the device): it rides the span and, by mode
+    (``fold_modes``), the ledger's ``fold`` block, and is no phase of the
+    wall. Per-hop numbers are these divided by ``n_hops``. Returns the
+    phase dict (engines keep it as ``last_phase_seconds``).
 
     Attribution caveat: the stage/ship deltas come from the PROCESS-WIDE
     shared transfer engine, so when several jobs sweep concurrently each
@@ -78,12 +84,12 @@ def sweep_phase_summary(sp, elapsed, fold_seconds, fold_stall_seconds,
     thread/track, instead of the summary."""
     stage = float(ship_delta.get("stage_stall_seconds", 0.0))
     wire = float(ship_delta.get("wire_stall_seconds", 0.0))
+    fold = float(fold_inline_seconds) + float(fold_stall_seconds)
     phases = {
-        "fold": float(fold_seconds),
+        "fold": fold,
         "stage": stage,
         "ship": wire,
-        "compute": max(float(elapsed) - float(fold_stall_seconds)
-                       - stage - wire, 0.0),
+        "compute": max(float(elapsed) - fold - stage - wire, 0.0),
     }
     m = _metrics()
     if m is not None:
@@ -96,6 +102,7 @@ def sweep_phase_summary(sp, elapsed, fold_seconds, fold_stall_seconds,
         led.add_sweep(phases, ship_delta, ship_bytes, n_hops,
                       fold_modes=fold_modes)
     sp.set(elapsed_seconds=round(float(elapsed), 6),
+           fold_cost_seconds=round(float(fold_seconds), 6),
            fold_stall_seconds=round(float(fold_stall_seconds), 6),
            ship_bytes=int(ship_bytes), n_hops=int(n_hops),
            **{f"{ph}_seconds": round(sec, 6) for ph, sec in phases.items()})
@@ -363,6 +370,10 @@ class DeviceSweep:
         #: run_sweep only: seconds the dispatch loop spent WAITING on the
         #: lookahead fold — 0 means the fold fully hid behind device compute
         self.fold_stall_seconds = 0.0
+        #: run_sweep only: the part of ``fold_seconds`` the dispatch loop's
+        #: own thread folded inline (no lookahead) — with the stall, the
+        #: ``fold`` phase of the sweep's wall
+        self.fold_inline_seconds = 0.0
         #: the LAST run_sweep's fold/stage/ship/compute breakdown
         #: (``sweep_phase_summary``) — the per-sweep phase summary
         self.last_phase_seconds: dict = {}
@@ -667,6 +678,7 @@ class DeviceSweep:
         self.fold_seconds = 0.0
         self.fold_mode_seconds = {}
         self.fold_stall_seconds = 0.0
+        self.fold_inline_seconds = 0.0
         self.ship_bytes = 0
         from ..utils.transfer import shared_engine
 
@@ -682,14 +694,17 @@ class DeviceSweep:
                 self.fold_stall_seconds,
                 shared_engine().stats.delta_since(before),
                 self.ship_bytes, len(times),
-                fold_modes=self.fold_mode_seconds)
+                fold_modes=self.fold_mode_seconds,
+                fold_inline_seconds=self.fold_inline_seconds)
         return out
 
     def _run_sweep_impl(self, program, times, window, windows, prefetch):
         results, steps = [], []
         if not prefetch or len(times) <= 1:
             for T in times:
+                f0 = self.fold_seconds
                 self.advance(T)
+                self.fold_inline_seconds += self.fold_seconds - f0
                 r, s = self._dispatch(program, T, window, windows)
                 results.append(r)
                 steps.append(s)

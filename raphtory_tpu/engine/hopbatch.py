@@ -435,30 +435,39 @@ def run_columns_delta(kind, tables, base, deltas_e, deltas_v, hop_times,
     W = C // H
     be_lat, be_alive, bv_lat, bv_alive = base
     tdt = tables.tdtype
-    U_e, de_pos, de_lat, de_alive = _pad_hop_deltas(deltas_e, H, tdt)
-    U_v, dv_pos, dv_lat, dv_alive = _pad_hop_deltas(deltas_v, H, tdt)
     weighted = weight_base is not None
     U_w = 0
-    if weighted:
-        longest = max((len(p) for p, _ in weight_deltas), default=1)
-        U_w = max(256, 1 << int(np.ceil(np.log2(max(longest, 1)))))
-        dw_pos = np.full((H, U_w), 2**31 - 1, np.int32)
-        dw_val = np.zeros((H, U_w), np.float32)
-        for h, (p, v) in enumerate(weight_deltas):
-            dw_pos[h, : len(p)] = p
-            dw_val[h, : len(v)] = v
-    if layout is not None:
-        if not h0_delta:
-            # host engine-order base → binned (resident bases are the
-            # previous BINNED dispatch's advanced state, passed through)
-            be_lat, be_alive = layout.bin_base(be_lat, be_alive)
-            if weighted:
-                weight_base = layout.bin_values(weight_base)
-        de_pos = layout.remap_positions(de_pos)
+    # the dispatch's payload brought into the kernel's layout on the host:
+    # deltas padded to fixed shapes, and (binned route) the base permuted
+    # and the delta positions remapped. ``cached``: the base is already
+    # device-resident in this layout, only O(sum delta) work is left
+    with TRACER.span("engine.layout", stage="payload", cached=h0_delta,
+                     partitions=0 if layout is None
+                     else layout.spec.partitions):
+        U_e, de_pos, de_lat, de_alive = _pad_hop_deltas(deltas_e, H, tdt)
+        U_v, dv_pos, dv_lat, dv_alive = _pad_hop_deltas(deltas_v, H, tdt)
         if weighted:
-            dw_pos = layout.remap_positions(dw_pos)
-        b_src, b_dst, _valid, b_slot, b_usrc, _perm = layout.device_args()
-        e_src_dev, e_dst_dev = b_src, b_dst
+            longest = max((len(p) for p, _ in weight_deltas), default=1)
+            U_w = max(256, 1 << int(np.ceil(np.log2(max(longest, 1)))))
+            dw_pos = np.full((H, U_w), 2**31 - 1, np.int32)
+            dw_val = np.zeros((H, U_w), np.float32)
+            for h, (p, v) in enumerate(weight_deltas):
+                dw_pos[h, : len(p)] = p
+                dw_val[h, : len(v)] = v
+        if layout is not None:
+            if not h0_delta:
+                # host engine-order base → binned (resident bases are the
+                # previous BINNED dispatch's advanced state, passed
+                # through)
+                be_lat, be_alive = layout.bin_base(be_lat, be_alive)
+                if weighted:
+                    weight_base = layout.bin_values(weight_base)
+            de_pos = layout.remap_positions(de_pos)
+            if weighted:
+                dw_pos = layout.remap_positions(dw_pos)
+            b_src, b_dst, _valid, b_slot, b_usrc, _perm = \
+                layout.device_args()
+            e_src_dev, e_dst_dev = b_src, b_dst
     runner = _compiled_delta(kind, tables.n_pad, tables.m_pad, H, W,
                              U_e, U_v, np.dtype(tdt).name,
                              r_init is not None, tuple(algo_args),
@@ -817,6 +826,11 @@ class _HopBatched:
         #: seconds the LAST run()'s dispatch loop spent WAITING on the
         #: lookahead fold — 0 means the fold hid entirely behind compute
         self.fold_stall_seconds = 0.0
+        #: the part of the LAST run()'s ``fold_seconds`` the dispatch
+        #: loop's own thread folded inline (one group, or prefetch off) —
+        #: with the stall, the ``fold`` phase of the run's wall; the rest
+        #: of ``fold_seconds`` overlapped the device on a worker
+        self.fold_inline_seconds = 0.0
         #: host→device FOLD-STATE payload bytes of the LAST run() — the
         #: quantity the resident-base design exists to minimise. Excluded
         #: on both fold paths, so comparisons are like for like: the
@@ -1021,8 +1035,8 @@ class _HopBatched:
         self.fold_seconds = 0.0
         self.fold_mode_seconds = {}
         self.fold_stall_seconds = 0.0
+        self.fold_inline_seconds = 0.0
         self.ship_bytes = 0
-        self._sync_layout()
         if warm_start and not self.supports_warm_start:
             raise ValueError(
                 f"{type(self).__name__} cannot warm-start: its superstep "
@@ -1043,6 +1057,7 @@ class _HopBatched:
             with TRACER.span("sweep.columnar",
                                 engine=type(self).__name__,
                                 hops=len(hop_times), chunks=chunks) as sp:
+                self._sync_layout()
                 out = self._run_chunks(hop_times, windows, chunks,
                                        warm_start, hop_callback)
                 self.last_phase_seconds = sweep_phase_summary(
@@ -1050,7 +1065,8 @@ class _HopBatched:
                     self.fold_stall_seconds,
                     shared_engine().stats.delta_since(before),
                     self.ship_bytes, len(hop_times),
-                    fold_modes=self.fold_mode_seconds)
+                    fold_modes=self.fold_mode_seconds,
+                    fold_inline_seconds=self.fold_inline_seconds)
             return out
         except Exception:
             # ANY mid-run failure (fold, hop_callback, dispatch) may leave
@@ -1293,7 +1309,10 @@ class _HopBatched:
                 dispatch)
         else:
             for c, g in enumerate(groups):
-                dispatch(fold(c, g, False), 0.0)
+                f0 = self.fold_seconds
+                folded = fold(c, g, False)     # on THIS thread: wall
+                self.fold_inline_seconds += self.fold_seconds - f0
+                dispatch(folded, 0.0)
         self._maybe_cache(cache, key, payloads, cap, delta)
         return jnp.concatenate(outs, axis=0), steps_box[0]
 

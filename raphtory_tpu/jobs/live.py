@@ -52,6 +52,7 @@ import numpy as np
 
 from ..obs import freshness as _fresh
 from ..obs import journal as _journal
+from ..obs import ledger as _ledger
 from ..obs.metrics import METRICS
 from ..obs.trace import TRACER, block_steps as _block_steps
 
@@ -142,6 +143,17 @@ class LiveEpochState:
                          seconds=_time.perf_counter() - t0, priced=False)
             return "skipped"
 
+        # ONE span for the whole epoch — engine build, repin, the delta
+        # statistics, dispatch, emit and the epoch's telemetry — so its
+        # duration is what a subscriber waits for; ``mode`` (and the
+        # engine's padded sizes) are set once known
+        with TRACER.span("live.epoch", time=t, algorithm=alg) as sp:
+            mode = self._epoch(q, t, alg, t0, log_n, sp)
+            sp.set(mode=mode)
+        return mode
+
+    def _epoch(self, q, t: int, alg: str, t0: float, log_n: int, sp) -> str:
+        led = self.job.ledger
         if not live_enabled():
             self.hb = None          # flipping the knob drops the engine
             self.last_out = None
@@ -149,7 +161,11 @@ class LiveEpochState:
 
         mode = "incremental"
         if self.hb is not None:
+            r0 = _time.perf_counter()
             status = self.hb.repin()
+            # adopting the appended suffix is the incremental fold's
+            # first half (the log scan that decides what to fold)
+            led.add_phase("fold", _time.perf_counter() - r0)
             if status == "rebuild":
                 # the adopted-suffix invariants broke (compaction, new
                 # vertex/pair, out-of-order arrival past t_prev, dtype
@@ -160,22 +176,33 @@ class LiveEpochState:
         if self.hb is None:
             if self._builder_failed:
                 return self._resweep(q, t, alg, t0)
-            try:
-                hb = self.job._columnar_builder()
-            except (TypeError, ValueError, MemoryError) as e:
-                _live_log.info("live epoch engine declined: %s: %s",
-                               type(e).__name__, e)
+            with _ledger.engine_build("rebase", self.job.graph.log,
+                                      led) as bsp:
+                try:
+                    hb = self.job._columnar_builder()
+                except (TypeError, ValueError, MemoryError) as e:
+                    _live_log.info("live epoch engine declined: %s: %s",
+                                   type(e).__name__, e)
+                    bsp.set(declined=type(e).__name__)
+                    hb = None
+                else:
+                    bsp.set(**_ledger.built(hb))
+                    windows = (list(q.windows) if q.windows is not None
+                               else [q.window])
+                    if (hb.device_mask_bytes(len(windows))
+                            > MAX_DEVICE_MASK_BYTES
+                            or hb.host_column_bytes(1)
+                            > MAX_HOST_COLUMN_BYTES):
+                        bsp.set(declined="memory_guard")
+                        hb = None   # a guard is a property of the
+                        #             graph's size
+            if hb is None:
                 self._builder_failed = True
                 return self._resweep(q, t, alg, t0)
-            windows = (list(q.windows) if q.windows is not None
-                       else [q.window])
-            if (hb.device_mask_bytes(len(windows)) > MAX_DEVICE_MASK_BYTES
-                    or hb.host_column_bytes(1) > MAX_HOST_COLUMN_BYTES):
-                self._builder_failed = True   # a guard is a property of
-                return self._resweep(q, t, alg, t0)  # the graph's size
             self.hb = hb
             mode = "rebase"
         hb = self.hb
+        sp.set(n_pad=int(hb.tables.n_pad), m_pad=int(hb.tables.m_pad))
 
         if hb.sw.t_prev is not None and t < int(hb.sw.t_prev):
             # time went backward (watermark regression is a caller bug,
@@ -196,7 +223,9 @@ class LiveEpochState:
             self.last_out = None
             self.since_resync = 0
 
+        d0 = _time.perf_counter()
         delta_rows, add_only = self._delta_stats(hb, t)
+        led.add_phase("fold", _time.perf_counter() - d0)
         windows = list(q.windows) if q.windows is not None else [q.window]
         warm = None
         if self.last_out is not None and mode == "incremental":
@@ -208,6 +237,8 @@ class LiveEpochState:
                 # monotonically grew since the seed was computed and no
                 # window can drop edges (kernel docstrings argue this)
                 warm = self.last_out
+        sp.set(mode=mode, delta_rows=int(delta_rows),
+               warm=warm is not None)
 
         shells = {}
 
@@ -216,17 +247,13 @@ class LiveEpochState:
                 hb.tables, sw, int(T))
 
         try:
-            with TRACER.span("live.epoch", mode=mode, time=t,
-                             algorithm=alg, delta_rows=int(delta_rows),
-                             warm=warm is not None):
-                ranks, steps = hb.run([t], windows, chunks=1,
-                                      hop_callback=grab_shell,
-                                      warm_state=warm)
-                b0 = _time.perf_counter()
-                ranks, steps = _block_steps(
-                    lambda: (np.asarray(ranks), steps))
-                self.job.ledger.add_phase("device_wait",
-                                          _time.perf_counter() - b0)
+            ranks, steps = hb.run([t], windows, chunks=1,
+                                  hop_callback=grab_shell,
+                                  warm_state=warm)
+            b0 = _time.perf_counter()
+            ranks, steps = _block_steps(
+                lambda: (np.asarray(ranks), steps))
+            led.add_phase("device_wait", _time.perf_counter() - b0)
         except Exception as e:
             # a transport failure or OOM on the incremental path falls
             # back to the oracle path for THIS epoch and rebuilds the
@@ -245,13 +272,14 @@ class LiveEpochState:
         elapsed = _time.perf_counter() - t0
         METRICS.snapshot_build_seconds.observe(hb.fold_seconds)
         METRICS.supersteps.inc(max(int(steps), 0))
-        self.job.ledger.count_supersteps(int(steps))
+        led.count_supersteps(int(steps))
         per_row = elapsed / max(len(windows), 1)
-        for i, w in enumerate(windows):
-            if self.job._kill.is_set():
-                break
-            self.job._emit(t, w, ranks[i], shells[t], int(steps),
-                           _time.perf_counter() - per_row)
+        with TRACER.span("job.emit", rows=len(windows)):
+            for i, w in enumerate(windows):
+                if self.job._kill.is_set():
+                    break
+                self.job._emit(t, w, ranks[i], shells[t], int(steps),
+                               _time.perf_counter() - per_row)
         self.last_out = ranks
         self.last_t = t
         self.last_log_n = log_n
@@ -312,9 +340,7 @@ class LiveEpochState:
     def _resweep(self, q, t: int, alg: str, t0: float) -> str:
         """The legacy full re-sweep — the oracle path every degraded
         epoch takes (``exact=False`` mirrors the pre-epoch live loop)."""
-        with TRACER.span("live.epoch", mode="resweep", time=t,
-                         algorithm=alg):
-            self.job._run_at(t, q, exact=False)
+        self.job._run_at(t, q, exact=False)
         self.last_t = t
         self.last_log_n = int(self.job.graph.log.n)
         self.served += 1

@@ -205,6 +205,7 @@ class Job:
         # real queueing here, and the ledger field is where it shows up)
         self.ledger.queue_wait_seconds = max(
             0.0, _time.perf_counter() - self._submitted)
+        job_ctx = None
         try:
             with TRACER.adopt(self._trace_ctx), \
                     TRACER.span("job", job_id=self.id,
@@ -213,14 +214,22 @@ class Job:
                     _ledger.activate(self.ledger):
                 self.trace_id = jsp.trace or None
                 self.ledger.trace_id = self.trace_id or ""
+                job_ctx = TRACER.capture()
                 self._run_query()
                 jsp.set(status=self.status)
             # wall is submit → done, so it CONTAINS the queue wait and
             # finish()'s residual (wall - queue_wait - phases) is exactly
             # the unattributed run time — the queue_wait + Σphases ==
             # wall invariant holds even once real admission queueing
-            # exists
-            self._publish_ledger(_time.perf_counter() - self._submitted)
+            # exists. Publishing runs after the ledger's wall is taken
+            # and before the waiter wakes: it is in the client's latency
+            # and outside the ledger, so the span (a child of `job`, in
+            # the job's trace) is its only record.
+            wall = _time.perf_counter() - self._submitted
+            with TRACER.adopt(job_ctx), \
+                    TRACER.span("job.publish", job_id=self.id,
+                                status=self.status):
+                self._publish_ledger(wall)
         finally:
             # _done fires LAST: a waiter woken by wait() must observe the
             # published SLO/exemplar/queue-wait/ledger state — publishing
@@ -346,9 +355,13 @@ class Job:
                     if self.graph.safe_time() >= q.end:
                         from ..core.sweep import SweepBuilder
 
-                        sweep = SweepBuilder(
-                            self.graph.log,
-                            include_occurrences=self.program.needs_occurrences)
+                        with _ledger.engine_build(
+                                "request", self.graph.log,
+                                self.ledger) as sp:
+                            sweep = SweepBuilder(
+                                self.graph.log, include_occurrences=self
+                                .program.needs_occurrences)
+                            sp.set(engine="SweepBuilder")
                     t = q.start
                     while t <= q.end and not self._kill.is_set():
                         self._run_at(t, q, sweep=sweep)
@@ -438,8 +451,12 @@ class Job:
                 _jobs_log.warning(
                     "coalesced wait timed out for %s after %.0fs — "
                     "falling back to the solo path", self.id, limit)
+                self.ledger.add_phase("sched_wait", _time.monotonic() - w0)
                 return False
         if pend.outcome == "declined":
+            # a solo window still cost this thread the collect window:
+            # the same phase a dispatched batch's queueing lands in
+            self.ledger.add_phase("sched_wait", _time.monotonic() - w0)
             return False
         if pend.outcome == "killed":
             self.status = "killed"
@@ -482,13 +499,14 @@ class Job:
                 pay["fold_seconds"] * pay["share"] / max(len(hops), 1))
         self.ledger.count_supersteps(steps)
         i = 0
-        for T in sorted({int(t) for t in hops}):
-            for w in windows:
-                if self._kill.is_set():
-                    return
-                self._emit(T, w, ranks[cols[i]], shells[int(T)], steps,
-                           _time.perf_counter() - per_row)
-                i += 1
+        with TRACER.span("job.emit", rows=len(cols)):
+            for T in sorted({int(t) for t in hops}):
+                for w in windows:
+                    if self._kill.is_set():
+                        return
+                    self._emit(T, w, ranks[cols[i]], shells[int(T)], steps,
+                               _time.perf_counter() - per_row)
+                    i += 1
 
     def _device_engine_ok(self) -> bool:
         """Shared eligibility gate for the device-resident engines (warm
@@ -515,8 +533,11 @@ class Job:
         if not self._device_engine_ok():
             return False
         try:
-            sweep = ShardedSweep(self.graph.log,
-                                 self.mesh.shape[_sh.V_AXIS])
+            with _ledger.engine_build("request", self.graph.log,
+                                      self.ledger) as sp:
+                sweep = ShardedSweep(self.graph.log,
+                                     self.mesh.shape[_sh.V_AXIS])
+                sp.set(**_ledger.built(sweep))
         except ValueError:
             return False  # e.g. shard count does not divide the global pad
 
@@ -568,22 +589,26 @@ class Job:
         windows = list(q.windows) if q.windows is not None else [q.window]
         if not hops or len(hops) * len(windows) > 1024:
             return None   # the cheap guard — before paying for tables
-        try:
-            hb = self._columnar_builder()
-        except (TypeError, ValueError, MemoryError) as e:
-            _jobs_log.info("columnar range route declined: %s: %s",
-                           type(e).__name__, e)
-            return None
-        # memory guards, sized by the ENGINE's own accounting (the fold
-        # strategy — delta vs host columns — changes what the host
-        # materialises). Oversized ranges stay on the O(1)-memory-per-hop
-        # paths (which rebuild their own tables; a rejected range pays
-        # the table build twice, acceptable next to the sweep it avoids
-        # misrouting).
-        if hb.device_mask_bytes(len(hops) * len(windows)) > 1 << 32:
-            return None
-        if hb.host_column_bytes(len(hops)) > 1 << 29:
-            return None
+        with _ledger.engine_build("request", self.graph.log,
+                                  self.ledger) as sp:
+            try:
+                hb = self._columnar_builder()
+            except (TypeError, ValueError, MemoryError) as e:
+                _jobs_log.info("columnar range route declined: %s: %s",
+                               type(e).__name__, e)
+                sp.set(declined=type(e).__name__)
+                return None
+            sp.set(**_ledger.built(hb))
+            # memory guards, sized by the ENGINE's own accounting (the
+            # fold strategy — delta vs host columns — changes what the
+            # host materialises). Oversized ranges stay on the
+            # O(1)-memory-per-hop paths (which rebuild their own tables;
+            # a rejected range pays the table build twice, acceptable
+            # next to the sweep it avoids misrouting).
+            if hb.device_mask_bytes(len(hops) * len(windows)) > 1 << 32 \
+                    or hb.host_column_bytes(len(hops)) > 1 << 29:
+                sp.set(declined="memory_guard")
+                return None
         return hops, windows, hb
 
     def _try_range_hopbatch(self, q: RangeQuery) -> bool:
@@ -658,12 +683,13 @@ class Job:
                 fold_seconds / max(len(hops), 1))
         METRICS.supersteps.inc(max(steps, 0))
         self.ledger.count_supersteps(steps)
-        for j, T in enumerate(hops):
-            if self._kill.is_set():
-                return
-            for i, w in enumerate(windows):
-                self._emit(T, w, ranks[j * W + i], shells[int(T)], steps,
-                           _time.perf_counter() - per_row)
+        with TRACER.span("job.emit", rows=len(hops) * W):
+            for j, T in enumerate(hops):
+                if self._kill.is_set():
+                    return
+                for i, w in enumerate(windows):
+                    self._emit(T, w, ranks[j * W + i], shells[int(T)],
+                               steps, _time.perf_counter() - per_row)
 
     def _try_range_mesh_columns(self, q: RangeQuery) -> bool:
         """View-axis mesh parallelism for qualifying Range queries: the
@@ -701,7 +727,11 @@ class Job:
             shells[int(T)] = _shell_from_fold(hb.tables, sw, int(T))
 
         t0 = _time.perf_counter()
-        _, cols = hb._fold_columns(hops, grab_shell)
+        # the mesh route folds full host columns on THIS thread (no
+        # prefetch lane): the same span the columnar engine's folds carry
+        with TRACER.span("hop.fold", hops=len(hops),
+                         engine=type(hb).__name__, mode="columns"):
+            _, cols = hb._fold_columns(hops, grab_shell)
         self.ledger.add_phase("fold", hb.fold_seconds)
         if isinstance(hb, HopBatchedSSSP):
             *cols, kw["weight_cols"] = cols
@@ -739,7 +769,10 @@ class Job:
         if not self._device_engine_ok():
             return False
         try:
-            sweep = DeviceSweep(self.graph.log)
+            with _ledger.engine_build("request", self.graph.log,
+                                      self.ledger) as sp:
+                sweep = DeviceSweep(self.graph.log)
+                sp.set(**_ledger.built(sweep))
         except ValueError:
             return False  # >2^31 distinct vertices: packed keys exhausted
         shell = _DeviceShell(sweep)
@@ -780,8 +813,10 @@ class Job:
                     _time.perf_counter() - s0)
                 self.ledger.add_phase("fold", _time.perf_counter() - s0)
                 windows = list(q.windows) if q.windows is not None else None
+                c0 = _time.perf_counter()
                 result, steps = run(windows)
                 rv = freeze_rv()
+                self.ledger.add_phase("compute", _time.perf_counter() - c0)
             except Exception as e:
                 if (_transient(e)
                         and (pending is not None or covered is not None)):
@@ -836,14 +871,16 @@ class Job:
         self.ledger.add_phase("device_wait", _time.perf_counter() - b0)
         METRICS.supersteps.inc(max(steps, 0))
         self.ledger.count_supersteps(steps)
-        if q.windows is not None:
-            for i, w in enumerate(q.windows):
-                r_i = jax.tree_util.tree_map(
-                    lambda a: np.asarray(a[i]), result)
-                self._emit(t, w, r_i, rv, steps, t0)
-        else:
-            result = jax.tree_util.tree_map(np.asarray, result)
-            self._emit(t, q.window, result, rv, steps, t0)
+        with TRACER.span("job.emit",
+                         rows=len(q.windows) if q.windows is not None else 1):
+            if q.windows is not None:
+                for i, w in enumerate(q.windows):
+                    r_i = jax.tree_util.tree_map(
+                        lambda a: np.asarray(a[i]), result)
+                    self._emit(t, w, r_i, rv, steps, t0)
+            else:
+                result = jax.tree_util.tree_map(np.asarray, result)
+                self._emit(t, q.window, result, rv, steps, t0)
 
     def _try_view_resident(self, t: int, q) -> bool:
         """Warm View/Live dispatch through the graph's shared resident
@@ -880,9 +917,11 @@ class Job:
             METRICS.snapshot_build_seconds.observe(_time.perf_counter() - s0)
             self.ledger.add_phase("fold", _time.perf_counter() - s0)
             windows = list(q.windows) if q.windows is not None else None
+            c0 = _time.perf_counter()
             result, steps = sweep.run(p, window=q.window, windows=windows)
             rv = _DeviceShell(sweep).freeze()
             b0 = _time.perf_counter()
+            self.ledger.add_phase("compute", b0 - c0)
             result, steps = _block_steps(lambda: (
                 jax.tree_util.tree_map(np.asarray, result), steps))
             self.ledger.add_phase("device_wait",
@@ -903,12 +942,14 @@ class Job:
             lock.release()
         METRICS.supersteps.inc(max(steps, 0))
         self.ledger.count_supersteps(steps)
-        if windows is not None:
-            for i, w in enumerate(windows):
-                r_i = jax.tree_util.tree_map(lambda a: a[i], result)
-                self._emit(t, w, r_i, rv, steps, t0)
-        else:
-            self._emit(t, q.window, result, rv, steps, t0)
+        with TRACER.span("job.emit",
+                         rows=len(windows) if windows is not None else 1):
+            if windows is not None:
+                for i, w in enumerate(windows):
+                    r_i = jax.tree_util.tree_map(lambda a: a[i], result)
+                    self._emit(t, w, r_i, rv, steps, t0)
+            else:
+                self._emit(t, q.window, result, rv, steps, t0)
         return True
 
     def _run_at(self, t: int, q, exact: bool = True, sweep=None) -> None:
@@ -936,18 +977,20 @@ class Job:
             self.ledger.add_phase("compute", _time.perf_counter() - c0)
             METRICS.supersteps.inc(max(steps, 0))  # once per device run
             self.ledger.count_supersteps(steps)
-            for i, w in enumerate(windows):
-                import jax
+            with TRACER.span("job.emit", rows=len(windows)):
+                for i, w in enumerate(windows):
+                    import jax
 
-                r_i = jax.tree_util.tree_map(lambda a: a[i], result)
-                self._emit(t, w, r_i, view, steps, t0)
+                    r_i = jax.tree_util.tree_map(lambda a: a[i], result)
+                    self._emit(t, w, r_i, view, steps, t0)
         else:
             result, steps = self._execute(view, window=q.window)
             steps = int(steps)
             self.ledger.add_phase("compute", _time.perf_counter() - c0)
             METRICS.supersteps.inc(max(steps, 0))
             self.ledger.count_supersteps(steps)
-            self._emit(t, q.window, result, view, steps, t0)
+            with TRACER.span("job.emit", rows=1):
+                self._emit(t, q.window, result, view, steps, t0)
 
     def _execute(self, view, window=None, windows=None):
         if self.mesh is not None:

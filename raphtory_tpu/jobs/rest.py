@@ -194,6 +194,9 @@ def _compile_cache_sizes() -> dict:
     # counts/seconds/last-shape-sig observed at the registry's
     # lower().compile() sites — next to the factory lru stats above
     out["kernels"] = _device.compile_block()
+    # what JAX itself traced / lowered / had compiled, however the
+    # program was built (obs/device.py jax.monitoring listener)
+    out["jax"] = _device.jax_builds_block()
     return out
 
 
@@ -500,6 +503,7 @@ class _Handler(BaseHTTPRequestHandler):
             tid = qs["trace_id"][0]
             payload["trace_id"] = tid
             payload["spans"] = TRACER.for_trace(tid)
+            payload["self_seconds"] = TRACER.self_seconds(payload["spans"])
         else:
             payload["spans"] = TRACER.recent(_num_param(qs, "n", 200, int))
         self._json(200, payload)

@@ -569,7 +569,12 @@ class ServingScheduler:
         total_cols = len(hops) * len(wlist)
         leader = take[0].job
         try:
-            hb = leader._columnar_builder()
+            # on the scheduler's thread, before the batch's dispatch
+            # starts: the members wait it out in their `sched_wait`, so
+            # the span is its record and no ledger gets a `build` phase
+            with _ledger.engine_build("request", leader.graph.log) as sp:
+                hb = leader._columnar_builder()
+                sp.set(**_ledger.built(hb))
             # the same memory guards the solo columnar route applies —
             # an over-guard batch declines rather than misrouting
             if (hb.device_mask_bytes(total_cols) > 1 << 32

@@ -1,10 +1,11 @@
-"""Observability — metrics + profiling (reference L8, SURVEY §5.1/§5.5).
+"""Observability — metrics + tracing (reference L8, SURVEY §5.1/§5.5).
 
 The reference wires Kamon counters/gauges into every actor and serves
 Prometheus on :11600 (``application.conf:208-213``); here the same signal
 set is prometheus_client metrics updated by the pipeline/job/compaction
-layers, plus a JAX profiler hook for device traces (the capability Kamon's
-AspectJ weaver has no analogue for)."""
+layers, plus spans whose ``TraceAnnotation`` twins land in any JAX
+profiler capture (``obs/trace.py``; whoever wants a device trace starts
+the profiler with the options it needs, as ``benchmark/run.py`` does)."""
 
 from .trace import (TRACER, TraceContext, Tracer,   # stdlib-only —
                     span)                           # always available
@@ -18,17 +19,15 @@ from .advisor import ADVISOR                       # stdlib-only
 from .freshness import FRESH                       # stdlib-only (numpy lazy)
 
 try:
-    # metrics + device profiling need prometheus_client / jax, which
-    # stripped transport-only environments may lack; the span tracer must
-    # keep working there (utils/transfer.py relies on this degradation)
+    # metrics need prometheus_client, which stripped transport-only
+    # environments may lack; the span tracer must keep working there
+    # (utils/transfer.py relies on this degradation)
     from .metrics import METRICS, Metrics, MetricsServer
-    from .profile import annotate, device_trace
 except ImportError:   # pragma: no cover — stripped environment
     METRICS = Metrics = MetricsServer = None
-    device_trace = annotate = None
 
-__all__ = ["METRICS", "Metrics", "MetricsServer", "device_trace",
-           "annotate", "TRACER", "TraceContext", "Tracer", "span",
+__all__ = ["METRICS", "Metrics", "MetricsServer",
+           "TRACER", "TraceContext", "Tracer", "span",
            "Ledger", "REGISTRY", "instrument", "SLO", "SERIES",
            "SAMPLER", "WORKLOAD", "BUDGET", "ADVISOR", "RESIDENT",
            "TIMING", "FRESH"]

@@ -47,6 +47,15 @@ Four pieces, all surfaced at ``/devicez`` and federated per process by
   amortise) the advisor's ``device-pressure`` rule reads. The AOT
   harvest is the observation point, so ``RTPU_LEDGER_XLA=0`` (or an
   analyses-incapable backend) darkens this plane with the estimates.
+* **Programs JAX builds** (``watch_jax_builds``). One ``jax.monitoring``
+  listener, on the compiling thread, turns JAX's own trace / lower /
+  backend-compile durations into ``xla.trace`` / ``xla.lower`` /
+  ``xla.backend_compile`` events of the ambient trace and counts them
+  (with the persistent cache's hits and misses) into
+  ``/statusz.compile_caches.jax`` — every program, however it was
+  built, not only the registry's one harvest per (kernel, shape). Rides
+  the flight recorder: with tracing off the listener returns after one
+  dictionary lookup and counts nothing.
 
 Knobs
 -----
@@ -70,6 +79,7 @@ from collections import deque
 
 from ..analysis.sanitizer import (note_shared as _san_note,
                                   track_shared as _san_track)
+from .trace import TRACER as _TRACER
 
 DEFAULT_RATE = 0.05
 #: bounded warm-sample window per (kernel, sig) — recent-biased, like
@@ -588,6 +598,98 @@ def clear_compiles() -> None:
     with _COMPILE_LOCK:
         _COMPILES.clear()
         _COMPILE_RING.clear()
+        for rec in _JAX_BUILDS.values():
+            rec["count"], rec["seconds"] = 0, 0.0
+        _JAX_FUNS.clear()
+        _JAX_CACHE.update(hits=0, misses=0)
+
+
+# ----------------------------------------- programs JAX builds (listener)
+
+#: jax.monitoring duration event -> the stage it times. JAX emits these
+#: wherever it traces a function to a jaxpr, lowers a jaxpr to an MLIR
+#: module, and asks the backend (or the persistent cache) for an
+#: executable — for every jit object, instrumented or not.
+_JAX_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+_JAX_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hits",
+    "/jax/compilation_cache/cache_misses": "misses",
+}
+_JAX_BUILDS = {stage: {"count": 0, "seconds": 0.0}
+               for stage in _JAX_STAGES.values()}
+_JAX_FUNS: dict[str, float] = {}
+_JAX_FUNS_CAP = 256
+_JAX_CACHE = {"hits": 0, "misses": 0}
+_JAX_WATCHING = [False]
+
+
+def _on_jax_duration(event, duration, **kw) -> None:
+    stage = _JAX_STAGES.get(event)
+    if stage is None or not _TRACER.enabled:
+        return
+    try:
+        fun = str(kw.get("fun_name") or "")
+        secs = float(duration)
+        _TRACER.complete("xla." + stage, secs, fun=fun)
+        with _COMPILE_LOCK:
+            rec = _JAX_BUILDS[stage]
+            rec["count"] += 1
+            rec["seconds"] += secs
+            _JAX_FUNS[fun] = _JAX_FUNS.get(fun, 0.0) + secs
+            if len(_JAX_FUNS) > _JAX_FUNS_CAP:
+                # dynamic function names must not grow the table: keep
+                # the half with the most seconds
+                keep = sorted(_JAX_FUNS.items(),
+                              key=lambda kv: -kv[1])[:_JAX_FUNS_CAP // 2]
+                _JAX_FUNS.clear()
+                _JAX_FUNS.update(keep)
+    except Exception:   # a listener inside jax's compile path never raises
+        pass
+
+
+def _on_jax_event(event, **kw) -> None:
+    which = _JAX_CACHE_EVENTS.get(event)
+    if which is None or not _TRACER.enabled:
+        return
+    with _COMPILE_LOCK:
+        _JAX_CACHE[which] += 1
+
+
+def watch_jax_builds() -> bool:
+    """Register the two ``jax.monitoring`` listeners, once a process.
+    False where jax (or its monitoring module) is missing."""
+    with _COMPILE_LOCK:
+        if _JAX_WATCHING[0]:
+            return True
+        try:
+            from jax import monitoring
+        except Exception:
+            return False
+        monitoring.register_event_duration_secs_listener(_on_jax_duration)
+        monitoring.register_event_listener(_on_jax_event)
+        _JAX_WATCHING[0] = True
+        return True
+
+
+def jax_builds_block() -> dict:
+    """The ``jax`` block of ``/statusz.compile_caches``: what JAX itself
+    traced, lowered and had compiled (or read from the persistent cache)
+    while the flight recorder was on — counts and seconds by stage, and
+    the ten function names with most seconds."""
+    with _COMPILE_LOCK:
+        stages = {st: {"count": r["count"],
+                       "seconds": round(r["seconds"], 4)}
+                  for st, r in _JAX_BUILDS.items()}
+        funs = sorted(_JAX_FUNS.items(), key=lambda kv: -kv[1])[:10]
+        cache = dict(_JAX_CACHE)
+    return {"watching": _JAX_WATCHING[0], "stages": stages,
+            "persistent_cache": cache,
+            "top_funs": [{"fun": f, "seconds": round(s, 4)}
+                         for f, s in funs]}
 
 
 # ------------------------------------------------------------- surfaces
@@ -656,6 +758,8 @@ def clear() -> None:
     RESIDENT.clear()
     clear_compiles()
 
+
+watch_jax_builds()
 
 _device_dump = os.environ.get("RTPU_DEVICE_DUMP")
 if _device_dump:

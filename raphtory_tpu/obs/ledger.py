@@ -10,13 +10,20 @@ pieces:
 
 * **Ledger** — a per-query accumulator every job carries: phase seconds
   (fold/stage/ship/compute from the sweep engines' phase breakdowns,
-  plus device_wait / emit / other measured by the jobs layer), fold
-  seconds by mode and fold-cache hits, H2D bytes + stall seconds
+  plus build / device_wait / emit / other measured by the jobs layer),
+  fold seconds by mode and fold-cache hits, H2D bytes + stall seconds
   (TransferEngine deltas), per-kernel device dispatch counts with
   estimated FLOPs / bytes-accessed, queue wait, and peak host RSS.
-  Jobs accept ``explain=1`` and return it with the result; the phase
-  seconds (queue wait included) sum to the job's wall time by
-  construction (``other`` is the explicit residual).
+  Jobs accept ``explain=1`` and return it with the result. The phases
+  are a PARTITION of the job thread's wall: every phase is seconds that
+  thread spent (folding inline or stalled on a worker's fold, building
+  an engine, driving the dispatch loop, waiting for the device,
+  reducing), so queue wait + phases = wall with ``other`` the explicit
+  residual. Work that overlapped the job thread on another thread (a
+  prefetched or parallel fold) is cost, not wall: it lives in the
+  ``fold`` block (seconds by mode). Phases that add up to MORE than the
+  wall are an accounting bug the ledger reports as
+  ``phase_overlap_seconds``, never clamps away.
 * **KernelRegistry** — process-wide: every compiled kernel the engines
   dispatch is registered by ``instrument()``, and ONCE per (kernel,
   argument-shape signature) the XLA ``cost_analysis()`` (FLOPs, bytes
@@ -504,6 +511,36 @@ def instrument(name: str, fn,
 # ---------------------------------------------------------------- ledger
 
 
+@contextlib.contextmanager
+def engine_build(reason: str, log, ledger: "Ledger | None" = None):
+    """One construction of an engine over ``log`` (its fold builder, its
+    global tables, its device buffers), on the thread that pays for it:
+    an ``engine.build`` span — the caller names what it built with
+    ``sp.set(**built(engine))`` — and the seconds into the ``build``
+    phase of ``ledger`` (default: this thread's active query ledger).
+    ``reason``: ``request`` (a Range builds one per request), ``rebase``
+    (a Live epoch whose pin could not be extended) or ``pin`` (the
+    resident View sweep's first pin, or its re-pin)."""
+    t0 = time.perf_counter()
+    try:
+        with TRACER.span("engine.build", reason=reason,
+                         events=int(log.n)) as sp:
+            yield sp
+    finally:
+        led = ledger if ledger is not None else current()
+        if led is not None:
+            led.add_phase("build", time.perf_counter() - t0)
+
+
+def built(engine) -> dict:
+    """``engine.build`` span attributes of a finished engine: its class
+    and the padded sizes of its global tables."""
+    t = engine.tables
+    return {"engine": type(engine).__name__, "n_pad": int(t.n_pad),
+            "m_pad": int(t.m_pad)}
+
+
+
 class Ledger:
     """Per-query resource accumulator — thread-safe (fold workers and the
     dispatch thread may record concurrently). ``merge()`` folds another
@@ -527,6 +564,9 @@ class Ledger:
         self.wall_seconds = 0.0
         self.status = "running"
         self.phase_seconds: dict[str, float] = {}
+        #: seconds by which queue wait + named phases EXCEED the wall at
+        #: finish() — 0 when the phases partition the job thread's wall
+        self.phase_overlap_seconds = 0.0
         self.fold_mode_seconds: dict[str, float] = {}
         self.fold_cache_hits = 0
         self.fold_cache_misses = 0
@@ -662,6 +702,7 @@ class Ledger:
             for ph, sec in snap["phase_seconds"].items():
                 self.phase_seconds[ph] = (
                     self.phase_seconds.get(ph, 0.0) + sec)
+            self.phase_overlap_seconds += snap["phase_overlap_seconds"]
             for mode, sec in snap["fold"]["seconds_by_mode"].items():
                 self.fold_mode_seconds[mode] = (
                     self.fold_mode_seconds.get(mode, 0.0) + sec)
@@ -716,8 +757,9 @@ class Ledger:
         seconds, H2D bytes, estimated FLOPs/bytes) scale by ``frac`` so
         the members' ledgers SUM to the batch's cost; per-rider counts
         (kernel dispatches, sweeps) land whole — every member's views
-        did ride that one dispatch. The batch's ``other`` residual is
-        skipped: each member computes its own residual at finish()."""
+        did ride that one dispatch. The batch's ``other`` residual (and
+        its ``phase_overlap_seconds``) is skipped: each member computes
+        its own at finish()."""
         frac = float(frac)
         with self._lock:
             for ph, sec in batch_snap["phase_seconds"].items():
@@ -760,7 +802,10 @@ class Ledger:
     def finish(self, wall_seconds: float, status: str = "done") -> None:
         """Close the ledger: record wall time, peak RSS, and the explicit
         ``other`` residual phase so queue wait + phase seconds sum to the
-        wall time exactly — the invariant /costz consumers rely on."""
+        wall time exactly — the invariant /costz consumers rely on. The
+        phases are seconds of ONE thread, so they cannot exceed the wall;
+        if they do (an interval counted twice) the excess is reported as
+        ``phase_overlap_seconds`` rather than clamped into a silent 0."""
         # one more device-memory read at close (outside the lock: it may
         # touch the backend) so short queries that never hit a sampled
         # dispatch still carry a peak-bytes observation where available
@@ -773,20 +818,23 @@ class Ledger:
             if dev_mem.get("available"):
                 self.peak_device_bytes = max(self.peak_device_bytes,
                                              dev_mem["bytes_in_use"])
-            known = sum(self.phase_seconds.values())
-            self.phase_seconds["other"] = max(
-                0.0, self.wall_seconds - self.queue_wait_seconds - known)
+            known = sum(sec for ph, sec in self.phase_seconds.items()
+                        if ph != "other")
+            resid = self.wall_seconds - self.queue_wait_seconds - known
+            self.phase_seconds["other"] = max(0.0, resid)
+            self.phase_overlap_seconds = max(0.0, -resid)
 
     # ---- classification / export ----
 
     def bound(self) -> str:
-        """Query-level resource verdict: host_bound when the fold phase
-        dominates, h2d_bound when staging/shipping does, else the
-        dominant kernel's roofline bound (docs/OBSERVABILITY.md)."""
+        """Query-level resource verdict: host_bound when the job thread's
+        host work (fold + engine build) dominates, h2d_bound when
+        staging/shipping does, else the dominant kernel's roofline bound
+        (docs/OBSERVABILITY.md)."""
         with self._lock:
             ph = dict(self.phase_seconds)
             kernels = {n: dict(k) for n, k in self.kernels.items()}
-        host = ph.get("fold", 0.0)
+        host = ph.get("fold", 0.0) + ph.get("build", 0.0)
         h2d = ph.get("stage", 0.0) + ph.get("ship", 0.0)
         dev = (ph.get("compute", 0.0) + ph.get("device_wait", 0.0))
         top = max((host, h2d, dev))
@@ -814,6 +862,7 @@ class Ledger:
             "wall_seconds": round(self.wall_seconds, 6),
             "phase_seconds": {ph: round(s, 6)
                               for ph, s in self.phase_seconds.items()},
+            "phase_overlap_seconds": round(self.phase_overlap_seconds, 6),
             "fold": {
                 "seconds_by_mode": {m: round(s, 6) for m, s in
                                     self.fold_mode_seconds.items()},

@@ -34,7 +34,10 @@ transfer layer can use it in stripped environments):
   returns a shared no-op and records nothing; when on, a span costs two
   ``perf_counter_ns`` calls plus one dict append. The ring survives
   crashes of everything except the process — dump it on failure and the
-  last N spans tell you what the system was doing.
+  last N spans tell you what the system was doing. Every span event
+  carries ``self``: its duration less its same-thread children (nested
+  spans and ``complete()`` events), summed by name by ``self_seconds``
+  — where a request's wall went, in one call (``/tracez?trace_id=``).
 * **Chrome trace-event exporter** — ``chrome_trace()`` / ``dump()``
   produce Perfetto / ``chrome://tracing`` compatible JSON: one ``X``
   (complete) event per span, one track per thread (``M`` thread-name
@@ -215,7 +218,7 @@ class Span:
     parent stack assumes it); attributes are plain JSON-able values."""
 
     __slots__ = ("name", "attrs", "sid", "parent", "trace", "_tracer",
-                 "_tid", "_t0", "_ann")
+                 "_tid", "_t0", "_ann", "_child_ns", "_runs")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self.name = name
@@ -227,6 +230,32 @@ class Span:
         self._tid = 0
         self._t0 = 0
         self._ann = None
+        #: ns spent inside same-thread children (self time = dur - this)
+        self._child_ns = 0
+        #: disjoint [start, end) ns runs already charged by ``complete()``
+        #: children, ascending — they may nest (a jit traced inside a
+        #: trace reports after the traces it contains), so only the part
+        #: of a new one that no earlier one covered counts
+        self._runs = None
+
+    def _cover(self, start_ns: int, end_ns: int) -> int:
+        """Charge an already-happened child interval (ending now, so no
+        earlier run ends after it) to this span; returns the ns of it
+        that no earlier ``complete()`` child had covered."""
+        runs = self._runs
+        if runs is None:
+            runs = self._runs = []
+        added = end_ns - start_ns
+        while runs and runs[-1][1] > start_ns:
+            r0, r1 = runs.pop()
+            added -= r1 - max(r0, start_ns)
+            start_ns = min(start_ns, r0)
+        runs.append((start_ns, end_ns))
+        if len(runs) > 64:      # only the newest can still be overlapped
+            del runs[0]
+        added = max(0, added)
+        self._child_ns += added
+        return added
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -284,6 +313,7 @@ class Span:
             stack.remove(self)
         if stack:
             top = stack[-1]
+            top._child_ns += dur_ns
             tr._active[self._tid] = (top.trace, top.sid, top.name)
         else:
             ctx = getattr(tr._local, "adopted", None)
@@ -298,6 +328,9 @@ class Span:
             "ph": "X", "name": self.name,
             "ts": (self._t0 - tr._epoch_ns) / 1e3,     # µs, tracer epoch
             "dur": dur_ns / 1e3,
+            # duration less the same-thread children: where THIS span's
+            # own code (not a named child) spent the time
+            "self": max(0, dur_ns - self._child_ns) / 1e3,
             "pid": tr._pid, "tid": self._tid,
             "sid": self.sid, "parent": self.parent,
             "trace": self.trace,
@@ -453,12 +486,16 @@ class Tracer:
         if self._threads.get(tid) != t.name:
             self._note_thread(tid, t.name)
         now = time.perf_counter_ns()
-        dur_ns = max(0.0, float(dur_s)) * 1e9
+        dur_ns = int(max(0.0, float(dur_s)) * 1e9)
         trace, parent = self._ambient()
+        st = self._stack()
+        # a child of the innermost open span of this thread: its seconds
+        # leave that span's self time (nested completes count once)
+        self_ns = st[-1]._cover(now - dur_ns, now) if st else dur_ns
         self._record({
             "ph": "X", "name": name,
             "ts": (now - dur_ns - self._epoch_ns) / 1e3,
-            "dur": dur_ns / 1e3,
+            "dur": dur_ns / 1e3, "self": self_ns / 1e3,
             "pid": self._pid, "tid": tid, "sid": next(self._ids),
             "parent": parent, "trace": trace, "args": attrs,
         })
@@ -559,6 +596,21 @@ class Tracer:
         are gone; the ``recorded``/``dropped`` counters say whether the
         window still covers the request."""
         return [e for e in list(self._ring) if e.get("trace") == trace_id]
+
+    @staticmethod
+    def self_seconds(events: list[dict]) -> dict[str, float]:
+        """Self time by span name over ``events`` (a ``for_trace``
+        result), largest first: each span's duration less its same-thread
+        children, so the values of one thread's spans add up to its root
+        span — the one-call answer to "where did this request's wall
+        go"."""
+        out: dict[str, float] = {}
+        for e in events:
+            if e.get("ph") == "X":
+                out[e["name"]] = out.get(e["name"], 0.0) \
+                    + e.get("self", e["dur"]) / 1e6
+        return {k: round(v, 6) for k, v in
+                sorted(out.items(), key=lambda kv: -kv[1])}
 
     def register_aux(self, name: str, fn) -> None:
         """Attach a zero-arg provider whose return value rides in every

@@ -310,14 +310,27 @@ def resolve(owner, tables, budget_bytes: int, tag: str = ""):
     the program cache keys through the returned layout's spec. ``owner``
     keys the cross-engine cache (the caller's log object outlives the
     per-engine tables); ``tag`` disambiguates different edge tables of
-    one owner (a view's deduped pairs vs its occurrence rows)."""
+    one owner (a view's deduped pairs vs its occurrence rows). Runs under
+    an ``engine.layout`` span (``stage=resolve``) that says whether the
+    cache served it: a miss is an O(m) sort on the caller's thread."""
+    from ..obs.trace import TRACER
+
+    with TRACER.span("engine.layout", stage="resolve") as sp:
+        layout, cached = _resolve(owner, tables, budget_bytes, tag)
+        sp.set(cached=cached,
+               partitions=0 if layout is None else layout.spec.partitions)
+    return layout
+
+
+def _resolve(owner, tables, budget_bytes: int, tag: str):
+    """``(layout or None, served without building one)``."""
     import os
 
     mode = os.environ.get("RTPU_PCPM", "auto")
     if not pcpm_enabled(tables.m_pad, mode):
-        return None
+        return None, True
     if getattr(tables, "e_src", None) is None:
-        return None   # host edge tables dropped (device-only surface)
+        return None, True   # host edge tables dropped (device-only surface)
     ov = os.environ.get("RTPU_PARTITIONS")
     P = partition_count(tables.n_pad, budget_bytes,
                         int(ov) if ov else None)
@@ -334,14 +347,13 @@ def resolve(owner, tables, budget_bytes: int, tag: str = ""):
             per_owner = None
         ent = per_owner.get(key) if per_owner is not None else None
     if ent is not None:
-        return ent
+        return ent, True
     layout = build_layout(tables.e_src, tables.e_dst, tables.n_pad,
                           tables.m, P)
     if per_owner is not None:
         with _LAYOUTS_LOCK:
-            ent = per_owner.setdefault(key, layout)
-        return ent
-    return layout
+            layout = per_owner.setdefault(key, layout)
+    return layout, False
 
 
 # ---------------------------------------------------------- traffic model

@@ -47,6 +47,7 @@ def run_columns_sharded(tables, e_lat, e_alive, v_lat, v_alive, hop_times,
     Returns ``(result [C, n_pad] hop-major, steps)`` — identical values to
     the single-device ``hopbatch`` runners (tested); columns pad up to a
     device multiple internally and the pad is dropped before returning."""
+    t_in = _time.perf_counter()
     devices = list(devices)
     n_dev = len(devices)
     H, C, hop_of_col, T_col, w_col = _column_layout(hop_times, windows)
@@ -95,16 +96,20 @@ def run_columns_sharded(tables, e_lat, e_alive, v_lat, v_alive, hop_times,
             raise ValueError(f"unknown columnar kind {kind!r}")
         return out, steps[None]   # scalar -> [1] so steps concatenates
 
-    from .sharded import _shard_map
+    from ..obs import ledger as _ledger
+    from ..obs.trace import TRACER
+    from .sharded import COLLECTIVES, _shard_map
 
-    shard = jax.jit(_shard_map(
+    # a NEW jit object on every call (not cached here): what JAX does
+    # with it — trace, lower, ask the backend or the persistent cache —
+    # shows as xla.* events in the request's trace (obs/device.py). The
+    # registry wrapper gives the route its kernel-table row and dispatch
+    # count; it harvests once per (kind, shapes), not per object.
+    shard = _ledger.instrument(f"columns.{kind}", jax.jit(_shard_map(
         block, mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), P(),   # tables replicate
                   P(C_AXIS), P(C_AXIS), P(C_AXIS), *extra_specs),
-        out_specs=(P(C_AXIS), P(C_AXIS))))
-
-    from ..obs.trace import TRACER
-    from .sharded import COLLECTIVES
+        out_specs=(P(C_AXIS), P(C_AXIS)))))
 
     repl = NamedSharding(mesh, P())
     put = lambda a: jax.device_put(jnp.asarray(a), repl)
@@ -175,4 +180,17 @@ def run_columns_sharded(tables, e_lat, e_alive, v_lat, v_alive, hop_times,
         bytes_=repl_bytes * max(1, n_dev - 1),
         seconds=_time.perf_counter() - t0, supersteps=1,
         barrier_wait=barrier_wait)
-    return result[:C], int(np.max(np.asarray(steps)))
+    # the dispatch above only ENQUEUED the program: this read is where
+    # the host waits for the chips (the same span, and the same ledger
+    # phase, as the vertex-sharded route's local completion wait)
+    b0 = _time.perf_counter()
+    with TRACER.span("comm.block_wait", route="replicate", process=proc,
+                     shards=n_dev):
+        steps = int(np.max(np.asarray(steps)))
+    led = _ledger.current()
+    if led is not None:
+        # the caller's thread, all of it: building and enqueueing the
+        # program (and putting the tables) is compute, the read the wait
+        led.add_phase("compute", b0 - t_in)
+        led.add_phase("device_wait", _time.perf_counter() - b0)
+    return result[:C], steps

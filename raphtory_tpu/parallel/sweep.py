@@ -40,8 +40,7 @@ class ShardedSweep:
 
     def __init__(self, log: EventLog, n_shards: int):
         self.sw = SweepBuilder(log, track_rows=False, preseed_pairs=True)
-        self.t = GlobalTables(self.sw)
-        t = self.t
+        t = self.t = self.tables = GlobalTables(self.sw)
         if t.n_pad % n_shards:
             raise ValueError(
                 f"vertex shards ({n_shards}) must divide the padded global "

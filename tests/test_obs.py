@@ -1,4 +1,4 @@
-"""Observability: metrics wiring + scrape server + profiler hooks (L8)."""
+"""Observability: metrics wiring + scrape server (L8)."""
 
 import urllib.request
 
@@ -9,7 +9,7 @@ from raphtory_tpu.core.service import TemporalGraph
 from raphtory_tpu.ingestion.pipeline import IngestionPipeline
 from raphtory_tpu.ingestion.source import RandomSource
 from raphtory_tpu.jobs.manager import AnalysisManager, ViewQuery
-from raphtory_tpu.obs import METRICS, MetricsServer, annotate, device_trace
+from raphtory_tpu.obs import METRICS, MetricsServer
 
 
 def _value(metric, labels=()):
@@ -68,17 +68,6 @@ def test_metrics_server_scrape():
         srv.stop()
 
 
-def test_profiler_annotation_and_trace(tmp_path):
-    import jax.numpy as jnp
-
-    with annotate("unit-span"):
-        jnp.ones(8).sum().block_until_ready()
-    with device_trace(str(tmp_path)):
-        jnp.ones(8).sum().block_until_ready()
-    # a trace directory with at least one artefact was produced
-    assert any(tmp_path.rglob("*"))
-
-
 def test_metrics_server_repeated_start_stop_leaks_no_threads():
     import threading
 
@@ -93,32 +82,6 @@ def test_metrics_server_repeated_start_stop_leaks_no_threads():
         assert srv._thread is None and srv._server is None
         assert not t.is_alive()
         assert t not in threading.enumerate()
-
-
-def test_device_trace_tolerates_nested_and_failed_sessions(tmp_path):
-    import jax.numpy as jnp
-
-    # nested sessions: the inner start_trace is refused by the profiler —
-    # device_trace must warn + no-op, never raise (and must not stop the
-    # OUTER session from its finally)
-    with device_trace(str(tmp_path / "outer")):
-        with device_trace(str(tmp_path / "inner")):
-            jnp.ones(4).sum().block_until_ready()
-        # the outer session is still active here and stops cleanly below
-        jnp.ones(4).sum().block_until_ready()
-    assert any((tmp_path / "outer").rglob("*"))
-
-    # a start_trace that raises outright also degrades to a no-op
-    import raphtory_tpu.obs.profile as prof
-
-    orig = prof.jax.profiler.start_trace
-    prof.jax.profiler.start_trace = lambda *a, **k: (_ for _ in ()).throw(
-        RuntimeError("no profiler backend"))
-    try:
-        with device_trace(str(tmp_path / "broken")):
-            jnp.ones(4).sum().block_until_ready()   # sweep survives
-    finally:
-        prof.jax.profiler.start_trace = orig
 
 
 def test_records_dropped_counter():
