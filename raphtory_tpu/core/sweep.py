@@ -767,28 +767,46 @@ def log_fingerprint(log) -> tuple:
     tr = _tracer()
     if tr is None:
         return _fingerprint(log)
-    # O(events) on the caller's thread, once per pin: every engine pins
-    # anew, so a Range request pays it per request
+    # O(events) on the caller's thread, once per pin. The engines over
+    # one unchanged live log share the pin of its per-log index
+    # (``engine/device_sweep.log_index``), so a run of requests pays it
+    # once, not once a request
     with tr.span("fold.fingerprint", events=int(log.n)):
         return _fingerprint(log)
 
 
-def _fingerprint(log) -> tuple:
+def extend_fingerprint(old_pin, new_pin) -> None:
+    """Carry a cached fingerprint from ``old_pin`` to ``new_pin``, a pin
+    of the same log that extends it by a suffix (no compaction between
+    them): each checksum is an xor-reduce keyed by the ABSOLUTE row
+    index, so the suffix's rows fold onto the prefix's in O(suffix). A
+    no-op when ``old_pin`` never computed one — ``log_fingerprint``
+    then computes the new pin's on first use."""
+    fp = getattr(old_pin, "_rtpu_fold_fp", None)
+    if fp is not None:
+        _fingerprint(new_pin, prefix=fp)
+
+
+def _fingerprint(log, prefix: tuple | None = None) -> tuple:
+    lo = 0 if prefix is None else int(prefix[0])
     t = log.column("time")
-    idx = np.arange(len(t), dtype=np.uint64)
+    idx = np.arange(lo, len(t), dtype=np.uint64)
     gold = np.uint64(0x9E3779B97F4A7C15)
 
-    def mix(a):
+    def mix(a, seed):
+        a = a[lo:]
         if not len(a):
-            return 0
+            return seed
         h = a.astype(np.int64, copy=False).view(np.uint64)
-        return int(np.bitwise_xor.reduce((h + gold) * (idx * gold + gold)))
+        return seed ^ int(
+            np.bitwise_xor.reduce((h + gold) * (idx * gold + gold)))
 
+    seeds = (0, 0, 0, 0) if prefix is None else prefix[2:]
     # src and dst stay SEPARATE components: xor-combining them would be
     # symmetric per row, colliding a graph with its (partial) transpose
-    fp = (int(len(t)), int(log.version), mix(t),
-          mix(log.column("src")), mix(log.column("dst")),
-          mix(log.column("kind").astype(np.int64)))
+    fp = (int(len(t)), int(log.version), mix(t, seeds[0]),
+          mix(log.column("src"), seeds[1]), mix(log.column("dst"), seeds[2]),
+          mix(log.column("kind"), seeds[3]))
     try:
         log._rtpu_fold_fp = fp   # pins are frozen: content never changes
     except AttributeError:

@@ -34,6 +34,7 @@ such programs fall back to the ``bsp`` path, see ``supported()``).
 
 from __future__ import annotations
 
+import copy
 import functools
 import threading as _threading
 import time as _time
@@ -45,7 +46,8 @@ import numpy as np
 
 from ..core.events import EDGE_ADD, EDGE_DELETE, EventLog
 from ..core.snapshot import INT64_MIN, _pad_bucket
-from ..core.sweep import _ENC_MASK, _ENC_SHIFT, SweepBuilder
+from ..core.sweep import (_ENC_MASK, _ENC_SHIFT, SweepBuilder,
+                          extend_fingerprint)
 from ..native import lib as _native
 from ..obs import ledger as _ledger
 from ..obs.trace import TRACER
@@ -228,12 +230,110 @@ class GlobalTables:
             return self.eng_of_rank[idx]
         return self.eng_of_rank[np.searchsorted(self.all_enc, enc)]
 
+    def holds_times(self, t: np.ndarray) -> bool:
+        """True if times ``t`` (an appended suffix's) fit the resident
+        time dtype chosen from the log these tables were built over —
+        the check every ``repin`` makes before keeping its tables."""
+        if self.tdtype == np.int64 or not len(t):
+            return True
+        return bool(int(t.min()) > np.iinfo(np.int32).min // 2
+                    and int(t.max()) < np.iinfo(np.int32).max // 2)
+
     def cast_times(self, a: np.ndarray) -> np.ndarray:
         """i64 fold times → the narrow resident dtype (INT64_MIN pad maps to
         the narrow dtype's min) — shared by every engine over these tables."""
         if self.tdtype == np.int64:
             return a
         return np.where(a == INT64_MIN, self.tmin, a).astype(self.tdtype)
+
+
+class LogIndex:
+    """What the device engines derive from a log ALONE, built once per
+    log and forked per engine: a PRISTINE preseeded ``SweepBuilder``
+    (``t_prev is None``; never advanced, never handed out) and the
+    ``GlobalTables`` over it. The builder's pin carries the log's
+    fold-cache fingerprint (``core/sweep.log_fingerprint`` caches it on
+    the pin), so engines forked from one index share it too. Everything
+    here is read-only to its users: a fork shares the log-derived arrays
+    by reference and copies the fold state (``SweepBuilder.fork``)."""
+
+    __slots__ = ("prototype", "tables")
+
+    def __init__(self, log: EventLog):
+        # fold state only (the engines never emit GraphViews, shells are
+        # vertex-side) — no add-row tracking
+        self.prototype = SweepBuilder(log, track_rows=False,
+                                      preseed_pairs=True)
+        self.tables = GlobalTables(self.prototype)
+
+    @property
+    def nbytes(self) -> int:
+        """Host bytes this index keeps alive beyond the log's own
+        columns (``_t``/``_k``/``_s``/``_d`` are views of those)."""
+        own = {k: v for k, v in vars(self.prototype).items()
+               if k not in ("_t", "_k", "_s", "_d")}
+        arrays = {id(a): a for a in (*own.values(),
+                                     *vars(self.tables).values())
+                  if isinstance(a, np.ndarray)}
+        return int(sum(a.nbytes for a in arrays.values()))
+
+    def adopt(self, log: EventLog) -> str:
+        """Bring the index up to ``log``'s current pin, exactly:
+        ``"hit"`` — same ``(n, compactions)``, so the pin's content is
+        the index's (rows below ``n`` never mutate); ``"extended"`` — the
+        log grew by a suffix with no new id or pair and no time past the
+        tables' dtype, adopted in O(suffix) with the fingerprint carried
+        over; ``"miss"`` — anything else (new id or pair, compaction,
+        shrink): the caller builds a new index. Caller holds the lock."""
+        sw = self.prototype
+        old_pin, n_old = sw.log, len(sw._t)
+        status = sw.repin(log)
+        if status == "noop":
+            return "hit"
+        if status != "extended" \
+                or not self.tables.holds_times(sw._t[n_old:]):
+            return "miss"   # the prototype may be rebound: discard it
+        extend_fingerprint(old_pin, sw.log)
+        return "extended"
+
+
+#: per-log cache of the index above, keyed by the CALLER's live log the
+#: way ``_DEVICE_EDGES`` is — one entry per log, replaced (never
+#: accumulated) when it goes stale, freed with the log
+_LOG_INDEXES = weakref.WeakKeyDictionary()
+_LOG_INDEX_LOCK = _threading.Lock()
+_LOG_INDEX_COUNTS = {"hit": 0, "extended": 0, "miss": 0}
+
+
+def log_index(log: EventLog):
+    """``(builder, tables, status)`` for a new engine over ``log``: a
+    private fork of the log's cached index, built here on a ``"miss"``
+    (``LogIndex.adopt`` names the three statuses). One lock covers the
+    lookup, a build and the fork — two jobs arriving together build the
+    index once, and a fork never sees a half-rebound prototype. ``tables``
+    is SHARED between engines and must not be written. A frozen log is
+    its own pin, so an entry would keep its weak key alive: such a log
+    gets an index of its own every time (status ``"miss"``), uncached."""
+    with _LOG_INDEX_LOCK:
+        idx = _LOG_INDEXES.get(log)
+        status = "miss" if idx is None else idx.adopt(log)
+        if status == "miss":
+            _LOG_INDEXES.pop(log, None)   # free the stale one first
+            idx = LogIndex(log)
+            if idx.prototype.log is not log:
+                _LOG_INDEXES[log] = idx
+        _LOG_INDEX_COUNTS[status] += 1
+        return idx.prototype.fork(), idx.tables, status
+
+
+def log_index_status() -> dict:
+    """The ``log_index`` block of ``/statusz``: lookups by outcome since
+    start, and the host bytes the live indexes hold."""
+    with _LOG_INDEX_LOCK:
+        c = _LOG_INDEX_COUNTS
+        return {"hits": c["hit"], "extends": c["extended"],
+                "misses": c["miss"],
+                "bytes": sum(i.nbytes for i in _LOG_INDEXES.values())}
 
 
 def normalize_windows(windows) -> list[int]:
@@ -313,10 +413,11 @@ class DeviceSweep:
     """
 
     def __init__(self, log: EventLog):
-        # fold state only (shells are vertex-side) — no add-row tracking
-        self.sw = SweepBuilder(log, track_rows=False, preseed_pairs=True)
-        self.tables = GlobalTables(self.sw)
-        t = self.tables
+        # the log's shared index: fold builder forked, tables a shallow
+        # copy (this sweep drops its references to the host tables below;
+        # the index's own stay whole)
+        self.sw, tables, self.index_status = log_index(log)
+        t = self.tables = copy.copy(tables)
         self.uv = t.uv
         self.all_enc = t.all_enc
         self.n, self.m = t.n, t.m
@@ -326,8 +427,8 @@ class DeviceSweep:
         # static device uploads — shared per log across sweeps (a repeat
         # View/rebuild over an unchanged log must not re-pay the transfer);
         # the host copies are not needed again on the single-chip path —
-        # free them rather than pin O(m_pad + n_pad) numpy for the sweep's
-        # lifetime
+        # drop them rather than pin O(m_pad + n_pad) numpy for the sweep's
+        # lifetime (over a frozen log, whose index is this sweep's alone)
         self.e_src, self.e_dst = _device_edges(log, t)
         self.vids = jnp.asarray(t.vids)
         t.e_src = t.e_dst = t.vids = None
@@ -401,10 +502,7 @@ class DeviceSweep:
         status = self.sw.repin(live_log)
         if status != "extended":
             return status
-        t_new = self.sw._t[n_old:]
-        if self.tdtype == np.int32 and len(t_new) and not (
-                int(t_new.min()) > np.iinfo(np.int32).min // 2
-                and int(t_new.max()) < np.iinfo(np.int32).max // 2):
+        if not self.tables.holds_times(self.sw._t[n_old:]):
             return "rebuild"   # suffix overflows the narrowed time dtype
         return "extended"
 

@@ -37,12 +37,12 @@ import numpy as np
 import logging
 
 from ..core.events import EventLog
-from ..core.sweep import (SweepBuilder, fold_cache, fold_pool, fold_workers,
+from ..core.sweep import (fold_cache, fold_pool, fold_workers,
                           log_fingerprint, prefetch_map)
 from ..obs import ledger as _ledger
 from ..obs.trace import TRACER
 from ..utils.transfer import _metrics
-from .device_sweep import (GlobalTables, _device_edges, normalize_windows,
+from .device_sweep import (_device_edges, log_index, normalize_windows,
                            sweep_phase_summary)
 
 _log = logging.getLogger(__name__)
@@ -806,12 +806,15 @@ class _HopBatched:
     differently on some XLA versions)."""
 
     def __init__(self, log: EventLog):
-        # fold state only — the columnar engines never emit GraphViews, so
-        # the per-hop add-row list merges are skipped entirely
-        self.sw = SweepBuilder(log, track_rows=False, preseed_pairs=True)
-        self.tables = GlobalTables(self.sw)
-        # cache key for the device edge tables: the CALLER's log object
-        # (sw.log is a fresh pin per engine and would never hit)
+        # the half of an engine that is a function of the log alone comes
+        # from the log's cached index: a private fork of its pristine fold
+        # builder, and its global tables by reference (read-only here).
+        # ``index_status`` says what the lookup cost: "hit" (a fork),
+        # "extended" (a suffix adopted first) or "miss" (built here)
+        self.sw, self.tables, self.index_status = log_index(log)
+        # cache key for the per-log caches (index, device edge tables,
+        # layout): the CALLER's log object — sw.log is the index's pin,
+        # replaced whenever the log grows
         self._log = log
         #: host seconds spent folding + writing columns in the LAST run()
         #: (callers report it as snapshot-build time; under the lookahead
@@ -933,11 +936,7 @@ class _HopBatched:
         status = self.sw.repin(self._log)
         if status != "extended":
             return status
-        t_new = self.sw._t[n_old:]
-        tdt = np.dtype(self.tables.tdtype)
-        if tdt == np.int32 and len(t_new) and not (
-                int(t_new.min()) > np.iinfo(np.int32).min // 2
-                and int(t_new.max()) < np.iinfo(np.int32).max // 2):
+        if not self.tables.holds_times(self.sw._t[n_old:]):
             return "rebuild"   # suffix overflows the narrowed time dtype
         return "extended"
 

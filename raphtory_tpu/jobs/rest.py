@@ -212,6 +212,7 @@ def _fold_cache_status() -> dict:
 
 def _statusz(manager: AnalysisManager,
              handler: "type[_Handler] | _Handler | None" = None) -> dict:
+    from ..engine.device_sweep import log_index_status
     from ..parallel.sharded import COLLECTIVES
     from ..utils.transfer import shared_engine
 
@@ -232,6 +233,9 @@ def _statusz(manager: AnalysisManager,
         # deadline-expired counters, admission backlog + price book
         "scheduler": manager.scheduler.status_block(),
         "compile_caches": _compile_cache_sizes(),
+        # the per-log engine index (engine/device_sweep.log_index):
+        # lookups by outcome, and the host bytes the live indexes hold
+        "log_index": log_index_status(),
         "fold_cache": _fold_cache_status(),
         "trace": TRACER.status(),
         "ledger": _ledger.status_block(),

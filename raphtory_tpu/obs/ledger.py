@@ -518,9 +518,12 @@ def engine_build(reason: str, log, ledger: "Ledger | None" = None):
     an ``engine.build`` span — the caller names what it built with
     ``sp.set(**built(engine))`` — and the seconds into the ``build``
     phase of ``ledger`` (default: this thread's active query ledger).
-    ``reason``: ``request`` (a Range builds one per request), ``rebase``
-    (a Live epoch whose pin could not be extended) or ``pin`` (the
-    resident View sweep's first pin, or its re-pin)."""
+    ``reason``: ``request`` (a Range makes one engine per request; what
+    is derived from the log alone comes from the log's cached index, so
+    only the request that finds the log changed builds it: ``index`` on
+    the span, from ``built``), ``rebase`` (a Live epoch whose pin could
+    not be extended) or ``pin`` (the resident View sweep's first pin, or
+    its re-pin)."""
     t0 = time.perf_counter()
     try:
         with TRACER.span("engine.build", reason=reason,
@@ -533,11 +536,13 @@ def engine_build(reason: str, log, ledger: "Ledger | None" = None):
 
 
 def built(engine) -> dict:
-    """``engine.build`` span attributes of a finished engine: its class
-    and the padded sizes of its global tables."""
+    """``engine.build`` span attributes of a finished engine: its class,
+    the padded sizes of its global tables, and what its lookup of the
+    log's index cost (``engine/device_sweep.log_index``): ``hit`` (a
+    fork), ``extended`` (a suffix adopted first) or ``miss`` (built)."""
     t = engine.tables
     return {"engine": type(engine).__name__, "n_pad": int(t.n_pad),
-            "m_pad": int(t.m_pad)}
+            "m_pad": int(t.m_pad), "index": engine.index_status}
 
 
 

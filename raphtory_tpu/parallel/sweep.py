@@ -23,8 +23,8 @@ import numpy as np
 
 from ..core.events import EventLog
 from ..core.snapshot import INT64_MIN
-from ..core.sweep import _ENC_MASK, _ENC_SHIFT, SweepBuilder
-from ..engine.device_sweep import GlobalTables, supported
+from ..core.sweep import _ENC_MASK, _ENC_SHIFT
+from ..engine.device_sweep import log_index, supported
 from . import sharded
 from .sharded import ShardedView, _build_halo, _pow2
 
@@ -39,8 +39,9 @@ class ShardedSweep:
     """
 
     def __init__(self, log: EventLog, n_shards: int):
-        self.sw = SweepBuilder(log, track_rows=False, preseed_pairs=True)
-        t = self.t = self.tables = GlobalTables(self.sw)
+        # the log's shared index: builder forked, tables read-only
+        self.sw, t, self.index_status = log_index(log)
+        self.t = self.tables = t
         if t.n_pad % n_shards:
             raise ValueError(
                 f"vertex shards ({n_shards}) must divide the padded global "

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from raphtory_tpu.core.events import EventLog
 from raphtory_tpu.core.service import TemporalGraph
 from raphtory_tpu.jobs import registry
 from raphtory_tpu.jobs.manager import (AnalysisManager, LiveQuery,
@@ -39,6 +40,13 @@ def log():
     # big enough that a job's fixed costs (thread start, imports done by
     # the warm-up) are small next to its phases
     return gab_like_log(n_vertices=4000, n_edges=120_000, t_span=T_SPAN)
+
+
+def _copy(log):
+    out = EventLog()
+    out.append_batch(*(log.column(c)
+                       for c in ("time", "kind", "src", "dst")))
+    return out
 
 
 def _pagerank():
@@ -71,6 +79,9 @@ def _named(spans, name):
 
 @pytest.mark.parametrize("kind", KINDS)
 def test_job_leaves_the_spans_of_table_a_in_one_trace(traced, log, kind):
+    if kind == "range":
+        # a log no test has indexed yet: its first request is the miss
+        log = _copy(log)
     mgr = AnalysisManager(TemporalGraph(log))
     job, spans = _run(mgr, kind)
     (root,) = _named(spans, "job")
@@ -122,8 +133,9 @@ def test_job_leaves_the_spans_of_table_a_in_one_trace(traced, log, kind):
             assert inner["ts"] + inner["dur"] <= ep["ts"] + ep["dur"] + 1.0
         assert ep["dur"] >= 0.9 * (root["dur"] - 20e3)
 
-    # a second request: a Range builds again, a View on the pinned sweep
-    # and the trace of it have no build at all
+    # a second request: a Range makes its engine again, now a fork of
+    # the log's index; a View on the pinned sweep and the trace of it
+    # have no build at all
     if kind != "live":
         job2, spans2 = _run(mgr, kind, k=1)
         builds2 = _named(spans2, "engine.build")
@@ -131,7 +143,11 @@ def test_job_leaves_the_spans_of_table_a_in_one_trace(traced, log, kind):
             assert builds2 == []
             assert "build" not in job2.ledger.phase_seconds
         else:
-            assert len(builds2) == 1
+            assert build["args"]["index"] == "miss"
+            (build2,) = builds2
+            assert build2["args"]["index"] == "hit"
+            assert job2.ledger.phase_seconds["build"] \
+                < job.ledger.phase_seconds["build"]
             assert _named(spans2, "engine.layout")[0]["args"]["cached"]
 
 
