@@ -48,8 +48,8 @@ def _compiled_propagate(n_pad: int, m_pad: int, chunk: int, F: int,
     streaming read, and reduces into a dense ``[n_per, F]`` block — the
     per-edge F-wide row gather this engine is bound by shrinks by the
     bucket dedup factor, and the accumulator slice is cache-resident.
-    Sum order changes: results agree to f32 tolerance (bitwise under
-    ``RTPU_PCPM=0``)."""
+    Sum order changes: results agree to f32 tolerance (bitwise on the
+    unbinned route, which every value but ``RTPU_PCPM=1`` takes)."""
     tdt = jnp.dtype(tdt)
     fdt = jnp.dtype(fdt)
     C = m_pad // chunk
@@ -172,13 +172,10 @@ class FeatureAggregator:
         transients (``[cap, F]`` payload, ``[cap_u, F]`` bucket) to fit
         the tile budget — oversized partitions fall back to the chunked
         scan."""
-        import os
-
         from ..ops import partition as _partition
 
         ds = self.ds
-        if not _partition.pcpm_enabled(ds.m_pad,
-                                       os.environ.get("RTPU_PCPM", "auto")):
+        if not _partition.pcpm_enabled():
             return None
         if self._host_tables is None:
             self._host_tables = _partition.HostTables(

@@ -53,7 +53,6 @@ from . import budget as _budget
 from . import device as _device
 from . import freshness as _freshness
 from . import journal as _journal
-from . import ledger as _ledger
 from . import workload as _workload
 from .slo import _metrics
 from .trace import TRACER
@@ -158,43 +157,6 @@ def _exemplars(queries: list, n: int = 3) -> list:
 # signals dict is assembled by gather_signals(); tests feed synthetic
 # dicts. Threshold constants live beside their rule. docs/OBSERVABILITY
 # "Advisor" documents the catalogue row-for-row from RULES below.
-
-
-def rule_hbm_bound_pcpm(sig: dict) -> dict | None:
-    """Compute-dominant AND hbm-bound kernels dominate the device bytes
-    AND the operator has EXPLICITLY disabled the partition-centric
-    kernels — the measured evidence says the disabled knob is the one
-    that would help (arXiv:1709.07122; `auto` needs no advice)."""
-    if sig.get("env", {}).get("RTPU_PCPM") != "0":
-        return None
-    queries = sig.get("queries", [])
-    split, total = _phase_split(queries)
-    if len(queries) < 4 or total < 1.0:
-        return None
-    compute = split.get("compute", 0.0) + split.get("device_wait", 0.0)
-    if compute < 0.5 * total:
-        return None
-    kernels = sig.get("kernels", [])
-    traffic = {}
-    for k in kernels:
-        b = (k.get("est_hbm_bytes") or k.get("bytes_accessed") or 0.0) \
-            * max(1, k.get("dispatches", 0))
-        bound = k.get("bound_refined") or k.get("bound") or "unknown"
-        traffic[bound] = traffic.get(bound, 0.0) + b
-    all_b = sum(traffic.values())
-    if not all_b or traffic.get("hbm_bound", 0.0) < 0.7 * all_b:
-        return None
-    return _finding(
-        "hbm-bound-enable-pcpm",
-        "compute phase dominates and hbm-bound kernels carry "
-        f"{traffic['hbm_bound'] / all_b:.0%} of device bytes, but "
-        "RTPU_PCPM=0 disables the destination-binned kernels",
-        "RTPU_PCPM", "unset RTPU_PCPM (auto) or set RTPU_PCPM=1",
-        {"compute_fraction": round(compute / total, 3),
-         "phase_seconds": {p: round(s, 4) for p, s in split.items()},
-         "device_bytes_by_bound": {b: round(v, 0)
-                                   for b, v in traffic.items()},
-         "queries": _exemplars(queries)})
 
 
 def rule_fold_stall_workers(sig: dict) -> dict | None:
@@ -680,9 +642,6 @@ def rule_shard_skew(sig: dict) -> dict | None:
 #: the catalogue: (rule_id, fn, reads, one-line description) — /advisez
 #: lists it and docs/OBSERVABILITY.md "Advisor" documents it verbatim
 RULES = (
-    ("hbm-bound-enable-pcpm", rule_hbm_bound_pcpm,
-     "kernel roofline classes + phase split",
-     "hbm-bound kernels dominate compute with RTPU_PCPM=0"),
     ("fold-stall-raise-workers", rule_fold_stall_workers,
      "phase split + fold-pool sizing",
      "host fold dominates while RTPU_FOLD_WORKERS is pinned low"),
@@ -748,12 +707,11 @@ def gather_signals(manager=None, cluster: dict | None = None) -> dict:
     (the caller does the network I/O — never under a lock)."""
     sig: dict = {
         "queries": recent_query_rows(QUERY_WINDOW),
-        "kernels": _ledger.REGISTRY.snapshot(),
         "budget": _budget.BUDGET.evaluate(),
         "workload_top": _workload.WORKLOAD.top_by_cost(3),
         "cpu_count": os.cpu_count(),
         "env": {k: os.environ.get(k) for k in
-                ("RTPU_PCPM", "RTPU_FOLD_WORKERS", "RTPU_PREFETCH_DEPTH",
+                ("RTPU_FOLD_WORKERS", "RTPU_PREFETCH_DEPTH",
                  "RTPU_TRANSFER_DEPTH", "RTPU_FOLD_CACHE_MB")},
         "cluster": cluster,
     }

@@ -22,11 +22,20 @@ through the spec, never read inside a cached factory (rtpulint RT001).
 
 Within each partition, edges sort by (src, dst): the pre-aggregation
 bucket reads stream sequentially, and the residual in-partition scatter
-lands in the cache-resident slice. ``RTPU_PCPM=0`` keeps every kernel on
-the unbinned route, bit-identical to today. Binned float reductions sum in
-a different order than the (dst, src)-sorted route — integer/min-plus
+lands in the cache-resident slice. Binned float reductions sum in a
+different order than the (dst, src)-sorted route — integer/min-plus
 results stay bitwise equal, float sums agree to reduction-order tolerance
 (docs/KERNELS.md).
+
+The layout is built only where it is asked for by name (``RTPU_PCPM=1``).
+Every other value, unset included, keeps every kernel on the dst-sorted
+pair table — the route the mesh dispatch always runs. The premise above
+is a cache between the accumulator and DRAM; a TPU has none, and there a
+superstep costs per row touched: the cap-padded bins are 1.68 x the rows
+of the sorted table on the benchmark's graph and the bucket level is a
+second gather (PERF.md section 6, PR 29). Nothing in a request's input
+tells a device with such a cache from one without, so no code chooses
+the layout.
 """
 
 from __future__ import annotations
@@ -40,12 +49,6 @@ import numpy as np
 #: alignment of the per-partition block capacities — keeps pad overhead
 #: ~0.1% instead of the up-to-2x a power-of-two pad would cost
 _ALIGN = 64
-
-#: below this padded pair count the binning overhead (layout build, extra
-#: permutation gathers) dominates what locality can give back — "auto"
-#: keeps tiny graphs on the unbinned route (docs/KERNELS.md "when PCPM
-#: loses")
-AUTO_MIN_PAIRS = 1 << 17
 
 #: modelled last-level cache a partition's accumulator slice must fit in,
 #: and the DRAM access granularity — the two constants of the traffic
@@ -279,18 +282,14 @@ _LAYOUTS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 _LAYOUTS_LOCK = threading.Lock()
 
 
-def pcpm_enabled(m_pad: int, mode: str) -> bool:
-    """``RTPU_PCPM`` decision for a graph of ``m_pad`` padded pairs:
-    ``"1"`` forces the binned route, ``"0"`` the unbinned one, anything
-    else — ``"auto"``, unset, set-but-empty, typos — bins only past
-    :data:`AUTO_MIN_PAIRS`, below which the layout overhead dominates
-    (docs/KERNELS.md). Only an explicit ``"1"`` may force tiny graphs
-    onto the binned route."""
-    if mode == "0":
-        return False
-    if mode == "1":
-        return True
-    return int(m_pad) >= AUTO_MIN_PAIRS
+def pcpm_enabled() -> bool:
+    """Whether ``RTPU_PCPM`` asks for the binned route by name: only an
+    explicit ``"1"`` does. Unset, empty, ``"0"``, ``"auto"`` and typos
+    all keep the dst-sorted pair table, at every size (module docstring).
+    Read at dispatch time, never inside a cached factory."""
+    import os
+
+    return os.environ.get("RTPU_PCPM") == "1"
 
 
 def tile_budget_bytes() -> int:
@@ -304,10 +303,11 @@ def tile_budget_bytes() -> int:
 
 def resolve(owner, tables, budget_bytes: int, tag: str = ""):
     """Layout for ``tables`` (GlobalTables / BulkGraph surface: ``e_src``,
-    ``e_dst``, ``n_pad``, ``m``, ``m_pad``) or ``None`` when the binned
-    route is off. Reads ``RTPU_PCPM`` / ``RTPU_PARTITIONS`` HERE — at
-    dispatch, outside any compiled-program factory — so both knobs reach
-    the program cache keys through the returned layout's spec. ``owner``
+    ``e_dst``, ``n_pad``, ``m``, ``m_pad``), or ``None`` unless the binned
+    route is asked for by name (``RTPU_PCPM=1``). Reads ``RTPU_PCPM`` /
+    ``RTPU_PARTITIONS`` HERE — at dispatch, outside any compiled-program
+    factory — so both knobs reach the program cache keys through the
+    returned layout's spec. ``owner``
     keys the cross-engine cache (the caller's log object outlives the
     per-engine tables); ``tag`` disambiguates different edge tables of
     one owner (a view's deduped pairs vs its occurrence rows). Runs under
@@ -326,8 +326,7 @@ def _resolve(owner, tables, budget_bytes: int, tag: str):
     """``(layout or None, served without building one)``."""
     import os
 
-    mode = os.environ.get("RTPU_PCPM", "auto")
-    if not pcpm_enabled(tables.m_pad, mode):
+    if not pcpm_enabled():
         return None, True
     if getattr(tables, "e_src", None) is None:
         return None, True   # host edge tables dropped (device-only surface)

@@ -469,23 +469,6 @@ def test_rules_quiet_on_empty_and_healthy_signals():
     assert evaluate_rules(sig) == []
 
 
-def test_rule_hbm_bound_pcpm_fires_only_when_disabled():
-    sig = {"env": {"RTPU_PCPM": "0"}, "queries": _queries(),
-           "kernels": [
-               {"est_hbm_bytes": 1e9, "dispatches": 10,
-                "bound_refined": "hbm_bound"},
-               {"est_hbm_bytes": 1e8, "dispatches": 1,
-                "bound": "compute_bound"}]}
-    (f,) = evaluate_rules(sig)
-    assert f["rule_id"] == "hbm-bound-enable-pcpm"
-    assert f["knob"] == "RTPU_PCPM"
-    assert f["evidence"]["compute_fraction"] == 1.0
-    assert "hbm_bound" in f["evidence"]["device_bytes_by_bound"]
-    # auto (unset) needs no advice — same evidence, no finding
-    sig["env"] = {}
-    assert evaluate_rules(sig) == []
-
-
 def test_rule_fold_stall_names_the_workers_knob():
     """The docs/OBSERVABILITY.md worked walkthrough: RTPU_FOLD_WORKERS=1
     mis-set on a 4-core box, fold dominating — the advisor names the

@@ -11,8 +11,6 @@ transitions when the knob flips between batches, the partition-blocked
 segment reduce, the bsp/features routes, and the ledger traffic model.
 """
 
-import os
-
 import numpy as np
 import pytest
 
@@ -85,20 +83,41 @@ def test_partition_count_auto_and_override():
     assert part.partition_count(8, budget, override=1000) == 8  # clamped
 
 
-def test_auto_mode_keeps_tiny_graphs_unbinned():
-    assert not part.pcpm_enabled(1 << 10, "auto")
-    assert part.pcpm_enabled(1 << 20, "auto")
-    assert part.pcpm_enabled(1 << 10, "1")
-    assert not part.pcpm_enabled(1 << 20, "0")
-    # set-but-empty and typos behave as auto — only an explicit "1" may
-    # force tiny graphs onto the binned route
-    assert not part.pcpm_enabled(1 << 10, "")
-    assert part.pcpm_enabled(1 << 20, "")
-    assert not part.pcpm_enabled(1 << 10, "2")
-    log = _log(5)
-    hb = HopBatchedPageRank(log)
-    os.environ.pop("RTPU_PCPM", None)
-    assert part.resolve(log, hb.tables, 256 << 20) is None  # tiny → off
+class _Owner:
+    """A weakref-able cache key for ``part.resolve`` over bare tables."""
+
+
+def _ring_tables(m: int):
+    """``m`` distinct pairs, dst-sorted, four to a vertex."""
+    n = max(m // 4, 8)
+    dst = np.repeat(np.arange(n, dtype=np.int32), 4)[:m]
+    src = ((dst.astype(np.int64) * 7 + np.tile(np.arange(1, 5), n)[:m])
+           % n).astype(np.int32)
+    return part.HostTables(src, dst, n, m)
+
+
+@pytest.mark.parametrize("mode,binned", [
+    (None, False), ("", False), ("auto", False), ("0", False),
+    ("2", False), ("true", False), ("1", True)])
+def test_layout_only_when_asked_for_by_name(monkeypatch, mode, binned):
+    """The rule of PR 29: no size picks the binned route. Only
+    ``RTPU_PCPM=1`` resolves a layout, at 2^10 pairs and at 2^20 (past
+    the 2^17 the old ``auto`` binned from); everything else — unset,
+    empty, ``auto``, ``0``, typos — keeps the dst-sorted pair table."""
+    if mode is None:
+        monkeypatch.delenv("RTPU_PCPM", raising=False)
+    else:
+        monkeypatch.setenv("RTPU_PCPM", mode)
+    assert part.pcpm_enabled() is binned
+    for m in (1 << 10, 1 << 20):
+        t = _ring_tables(m)
+        lay = part.resolve(_Owner(), t, 256 << 20)
+        if not binned:
+            assert lay is None
+        else:
+            assert lay.m == m and int(lay.valid.sum()) == m
+            assert lay.spec.partitions == part.partition_count(
+                t.n_pad, 256 << 20)
 
 
 # ---------------------------------------------------------------------------
@@ -408,3 +427,123 @@ def test_instrument_records_refined_fields(monkeypatch):
     assert "kernels_by_bound_refined" in cz
     assert "est_hbm_bytes" in cz["classification_rule"] \
         or "est_hbm_bytes" in str(cz["classification_rule"])
+
+
+# ---------------------------------------------------------------------------
+# PR 29: with the knob unset every route runs the dst-sorted pair table
+
+
+def _layout_spans(fn):
+    """Run ``fn`` under the flight recorder; its ``engine.layout`` spans."""
+    from raphtory_tpu.obs.trace import TRACER
+
+    was = TRACER.enabled
+    TRACER.enable()
+    try:
+        seen = TRACER.recorded
+        out = fn()
+        new = TRACER.recent(TRACER.recorded - seen)
+    finally:
+        (TRACER.enable if was else TRACER.disable)()
+    return out, [e for e in new if e["name"] == "engine.layout"]
+
+
+@pytest.mark.parametrize("size", ["past_old_threshold", "small"])
+def test_unset_knob_dispatches_on_the_sorted_table(monkeypatch, size):
+    """No layout is resolved, built or uploaded for a columnar run unless
+    it is asked for — also on a log the old ``auto`` would have binned
+    (``m_pad`` >= 2^17). The ``engine.layout`` spans are the engagement
+    counter: ``partitions`` says which table served the dispatch."""
+    from raphtory_tpu.utils.synth import gab_like_log
+
+    monkeypatch.delenv("RTPU_PCPM", raising=False)
+    if size == "small":
+        log, hops, windows = _log(5), HOPS, WINDOWS
+    else:
+        log = gab_like_log(n_vertices=6000, n_edges=150_000, t_span=1000)
+        hops, windows = [700, 900], [1000, 200]
+    hb = HopBatchedPageRank(log, tol=0, max_steps=3)
+    assert (hb.tables.m_pad >= 1 << 17) == (size != "small")
+    (out, steps), spans = _layout_spans(lambda: hb.run(hops, windows))
+    assert hb._active_layout is None
+    assert np.asarray(out).shape == (len(hops) * len(windows),
+                                     hb.tables.n_pad)
+    assert {s["args"]["stage"] for s in spans} == {"resolve", "payload"}
+    assert {s["args"]["partitions"] for s in spans} == {0}
+    # asked for by name, an engine over the same log bins it
+    monkeypatch.setenv("RTPU_PCPM", "1")
+    hb1 = HopBatchedPageRank(log, tol=0, max_steps=3)
+    (out1, _), spans1 = _layout_spans(lambda: hb1.run(hops, windows))
+    assert hb1._active_layout is not None
+    assert {s["args"]["partitions"] for s in spans1} \
+        == {hb1._active_layout.spec.partitions}
+    np.testing.assert_allclose(np.asarray(out1), np.asarray(out),
+                               atol=2e-6, rtol=0)
+
+
+def test_one_chip_default_route_is_the_mesh_routes_table(monkeypatch):
+    """Knob unset and ``0`` are one route, bit for bit, and it is the
+    table ``run_columns_sharded`` runs on the mesh: the two agree to the
+    tolerance tests/test_columns_sharded.py holds them to."""
+    import jax
+
+    from raphtory_tpu.parallel.columns import run_columns_sharded
+
+    log = random_log(np.random.default_rng(3), n_events=900, n_ids=50,
+                     t_span=100)
+    hops, windows = [20, 40, 60, 80, 99], [1000, 25]
+
+    def one_chip():
+        hb = HopBatchedPageRank(log, tol=1e-7, max_steps=20)
+        out, steps = hb.run(hops, windows)
+        assert hb._active_layout is None
+        return np.asarray(out), int(steps)
+
+    monkeypatch.delenv("RTPU_PCPM", raising=False)
+    unset, steps1 = one_chip()
+    hb = HopBatchedPageRank(log, tol=1e-7, max_steps=20)
+    _, cols = hb._fold_columns([int(x) for x in hops])
+    many, steps2 = run_columns_sharded(
+        hb.tables, *cols, hops, windows, jax.devices()[:4],
+        tol=1e-7, max_steps=20)
+    monkeypatch.setenv("RTPU_PCPM", "0")
+    zero, steps0 = one_chip()
+    assert np.array_equal(unset, zero) and steps0 == steps1
+    np.testing.assert_allclose(unset, np.asarray(many),
+                               rtol=1e-5, atol=1e-7)
+    assert steps1 == steps2
+
+
+def test_cold_view_default_passes_no_binned_operands(monkeypatch):
+    """``bsp.run_async`` with the knob unset compiles the flat exchange
+    (``pcpm`` None in the program's cache key) and hands it no
+    ``b_perm`` / ``b_valid`` / ``b_dst``; ``RTPU_PCPM=1`` adds the three
+    and agrees to reduction-order tolerance."""
+    from raphtory_tpu.algorithms import PageRank
+    from raphtory_tpu.core.snapshot import build_view
+    from raphtory_tpu.engine import bsp
+
+    calls = []
+    real = bsp._compiled_runner
+
+    def spy(*key):
+        runner = real(*key)
+
+        def run(*operands):
+            calls.append((key[-1], len(operands)))
+            return runner(*operands)
+        return run
+
+    monkeypatch.setattr(bsp, "_compiled_runner", spy)
+    view = build_view(_log(23), 60)
+    pr = PageRank(max_steps=20, tol=1e-7)
+    monkeypatch.delenv("RTPU_PCPM", raising=False)
+    assert bsp._view_layout(view, view.e_src, view.e_dst, False) is None
+    flat, _ = bsp.run(pr, view, windows=[100, 30, -1])
+    monkeypatch.setenv("RTPU_PCPM", "1")
+    binned, _ = bsp.run(pr, view, windows=[100, 30, -1])
+    (spec0, n0), (spec1, n1) = calls
+    assert spec0 is None and isinstance(spec1, part.PartitionSpec)
+    assert n1 == n0 + 3
+    np.testing.assert_allclose(np.asarray(binned), np.asarray(flat),
+                               atol=2e-6, rtol=0)
