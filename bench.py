@@ -2921,198 +2921,6 @@ def bench_sanitize_overhead():
     }
 
 
-def bench_pcpm_ab():
-    """Partition-centric (PCPM) kernels vs the unbinned route — the
-    destination-binned layout's proof row (docs/KERNELS.md).
-
-    Protocol: interleaved RTPU_PCPM=0/1 PAIRS on the headline windowed-
-    PageRank sweep (drift on a shared box cancels within a pair; the
-    reported value is the MEDIAN per-pair speedup, robust to the 2-core
-    container's scheduling outliers), plus per-kernel micro rows — the
-    PR/CC/BFS delta kernels on a cold unwarmed single dispatch (the
-    superstep-loop-dominated shape where binning acts) and the feature
-    aggregation engine. Every arm runs under an activated ledger; the
-    registry snapshot rides in the row so the roofline story (est HBM
-    bytes per dispatch, bound vs bound_refined) is recorded next to the
-    wall numbers, not just asserted. RTPU_BENCH_CHEAP=1 shrinks the log
-    and pair count for CI (the value stays a ratio, machine-portable)."""
-    import jax
-
-    from raphtory_tpu.engine.device_sweep import DeviceSweep
-    from raphtory_tpu.engine.features import FeatureAggregator
-    from raphtory_tpu.engine.hopbatch import (HopBatchedBFS, HopBatchedCC,
-                                              HopBatchedPageRank)
-    from raphtory_tpu.obs import ledger as ledger_mod
-    from raphtory_tpu.utils.synth import gab_like_log
-
-    cheap = os.environ.get("RTPU_BENCH_CHEAP", "0") not in ("", "0")
-    if cheap:
-        log = gab_like_log(n_vertices=8_000, n_edges=140_000,
-                           t_span=_GAB_SPAN)
-        n_hops, n_pairs = 8, 2
-    else:
-        log = _gab_log()
-        n_hops, n_pairs = 12, 5
-    view_times = np.linspace(0.45 * _GAB_SPAN, _GAB_SPAN,
-                             n_hops).astype(np.int64)
-    windows = [2_600_000, 604_800, 86_400]
-    hops = [int(T) for T in view_times]
-    n_chunks = _chunks(3, "PR")
-
-    saved = os.environ.get("RTPU_PCPM")
-
-    def setenv(v):
-        if v is None:
-            os.environ.pop("RTPU_PCPM", None)
-        else:
-            os.environ["RTPU_PCPM"] = v
-
-    def ab_pairs(once, pairs):
-        """[(off_s, on_s)] interleaved; each arm GC-collected first."""
-        import gc
-
-        out = []
-        for _ in range(pairs):
-            gc.collect()
-            setenv("0")
-            a = once()
-            gc.collect()
-            setenv("1")
-            b = once()
-            out.append((a, b))
-        return out
-
-    def median_ratio(pairs):
-        rs = sorted(a / b for a, b in pairs)
-        mid = len(rs) // 2
-        # true median: even counts average the middle two — indexing
-        # rs[mid] alone would report the optimistic upper sample for the
-        # 2-pair cheap CI shape
-        return rs[mid] if len(rs) % 2 else (rs[mid - 1] + rs[mid]) / 2.0
-
-    def headline_once():
-        hb = HopBatchedPageRank(log, tol=1e-7, max_steps=20)
-        t0 = _time.perf_counter()
-        ranks, _ = hb.run(hops, windows, chunks=n_chunks, warm_start=True)
-        _sync(ranks)
-        return _time.perf_counter() - t0
-
-    def kernel_once(mk):
-        """Cold single-dispatch sweep; returns wall MINUS host fold — the
-        compute term the binning targets (fold work is identical on both
-        arms, so subtracting it sharpens the pair ratio)."""
-        hb = mk()
-        t0 = _time.perf_counter()
-        out, _ = hb.run(hops, windows, chunks=1)
-        _sync(out)
-        return _time.perf_counter() - t0 - hb.fold_seconds
-
-    led = ledger_mod.Ledger("bench_pcpm_ab", "PageRank")
-    # the registry is process-global: in a full-suite run earlier configs
-    # dispatched the same kernels, so report only THIS config's dispatch
-    # deltas (harvested analyses are per-(kernel, sig) and unaffected)
-    disp_before = {(r["kernel"], r["sig"]): r["dispatches"]
-                   for r in ledger_mod.REGISTRY.snapshot()}
-    t_all = _time.perf_counter()
-    try:
-        with ledger_mod.activate(led):
-            for v in ("0", "1"):    # compile + harvest both arms, untimed
-                setenv(v)
-                headline_once()
-            headline = ab_pairs(headline_once, n_pairs)
-
-            micro = {}
-            mks = {
-                "pagerank_delta": lambda: HopBatchedPageRank(
-                    log, tol=1e-7, max_steps=20),
-                "cc_delta": lambda: HopBatchedCC(log, max_steps=50),
-                "bfs_delta": lambda: HopBatchedBFS(log, (0, 1, 2),
-                                                   max_steps=50),
-            }
-            for name, mk in mks.items():
-                for v in ("0", "1"):
-                    setenv(v)
-                    kernel_once(mk)
-                micro[name] = ab_pairs(lambda: kernel_once(mk), n_pairs)
-
-            # feature aggregation: the F-wide row gather the engine
-            # documents as its bound term — the bucket dedup's micro row
-            ds = DeviceSweep(log)
-            ds.advance(int(view_times[-1]))
-            fa = FeatureAggregator(ds, feature_dim=64 if cheap else 128)
-            X = fa.random_features(0)
-
-            def features_once():
-                t0 = _time.perf_counter()
-                H = fa.propagate(X, window=2_600_000, rounds=3)
-                _sync(H)
-                return _time.perf_counter() - t0
-
-            for v in ("0", "1"):
-                setenv(v)
-                features_once()   # also builds + caches the layout
-            micro["features_aggregate"] = ab_pairs(features_once, n_pairs)
-    finally:
-        setenv(saved)
-
-    led.finish(_time.perf_counter() - t_all)
-    speedup = median_ratio(headline)
-    kernels = []
-    for r in ledger_mod.REGISTRY.snapshot():
-        if not r["kernel"].startswith(("hopbatch.", "bsp.")):
-            continue
-        d = r["dispatches"] - disp_before.get((r["kernel"], r["sig"]), 0)
-        if d > 0:
-            kernels.append(dict(r, dispatches=d))
-    # the acceptance pair: the PageRank delta kernel's per-dispatch est
-    # HBM bytes, unbinned sig vs binned sig (the binned record carries
-    # the partition traffic model; xla bytes_accessed rides next to it)
-    pr_recs = [
-        {k: r.get(k) for k in ("sig", "bound", "bound_refined",
-                               "bytes_accessed", "est_hbm_bytes",
-                               "intensity", "intensity_refined",
-                               "dispatches")}
-        for r in kernels if r["kernel"] == "hopbatch.delta.pagerank"]
-    return {
-        # cheap mode is a DIFFERENT protocol (smaller graph, fewer pairs)
-        # whose speedup is not comparable to the full shape — its own
-        # metric string keeps perfwatch's series coherent
-        "config": "pcpm_ab_cheap" if cheap else "pcpm_ab",
-        "metric": ("PCPM destination-binned kernels vs unbinned on the "
-                   "headline windowed-PageRank sweep (median interleaved "
-                   "pair speedup, "
-                   + ("CI cheap shape)" if cheap else "GAB-scale)")),
-        "value": round((speedup - 1.0) * 100.0, 2),
-        "unit": "percent_faster_with_pcpm",
-        "detail": {
-            "engine": "hop_batched_columnar",
-            "cheap_mode": cheap,
-            "timing": ("interleaved_pcpm_off_on_pairs_median_ratio — "
-                       "per-pair ratios cancel shared-box drift; arms "
-                       "differ ONLY in RTPU_PCPM"),
-            "headline_pairs_seconds": [[round(a, 4), round(b, 4)]
-                                       for a, b in headline],
-            "headline_median_speedup": round(speedup, 4),
-            "kernel_micro": {
-                name: {
-                    "pairs_seconds": [[round(a, 4), round(b, 4)]
-                                      for a, b in pairs],
-                    "median_speedup": round(median_ratio(pairs), 4),
-                    "timing": ("cold_single_dispatch_minus_fold"
-                               if name != "features_aggregate"
-                               else "resident_propagate_3_rounds"),
-                } for name, pairs in micro.items()},
-            "partitions": "auto (RTPU_PARTITIONS unset)",
-            # roofline reclassification evidence, recorded not asserted:
-            # per-kernel est HBM bytes per dispatch + bound transitions
-            "pagerank_delta_kernel_records": pr_recs,
-            "kernels": kernels,
-            "ledger": led.as_dict() if hasattr(led, "as_dict") else None,
-            "baseline": "the RTPU_PCPM=0 arm of this same row",
-        },
-    }
-
-
 def bench_multichip_obs_overhead():
     """Distributed-observability overhead on a REAL 2-process localhost
     cluster (ISSUE 10 acceptance: <= 5%).
@@ -3365,7 +3173,6 @@ def bench_sparse_collectives():
 
 CONFIGS = {
     "headline": bench_headline,
-    "pcpm_ab": bench_pcpm_ab,
     "fold_parallel": bench_fold_parallel,
     "ledger_overhead": bench_ledger_overhead,
     "sanitize_overhead": bench_sanitize_overhead,

@@ -50,7 +50,7 @@ import sys
 #: percentage points instead (a 1% → 3% overhead move is +2pp, not 3x).
 _UNIT_CLASSES = (
     # improvement-direction percents (percent_faster_*) must match BEFORE
-    # the generic lower-is-better percent rule — a binned-kernel speedup
+    # the generic lower-is-better percent rule — a measured speedup
     # coming in ABOVE its trajectory is good news, not a regression
     ("percent_faster", ("higher", None)),
     ("percent", ("lower", None)),        # absolute band, see _PERCENT_PP
@@ -278,8 +278,8 @@ def selftest() -> int:
         ([10.0, 10.3, 9.8], 9.6, "views/sec", False),    # noise
         ([1.2, 3.8], 100.0, "percent_overhead", True),   # 2x-slowdown arm
         ([1.2, 3.8], 6.0, "percent_overhead", False),    # noisy CI runner
-        ([35.0, 40.0], 2.0, "percent_faster_with_pcpm", True),   # win lost
-        ([35.0, 40.0], 55.0, "percent_faster_with_pcpm", False),  # bigger win
+        ([35.0, 40.0], 2.0, "percent_faster_with_scheduler", True),   # lost
+        ([35.0, 40.0], 55.0, "percent_faster_with_scheduler", False),  # won
         ([1.6], 0.9, "x_fold_speedup", True),            # speedup lost
         ([0.02, 0.025], 0.05, "seconds", True),          # 2x slower view
         ([0.02, 0.025], 0.024, "seconds", False),

@@ -25,7 +25,7 @@ import numpy as np
 from ..core.snapshot import GraphView
 from ..obs import ledger as _ledger
 from ..obs.trace import TRACER, block_steps
-from ..ops.segment import partition_segment_reduce, segment_combine
+from ..ops.segment import segment_combine
 from .program import Context, Edges, VertexProgram
 
 _elem = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
@@ -43,7 +43,7 @@ def _unpack_bits(packed: jnp.ndarray, n: int) -> jnp.ndarray:
     return bits.reshape(packed.shape[0], n).astype(bool)
 
 
-def make_runner(program: VertexProgram, n: int, m: int, k: int, pcpm=None):
+def make_runner(program: VertexProgram, n: int, m: int, k: int):
     """The raw (unjitted) superstep program for given padded shapes — the
     jittable forward step of the framework; see also ``__graft_entry__``.
 
@@ -51,23 +51,20 @@ def make_runner(program: VertexProgram, n: int, m: int, k: int, pcpm=None):
     little bit order). Arrays a program opts out of (``needs_vids`` /
     ``needs_vertex_times`` / ``needs_edge_times`` False) may be passed as
     1-element dummies — the runner substitutes pad defaults on device, so
-    the host never stages or transfers them. ``pcpm`` (a
-    ``ops.partition.PartitionSpec``) appends the destination-binned
-    layout operands (perm, valid, b_dst) — see ``make_mask_runner``."""
-    core = make_mask_runner(program, n, m, k, pcpm)
+    the host never stages or transfers them."""
+    core = make_mask_runner(program, n, m, k)
 
     def run(v_masks_p, e_masks_p, vids, v_latest, v_first,
             e_src, e_dst, e_latest, e_first,
-            time, windows, eprops, vprops, *rest):
+            time, windows, eprops, vprops):
         return core(_unpack_bits(v_masks_p, n), _unpack_bits(e_masks_p, m),
                     vids, v_latest, v_first, e_src, e_dst, e_latest, e_first,
-                    time, windows, eprops, vprops, *rest)
+                    time, windows, eprops, vprops)
 
     return run
 
 
-def make_mask_runner(program: VertexProgram, n: int, m: int, k: int,
-                     pcpm=None):
+def make_mask_runner(program: VertexProgram, n: int, m: int, k: int):
     """The superstep core over UNPACKED bool masks (v_masks[k,n],
     e_masks[k,m]) — shared by the bit-packed host path (``make_runner``) and
     the device-resident sweep engine (``device_sweep.py``), which computes
@@ -85,16 +82,7 @@ def make_mask_runner(program: VertexProgram, n: int, m: int, k: int,
 
     def run(v_masks, e_masks, vids, v_latest, v_first,
             e_src, e_dst, e_latest, e_first,
-            time, windows, eprops, vprops, *rest):
-        if pcpm is not None:
-            # destination-binned exchange (ops/partition.py): the sorted
-            # combine's flat scatter becomes P dense per-partition
-            # reductions, each into a cache-resident n_per-row block
-            b_perm, b_valid, b_dst = rest
-            b_local = (b_dst.reshape(pcpm.partitions, pcpm.cap)
-                       - jnp.arange(pcpm.partitions,
-                                    dtype=b_dst.dtype)[:, None]
-                       * pcpm.n_per)
+            time, windows, eprops, vprops):
         if not program.needs_vids:
             vids = jnp.full((n,), -1, jnp.int64)
         if not program.needs_vertex_times:
@@ -115,24 +103,9 @@ def make_mask_runner(program: VertexProgram, n: int, m: int, k: int,
                 (k * m,) + a.shape[1:])
 
         def combine_flat(tree_flat, ids, sorted_):
-            # the binned route owns the DESTINATION direction (the layout
-            # bins by dst); the reverse direction keeps the flat scatter.
             # No branch here may depend on the backend's name: the chip
             # must run the combine the CPU tests run.
-            use_pcpm = pcpm is not None and sorted_
-
             def leaf(x):
-                if use_pcpm:
-                    xb = x.reshape((k, m) + x.shape[1:])[:, b_perm]
-                    mb = em_flat.reshape(k, m)[:, b_perm] \
-                        & b_valid[None, :]
-                    P, cap = pcpm.partitions, pcpm.cap
-                    out = jax.vmap(
-                        lambda xw, mw: partition_segment_reduce(
-                            xw.reshape((P, cap) + x.shape[1:]),
-                            b_local, pcpm.n_per, n, program.combiner,
-                            mw.reshape(P, cap)))(xb, mb)
-                    return out                       # [k, n, ...]
                 out = segment_combine(x, ids, k * n, program.combiner,
                                       em_flat, indices_are_sorted=sorted_)
                 return out.reshape((k, n) + x.shape[1:])
@@ -228,38 +201,16 @@ def make_mask_runner(program: VertexProgram, n: int, m: int, k: int,
 
 @functools.lru_cache(maxsize=256)
 def _compiled_runner(program: VertexProgram, n: int, m: int, k: int,
-                     prop_keys: tuple, vprop_keys: tuple, pcpm=None):
+                     prop_keys: tuple, vprop_keys: tuple):
     """One compiled program per (algorithm instance, padded shapes, #windows).
 
     Range sweeps at the same bucketed shape hit this cache — the amortisation
     the reference never had (fresh handshake per hop,
-    ``RangeAnalysisTask.scala:18-35``). ``pcpm`` (a ``PartitionSpec``,
-    resolved by the DISPATCH site so ``RTPU_PCPM``/``RTPU_PARTITIONS``
-    are part of this cache key) selects the destination-binned exchange."""
-    from ..ops.partition import edge_traffic_model
-
-    return _ledger.instrument(f"bsp.superstep.{type(program).__name__}",
-                              jax.jit(make_runner(program, n, m, k, pcpm)),
-                              traffic=edge_traffic_model(m, k, n, pcpm))
-
-
-def _view_layout(view: GraphView, e_src, e_dst, occurrences: bool):
-    """Destination-binned layout for a view's edge table, or None when
-    ``RTPU_PCPM`` keeps the flat exchange. Knobs are read HERE, at
-    dispatch, and reach the compiled runner's cache key through the
-    layout's spec. The REAL row count matters: the cap-padded pow2 tail
-    (dst = n_pad-1) must become invalid cap-pad slots, not binned edges
-    that inflate the last partition's capacity by the pad count."""
-    from ..ops import partition as _partition
-
-    if occurrences:
-        rows = view._occ_rows
-        m = int((rows >= 0).sum()) if rows is not None else len(e_src)
-    else:
-        m = int(view.m_active)
-    return _partition.resolve(
-        view, _partition.HostTables(e_src, e_dst, view.n_pad, m),
-        _partition.tile_budget_bytes(), tag="occ" if occurrences else "e")
+    ``RangeAnalysisTask.scala:18-35``)."""
+    return _ledger.instrument(
+        f"bsp.superstep.{type(program).__name__}",
+        jax.jit(make_runner(program, n, m, k)),
+        traffic=_ledger.edge_traffic_model(m, k, n))
 
 
 def _gather_props(view: GraphView, keys, kind: str):
@@ -333,22 +284,9 @@ def run_async(
             v_masks[i] = vm[0]
             e_masks[i] = e_base_mask & (e_latest >= view.time - w)
 
-    # build the layout only when the binned route can actually engage:
-    # custom exchanges and in-only programs never take the sorted-combine
-    # path — paying an O(m log m) build + upload per fresh view for a
-    # route that won't run would be pure overhead
-    binnable = (program.combiner != "custom"
-                and program.direction in ("out", "both"))
-    layout = _view_layout(view, e_src, e_dst,
-                          program.needs_occurrences) if binnable else None
-    extra = ()
-    if layout is not None:
-        b_src, b_dst, b_valid, _slot, _u, b_perm = layout.device_args()
-        extra = (b_perm, b_valid, b_dst)
     runner = _compiled_runner(
         program, view.n_pad, m_pad, k,
         tuple(program.edge_props), tuple(program.vertex_props),
-        None if layout is None else layout.spec,
     )
     eprops = _gather_props(
         view, program.edge_props,
@@ -359,8 +297,7 @@ def run_async(
     dummy64 = jnp.zeros((1,), jnp.int64)
     with TRACER.span("bsp.dispatch", n=int(view.n_pad), m=int(m_pad),
                         windows=k, time=int(view.time),
-                        program=type(program).__name__,
-                        pcpm=layout is not None):
+                        program=type(program).__name__):
         result, steps = runner(
             jnp.asarray(np.packbits(v_masks, axis=1, bitorder="little")),
             jnp.asarray(np.packbits(e_masks, axis=1, bitorder="little")),
@@ -373,7 +310,6 @@ def run_async(
             jnp.asarray(e_latest) if program.needs_edge_times else dummy64,
             jnp.asarray(e_first) if program.needs_edge_times else dummy64,
             jnp.asarray(view.time, jnp.int64), win_arr, eprops, vprops,
-            *extra,
         )
     if not batched:
         result = jax.tree_util.tree_map(lambda a: a[0], result)

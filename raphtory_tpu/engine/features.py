@@ -35,94 +35,53 @@ from .device_sweep import DeviceSweep
 @functools.lru_cache(maxsize=64)
 def _compiled_propagate(n_pad: int, m_pad: int, chunk: int, F: int,
                         rounds: int, self_weight: float, tdt: str,
-                        fdt: str = "float32", pcpm=None):
+                        fdt: str = "float32"):
     """``fdt`` is the feature STORAGE dtype: bfloat16 halves the HBM bytes
     of the per-edge row gathers (the term this engine is bound by on TPU)
     while accumulation, degree-normalise and the L2 norm stay float32 —
-    the standard mixed-precision aggregation recipe.
-
-    ``pcpm`` (``ops/partition.PartitionSpec``) is the partition-centric
-    route: the edge scan walks DESTINATION PARTITIONS instead of raw
-    chunks. Per partition the kernel gathers each distinct source row
-    ONCE into a pre-aggregation bucket (``[cap_u, F]``), expands it as a
-    streaming read, and reduces into a dense ``[n_per, F]`` block — the
-    per-edge F-wide row gather this engine is bound by shrinks by the
-    bucket dedup factor, and the accumulator slice is cache-resident.
-    Sum order changes: results agree to f32 tolerance (bitwise on the
-    unbinned route, which every value but ``RTPU_PCPM=1`` takes)."""
+    the standard mixed-precision aggregation recipe."""
     tdt = jnp.dtype(tdt)
     fdt = jnp.dtype(fdt)
     C = m_pad // chunk
 
-    def propagate(X, e_src, e_dst, e_lat, e_alive, time, window, *rest):
+    def propagate(X, e_src, e_dst, e_lat, e_alive, time, window):
         X = X.astype(fdt)
         info = jnp.iinfo(tdt)
         lo = jnp.clip(time - window, info.min, info.max).astype(tdt)
         mask = e_alive & ((window < 0) | (e_lat >= lo))   # [m_pad]
-        if pcpm is not None:
-            P, n_per = pcpm.partitions, pcpm.n_per
-            cap, cap_u = pcpm.cap, pcpm.cap_u
-            b_perm, b_valid, b_dst, b_slot, u_src = rest
-            bm = (mask[b_perm] & b_valid).reshape(P, cap)
-            iota = jnp.arange(P, dtype=jnp.int32)[:, None]
-            loc = b_dst.reshape(P, cap) - iota * n_per
-            sl = b_slot.reshape(P, cap) - iota * cap_u
-            u2 = u_src.reshape(P, cap_u)
+        src_c = e_src.reshape(C, chunk)
+        dst_c = e_dst.reshape(C, chunk)
+        msk_c = mask.reshape(C, chunk)
+        ones = jnp.ones((chunk,), jnp.float32)
 
-            def deg_body(_, ins):
-                loc_p, mk_p = ins
-                return None, jax.ops.segment_sum(
-                    mk_p.astype(jnp.float32), loc_p, num_segments=n_per)
+        # masked in-degree is round-invariant — one per-element pass
+        # total, not one per round
+        def deg_body(deg, ins):
+            d, mk = ins
+            return deg + jax.ops.segment_sum(
+                jnp.where(mk, ones, 0.0), d, num_segments=n_pad,
+                indices_are_sorted=True), None
 
-            _, degs = jax.lax.scan(deg_body, None, (loc, bm))
-            deg = degs.reshape(P * n_per)[:n_pad]
-        else:
-            src_c = e_src.reshape(C, chunk)
-            dst_c = e_dst.reshape(C, chunk)
-            msk_c = mask.reshape(C, chunk)
-            ones = jnp.ones((chunk,), jnp.float32)
-
-            # masked in-degree is round-invariant — one per-element pass
-            # total, not one per round
-            def deg_body(deg, ins):
-                d, mk = ins
-                return deg + jax.ops.segment_sum(
-                    jnp.where(mk, ones, 0.0), d, num_segments=n_pad,
-                    indices_are_sorted=True), None
-
-            deg, _ = jax.lax.scan(deg_body,
-                                  jnp.zeros((n_pad,), jnp.float32),
-                                  (dst_c, msk_c))
+        deg, _ = jax.lax.scan(deg_body,
+                              jnp.zeros((n_pad,), jnp.float32),
+                              (dst_c, msk_c))
         inv_deg = 1.0 / jnp.maximum(deg, 1.0)
 
         def one_round(H, _):
-            if pcpm is not None:
-                def part_body(_, ins):
-                    u_p, sl_p, loc_p, mk_p = ins
-                    # ONE fdt row per distinct (partition, src) — the
-                    # bucket dedup is the whole gather-traffic win
-                    vals = H[u_p, :].astype(jnp.float32)   # [cap_u, F]
-                    G = jnp.where(mk_p[:, None], vals[sl_p, :], 0.0)
-                    return None, jax.ops.segment_sum(
-                        G, loc_p, num_segments=n_per)
+            def chunk_body(agg, ins):
+                s, d, mk = ins
+                # gather reads fdt rows from HBM; the f32 convert
+                # happens in-flight, so bf16 storage halves the
+                # streamed bytes
+                G = jnp.where(mk[:, None], H[s, :].astype(jnp.float32),
+                              0.0)
+                return agg + jax.ops.segment_sum(
+                    G, d, num_segments=n_pad,
+                    indices_are_sorted=True), None
 
-                _, aggs = jax.lax.scan(part_body, None, (u2, sl, loc, bm))
-                agg = aggs.reshape(P * n_per, F)[:n_pad]
-            else:
-                def chunk_body(agg, ins):
-                    s, d, mk = ins
-                    # gather reads fdt rows from HBM; the f32 convert
-                    # happens in-flight, so bf16 storage halves the
-                    # streamed bytes
-                    G = jnp.where(mk[:, None], H[s, :].astype(jnp.float32),
-                                  0.0)
-                    return agg + jax.ops.segment_sum(
-                        G, d, num_segments=n_pad,
-                        indices_are_sorted=True), None
-
-                agg, _ = jax.lax.scan(
-                    chunk_body, jnp.zeros((n_pad, F), jnp.float32),
-                    (src_c, dst_c, msk_c))
+            agg, _ = jax.lax.scan(
+                chunk_body, jnp.zeros((n_pad, F), jnp.float32),
+                (src_c, dst_c, msk_c))
             H2 = agg * inv_deg[:, None]
             H2 = self_weight * H.astype(jnp.float32) \
                 + (1.0 - self_weight) * H2
@@ -156,37 +115,6 @@ class FeatureAggregator:
         # feature storage dtype: "bfloat16" halves the HBM-bound row
         # traffic on TPU; accumulation stays float32 (_compiled_propagate)
         self.dtype = jnp.dtype(dtype)
-        # host copies of the edge tables for the partition-layout build —
-        # the sweep dropped its own after upload, so the first resolve
-        # pulls them back once (D2H of 2 * m_pad i32)
-        self._host_tables = None
-        # the spec the LAST propagate dispatched with (None = unbinned) —
-        # what traffic_bytes reports on, without re-resolving anything
-        self._active_spec = None
-
-    def _pcpm_layout(self):
-        """Resolved partition layout for this aggregator, or None — one
-        ``ops.partition.resolve`` call (knobs read per dispatch, the spec
-        rides into the compiled-program cache key; layouts cached per
-        sweep). The binned route additionally requires the per-partition
-        transients (``[cap, F]`` payload, ``[cap_u, F]`` bucket) to fit
-        the tile budget — oversized partitions fall back to the chunked
-        scan."""
-        from ..ops import partition as _partition
-
-        ds = self.ds
-        if not _partition.pcpm_enabled():
-            return None
-        if self._host_tables is None:
-            self._host_tables = _partition.HostTables(
-                np.asarray(ds.e_src), np.asarray(ds.e_dst), ds.n_pad, ds.m)
-        budget = _partition.tile_budget_bytes()
-        lay = _partition.resolve(ds, self._host_tables, budget)
-        if lay is None or not lay.spec.preagg \
-                or lay.spec.cap * self.F * 4 > budget \
-                or lay.spec.cap_u * self.F * 4 > budget:
-            return None
-        return lay
 
     def random_features(self, seed: int = 0):
         """Deterministic on-device init (unit-norm rows) — no host transfer."""
@@ -202,45 +130,23 @@ class FeatureAggregator:
             ds.advance(time)
         if ds.t_now is None:
             raise ValueError("advance the sweep (or pass time=) first")
-        layout = self._pcpm_layout()
-        self._active_spec = None if layout is None else layout.spec
-        extra = ()
-        if layout is not None:
-            b_src, b_dst, b_valid, b_slot, u_src, b_perm = \
-                layout.device_args()
-            extra = (b_perm, b_valid, b_dst, b_slot, u_src)
         fn = _compiled_propagate(
             ds.n_pad, ds.m_pad, self.chunk, self.F, int(rounds),
-            self.self_weight, np.dtype(ds.tdtype).name, self.dtype.name,
-            None if layout is None else layout.spec)
+            self.self_weight, np.dtype(ds.tdtype).name, self.dtype.name)
         v_lat, v_alive, v_first, e_lat, e_alive, e_first = ds._bufs
         return fn(X, ds.e_src, ds.e_dst, e_lat, e_alive,
                   jnp.asarray(ds.t_now, jnp.int64),
                   jnp.asarray(-1 if window is None else int(window),
-                              jnp.int64), *extra)
+                              jnp.int64))
 
     def traffic_bytes(self, rounds: int) -> int:
         """Approximate HBM bytes per propagate call (for utilisation
         reporting): per round, the edge axis streams a gathered F-row and
         writes it once into the accumulator, plus index/mask columns; the
-        masked-degree pass runs ONCE per call (round-invariant). Reports
-        the mode the LAST propagate dispatched in — a pure read, never a
-        layout build. On the partition-centric route the per-edge row
-        GATHER shrinks to one row per (partition, src) bucket — the dedup
-        factor the binning exists for — while the expansion streams at
-        fdt width."""
+        masked-degree pass runs ONCE per call (round-invariant)."""
         fb = self.dtype.itemsize                # feature storage bytes/lane
         per_edge = self.F * (fb + 4) + 2 * 4 + 1  # fdt gather + f32 scatter
         per_vertex = self.F * (2 * 4 + fb)      # f32 acc read+write, fdt H
-        s = self._active_spec
-        if s is not None:
-            B = s.partitions * s.cap
-            u_rows = s.partitions * s.cap_u
-            deg_pass = B * (4 + 1)
-            per_round = (u_rows * self.F * fb          # bucket fill
-                         + B * (self.F * (fb + 4) + 4 + 1)  # expand+scatter
-                         + self.ds.n_pad * per_vertex)
-            return deg_pass + rounds * per_round
         deg_pass = self.ds.m_pad * (4 + 1)      # dst ids + mask, one pass
         return deg_pass + rounds * (self.ds.m_pad * per_edge
                                     + self.ds.n_pad * per_vertex)

@@ -115,16 +115,6 @@ def _tile_budget_bytes() -> int:
     return int(os.environ.get("RTPU_TILE_BUDGET_MB", 256)) << 20
 
 
-def _traffic(m_pad: int, C: int, n_pad: int, spec) -> dict:
-    """Engine-side DRAM traffic model of one message-combine superstep
-    (``ops/partition.edge_traffic_model``) — attached to every compiled
-    columnar kernel so the ledger can report partition-aware est HBM
-    bytes next to the locality-blind XLA ``bytes_accessed`` harvest."""
-    from ..ops.partition import edge_traffic_model
-
-    return edge_traffic_model(m_pad, C, n_pad, spec)
-
-
 def _edge_tile_for(m_pad: int, C: int, budget_bytes: int) -> int | None:
     """Edge-tile length for the columnar kernels, or None for single-shot.
 
@@ -154,7 +144,7 @@ def _edge_tile_for(m_pad: int, C: int, budget_bytes: int) -> int | None:
 
 def _pagerank_columns(me, mv, e_src, e_dst, n_pad: int, damping: float,
                       tol: float, max_steps: int, r_init=None,
-                      tile_budget: int | None = None, pcpm=None):
+                      tile_budget: int | None = None):
     """Power iteration over per-column masks ``me [m_pad, C]`` /
     ``mv [n_pad, C]`` — dangling redistribution, tol halting with
     converged-column freeze; semantics of ``algorithms/pagerank.py``.
@@ -168,17 +158,9 @@ def _pagerank_columns(me, mv, e_src, e_dst, n_pad: int, damping: float,
     to its own alive set, floored so newly-alive vertices get mass, and
     renormalised.
 
-    ``pcpm`` = ``(spec, slot, u_src)`` switches the edge operands to the
-    destination-binned layout (``ops/partition.py``): ``me``/``e_src``/
-    ``e_dst`` are then the BINNED ``[B(, C)]`` arrays (ids stay global, so
-    every reduce keeps its shape) and the superstep gather goes through
-    the per-(partition, src) pre-aggregation buckets. Binned edges are
-    (partition, src)-ordered, so destination ids are NOT sorted — the
-    scatter instead stays inside one cache-resident partition slice
-    (docs/KERNELS.md). Float sums reorder: results agree to reduction
-    tolerance, not bitwise."""
+    ``e_src``/``e_dst`` are the (dst, src)-sorted pair table, so every
+    combine at the destination is a sorted segment reduction."""
     C = me.shape[1]
-    dst_sorted = pcpm is None
     # Edge traffic is tiled past the payload budget (_edge_tile_for): the
     # f32 view of the mask and the per-iteration gather payload are both
     # [m_pad, C] transients that at 28M pairs x 128 columns outgrow a
@@ -203,9 +185,8 @@ def _pagerank_columns(me, mv, e_src, e_dst, n_pad: int, damping: float,
                     payload_of(es, mk), ed if by_dst else es,
                     num_segments=n_pad,
                     # tiles are contiguous slices of the globally
-                    # (dst, src)-sorted order — UNLESS binned, whose
-                    # (partition, src) order leaves dst unsorted
-                    indices_are_sorted=by_dst and dst_sorted), None
+                    # (dst, src)-sorted order
+                    indices_are_sorted=by_dst), None
 
             acc, _ = jax.lax.scan(step, acc0, main)
             if rem[0].shape[0]:
@@ -213,7 +194,7 @@ def _pagerank_columns(me, mv, e_src, e_dst, n_pad: int, damping: float,
                 acc = acc + jax.ops.segment_sum(
                     payload_of(es, mk), ed if by_dst else es,
                     num_segments=n_pad,
-                    indices_are_sorted=by_dst and dst_sorted)
+                    indices_are_sorted=by_dst)
             return acc
 
         out_deg = tiled_sum(
@@ -240,23 +221,13 @@ def _pagerank_columns(me, mv, e_src, e_dst, n_pad: int, damping: float,
         if tile is not None:
             agg = tiled_sum(
                 lambda es, mk: jnp.where(mk, rd[es, :], 0.0), by_dst=True)
-        elif pcpm is not None and pcpm[0].preagg:
-            # PCPM two-level gather: one state row per (partition, src)
-            # bucket — each source read ONCE per partition it reaches —
-            # then a streaming expansion through the resident bucket
-            spec, slot, u_src = pcpm
-            vals = rd[u_src, :]                       # [P*cap_u, C]
-            payload = jnp.where(me, vals[slot, :], 0.0)
-            agg = jax.ops.segment_sum(
-                payload, e_dst, num_segments=n_pad,
-                indices_are_sorted=False)
         else:
             # row gather [m, C]; the bool mask gates via where — only the
             # bool mask stays live across the loop
             payload = jnp.where(me, rd[e_src, :], 0.0)
             agg = jax.ops.segment_sum(
                 payload, e_dst, num_segments=n_pad,
-                indices_are_sorted=dst_sorted)
+                indices_are_sorted=True)
         dangling = jnp.sum(jnp.where(dangling_mask, r, 0.0), axis=0)
         new = ((1.0 - damping) / n_act[None, :]
                + damping * (agg + dangling[None, :] / n_act[None, :]))
@@ -281,40 +252,27 @@ def _pagerank_columns(me, mv, e_src, e_dst, n_pad: int, damping: float,
     return r.T, steps   # [C, n_pad], hop-major columns
 
 
-def _bin_masks(me, pcpm_args):
-    """Host-column edge masks → the binned layout, in-program: one
-    loop-invariant permutation gather, amortised over the supersteps.
-    ``pcpm_args`` = (spec, perm, valid, slot, u_src) as the dispatcher
-    appended them; returns (binned me, (spec, slot, u_src)) for the
-    kernel bodies."""
-    spec, perm, valid, slot, u_src = pcpm_args
-    return me[perm, :] & valid[:, None], (spec, slot, u_src)
-
-
 @functools.lru_cache(maxsize=64)
 def _compiled(n_pad: int, m_pad: int, H: int, C: int, damping: float,
               tol: float, max_steps: int, tdt: str, warm: bool = False,
-              tile_budget: int | None = None, pcpm=None):
+              tile_budget: int | None = None):
     tdt = jnp.dtype(tdt)
 
     def run(e_src, e_dst, e_lat, e_alive, v_lat, v_alive,
             hop_of_col, T_col, w_col, *rest):
         me, mv = _column_masks(tdt, e_lat, e_alive, v_lat, v_alive,
                                hop_of_col, T_col, w_col)
-        pc = None
-        if pcpm is not None:
-            *rest, perm, valid, slot, u_src = rest
-            me, pc = _bin_masks(me, (pcpm, perm, valid, slot, u_src))
         # warm arg: previous chunk's full [C, n_pad] output; tail slice +
         # per-hop tile in-program (see _compiled_delta)
         W = C // H
         r0 = jnp.tile(rest[0][-W:], (H, 1)).T if warm else None
         return _pagerank_columns(me, mv, e_src, e_dst, n_pad,
                                  damping, tol, max_steps, r_init=r0,
-                                 tile_budget=tile_budget, pcpm=pc)
+                                 tile_budget=tile_budget)
 
-    return _ledger.instrument("hopbatch.pagerank_cols", jax.jit(run),
-                              traffic=_traffic(m_pad, C, n_pad, pcpm))
+    return _ledger.instrument(
+        "hopbatch.pagerank_cols", jax.jit(run),
+        traffic=_ledger.edge_traffic_model(m_pad, C, n_pad))
 
 
 @functools.lru_cache(maxsize=64)
@@ -322,7 +280,7 @@ def _compiled_delta(kind: str, n_pad: int, m_pad: int, H: int, W: int,
                     U_e: int, U_v: int, tdt: str, warm: bool,
                     algo_args: tuple, weighted: bool = False,
                     U_w: int = 0, h0: bool = False,
-                    tile_budget: int | None = None, pcpm=None):
+                    tile_budget: int | None = None):
     """Delta-fed columnar kernels: masks rebuilt on device from base state
     + per-hop deltas (``_masks_from_deltas``), then the shared algorithm
     body. ``kind``: pagerank | cc | bfs (``weighted`` adds a per-pair
@@ -330,24 +288,12 @@ def _compiled_delta(kind: str, n_pad: int, m_pad: int, H: int, W: int,
     static parameter tuple. ``h0=True`` is the resident-base variant: the
     base inputs are the previous dispatch's advanced state, delta[0] is
     applied before hop 0. Every variant returns ``(result, steps,
-    advanced_base)`` so the caller can keep the fold state on device.
-
-    ``pcpm`` (a ``PartitionSpec``) is the destination-binned variant: the
-    PAIR-side base arrays arrive pre-binned from the host, the pair delta
-    positions are pre-remapped to binned slots, and ``e_src``/``e_dst``
-    are the layout's global ``b_src``/``b_dst`` — the mask rebuild is then
-    IDENTICAL code over the binned coordinate space, and the advanced
-    base stays binned across resident batches. Trailing args carry the
-    layout's (slot, u_src) bucket tables."""
+    advanced_base)`` so the caller can keep the fold state on device."""
     tdt_ = jnp.dtype(tdt)
 
     def run(e_src, e_dst, be_lat, be_alive, bv_lat, bv_alive,
             de_pos, de_lat, de_alive, dv_pos, dv_lat, dv_alive,
             T_col, w_col, *rest):
-        pc = None
-        if pcpm is not None:
-            *rest, slot, u_src = rest
-            pc = (pcpm, slot, u_src)
         me, mv, adv = _masks_from_deltas(
             tdt_, H, W, be_lat, be_alive, bv_lat, bv_alive,
             de_pos, de_lat, de_alive, dv_pos, dv_lat, dv_alive,
@@ -360,14 +306,13 @@ def _compiled_delta(kind: str, n_pad: int, m_pad: int, H: int, W: int,
             r0 = jnp.tile(rest[0][-W:], (H, 1)).T if warm else None
             out, steps = _pagerank_columns(
                 me, mv, e_src, e_dst, n_pad, damping, tol, max_steps,
-                r_init=r0, tile_budget=tile_budget, pcpm=pc)
+                r_init=r0, tile_budget=tile_budget)
             return out, steps, adv
         if kind == "cc":
             (max_steps,) = algo_args
             l0 = jnp.tile(rest[0][-W:], (H, 1)).T if warm else None
             out, steps = _cc_columns(me, mv, e_src, e_dst, n_pad, max_steps,
-                                     tile_budget=tile_budget, pcpm=pc,
-                                     l_init=l0)
+                                     tile_budget=tile_budget, l_init=l0)
             return out, steps, adv
         max_steps, directed = algo_args
         ew = 1.0
@@ -386,12 +331,12 @@ def _compiled_delta(kind: str, n_pad: int, m_pad: int, H: int, W: int,
         d0 = jnp.tile(rest[nxt][-W:], (H, 1)).T if warm else None
         out, steps = _bfs_columns(me, mv, e_src, e_dst, n_pad, max_steps,
                                   directed, rest[0], ew,  # rest[0]: seeds
-                                  tile_budget=tile_budget, pcpm=pc,
-                                  d_init=d0)
+                                  tile_budget=tile_budget, d_init=d0)
         return out, steps, adv
 
-    return _ledger.instrument(f"hopbatch.delta.{kind}", jax.jit(run),
-                              traffic=_traffic(m_pad, H * W, n_pad, pcpm))
+    return _ledger.instrument(
+        f"hopbatch.delta.{kind}", jax.jit(run),
+        traffic=_ledger.edge_traffic_model(m_pad, H * W, n_pad))
 
 
 def _pad_hop_deltas(deltas, H: int, tdt):
@@ -414,8 +359,7 @@ def run_columns_delta(kind, tables, base, deltas_e, deltas_v, hop_times,
                       windows, *, algo_args: tuple, seed_mask=None,
                       e_src_dev=None, e_dst_dev=None, r_init=None,
                       weight_base=None, weight_deltas=None,
-                      h0_delta: bool = False, ship_counter=None,
-                      layout=None):
+                      h0_delta: bool = False, ship_counter=None):
     """Dispatch a delta-fed columnar kernel (``kind``: pagerank|cc|bfs)
     over ``_HopBatched._fold_deltas`` output; returns ``(result, steps,
     advanced_base)``. ``weight_base`` + ``weight_deltas`` ([(pos, val)]
@@ -423,27 +367,17 @@ def run_columns_delta(kind, tables, base, deltas_e, deltas_v, hop_times,
     device too. ``h0_delta=True`` means ``base`` (and ``weight_base``)
     are the previous dispatch's device-resident advanced state and
     delta[0] carries the inter-batch catch-up — the sweep then ships
-    O(Σ delta) bytes with no full-table upload at all.
-
-    ``layout`` (``ops/partition.PartitionLayout``) routes the dispatch
-    through the destination-binned kernels: pair-side base state is
-    permuted into the binned layout HERE (one O(m) fancy-index, skipped
-    entirely on resident batches whose device base is already binned) and
-    pair delta positions are remapped O(Σ delta); the layout's spec rides
-    into the compiled-program cache key."""
+    O(Σ delta) bytes with no full-table upload at all."""
     H, C, _, T_col, w_col = _column_layout(hop_times, windows)
     W = C // H
     be_lat, be_alive, bv_lat, bv_alive = base
     tdt = tables.tdtype
     weighted = weight_base is not None
     U_w = 0
-    # the dispatch's payload brought into the kernel's layout on the host:
-    # deltas padded to fixed shapes, and (binned route) the base permuted
-    # and the delta positions remapped. ``cached``: the base is already
-    # device-resident in this layout, only O(sum delta) work is left
-    with TRACER.span("engine.layout", stage="payload", cached=h0_delta,
-                     partitions=0 if layout is None
-                     else layout.spec.partitions):
+    # the dispatch's payload brought into the kernel's shapes on the host:
+    # deltas padded to fixed shapes. ``cached``: the base is already
+    # device-resident, only O(sum delta) work is left
+    with TRACER.span("engine.layout", stage="payload", cached=h0_delta):
         U_e, de_pos, de_lat, de_alive = _pad_hop_deltas(deltas_e, H, tdt)
         U_v, dv_pos, dv_lat, dv_alive = _pad_hop_deltas(deltas_v, H, tdt)
         if weighted:
@@ -454,25 +388,10 @@ def run_columns_delta(kind, tables, base, deltas_e, deltas_v, hop_times,
             for h, (p, v) in enumerate(weight_deltas):
                 dw_pos[h, : len(p)] = p
                 dw_val[h, : len(v)] = v
-        if layout is not None:
-            if not h0_delta:
-                # host engine-order base → binned (resident bases are the
-                # previous BINNED dispatch's advanced state, passed
-                # through)
-                be_lat, be_alive = layout.bin_base(be_lat, be_alive)
-                if weighted:
-                    weight_base = layout.bin_values(weight_base)
-            de_pos = layout.remap_positions(de_pos)
-            if weighted:
-                dw_pos = layout.remap_positions(dw_pos)
-            b_src, b_dst, _valid, b_slot, b_usrc, _perm = \
-                layout.device_args()
-            e_src_dev, e_dst_dev = b_src, b_dst
     runner = _compiled_delta(kind, tables.n_pad, tables.m_pad, H, W,
                              U_e, U_v, np.dtype(tdt).name,
                              r_init is not None, tuple(algo_args),
-                             weighted, U_w, h0_delta, _tile_budget_bytes(),
-                             None if layout is None else layout.spec)
+                             weighted, U_w, h0_delta, _tile_budget_bytes())
     if ship_counter is not None:
         # FOLD-STATE host→device payload of THIS dispatch (padded shapes;
         # device-resident inputs — h0 base, cached tables — ship nothing).
@@ -494,15 +413,13 @@ def run_columns_delta(kind, tables, base, deltas_e, deltas_v, hop_times,
         extra.extend((weight_base, dw_pos, dw_val))
     if r_init is not None:
         extra.append(r_init)
-    if layout is not None:
-        extra.extend((b_slot, b_usrc))   # device-resident bucket tables
     # the whole dispatch payload ships through the pipelined engine: array
     # k+1 stages while k is on the wire, each slice retried on transport
     # errors (device-resident inputs pass through untouched)
     from ..utils.transfer import shared_engine
 
     with TRACER.span("hop.compute", kind=kind, hops=H, cols=H * W,
-                        resident_base=h0_delta, pcpm=layout is not None):
+                        resident_base=h0_delta):
         return runner(*shared_engine().put_many([
             e_src_dev if e_src_dev is not None else tables.e_src,
             e_dst_dev if e_dst_dev is not None else tables.e_dst,
@@ -546,15 +463,12 @@ def _edge_accumulate(seg, payload_of, combine, init, e_from, e_to, me, ew,
 
 
 def _cc_columns(me, mv, e_src, e_dst, n_pad: int, max_steps: int,
-                tile_budget: int | None = None, pcpm=None, l_init=None):
+                tile_budget: int | None = None, l_init=None):
     """Columnar min-label propagation — connected components for every
     (hop, window) column at once (semantics of
     ``algorithms/connected_components.py``: undirected min over both
     directions, labels are global padded indices). Shared by the
-    single-device kernel and the column-sharded mesh runner. ``pcpm``
-    switches to the destination-binned operands (``_pagerank_columns``
-    docstring); min reductions are order-exact, so binned results stay
-    BITWISE equal to the unbinned route.
+    single-device kernel and the column-sharded mesh runner.
 
     ``l_init`` ([n_pad, C] i32) warm-starts the propagation from a
     previous epoch's labels: the start is ``min(own index, l_init)``.
@@ -577,19 +491,14 @@ def _cc_columns(me, mv, e_src, e_dst, n_pad: int, max_steps: int,
     def body(carry):
         step, lab, halted = carry
 
-        def pull(idx_from, idx_to, sorted_, pre=None):
-            pay = lambda ef, mk, _: jnp.where(mk, lab[ef, :], I32_MAX)
-            if pre is not None and tile is None and pre[0].preagg:
-                _, slot, u_src = pre
-                vals = lab[u_src, :]                  # bucket gather
-                pay = lambda ef, mk, _: jnp.where(mk, vals[slot, :],
-                                                  I32_MAX)
+        def pull(idx_from, idx_to, sorted_):
             return _edge_accumulate(
-                jax.ops.segment_min, pay,
+                jax.ops.segment_min,
+                lambda ef, mk, _: jnp.where(mk, lab[ef, :], I32_MAX),
                 jnp.minimum, max0, idx_from, idx_to, me, None,
                 n_pad, tile, sorted_)
 
-        agg = jnp.minimum(pull(e_src, e_dst, pcpm is None, pre=pcpm),
+        agg = jnp.minimum(pull(e_src, e_dst, True),
                           pull(e_dst, e_src, False))
         new = jnp.where(mv, jnp.minimum(lab, agg), I32_MAX)
         col_done = jnp.all(new == lab, axis=0)
@@ -610,32 +519,27 @@ def _cc_columns(me, mv, e_src, e_dst, n_pad: int, max_steps: int,
 
 @functools.lru_cache(maxsize=64)
 def _compiled_cc(n_pad: int, m_pad: int, H: int, C: int, max_steps: int,
-                 tdt: str, tile_budget: int | None = None, pcpm=None):
+                 tdt: str, tile_budget: int | None = None):
     tdt = jnp.dtype(tdt)
 
     def run(e_src, e_dst, e_lat, e_alive, v_lat, v_alive,
-            hop_of_col, T_col, w_col, *rest):
+            hop_of_col, T_col, w_col):
         me, mv = _column_masks(tdt, e_lat, e_alive, v_lat, v_alive,
                                hop_of_col, T_col, w_col)
-        pc = None
-        if pcpm is not None:
-            me, pc = _bin_masks(me, (pcpm,) + rest[-4:])
         return _cc_columns(me, mv, e_src, e_dst, n_pad, max_steps,
-                           tile_budget=tile_budget, pcpm=pc)
+                           tile_budget=tile_budget)
 
-    return _ledger.instrument("hopbatch.cc_cols", jax.jit(run),
-                              traffic=_traffic(m_pad, C, n_pad, pcpm))
+    return _ledger.instrument(
+        "hopbatch.cc_cols", jax.jit(run),
+        traffic=_ledger.edge_traffic_model(m_pad, C, n_pad))
 
 
 def _bfs_columns(me, mv, e_src, e_dst, n_pad: int, max_steps: int,
                  directed: bool, seed_mask, ew,
-                 tile_budget: int | None = None, pcpm=None, d_init=None):
+                 tile_budget: int | None = None, d_init=None):
     """Columnar min-plus traversal (``algorithms/traversal.SSSP``
-    semantics); ``ew`` is 1.0 for hop counting or [m_pad, C] f32 weights
-    (BINNED when ``pcpm`` is set, like ``me``/``e_src``/``e_dst`` — see
-    ``_pagerank_columns``). Min-plus is order-exact, so binned results
-    stay bitwise equal. Shared by the single-device kernel and the
-    column-sharded runner.
+    semantics); ``ew`` is 1.0 for hop counting or [m_pad, C] f32 weights.
+    Shared by the single-device kernel and the column-sharded runner.
 
     ``d_init`` ([n_pad, C] f32) warm-starts the relaxation with
     ``min(cold seed, d_init)``: valid whenever every finite ``d_init``
@@ -657,20 +561,15 @@ def _bfs_columns(me, mv, e_src, e_dst, n_pad: int, max_steps: int,
     def body(carry):
         step, dist, halted = carry
 
-        def pull(idx_from, idx_to, sorted_, pre=None):
-            pay = lambda ef, mk, ex: jnp.where(
-                mk, dist[ef, :] + (ew if ex is None else ex), INF)
-            if pre is not None and tile is None and pre[0].preagg:
-                _, slot, u_src = pre
-                vals = dist[u_src, :]                 # bucket gather
-                pay = lambda ef, mk, ex: jnp.where(
-                    mk, vals[slot, :] + (ew if ex is None else ex), INF)
+        def pull(idx_from, idx_to, sorted_):
             return _edge_accumulate(
-                jax.ops.segment_min, pay,
+                jax.ops.segment_min,
+                lambda ef, mk, ex: jnp.where(
+                    mk, dist[ef, :] + (ew if ex is None else ex), INF),
                 jnp.minimum, inf0, idx_from, idx_to, me, ew_arr,
                 n_pad, tile, sorted_)
 
-        agg = pull(e_src, e_dst, pcpm is None, pre=pcpm)
+        agg = pull(e_src, e_dst, True)
         if not directed:
             agg = jnp.minimum(agg, pull(e_dst, e_src, False))
         new = jnp.where(mv, jnp.minimum(dist, agg), INF)
@@ -693,7 +592,7 @@ def _bfs_columns(me, mv, e_src, e_dst, n_pad: int, max_steps: int,
 @functools.lru_cache(maxsize=64)
 def _compiled_bfs(n_pad: int, m_pad: int, H: int, C: int, max_steps: int,
                   directed: bool, tdt: str, weighted: bool = False,
-                  tile_budget: int | None = None, pcpm=None):
+                  tile_budget: int | None = None):
     tdt = jnp.dtype(tdt)
 
     def run(e_src, e_dst, e_lat, e_alive, v_lat, v_alive,
@@ -701,17 +600,13 @@ def _compiled_bfs(n_pad: int, m_pad: int, H: int, C: int, max_steps: int,
         me, mv = _column_masks(tdt, e_lat, e_alive, v_lat, v_alive,
                                hop_of_col, T_col, w_col)
         ew = rest[0][hop_of_col].T if weighted else 1.0   # [m_pad, C]
-        pc = None
-        if pcpm is not None:
-            me, pc = _bin_masks(me, (pcpm,) + rest[-4:])
-            if weighted:
-                ew = ew[rest[-4], :]   # weights follow the edge permutation
         return _bfs_columns(me, mv, e_src, e_dst, n_pad, max_steps,
                             directed, seed_mask, ew,
-                            tile_budget=tile_budget, pcpm=pc)
+                            tile_budget=tile_budget)
 
-    return _ledger.instrument("hopbatch.bfs_cols", jax.jit(run),
-                              traffic=_traffic(m_pad, C, n_pad, pcpm))
+    return _ledger.instrument(
+        "hopbatch.bfs_cols", jax.jit(run),
+        traffic=_ledger.edge_traffic_model(m_pad, C, n_pad))
 
 
 def _seed_mask(tables, seed_vids) -> np.ndarray:
@@ -727,19 +622,10 @@ def _seed_mask(tables, seed_vids) -> np.ndarray:
     return seed_mask
 
 
-def _layout_dispatch_args(layout):
-    """(e_src_dev, e_dst_dev, trailing pcpm args) for a host-column
-    dispatch through the binned kernels — the edge operands become the
-    layout's global ``b_src``/``b_dst`` and the kernels bin the fold-state
-    masks in-program via the appended (perm, valid, slot, u_src)."""
-    b_src, b_dst, valid, slot, u_src, perm = layout.device_args()
-    return b_src, b_dst, (perm, valid, slot, u_src)
-
-
 def run_bfs_columns(tables, e_lat, e_alive, v_lat, v_alive, hop_times,
                     windows, seed_vids, *, directed: bool = False,
                     max_steps: int = 100, e_src_dev=None, e_dst_dev=None,
-                    weight_cols=None, layout=None):
+                    weight_cols=None):
     """Columnar min-plus traversal over prebuilt fold columns;
     ``seed_vids`` are external vertex ids looked up in the global dense
     space (absent ids ignored). ``weight_cols`` ([H, m_pad] f32, missing
@@ -748,13 +634,9 @@ def run_bfs_columns(tables, e_lat, e_alive, v_lat, v_alive, hop_times,
     seed_mask = _seed_mask(tables, seed_vids)
     runner = _compiled_bfs(tables.n_pad, tables.m_pad, H, C, int(max_steps),
                            bool(directed), np.dtype(tables.tdtype).name,
-                           weight_cols is not None, _tile_budget_bytes(),
-                           None if layout is None else layout.spec)
+                           weight_cols is not None, _tile_budget_bytes())
     extra = (seed_mask,) if weight_cols is None \
         else (seed_mask, weight_cols)
-    if layout is not None:
-        e_src_dev, e_dst_dev, pc = _layout_dispatch_args(layout)
-        extra = extra + pc
     return _dispatch_columns(runner, tables,
                              (e_lat, e_alive, v_lat, v_alive),
                              hop_of_col, T_col, w_col, e_src_dev, e_dst_dev,
@@ -763,19 +645,14 @@ def run_bfs_columns(tables, e_lat, e_alive, v_lat, v_alive, hop_times,
 
 def run_cc_columns(tables, e_lat, e_alive, v_lat, v_alive, hop_times,
                    windows, *, max_steps: int = 100,
-                   e_src_dev=None, e_dst_dev=None, layout=None):
+                   e_src_dev=None, e_dst_dev=None):
     """Columnar connected components over prebuilt per-hop fold columns."""
     H, C, hop_of_col, T_col, w_col = _column_layout(hop_times, windows)
     runner = _compiled_cc(tables.n_pad, tables.m_pad, H, C, int(max_steps),
-                          np.dtype(tables.tdtype).name, _tile_budget_bytes(),
-                          None if layout is None else layout.spec)
-    extra = ()
-    if layout is not None:
-        e_src_dev, e_dst_dev, extra = _layout_dispatch_args(layout)
+                          np.dtype(tables.tdtype).name, _tile_budget_bytes())
     return _dispatch_columns(runner, tables,
                              (e_lat, e_alive, v_lat, v_alive),
-                             hop_of_col, T_col, w_col, e_src_dev, e_dst_dev,
-                             *extra)
+                             hop_of_col, T_col, w_col, e_src_dev, e_dst_dev)
 
 
 def _payload_nbytes(obj) -> int:
@@ -812,8 +689,8 @@ class _HopBatched:
         # ``index_status`` says what the lookup cost: "hit" (a fork),
         # "extended" (a suffix adopted first) or "miss" (built here)
         self.sw, self.tables, self.index_status = log_index(log)
-        # cache key for the per-log caches (index, device edge tables,
-        # layout): the CALLER's log object — sw.log is the index's pin,
+        # cache key for the per-log caches (index, device edge tables):
+        # the CALLER's log object — sw.log is the index's pin,
         # replaced whenever the log grows
         self._log = log
         #: host seconds spent folding + writing columns in the LAST run()
@@ -854,13 +731,6 @@ class _HopBatched:
         # so follow-on chunks/batches ship only deltas over the
         # host→device link
         self._dev_base = None
-        # the PCPM layout spec the resident base is expressed in (None =
-        # engine order): a knob flip between batches must drop residency,
-        # never scatter one layout's delta onto the other's state
-        self._dev_base_spec = None
-        # the run's resolved partition layout (ops/partition.py), fixed
-        # for the whole run at its start — None on the unbinned route
-        self._active_layout = None
         # cross-epoch warm seed (run(..., warm_state=...)): initialises
         # the FIRST dispatch's iteration from a previous run's output —
         # the live epoch engine's warm-start channel (jobs/live.py)
@@ -912,8 +782,6 @@ class _HopBatched:
             self._drop_residency()
             raise
         self._dev_base = adv
-        self._dev_base_spec = (None if self._active_layout is None
-                               else self._active_layout.spec)
         # resident-buffer gauge (obs/device.py): the advanced base is
         # what the next batch scatters onto instead of shipping a full
         # snapshot — a live row, re-upserted per delta dispatch
@@ -939,23 +807,6 @@ class _HopBatched:
         if not self.tables.holds_times(self.sw._t[n_old:]):
             return "rebuild"   # suffix overflows the narrowed time dtype
         return "extended"
-
-    def _sync_layout(self):
-        """Resolve the partition layout ONCE per run (``RTPU_PCPM`` /
-        ``RTPU_PARTITIONS`` are dispatch-time knobs), and drop the
-        device-resident advanced base when it is expressed in a different
-        edge layout than this run will dispatch in — a catch-up delta
-        remapped for one layout scattered onto the other's state would be
-        silently wrong, not slow."""
-        from ..ops import partition as _partition
-
-        lay = _partition.resolve(self._log, self.tables,
-                                 _tile_budget_bytes())
-        spec = None if lay is None else lay.spec
-        if self._dev_base is not None and self._dev_base_spec != spec:
-            self._drop_residency()
-        self._active_layout = lay
-        return lay
 
     #: set True by subclasses whose iteration is a contraction (safe to
     #: warm-start from the previous chunk's solution)
@@ -1056,7 +907,6 @@ class _HopBatched:
             with TRACER.span("sweep.columnar",
                                 engine=type(self).__name__,
                                 hops=len(hop_times), chunks=chunks) as sp:
-                self._sync_layout()
                 out = self._run_chunks(hop_times, windows, chunks,
                                        warm_start, hop_callback)
                 self.last_phase_seconds = sweep_phase_summary(
@@ -1326,7 +1176,6 @@ class _HopBatched:
         to what ``run(hop_times, ..., chunks=chunks)`` would dispatch."""
         hop_times = [int(x) for x in hop_times]
         chunks = max(1, min(int(chunks), len(hop_times)))
-        self._sync_layout()
         if chunks > 1 and len(hop_times) % chunks:
             chunks = 1
         per = len(hop_times) // chunks
@@ -1780,8 +1629,7 @@ class HopBatchedPageRank(_HopBatched):
         return run_columns(
             self.tables, *cols, hop_times, windows,
             damping=self.damping, tol=self.tol, max_steps=self.max_steps,
-            e_src_dev=self._e_src, e_dst_dev=self._e_dst, r_init=r_init,
-            layout=self._active_layout)
+            e_src_dev=self._e_src, e_dst_dev=self._e_dst, r_init=r_init)
 
     def _dispatch_deltas(self, payload, hop_times, windows, r_init=None):
         base, deltas_e, deltas_v = payload
@@ -1792,8 +1640,7 @@ class HopBatchedPageRank(_HopBatched):
             algo_args=(float(self.damping), float(self.tol),
                        int(self.max_steps)),
             e_src_dev=self._e_src, e_dst_dev=self._e_dst, r_init=r_init,
-            h0_delta=h0, ship_counter=self._count_ship,
-            layout=self._active_layout))
+            h0_delta=h0, ship_counter=self._count_ship))
 
 
 class HopBatchedBFS(_HopBatched):
@@ -1831,8 +1678,7 @@ class HopBatchedBFS(_HopBatched):
         return run_bfs_columns(
             self.tables, *cols, hop_times, windows, self.seeds,
             directed=self.directed, max_steps=self.max_steps,
-            e_src_dev=self._e_src, e_dst_dev=self._e_dst,
-            layout=self._active_layout)
+            e_src_dev=self._e_src, e_dst_dev=self._e_dst)
 
     def _dispatch_deltas(self, payload, hop_times, windows, r_init=None):
         # r_init is the cross-epoch warm seed (min-merged distances);
@@ -1845,7 +1691,7 @@ class HopBatchedBFS(_HopBatched):
             algo_args=(int(self.max_steps), bool(self.directed)),
             seed_mask=self._seed, r_init=r_init,
             e_src_dev=self._e_src, e_dst_dev=self._e_dst, h0_delta=h0,
-            ship_counter=self._count_ship, layout=self._active_layout))
+            ship_counter=self._count_ship))
 
 
 class HopBatchedSSSP(HopBatchedBFS):
@@ -2044,7 +1890,7 @@ class HopBatchedSSSP(HopBatchedBFS):
             self.tables, *base, hop_times, windows, self.seeds,
             directed=self.directed, max_steps=self.max_steps,
             e_src_dev=self._e_src, e_dst_dev=self._e_dst,
-            weight_cols=wcols, layout=self._active_layout)
+            weight_cols=wcols)
 
     def _dispatch_deltas(self, payload, hop_times, windows, r_init=None):
         # never warm-started: a weight update can RAISE a pair's weight,
@@ -2061,7 +1907,7 @@ class HopBatchedSSSP(HopBatchedBFS):
             seed_mask=self._seed,
             e_src_dev=self._e_src, e_dst_dev=self._e_dst,
             weight_base=w_base, weight_deltas=w_deltas, h0_delta=h0,
-            ship_counter=self._count_ship, layout=self._active_layout))
+            ship_counter=self._count_ship))
 
 
 class HopBatchedCC(_HopBatched):
@@ -2085,15 +1931,14 @@ class HopBatchedCC(_HopBatched):
             hop_times, windows, algo_args=(int(self.max_steps),),
             r_init=r_init,
             e_src_dev=self._e_src, e_dst_dev=self._e_dst, h0_delta=h0,
-            ship_counter=self._count_ship, layout=self._active_layout))
+            ship_counter=self._count_ship))
 
     def _dispatch_cols(self, cols, hop_times, windows, r_init=None):
         assert r_init is None   # guarded by supports_warm_start
         return run_cc_columns(
             self.tables, *cols, hop_times, windows,
             max_steps=self.max_steps,
-            e_src_dev=self._e_src, e_dst_dev=self._e_dst,
-            layout=self._active_layout)
+            e_src_dev=self._e_src, e_dst_dev=self._e_dst)
 
 
 def _dispatch_columns(runner, tables, cols, hop_of_col, T_col,
@@ -2116,7 +1961,7 @@ def _dispatch_columns(runner, tables, cols, hop_of_col, T_col,
 def _compiled_scale(n_pad: int, m_pad: int, H: int, W: int, U_e: int,
                     U_v: int, damping: float, tol: float, max_steps: int,
                     scan_masks: bool = False,
-                    tile_budget: int | None = None, pcpm=None):
+                    tile_budget: int | None = None):
     """Scale variant of the columnar PageRank: per-hop fold state is
     REBUILT ON DEVICE from the base state plus per-hop update lists, so a
     sweep ships O(base + deltas) bytes instead of O(m_pad * H) — at
@@ -2133,23 +1978,11 @@ def _compiled_scale(n_pad: int, m_pad: int, H: int, W: int, U_e: int,
     the fallback shape for remote compilers that choke on the unrolled
     program (RTPU_SCALE_MASKS=scan); results are identical (tested)."""
 
-    def run(e_src, e_dst, base_e, base_v, de_pos, de_t, dv_pos, dv_t, thr,
-            *rest):
+    def run(e_src, e_dst, base_e, base_v, de_pos, de_t, dv_pos, dv_t, thr):
         thr_hw = thr.reshape(H, W)
-        # binned variant: hop state still advances in ENGINE order (the
-        # update lists target engine positions) — only the mask COLUMNS
-        # are emitted through the layout permutation, one cheap 1-D
-        # gather of the running scatter-max per hop
-        pc = perm = valid = None
-        if pcpm is not None:
-            perm, valid, slot, u_src = rest
-            pc = (pcpm, slot, u_src)
 
-        def hop_masks(base, d_pos, d_t, bin_rows: bool):
+        def hop_masks(base, d_pos, d_t):
             def col_of(cur, th):
-                if bin_rows and perm is not None:
-                    return (cur[perm][:, None] >= th[None, :]) \
-                        & valid[:, None]
                 return cur[:, None] >= th[None, :]
 
             if scan_masks:
@@ -2167,14 +2000,15 @@ def _compiled_scale(n_pad: int, m_pad: int, H: int, W: int, U_e: int,
                 cur = cur.at[d_pos[h]].max(d_t[h])
                 cols.append(col_of(cur, thr[h * W:(h + 1) * W]))
             return jnp.concatenate(cols, axis=1)   # [len, H*W] hop-major
-        me = hop_masks(base_e, de_pos, de_t, True)
-        mv = hop_masks(base_v, dv_pos, dv_t, False)
+        me = hop_masks(base_e, de_pos, de_t)
+        mv = hop_masks(base_v, dv_pos, dv_t)
         return _pagerank_columns(me, mv, e_src, e_dst, n_pad,
                                  damping, tol, max_steps,
-                                 tile_budget=tile_budget, pcpm=pc)
+                                 tile_budget=tile_budget)
 
-    return _ledger.instrument("hopbatch.pagerank_scale", jax.jit(run),
-                              traffic=_traffic(m_pad, H * W, n_pad, pcpm))
+    return _ledger.instrument(
+        "hopbatch.pagerank_scale", jax.jit(run),
+        traffic=_ledger.edge_traffic_model(m_pad, H * W, n_pad))
 
 
 def _delta_fingerprint(deltas_e, deltas_v) -> tuple:
@@ -2280,27 +2114,15 @@ def run_scale_columns(bulk, base_e, base_v, deltas_e, deltas_v, hop_times,
                 "mislabelled; re-run prepare_scale_payload on these deltas")
     import os
 
-    from ..ops import partition as _partition
-
     scan_masks = os.environ.get("RTPU_SCALE_MASKS", "unroll") == "scan"
-    budget = _tile_budget_bytes()
-    # RTPU_PCPM / RTPU_PARTITIONS resolved here, at dispatch — the spec
-    # carries both knobs into the compiled-program cache key
-    layout = _partition.resolve(bulk, bulk, budget)
-    extra = ()
-    if layout is not None:
-        b_src, b_dst, valid, slot, u_src, perm = layout.device_args()
-        e_src_dev, e_dst_dev = b_src, b_dst
-        extra = (perm, valid, slot, u_src)
     runner = _compiled_scale(bulk.n_pad, bulk.m_pad, H, W, U_e, U_v,
                              float(damping), float(tol), int(max_steps),
-                             scan_masks, budget,
-                             None if layout is None else layout.spec)
+                             scan_masks, _tile_budget_bytes())
     return runner(
         e_src_dev if e_src_dev is not None else jnp.asarray(bulk.e_src),
         e_dst_dev if e_dst_dev is not None else jnp.asarray(bulk.e_dst),
         jnp.asarray(base_e), jnp.asarray(base_v),
-        de_pos, de_t, dv_pos, dv_t, thr, *extra)
+        de_pos, de_t, dv_pos, dv_t, thr)
 
 
 def _column_layout(hop_times, windows):
@@ -2354,7 +2176,7 @@ def stack_grids(grids):
 def run_columns(tables, e_lat, e_alive, v_lat, v_alive, hop_times, windows,
                 *, damping: float = 0.85, tol: float = 1e-7,
                 max_steps: int = 20, e_src_dev=None, e_dst_dev=None,
-                r_init=None, layout=None):
+                r_init=None):
     """Dispatch the columnar PageRank over prebuilt per-hop fold columns —
     shared by the incremental-fold class above and the add-only bulk loader
     (``core/bulk.bulk_hop_columns``). `tables` needs the GlobalTables /
@@ -2366,12 +2188,8 @@ def run_columns(tables, e_lat, e_alive, v_lat, v_alive, hop_times, windows,
     runner = _compiled(tables.n_pad, tables.m_pad, H, C, float(damping),
                        float(tol), int(max_steps),
                        np.dtype(tables.tdtype).name, r_init is not None,
-                       _tile_budget_bytes(),
-                       None if layout is None else layout.spec)
+                       _tile_budget_bytes())
     extra = () if r_init is None else (r_init,)
-    if layout is not None:
-        e_src_dev, e_dst_dev, pc = _layout_dispatch_args(layout)
-        extra = extra + pc
     return _dispatch_columns(runner, tables,
                              (e_lat, e_alive, v_lat, v_alive),
                              hop_of_col, T_col, w_col, e_src_dev, e_dst_dev,

@@ -511,7 +511,7 @@ def rule_device_pressure(sig: dict) -> dict | None:
                 "spills or OOMs",
                 "RTPU_TILE_BUDGET_MB",
                 "lower RTPU_TILE_BUDGET_MB (shrinks the columnar edge "
-                "tile), raise RTPU_PARTITIONS, or shed resident engines "
+                "tile), or shed resident engines "
                 "(see the /devicez resident registry for what is "
                 "pinned)",
                 {"memory": mem,
@@ -602,7 +602,7 @@ def rule_shard_skew(sig: dict) -> dict | None:
     name, kind, val = worst
     # route evidence: if the chooser is already taking the sparse route
     # (or frontier densities say it should), say so — the remediation
-    # differs between "re-partition" and "let the sparse route absorb it"
+    # differs between "nothing to turn" and "let the sparse route absorb it"
     route_counts: dict[str, int] = {}
     density: dict[str, float] = {}
     for n, p in rows.items():
@@ -616,16 +616,17 @@ def rule_shard_skew(sig: dict) -> dict | None:
     sparse_fits = any(d < 1.0 / 3.0 for d in density.values())
     if sparse_taken:
         fix = ("the sparse frontier route is already absorbing the skew "
-               "(docs/COMM.md) — if bytes stay high, re-balance with "
-               "RTPU_PARTITIONS")
+               "(docs/COMM.md): no knob re-balances the static vertex "
+               "ranges")
     elif sparse_fits:
         fix = ("frontier density is under the sparse crossover — set "
                "RTPU_COMM_ROUTE=auto (or =sparse) so min-merge sweeps "
                "exchange compacted frontiers instead of dense state "
-               "(docs/COMM.md), or re-balance with RTPU_PARTITIONS")
+               "(docs/COMM.md)")
     else:
-        fix = ("re-balance: raise RTPU_PARTITIONS; dense frontiers keep "
-               "the sparse route out of crossover here (docs/COMM.md)")
+        fix = ("dense frontiers keep the sparse route out of crossover "
+               "here (docs/COMM.md), and no knob re-balances the static "
+               "vertex ranges: leave RTPU_COMM_ROUTE=auto")
     return _finding(
         "shard-skew",
         f"{name} reports {kind} partition skew {val:.1f}x (max/mean "

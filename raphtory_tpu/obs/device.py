@@ -1,7 +1,7 @@
 """Device runtime plane — the MEASURED half of the ledger.
 
 Everything the ledger (``obs/ledger.py``) knows about the device is a
-compile-time estimate: XLA ``cost_analysis()`` FLOPs/bytes and the PCPM
+compile-time estimate: XLA ``cost_analysis()`` FLOPs/bytes and the edge
 traffic model, never a clock or a memory counter. This module is the
 counterpart that measures — the instrument the adaptive runtime
 (ROADMAP item 4) needs before it can trust the model it actuates on.

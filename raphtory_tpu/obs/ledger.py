@@ -4,7 +4,7 @@ The tracing layer (``obs/trace.py``) answers *when* time goes and the
 fold metrics answer *one* subsystem; nothing could answer "what did this
 query cost, which resource is it bound on, and did HEAD regress?" — the
 accounting the serving scheduler (admission control sized by measured
-cost) and the PCPM kernel work (per-kernel HBM-bytes evidence that the
+cost) and the kernel work (per-kernel HBM-bytes evidence that the
 hop kernels are gather-bound, arXiv:1709.07122) both block on. Three
 pieces:
 
@@ -339,13 +339,12 @@ class KernelRegistry:
         any failure leaves the record in host-side mode.
 
         ``traffic`` is an optional ENGINE-SIDE DRAM traffic model
-        (``ops/partition.edge_traffic_model``): XLA's ``bytes_accessed``
-        sums logical operand bytes and is blind to access LOCALITY, so a
-        partition-binned kernel that turns random cacheline traffic into
-        cache-resident streams harvests the same (or higher) logical
-        bytes. The model supplies ``est_hbm_bytes`` — what the kernel is
-        expected to move through DRAM — and the record carries BOTH, plus
-        a ``bound_refined`` classification over the modelled bytes
+        (:func:`edge_traffic_model`): XLA's ``bytes_accessed`` sums
+        logical operand bytes and is blind to access LOCALITY — a random
+        row gather moves whole lines, not the row's bytes. The model
+        supplies ``est_hbm_bytes`` — what the kernel is expected to move
+        through DRAM — and the record carries BOTH, plus a
+        ``bound_refined`` classification over the modelled bytes
         (docs/OBSERVABILITY.md "Cost ledger")."""
         rec = self._ensure(name, sig)
         if traffic:
@@ -497,6 +496,35 @@ class InstrumentedKernel:
     # wrapped callable reachable for debugging
     def __repr__(self):
         return f"InstrumentedKernel({self.name!r})"
+
+
+#: modelled cache a random-access working set must outgrow before a row
+#: access costs a full line, and the DRAM access granularity — the two
+#: constants of :func:`edge_traffic_model`
+CACHE_BYTES = 2 << 20
+CACHELINE = 64
+
+
+def edge_traffic_model(m_pad: int, C: int, n_pad: int,
+                       itemsize: int = 4) -> dict:
+    """Modelled DRAM bytes of ONE message-combine superstep over the
+    dst-sorted pair table — the locality-aware refinement of the
+    locality-blind XLA ``bytes_accessed`` harvest. A random access into an
+    operand whose working set exceeds :data:`CACHE_BYTES` costs a full
+    :data:`CACHELINE`; streamed operands cost their payload bytes once.
+
+    Every edge gathers a state row at random (all the lines the row spans
+    move) and scatter-ADDS a row at random — a read-modify-write, so the
+    touched lines move TWICE; the ids and the bool mask stream."""
+    row = C * itemsize
+    rand = row
+    if n_pad * row > CACHE_BYTES:
+        rand = -(-row // CACHELINE) * CACHELINE
+    streamed = m_pad * (2 * 4 + C)   # ids + bool mask
+    return {"model": "edge_superstep", "columns": int(C),
+            "random_rows": int(2 * m_pad),
+            "streamed_bytes": int(streamed),
+            "est_hbm_bytes": int(3 * m_pad * rand + streamed)}
 
 
 def instrument(name: str, fn,
@@ -1014,8 +1042,8 @@ def costz() -> dict:
             "intensity = flops / bytes_accessed; hbm_bound if intensity "
             "< ridge else compute_bound; unknown without harvested "
             "analysis. bound_refined repeats the rule over est_hbm_bytes "
-            "— the engine-side partition-aware DRAM traffic model "
-            "(ops/partition.edge_traffic_model) where one is attached, "
+            "— the engine-side locality-aware DRAM traffic model "
+            "(obs/ledger.edge_traffic_model) where one is attached, "
             "since XLA's bytes_accessed is blind to access locality"),
         "kernels": kernels,
         "kernels_by_bound": KernelRegistry.bound_counts(kernels),

@@ -248,7 +248,7 @@ class Metrics:
             "compute) — the phase breakdown the span tracer also attaches "
             "to every sweep span", ["phase"], registry=r)
         # per-query resource ledger (obs/ledger.py): what a query COST,
-        # by algorithm — the accounting admission control and the PCPM
+        # by algorithm — the accounting admission control and the
         # kernel work size themselves from
         # SLO surface (obs/slo.py): per-request end-to-end latency by
         # algorithm and phase, bucketed on the SAME grid as the stdlib

@@ -58,42 +58,6 @@ def segment_combine(
     )
 
 
-def partition_segment_reduce(
-    data: jnp.ndarray,
-    local_ids: jnp.ndarray,
-    n_per: int,
-    num_segments: int,
-    op: str = "sum",
-    mask: jnp.ndarray | None = None,
-):
-    """Partition-blocked segment reduction — the PCPM combine primitive
-    (``ops/partition.py``; docs/KERNELS.md).
-
-    ``data`` is ``[P, cap, ...]`` destination-binned edge payloads and
-    ``local_ids`` ``[P, cap]`` the in-partition destination rows
-    (``dst - p * n_per``). Each partition reduces into its own DENSE
-    ``n_per``-row block — P independent small reductions XLA can pipeline,
-    each with a cache-resident accumulator slice, instead of one scatter
-    whose target spans the whole vertex space. The blocks concatenate to
-    ``[P * n_per, ...]`` and slice to ``num_segments`` (the last partition
-    may overhang a non-dividing vertex count).
-
-    Masked rows are replaced with the combiner's neutral element, so
-    cap-padding and window-dead edges are no-ops. Sum results equal a flat
-    ``segment_sum`` up to f32 reduction order; min/max are order-exact.
-    """
-    if op not in _SEG:
-        raise ValueError(f"unknown combiner {op!r}; use one of {sorted(_SEG)}")
-    if mask is not None:
-        m = mask.reshape(mask.shape + (1,) * (data.ndim - mask.ndim))
-        data = jnp.where(m, data, neutral(op, data.dtype))
-    P = data.shape[0]
-    seg = _SEG[op]
-    out = jax.vmap(
-        lambda d, i: seg(d, i, num_segments=n_per))(data, local_ids)
-    return out.reshape((P * n_per,) + data.shape[2:])[:num_segments]
-
-
 _V_BITS = 31  # segment_mode value budget: non-negative ints < 2**31
 
 

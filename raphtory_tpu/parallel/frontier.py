@@ -15,11 +15,10 @@ node before crossing the expensive link):
   in-direction), so a row's complete aggregate is computed entirely by
   its owner — the per-process kernel IS the node-aware pre-aggregation
   stage: contributions from every locally-owned shard and both edge
-  directions min-merge on the host's device before anything reaches DCN
-  (``ops/partition`` bucket discipline, applied to the comm plane).
+  directions min-merge on the host's device before anything reaches DCN.
 * The changed-since-last-superstep rows are compacted host-side into a
   ``(indices, values)`` slice, padded to a bucketed power-of-two length
-  (``ops.partition.frontier_bucket``, floor ``RTPU_SPARSE_BUCKETS``) so
+  (:func:`frontier_bucket`, floor ``RTPU_SPARSE_BUCKETS``) so
   the ``process_allgather`` shape set stays bounded — no compile storm
   as the frontier grows and collapses (rtpulint RT013 discipline for
   collective shapes).
@@ -50,7 +49,6 @@ import numpy as np
 from ..engine.bsp import _merge_aggs
 from ..engine.program import Context, Edges, VertexProgram
 from ..obs import ledger as _ledger
-from ..ops.partition import frontier_bucket, sparse_bucket_floor
 from ..ops.segment import segment_combine
 
 #: global frontier density past which a sparse slot (index + value) moves
@@ -64,6 +62,45 @@ CROSSOVER_DENSITY = 1.0 / 3.0
 #: algorithms are sparse by construction, so the first auto dispatch
 #: goes sparse and measures itself
 PRIOR_DENSITY = 0.05
+
+
+#: default floor for sparse-frontier slice buckets (slots). Small enough
+#: that a near-quiescent superstep ships ~KBs; large enough that the
+#: power-of-two ladder above it has only ~log2(n/floor) rungs, so the
+#: collective shape set — and with it the process_allgather compile-key
+#: set — stays bounded (docs/COMM.md "bucketed padding").
+SPARSE_BUCKET_FLOOR = 256
+
+
+def sparse_bucket_floor() -> int:
+    """Resolved ``RTPU_SPARSE_BUCKETS`` (slot floor for frontier-slice
+    buckets). Read HERE, at dispatch time, by the sparse comm route —
+    never from inside a compiled-program cache factory (rtpulint RT001);
+    the resolved bucket length reaches collective shapes as an argument."""
+    import os
+
+    try:
+        v = int(os.environ.get("RTPU_SPARSE_BUCKETS", SPARSE_BUCKET_FLOOR))
+    except ValueError:
+        v = SPARSE_BUCKET_FLOOR
+    return max(8, v)
+
+
+def frontier_bucket(count: int, floor: int | None = None,
+                    cap: int | None = None) -> int:
+    """Bucketed capacity for a compacted frontier slice: the smallest
+    power of two >= ``count``, floored at ``floor`` slots (default: the
+    resolved ``RTPU_SPARSE_BUCKETS``), so every frontier size in a
+    power-of-two band reuses one collective shape. ``cap`` (when given)
+    bounds the bucket from above — the dense-slice size, past which
+    padding buys nothing."""
+    floor = sparse_bucket_floor() if floor is None else max(1, int(floor))
+    b = floor
+    while b < count:
+        b <<= 1
+    if cap is not None:
+        b = min(b, max(int(cap), 1))
+    return b
 
 
 def supported(program: VertexProgram) -> bool:

@@ -351,7 +351,7 @@ def choose_route(program, view, sv, mesh, requested: str, k: int,
         "all_gather": (view.n_pad - sv.n_loc) * k * item * n_dev,
         "halo": sv.halo_rows(program.direction) * k * item * n_dev,
         "sparse": max(density * k * view.n_pad * slot,
-                      _partition_floor() * n_procs * slot),
+                      _frontier.sparse_bucket_floor() * n_procs * slot),
     }
     dense_pick = _dense_auto(sv, view, program, S)
 
@@ -426,12 +426,6 @@ def choose_route(program, view, sv, mesh, requested: str, k: int,
             "route_history": hist,
         },
     }
-
-
-def _partition_floor() -> int:
-    from ..ops.partition import sparse_bucket_floor
-
-    return sparse_bucket_floor()
 
 
 def _shard_map(fn, *, mesh, in_specs, out_specs):

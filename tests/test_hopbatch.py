@@ -13,6 +13,42 @@ from raphtory_tpu.engine.hopbatch import HopBatchedPageRank
 from test_sweep import random_log
 
 
+def _assert_columns_match_per_view(hb, cols, program, log, hops, windows,
+                                   agree):
+    """Every column of a columnar result against ``bsp.run`` over that
+    hop's ``build_view`` — this file's per-view oracle, all of a view's
+    windowed vertices at once. ``agree(want, got, view, uv)``
+    compares the two value vectors over those vertices."""
+    cols = np.asarray(cols)
+    assert cols.shape == (len(hops) * len(windows), hb.tables.n_pad)
+    for j, T in enumerate(hops):
+        view = build_view(log, T)
+        want, _ = bsp.run(program, view,
+                          windows=[-1 if w is None else w for w in windows])
+        want = np.asarray(want)
+        pos = np.searchsorted(hb.tables.uv, view.vids)
+        for i, w in enumerate(windows):
+            mask = (np.asarray(view.v_mask) if w is None
+                    else view.window_masks([w])[0][0])
+            agree(want[i][mask], cols[j * len(windows) + i][pos[mask]],
+                  view, hb.tables.uv)
+
+
+def _close(atol):
+    def agree(want, got, view, uv):
+        np.testing.assert_allclose(got, want, atol=atol, rtol=0)
+    return agree
+
+
+def _same(want, got, view, uv):
+    np.testing.assert_array_equal(want, got)     # inf == inf
+
+
+def _same_component(want, got, view, uv):
+    # both label spaces decode to the component's min vid
+    np.testing.assert_array_equal(view.vids[want], uv[got])
+
+
 @pytest.mark.parametrize("seed", [0, 5])
 def test_hopbatch_matches_per_view_pagerank(seed):
     rng = np.random.default_rng(seed)
@@ -21,25 +57,9 @@ def test_hopbatch_matches_per_view_pagerank(seed):
     windows = [100, 30, None]
     hb = HopBatchedPageRank(log, tol=1e-7, max_steps=20)
     ranks, steps = hb.run(hops, windows)
-    ranks = np.asarray(ranks)
-    assert ranks.shape == (len(hops) * len(windows), hb.tables.n_pad)
-
-    pr = PageRank(max_steps=20, tol=1e-7)
-    for j, T in enumerate(hops):
-        view = build_view(log, T)
-        want, _ = bsp.run(pr, view,
-                          windows=[w if w is not None else -1
-                                   for w in windows])
-        for i, w in enumerate(windows):
-            col = ranks[j * len(windows) + i]
-            mask = (np.asarray(view.v_mask) if w is None
-                    else view.window_masks([w])[0][0])
-            for vi, vid in enumerate(view.vids):
-                if not mask[vi]:
-                    continue
-                p = int(np.searchsorted(hb.tables.uv, vid))
-                assert float(np.asarray(want)[i, vi]) == pytest.approx(
-                    float(col[p]), abs=2e-5), (T, w, int(vid))
+    _assert_columns_match_per_view(hb, ranks, PageRank(max_steps=20,
+                                                       tol=1e-7),
+                                   log, hops, windows, _close(2e-5))
 
 
 def test_hopbatch_rejects_unsorted_hops_and_is_reusable():
@@ -72,23 +92,9 @@ def test_hopbatch_cc_matches_per_view(seed):
     windows = [100, 20]
     hb = HopBatchedCC(log, max_steps=60)
     labels, _ = hb.run(hops, windows)
-    labels = np.asarray(labels)
-
-    cc = ConnectedComponents(max_steps=60)
-    for j, T in enumerate(hops):
-        view = build_view(log, T)
-        want, _ = bsp.run(cc, view, windows=windows)
-        for i, w in enumerate(windows):
-            col = labels[j * len(windows) + i]
-            mask = view.window_masks([w])[0][0]
-            # both label spaces decode to the component's min vid
-            for vi, vid in enumerate(view.vids):
-                if not mask[vi]:
-                    continue
-                rep_view = int(view.vids[int(np.asarray(want)[i, vi])])
-                p = int(np.searchsorted(hb.tables.uv, vid))
-                rep_hb = int(hb.tables.uv[int(col[p])])
-                assert rep_view == rep_hb, (T, w, int(vid))
+    _assert_columns_match_per_view(hb, labels,
+                                   ConnectedComponents(max_steps=60),
+                                   log, hops, windows, _same_component)
 
 
 @pytest.mark.parametrize("directed", [False, True])
@@ -103,24 +109,10 @@ def test_hopbatch_bfs_matches_per_view(directed):
     seeds = (0, 1, 2)
     hb = HopBatchedBFS(log, seeds, directed=directed, max_steps=40)
     dist, _ = hb.run(hops, windows)
-    dist = np.asarray(dist)
-
     bfs = SSSP(seeds=seeds, weight_prop=None, directed=directed,
                max_steps=40)
-    for j, T in enumerate(hops):
-        view = build_view(log, T)
-        want, _ = bsp.run(bfs, view, windows=windows)
-        for i, w in enumerate(windows):
-            col = dist[j * len(windows) + i]
-            mask = view.window_masks([w])[0][0]
-            for vi, vid in enumerate(view.vids):
-                if not mask[vi]:
-                    continue
-                p = int(np.searchsorted(hb.tables.uv, vid))
-                a = float(np.asarray(want)[i, vi])
-                b = float(col[p])
-                assert (np.isinf(a) and np.isinf(b)) or a == b, \
-                    (T, w, int(vid), a, b)
+    _assert_columns_match_per_view(hb, dist, bfs, log, hops, windows,
+                                   _same)
 
 
 @pytest.mark.parametrize("chunks", [2, 3, 6])
@@ -574,3 +566,263 @@ def test_ship_bytes_accounting(monkeypatch):
     # so it ships less than one base snapshot (and less than run 1)
     hb.run([8_900, 9_000], [3_000])
     assert hb.ship_bytes < base_bytes and hb.ship_bytes < run1
+
+
+# ---------------------------------------------------------------------------
+# one edge table at the widths the benchmark's cells dispatch
+
+
+def _weighted_delete_log():
+    from raphtory_tpu.core.events import EventLog
+
+    rng = np.random.default_rng(4)
+    log = EventLog()
+    for _ in range(400):
+        s, d = int(rng.integers(0, 25)), int(rng.integers(0, 25))
+        log.add_edge(int(rng.integers(0, 60)), s, d,
+                     {"w": float(rng.uniform(0.5, 3.0))})
+        if rng.random() < 0.15:
+            log.delete_edge(int(rng.integers(0, 60)), s, d)
+    return log
+
+
+def _family(name):
+    """(logs, engine of a log, per-view program, agree) of one columnar
+    family, on adversarial delete/tombstone logs."""
+    from raphtory_tpu.algorithms import SSSP, ConnectedComponents
+    from raphtory_tpu.engine.hopbatch import (HopBatchedBFS, HopBatchedCC,
+                                              HopBatchedSSSP)
+
+    def logs(seeds, **kw):
+        return [random_log(np.random.default_rng(s), **kw) for s in seeds]
+
+    if name == "pagerank":
+        return (logs((0, 7), n_events=600, n_ids=40, t_span=80),
+                lambda log: HopBatchedPageRank(log, tol=1e-7, max_steps=20),
+                PageRank(max_steps=20, tol=1e-7), _close(2e-6))
+    if name == "cc":
+        return (logs((1, 9), n_events=500, n_ids=35, t_span=70),
+                lambda log: HopBatchedCC(log, max_steps=60),
+                ConnectedComponents(max_steps=60), _same_component)
+    if name.startswith("bfs"):
+        directed = name == "bfs-directed"
+        return (logs((6,), n_events=400, n_ids=30, t_span=60),
+                lambda log: HopBatchedBFS(log, (0, 1, 2), directed=directed,
+                                          max_steps=40),
+                SSSP(seeds=(0, 1, 2), weight_prop=None, directed=directed,
+                     max_steps=40), _same)
+    return ([_weighted_delete_log()],
+            lambda log: HopBatchedSSSP(log, (0, 1), "w", directed=False,
+                                       max_steps=40),
+            SSSP(seeds=(0, 1), weight_prop="w", directed=False,
+                 max_steps=40), _same)
+
+
+#: C columns as (hops, windows): 1 = the Live and View dispatch, 3 = a mesh
+#: chip's share of a Range, 6 = the one-chip Range chunk, 12 = the whole
+#: Range in one dispatch (ROADMAP S1 (ii))
+CELL_WIDTHS = {1: ([59], [30]), 3: ([59], [100, 30, None]),
+               6: ([25, 59], [100, 30, None]),
+               12: ([20, 45, 46, 59], [100, 30, None])}
+
+
+@pytest.mark.parametrize("C", sorted(CELL_WIDTHS))
+@pytest.mark.parametrize("family", ["pagerank", "cc", "bfs-directed",
+                                    "bfs-undirected", "sssp-weighted"])
+def test_columnar_matches_per_view_at_cell_widths(family, C):
+    """The columnar kernel over the dst-sorted pair table agrees with the
+    per-view engine at every column count a cell dispatches: PageRank to
+    reduction-order tolerance, the min-merge families bitwise."""
+    hops, windows = CELL_WIDTHS[C]
+    logs, engine, program, agree = _family(family)
+    for log in logs:
+        hb = engine(log)
+        out, _ = hb.run(hops, windows)
+        _assert_columns_match_per_view(hb, out, program, log, hops,
+                                       windows, agree)
+
+
+@pytest.fixture(scope="module")
+def wide_log():
+    """> 2^16 distinct pairs: past the floor under which nothing tiles."""
+    from raphtory_tpu.utils.synth import gab_like_log
+
+    return gab_like_log(n_vertices=6000, n_edges=150_000, t_span=1000)
+
+
+@pytest.mark.parametrize("C", [1, 12])
+@pytest.mark.parametrize("family", ["pagerank", "cc"])
+def test_edge_tiled_matches_single_shot_at_cell_widths(monkeypatch,
+                                                       wide_log, family, C):
+    """The ``lax.scan`` over edge tiles PLUS a remainder slice (a tile
+    that does not divide ``m_pad``) against the single-shot kernel, at the
+    narrowest and the widest dispatch: PageRank to f32 reassociation
+    tolerance, min-label propagation exactly."""
+    from raphtory_tpu.engine import hopbatch as hb_mod
+    from raphtory_tpu.engine.hopbatch import HopBatchedCC
+
+    hops, windows = {1: ([900], [200]),
+                     12: ([400, 600, 800, 999], [1000, 200, None])}[C]
+    engine = {"pagerank": lambda: HopBatchedPageRank(wide_log, tol=0.0,
+                                                     max_steps=6),
+              "cc": lambda: HopBatchedCC(wide_log, max_steps=30)}[family]
+    hb = engine()
+    one, s1 = hb.run(hops, windows)
+    m_pad, tiles = hb.tables.m_pad, []
+
+    def odd_tile(m_pad, C, budget_bytes):
+        tiles.append((m_pad, 40_000))
+        return 40_000
+
+    monkeypatch.setattr(hb_mod, "_edge_tile_for", odd_tile)
+    hb_mod._compiled_delta.cache_clear()    # the tile is chosen at trace time
+    try:
+        tiled, s2 = engine().run(hops, windows)
+    finally:
+        hb_mod._compiled_delta.cache_clear()
+    assert tiles and all(m == m_pad and m > t and m % t
+                         for m, t in tiles)
+    assert int(s1) == int(s2)
+    if family == "pagerank":
+        np.testing.assert_allclose(np.asarray(one), np.asarray(tiled),
+                                   atol=1e-6, rtol=0)
+    else:
+        np.testing.assert_array_equal(np.asarray(one), np.asarray(tiled))
+
+
+def test_logs_padding_to_one_shape_share_one_compiled_program():
+    """No property of a log but its padded shapes is in a program's cache
+    key: two engines over logs whose pair counts differ, padded to the
+    same ``m_pad`` / ``n_pad``, dispatch ONE ``_compiled_delta`` entry
+    (PERF.md section 6, PR 29: every Live rebase used to build one)."""
+    from raphtory_tpu.core.events import EventLog
+    from raphtory_tpu.engine import hopbatch as hb_mod
+
+    def ring(n_pairs):
+        log = EventLog()
+        for i in range(n_pairs):
+            log.add_edge(i % 50, i % 30, (i * 7 + 1 + i // 30) % 30)
+        return log
+
+    hops, windows = [20, 49], [100, 10]
+    a = HopBatchedPageRank(ring(200), tol=0.0, max_steps=3)
+    a.run(hops, windows)
+    misses = hb_mod._compiled_delta.cache_info().misses
+    b = HopBatchedPageRank(ring(230), tol=0.0, max_steps=3)
+    assert b.tables.m != a.tables.m
+    assert (b.tables.m_pad, b.tables.n_pad) == (a.tables.m_pad,
+                                                a.tables.n_pad)
+    b.run(hops, windows)
+    assert hb_mod._compiled_delta.cache_info().misses == misses
+
+
+# ---------------------------------------------------------------------------
+# what a dispatch is measured by
+
+
+def test_instrument_records_refined_fields(monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    from raphtory_tpu.obs import ledger as ledger_mod
+
+    monkeypatch.setenv("RTPU_LEDGER", "1")
+    traffic = ledger_mod.edge_traffic_model(3_735_552, 12, 131_072)
+    # every pair gathers a row and read-modify-writes one, each a whole
+    # 64 B line here (the state outgrows the modelled cache)
+    assert traffic["random_rows"] == 2 * 3_735_552
+    assert traffic["est_hbm_bytes"] \
+        == 3 * 3_735_552 * 64 + traffic["streamed_bytes"]
+    # a state that fits the cache costs its payload bytes, not lines
+    tiny = ledger_mod.edge_traffic_model(4096, 4, 256)
+    assert tiny["est_hbm_bytes"] == 4096 * (2 * 4 + 4) + 3 * 4096 * 16
+    fn = ledger_mod.instrument("test.edge_traffic",
+                               jax.jit(lambda x: x * 2.0), traffic=traffic)
+    out = fn(jnp.arange(8, dtype=jnp.float32))
+    jax.block_until_ready(out)
+    rec = [r for r in ledger_mod.REGISTRY.snapshot()
+           if r["kernel"] == "test.edge_traffic"][0]
+    assert rec["est_hbm_bytes"] == traffic["est_hbm_bytes"]
+    assert rec["traffic_model"]["model"] == "edge_superstep"
+    if rec["mode"] == "xla":                   # harvest available
+        assert rec["bound_refined"] in ("hbm_bound", "compute_bound")
+        # the raw XLA harvest stays untouched next to the model
+        assert rec["bytes_accessed"] != rec["est_hbm_bytes"]
+    # /costz surfaces both classifications
+    cz = ledger_mod.costz()
+    assert "kernels_by_bound_refined" in cz
+    assert "est_hbm_bytes" in str(cz["classification_rule"])
+
+
+@pytest.mark.parametrize("size", ["past_2^17_pairs", "small"])
+def test_columnar_run_dispatches_on_the_sorted_table(monkeypatch, size,
+                                                     request):
+    """A columnar run hands its kernel the ``tables.m_pad`` rows of the
+    dst-sorted pair table and nothing after the documented operands, at
+    every size, and wraps each dispatch's host preparation in an
+    ``engine.layout stage=payload`` span (the span the benchmark's
+    ``range.layout_share`` reads)."""
+    from raphtory_tpu.engine import hopbatch as hb_mod
+    from raphtory_tpu.obs.trace import TRACER
+
+    if size == "small":
+        log = random_log(np.random.default_rng(5), n_events=600, n_ids=40,
+                         t_span=80)
+        hops, windows = [20, 45, 46, 79], [100, 30, None]
+    else:
+        log = request.getfixturevalue("wide_log")
+        hops, windows = [700, 900], [1000, 200]
+    hb = HopBatchedPageRank(log, tol=0, max_steps=3)
+    assert (hb.tables.m_pad >= 1 << 17) == (size != "small")
+    real, seen = hb_mod._compiled_delta, []
+
+    def spy(*key):
+        runner = real(*key)
+
+        def run(*operands):
+            seen.append((key[2], [tuple(o.shape) for o in operands]))
+            return runner(*operands)
+        return run
+
+    monkeypatch.setattr(hb_mod, "_compiled_delta", spy)
+    was = TRACER.enabled
+    TRACER.enable()
+    try:
+        before = TRACER.recorded
+        out, _ = hb.run(hops, windows)
+        events = TRACER.recent(TRACER.recorded - before)
+    finally:
+        (TRACER.enable if was else TRACER.disable)()
+    assert np.asarray(out).shape == (len(hops) * len(windows),
+                                     hb.tables.n_pad)
+    spans = [e["args"] for e in events if e["name"] == "engine.layout"]
+    assert len(spans) == len(seen) == 1
+    assert spans[0]["stage"] == "payload" and spans[0]["cached"] is False
+    m_pad, shapes = seen[0]
+    assert m_pad == hb.tables.m_pad
+    # e_src, e_dst, pair base (lat, alive): the pair table's rows; then
+    # the vertex base, the two delta triples and the column descriptors
+    assert shapes[:4] == [(hb.tables.m_pad,)] * 4 and len(shapes) == 14
+
+
+def test_one_chip_default_route_is_the_mesh_routes_table():
+    """One table on one chip and on the mesh: the columnar run agrees
+    with ``run_columns_sharded`` over the same fold columns to the
+    tolerance tests/test_columns_sharded.py holds the mesh to."""
+    import jax
+
+    from raphtory_tpu.parallel.columns import run_columns_sharded
+
+    log = random_log(np.random.default_rng(3), n_events=900, n_ids=50,
+                     t_span=100)
+    hops, windows = [20, 40, 60, 80, 99], [1000, 25]
+    one, steps1 = HopBatchedPageRank(log, tol=1e-7, max_steps=20).run(
+        hops, windows)
+    hb = HopBatchedPageRank(log, tol=1e-7, max_steps=20)
+    _, cols = hb._fold_columns([int(x) for x in hops])
+    many, steps2 = run_columns_sharded(
+        hb.tables, *cols, hops, windows, jax.devices()[:4],
+        tol=1e-7, max_steps=20)
+    np.testing.assert_allclose(np.asarray(one), np.asarray(many),
+                               rtol=1e-5, atol=1e-7)
+    assert int(steps1) == int(steps2)

@@ -76,42 +76,42 @@ def test_env_read_suppressed():
     assert fs == []
 
 
-def test_env_partition_count_in_cached_factory_flagged():
-    """The PR 7 bug class RT001 exists for: an env-derived PARTITION
-    COUNT resolved inside an lru_cached kernel factory (directly or
-    through the module-helper idiom) — flipping RTPU_PARTITIONS
-    mid-process would silently reuse programs binned for the old layout,
-    exactly the RTPU_TILE_BUDGET_MB failure of PR 2."""
+def test_env_tile_length_in_cached_factory_flagged():
+    """The bug class RT001 exists for: an env-derived TILE LENGTH
+    resolved inside an lru_cached kernel factory through a helper that
+    falls back to a size rule — changing RTPU_TILE_BUDGET_MB mid-process
+    would silently reuse programs tiled for the old budget (the failure
+    of PR 2)."""
     fs = lint("""
         import functools
         import os
 
-        def _partition_count(n_pad):
-            ov = os.environ.get("RTPU_PARTITIONS")
-            return int(ov) if ov else max(1, n_pad // 2048)
+        def _edge_tile(m_pad):
+            ov = os.environ.get("RTPU_TILE_BUDGET_MB")
+            return int(ov) << 18 if ov else max(1, m_pad // 2048)
 
         @functools.lru_cache(maxsize=16)
-        def compiled_binned(n_pad, m_pad):
-            parts = _partition_count(n_pad)
-            return (n_pad, m_pad, parts)
+        def compiled_tiled(n_pad, m_pad):
+            tile = _edge_tile(m_pad)
+            return (n_pad, m_pad, tile)
     """)
     assert "env-not-in-cache-key" in rules_of(fs)
-    assert any("RTPU_PARTITIONS" in f.message for f in fs)
+    assert any("RTPU_TILE_BUDGET_MB" in f.message for f in fs)
 
-    # the shipped idiom: the DISPATCH site resolves the knobs and the
-    # factory receives the layout's static spec as a cache-key argument
+    # the shipped idiom: the DISPATCH site resolves the knob and the
+    # factory receives the resolved budget as a cache-key argument
     fs = lint("""
         import functools
         import os
 
         @functools.lru_cache(maxsize=16)
-        def compiled_binned(n_pad, m_pad, pcpm_spec):
-            return (n_pad, m_pad, pcpm_spec)
+        def compiled_tiled(n_pad, m_pad, tile_budget):
+            return (n_pad, m_pad, tile_budget)
 
-        def dispatch(n_pad, m_pad, layout):
-            enabled = os.environ.get("RTPU_PCPM", "auto") != "0"
-            spec = layout.spec if enabled else None
-            return compiled_binned(n_pad, m_pad, spec)
+        def dispatch(n_pad, m_pad, tiled):
+            ov = os.environ.get("RTPU_TILE_BUDGET_MB", "256")
+            budget = int(ov) << 20 if tiled else None
+            return compiled_tiled(n_pad, m_pad, budget)
     """)
     assert fs == []
 
@@ -1271,6 +1271,37 @@ def test_undocumented_knob_rule_passes_without_baseline_help():
     files, docs = _repo_scan_inputs()
     fs = analyze_project(files, docs_text=docs, rules={"RT007"})
     assert fs == []
+
+
+def test_knob_table_rows_and_code_agree_both_ways():
+    """ROADMAP D4's count, as a rule: every ``RTPU_*`` name the package's
+    source holds has a ROW of its own in docs/OPERATIONS.md (RT007 above
+    accepts a mention anywhere in the text), and every row names a knob
+    some code still reads — a row outliving its knob sends an operator
+    to turn something that turns nothing."""
+    import re
+
+    knob = re.compile(r"RTPU_[A-Z0-9]+(?:_[A-Z0-9]+)*")
+
+    def names_under(*roots):
+        from raphtory_tpu.analysis.cli import _iter_py_files
+
+        out = set()
+        for path in _iter_py_files([os.path.join(REPO, r) for r in roots]):
+            with open(path) as fh:
+                out |= set(knob.findall(fh.read()))
+        return out
+
+    rows = set()
+    with open(os.path.join(REPO, "docs", "OPERATIONS.md")) as fh:
+        for line in fh:
+            if line.startswith("| `RTPU_"):
+                rows |= set(knob.findall(line.split("|")[1]))
+    package = names_under("raphtory_tpu")
+    assert package - rows == set(), "knobs without a row"
+    # rows may also name the knobs of the drivers around the package
+    drivers = names_under("tools", "tests", "bench.py", "chip_smoke.py")
+    assert rows - package - drivers == set(), "rows without a reader"
 
 
 # ---------------------------------------------------------------------------

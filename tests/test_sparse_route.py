@@ -21,8 +21,9 @@ from raphtory_tpu.analysis.sanitizer import (MeshSanitizer,
                                              mesh_prefix_divergence)
 from raphtory_tpu.core.snapshot import build_view
 from raphtory_tpu.obs import device as obs_device
-from raphtory_tpu.ops.partition import frontier_bucket, sparse_bucket_floor
 from raphtory_tpu.parallel import frontier, sharded
+from raphtory_tpu.parallel.frontier import (frontier_bucket,
+                                            sparse_bucket_floor)
 from raphtory_tpu.parallel.sweep import ShardedSweep
 
 from test_sweep import random_log
