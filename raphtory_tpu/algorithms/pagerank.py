@@ -6,6 +6,13 @@ power-iteration formulation: each superstep every vertex pulls
 ``rank/out_deg`` along in-edges (sum combiner), applies damping with a
 dangling-mass correction, and votes to halt when its rank moved less than
 ``tol``. f32 on device; windowed sweeps batch as a leading vmap axis.
+
+The division is done once a vertex (``share``, formed in ``init`` and
+``update``), not once an edge in ``message``: a superstep costs per edge row
+touched, and every state leaf ``message`` reads is one more gather an edge
+(docs/KERNELS.md). ``share`` is ``rank / max(out_deg, 1)`` exactly — the same
+division of the same operands, so the ranks are bit-identical to dividing
+per edge; a reciprocal multiply would not be.
 """
 
 from __future__ import annotations
@@ -15,6 +22,11 @@ from dataclasses import dataclass
 import jax.numpy as jnp
 
 from ..engine.program import Context, Edges, VertexProgram
+
+
+def _share(rank, ctx: Context):
+    """What one out-edge of each vertex carries: rank / max(out_deg, 1)."""
+    return rank / jnp.maximum(ctx.out_deg.astype(jnp.float32), 1.0)
 
 
 @dataclass(frozen=True)
@@ -32,11 +44,10 @@ class PageRank(VertexProgram):
     def init(self, ctx: Context):
         n = jnp.maximum(ctx.num_vertices, 1.0)
         rank = jnp.where(ctx.v_mask, 1.0 / n, 0.0).astype(jnp.float32)
-        return {"rank": rank, "out_deg": ctx.out_deg.astype(jnp.float32)}
+        return {"rank": rank, "share": _share(rank, ctx)}
 
     def message(self, src_state, edge: Edges):
-        deg = jnp.maximum(src_state["out_deg"], 1.0)
-        return src_state["rank"] / deg
+        return src_state["share"]
 
     def update(self, state, agg, ctx: Context):
         n = jnp.maximum(ctx.num_vertices, 1.0)
@@ -48,7 +59,7 @@ class PageRank(VertexProgram):
         new = (1.0 - self.damping) / n + self.damping * (agg + dangling / n)
         new = jnp.where(ctx.v_mask, new, 0.0).astype(jnp.float32)
         votes = jnp.abs(new - state["rank"]) < self.tol
-        return {"rank": new, "out_deg": state["out_deg"]}, votes
+        return {"rank": new, "share": _share(new, ctx)}, votes
 
     def finalize(self, state, ctx: Context):
         return state["rank"]

@@ -6,13 +6,18 @@ vid-by-vid (and for ConnectedComponents via the representative vid each
 label decodes to, which is the component's minimum id in both spaces).
 """
 
+import dataclasses
+import re
+
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from raphtory_tpu.algorithms import ConnectedComponents, DegreeBasic, PageRank
 from raphtory_tpu.core.snapshot import build_view
 from raphtory_tpu.engine import bsp
-from raphtory_tpu.engine.device_sweep import DeviceSweep, supported
+from raphtory_tpu.engine.device_sweep import (DeviceSweep, _compiled_run,
+                                              supported)
 
 from test_sweep import random_log
 
@@ -151,3 +156,68 @@ def test_empty_log_and_pre_history_time():
     assert float(np.asarray(got).sum()) == pytest.approx(0.0)
     got, _ = ds.run(PageRank(max_steps=5), 150)
     assert float(np.asarray(got).sum()) == pytest.approx(1.0, abs=1e-4)
+
+
+@dataclasses.dataclass(frozen=True)
+class TwoLeafPageRank(PageRank):
+    """The per-edge form: ``message`` reads two state leaves and divides once
+    an edge. Kept here as the reference ``PageRank``'s per-vertex share is
+    held to, bit for bit."""
+
+    def init(self, ctx):
+        return {"rank": super().init(ctx)["rank"],
+                "out_deg": ctx.out_deg.astype(jnp.float32)}
+
+    def message(self, src_state, edge):
+        return src_state["rank"] / jnp.maximum(src_state["out_deg"], 1.0)
+
+    def update(self, state, agg, ctx):
+        new, votes = super().update(state, agg, ctx)
+        return {"rank": new["rank"], "out_deg": state["out_deg"]}, votes
+
+
+def _compiled_gathers(ds, program, k):
+    """Gather ops in the program a ``k``-window View of ``ds`` compiles to."""
+    runner = _compiled_run(program, ds.n_pad, ds.m_pad, k,
+                           np.dtype(ds.tdtype).name)
+    args = (*ds._bufs, ds.vids, ds.e_src, ds.e_dst,
+            jnp.asarray(0, jnp.int64), jnp.zeros((k,), jnp.int64))
+    text = runner.fn.lower(*args).compile().as_text()
+    return len(re.findall(r" gather\(", text))
+
+
+PER_EDGE_WINDOWS = [[30], [100, 30, 7]]
+
+
+def _churned_sweep():
+    """A resident sweep over a seeded log with vertex and edge deletes."""
+    return DeviceSweep(random_log(np.random.default_rng(11), n_events=600,
+                                  n_ids=40, t_span=80))
+
+
+@pytest.mark.parametrize("windows", PER_EDGE_WINDOWS, ids=["k1", "k3"])
+def test_pagerank_superstep_gathers_one_leaf_an_edge(windows):
+    """A superstep costs per edge row touched (docs/KERNELS.md): PageRank's
+    ``message`` reads one per-vertex share, so its compiled View program
+    holds one gather fewer than the form that divides two leaves per edge."""
+    ds = _churned_sweep()
+    ds.advance(60)
+    k = len(windows)
+    one = _compiled_gathers(ds, PageRank(max_steps=20, tol=0.0), k)
+    two = _compiled_gathers(ds, TwoLeafPageRank(max_steps=20, tol=0.0), k)
+    assert one == two - 1, (one, two)
+
+
+@pytest.mark.parametrize("windows", PER_EDGE_WINDOWS, ids=["k1", "k3"])
+def test_pagerank_share_is_bit_identical_to_per_edge_division(windows):
+    """``share = rank / max(out_deg, 1)`` once a vertex is the same division
+    of the same operands as once an edge: the ranks are equal, not close. A
+    reciprocal multiply in its place would fail here."""
+    ds = _churned_sweep()
+    for T in [35, 60, 79]:
+        got, gs = ds.run(PageRank(max_steps=20, tol=0.0), T, windows=windows)
+        want, ws = ds.run(TwoLeafPageRank(max_steps=20, tol=0.0), T,
+                          windows=windows)
+        assert int(gs) == int(ws) == 20
+        assert np.asarray(got).any()
+        assert np.array_equal(np.asarray(got), np.asarray(want)), T
