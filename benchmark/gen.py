@@ -7,7 +7,7 @@ vertex ids (order-preserving, see ``vertex_ids``), every event time,
 which pair happens when, and the whole tail. So every seed has exactly
 the same number of vertex ids, of distinct (src, dst) pairs and of
 events, and the same graph in the space of id ranks — hence the same
-padded and binned shapes — while no id, time or answer repeats.
+padded shapes — while no id, time or answer repeats.
 """
 
 from __future__ import annotations
@@ -40,8 +40,8 @@ def vertex_ids(cfg: dict, seed: int):
     run's seed draws ``2**scale`` distinct ids out of ``id_space`` and
     hands them out IN ORDER. So every id changes with the seed, while
     the order of the ids — and with it the graph as the engines see it,
-    in the dense space of id ranks, and every padded or binned size
-    derived from it — is the same on every seed."""
+    in the dense space of id ranks, and every padded size derived from
+    it — is the same on every seed."""
     g = cfg["graph"]
     n_ids = 1 << g["scale"]
     shuffle = np.random.default_rng(

@@ -28,6 +28,22 @@ found by name; the configuration's ``algorithm.module`` names its
 reference under ``algorithms/`` and the traffic's ``loop`` its driver
 under ``loops/`` — adding a cell, a metric, an algorithm or a kind of
 loop adds files and edits none.
+
+What a later PR's cell is made of (``tests/benchmark`` holds a made-up
+one to every rule, ``benchmark_rules.py``):
+  files     ``configs/<c>.json`` (its sizes, ``source``, ``reduced``,
+            guarantees and ``correct.limits``); ``traffic/<t>.json``
+            only for a new mix; ``layer_metrics/<m>.json`` (a reducer of
+            ``layers.py`` and its parameters) for each new metric.
+  entries   appended to BENCHMARK.json: the configuration, the workload
+            ``<c>.<t>``, the cell's name on the ``workloads`` list of
+            every metric it reports (``setup.*`` among them; a per-layer
+            metric's ``moves`` must be an end-to-end metric the cell
+            reports), new ``per_layer`` entries last, each with its list.
+  pinned    a traffic file's ``routes.*.kernels`` is checked by
+            ``correct``: a cell of another algorithm or engine needs a
+            traffic file of its own.
+Nothing else is edited: no code, no test, no file that is there.
 """
 
 from __future__ import annotations
@@ -55,11 +71,15 @@ import numpy as np  # noqa: E402
 from benchmark import (BenchFailure, algorithms, client, gen,  # noqa: E402
                        layers, loops, reference, xplane)
 
+#: the host spans a device idle gap is named by (the innermost one that
+#: covers it, ``xplane.gaps_by_span``); no metric reads the names
 HOST_SPANS = ("rest.request", "job", "sweep.columnar", "hop.fold",
               "hop.ship", "hop.compute", "ship.stage", "ship.wire",
               "superstep.block", "snapshot.fold", "bsp.dispatch",
               "live.epoch", "comm.exchange", "xla.compile", "fold.stall",
-              "ingest.append")
+              "ingest.append", "engine.build", "engine.layout",
+              "comm.block_wait", "job.emit", "job.publish",
+              "fold.fingerprint")
 
 
 def load_json(*parts):
@@ -362,8 +382,8 @@ class Run:
 def shapes_of(costz_kernels: list, want: dict) -> dict:
     """Padded shapes of the cell's kernel, from the program's own
     ``/costz`` signatures: every distinct 1-D length (``m_pad``, the
-    edge slots, is the largest; where the engine bins its edges that is
-    the binned size) and the 2-D shapes of the padded per-hop deltas.
+    rows of the dst-sorted pair table a superstep runs, is the largest)
+    and the 2-D shapes of the padded per-hop deltas.
     ``n_pad`` is filled in later: the smallest length that holds the
     graph's vertex ids."""
     import re

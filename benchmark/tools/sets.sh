@@ -5,6 +5,7 @@
 # (then `python3 benchmark/tools/spread.py chiprun_out/<tag>`); SETS=A for
 # one set, e.g. short runs on seeds no set has had.
 w=$1; tag=$2; secs=$3; shift 3
+mkdir -p "$(dirname chiprun_out/$tag)"    # <tag> may name a directory of the call's own
 for set in ${SETS:-A B}; do for s in "$@"; do
   out=chiprun_out/${tag}_${set}_$s
   python3 benchmark/run.py --workload $w --seed $s --seconds $secs --trace ${TRACE:-0} > $out.out 2> $out.err

@@ -1,63 +1,22 @@
-"""The per-layer metrics ISSUE 25 added (its table D): each is one data
-file on a reducer `benchmark/layers.py` already had, in a layer PERF.md
-names, reported by exactly the cell the table gives; and each reduces a
-hand-made record to the number its definition says."""
-
-import os
+"""The per-layer metrics ISSUE 25 added (its table D,
+``benchmark_rules.TABLE_D``): each is one data file on a reducer
+`benchmark/layers.py` already had, in a layer PERF.md names, reported by
+the cell the table gives (first in its list; a later PR's cell may
+follow); and each reduces a hand-made record to the number its
+definition says."""
 
 import pytest
 
-from benchmark import layers, run
+pytest.register_assert_rewrite("benchmark_rules")
+
+import benchmark_rules as rules  # noqa: E402
+from benchmark_rules import TABLE_D  # noqa: E402
+
+from benchmark import layers, run  # noqa: E402
 
 ROOT = run.ROOT
-BENCH = run.load_json(ROOT, "BENCHMARK.json")
-CELLS = [w["name"] for w in BENCH["workloads"]]
-
-#: name -> (cell, reducer, layer, moves, unit, better, source)
-TABLE_D = {
-    "range.build_share": ("twitter_wpr.range_windows", "ledger_phase_share",
-                          "engines", "views_per_s", "%", "lower"),
-    "range.emit_share": ("twitter_wpr.range_windows", "ledger_phase_share",
-                         "REST / jobs", "views_per_s", "%", "lower"),
-    "range.layout_share": ("twitter_wpr.range_windows", "span_share",
-                           "engines", "views_per_s", "%", "lower"),
-    "range.program_builds": ("twitter_wpr.range_windows", "span_count",
-                             "engines", "views_per_s", "count", "lower"),
-    "mesh_range.build_share": ("twitter_wpr_x4.range_windows",
-                               "ledger_phase_share", "engines",
-                               "mesh_views_per_s", "%", "lower"),
-    "mesh_range.compute_share": ("twitter_wpr_x4.range_windows",
-                                 "ledger_phase_share", "engines",
-                                 "mesh_views_per_s", "%", "higher"),
-    "mesh_range.block_wait_share": ("twitter_wpr_x4.range_windows",
-                                    "span_share", "mesh",
-                                    "mesh_views_per_s", "%", "higher"),
-    "mesh_range.program_builds": ("twitter_wpr_x4.range_windows",
-                                  "span_count", "engines",
-                                  "mesh_views_per_s", "count", "lower"),
-    "mesh_range.program_build_share": ("twitter_wpr_x4.range_windows",
-                                       "span_share", "engines",
-                                       "mesh_views_per_s", "%", "lower"),
-    "mesh_range.program_lower_share": ("twitter_wpr_x4.range_windows",
-                                       "span_share", "engines",
-                                       "mesh_views_per_s", "%", "lower"),
-    "live.build_s_per_epoch": ("twitter_wpr.live_tail", "ledger_sum_per",
-                               "engines", "live_staleness_p50_s", "s",
-                               "lower"),
-    "live.jobs_other_share": ("twitter_wpr.live_tail", "ledger_phase_share",
-                              "REST / jobs", "live_staleness_p50_s", "%",
-                              "lower"),
-    "live.program_builds": ("twitter_wpr.live_tail", "span_count", "engines",
-                            "live_staleness_p50_s", "count", "lower"),
-    "live.program_build_share": ("twitter_wpr.live_tail", "span_share",
-                                 "engines", "live_staleness_p50_s", "%",
-                                 "lower"),
-    "view.publish_share": ("twitter_wpr.view_asof", "span_share",
-                           "REST / jobs", "view_p50_s", "%", "lower"),
-    "view.program_builds": ("twitter_wpr.view_asof", "span_count", "engines",
-                            "view_p50_s", "count", "lower"),
-}
-SPAN_REDUCERS = ("span_share", "span_count")
+BENCH = rules.load_bench(ROOT)
+CELLS = rules.cells_of(BENCH)
 
 
 def _span(name, dur_s, **args):
@@ -111,38 +70,18 @@ EXPECTED = {
 }
 
 
-def test_table_d_is_appended_and_nothing_else_moved():
-    names = [m["name"] for m in BENCH["per_layer"]]
-    assert names[-len(TABLE_D):] == list(TABLE_D)
-    assert len(names) == len(set(names)) == 31 + len(TABLE_D)
+def test_table_d_is_there_once_and_in_its_order():
+    rules.table_d_is_there_once_and_in_order(BENCH)
 
 
 @pytest.mark.parametrize("name", list(TABLE_D))
 def test_new_metric_resolves_to_a_file_a_reducer_and_a_layer(name):
-    cell, reducer, layer, moves, unit, better = TABLE_D[name]
-    (entry,) = [m for m in BENCH["per_layer"] if m["name"] == name]
-    assert entry["workloads"] == [cell]
-    assert (entry["layer"], entry["moves"], entry["unit"],
-            entry["better"]) == (layer, moves, unit, better)
-    assert entry["source"] == ("program_span" if reducer in SPAN_REDUCERS
-                               else "program_counter")
-    path = os.path.join(ROOT, "benchmark", "layer_metrics", name + ".json")
-    spec = run.load_json(path)
-    assert spec["reducer"] == reducer and reducer in layers.REDUCERS
-    assert spec["what"]
-    perf = open(os.path.join(ROOT, "PERF.md")).read()
-    assert layer in perf and f"`{name}`" in perf, \
-        f"PERF.md does not name {name} under a layer"
+    rules.table_d_metric_resolves(BENCH, ROOT, name)
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_every_cell_loads_and_reports_its_share_of_table_d(cell):
-    loaded = run.load_cell(cell)
-    got = {s["name"] for s in loaded["per_layer"]} & set(TABLE_D)
-    want = {n for n, row in TABLE_D.items() if row[0] == cell}
-    assert got == want
-    e2e = {m["name"] for m in loaded["end_to_end"]}
-    assert {TABLE_D[n][3] for n in got} <= e2e
+    rules.cell_reports_its_share_of_table_d(BENCH, ROOT, cell)
 
 
 @pytest.mark.parametrize("name", list(TABLE_D))

@@ -5,7 +5,7 @@ import os
 
 import pytest
 
-from benchmark import xplane
+from benchmark import run, xplane
 from benchmark.algorithms import pagerank
 
 TRACE = os.path.join(os.path.dirname(xplane.__file__), "testdata",
@@ -52,6 +52,26 @@ def test_union_and_gaps_on_hand_made_intervals():
     assert gaps == {"job": pytest.approx(6.0), "hop.fold": pytest.approx(2.0)}
     assert xplane.gaps_by_span(busy, 0.0, 10e9, [], set()) == {
         "no_span": pytest.approx(8.0)}
+
+
+def test_a_gap_is_named_by_the_innermost_span_the_harness_lists():
+    """An engine build inside a job: its seconds are `engine.build`'s
+    where the harness lists that span, and `job`'s where it does not."""
+    busy = [[4e9, 6e9]]
+    host = [("job", 0.0, 10e9), ("engine.build", 1e9, 3e9),
+            ("comm.block_wait", 5e9, 9e9), ("job.emit", 9e9, 9.5e9)]
+    for name in ("engine.build", "engine.layout", "comm.block_wait",
+                 "job.emit", "job.publish", "fold.fingerprint"):
+        assert name in run.HOST_SPANS
+    gaps = xplane.gaps_by_span(busy, 0.0, 10e9, host, set(run.HOST_SPANS))
+    # [0,1] job, [1,3] engine.build, [3,4] job, [6,9] comm.block_wait,
+    # [9,9.5] job.emit, [9.5,10] job
+    assert gaps == {"job": pytest.approx(2.5),
+                    "engine.build": pytest.approx(2.0),
+                    "comm.block_wait": pytest.approx(3.0),
+                    "job.emit": pytest.approx(0.5)}
+    before = xplane.gaps_by_span(busy, 0.0, 10e9, host, {"job"})
+    assert before == {"job": pytest.approx(8.0)}
 
 
 def test_names():
