@@ -840,6 +840,11 @@ class FoldCache:
         self.hits = 0
         self.misses = 0
         self.evictions = 0
+        # entries refused for size (larger than the whole bound), and the
+        # largest of them: a refusal evicts nothing, so without these a
+        # log whose checkpoints outgrew the bound reads as a quiet cache
+        self.refused = 0
+        self.refused_bytes = 0
         # (fp, config) -> ascending checkpoint times, for nearest lookup
         self._ckpt_times: dict[tuple, list] = {}
         # lockset-sanitizer registration (None unless RTPU_SANITIZE):
@@ -878,6 +883,19 @@ class FoldCache:
             tr.instant("fold.cache", hit=hit, kind=str(key[0]),
                        bytes=int(nbytes), cached_bytes=self._bytes)
 
+    def _refuse(self, kind: str, nbytes: int) -> bool:
+        """Count an entry larger than the whole bound; always False."""
+        with self._lock:
+            self._note_shared(write=True)
+            self.refused += 1
+            self.refused_bytes = max(self.refused_bytes, nbytes)
+            cached = self._bytes
+        tr = _tracer()
+        if tr is not None:
+            tr.instant("fold.cache", hit=False, kind=kind, bytes=nbytes,
+                       cached_bytes=cached, refused=True)
+        return False
+
     # -- payload entries --
 
     def get(self, key: tuple):
@@ -897,11 +915,12 @@ class FoldCache:
 
     def put(self, key: tuple, value, nbytes: int) -> bool:
         """Insert (or refresh) ``key``; evicts LRU entries past the byte
-        bound. Values larger than the whole bound are refused (False) —
-        one oversized sweep must not flush every other tenant."""
+        bound. Values larger than the whole bound are refused (False,
+        counted in ``stats()``) — one oversized sweep must not flush
+        every other tenant."""
         nbytes = int(nbytes)
         if nbytes > self.max_bytes:
-            return False
+            return self._refuse(str(key[0]), nbytes)
         with self._lock:
             self._note_shared(write=True)
             old = self._entries.pop(key, None)
@@ -918,8 +937,10 @@ class FoldCache:
     # -- checkpoint entries --
 
     def put_checkpoint(self, fp: tuple, cp: FoldCheckpoint) -> bool:
-        if cp.t_prev is None or cp.nbytes > self.max_bytes:
+        if cp.t_prev is None:
             return False
+        if cp.nbytes > self.max_bytes:
+            return self._refuse("ckpt", cp.nbytes)
         key = ("ckpt", fp, cp.config, int(cp.t_prev))
         with self._lock:
             self._note_shared(write=True)
@@ -972,7 +993,9 @@ class FoldCache:
             self._note_shared(write=False)
             return {"entries": len(self._entries), "bytes": self._bytes,
                     "max_bytes": self.max_bytes, "hits": self.hits,
-                    "misses": self.misses, "evictions": self.evictions}
+                    "misses": self.misses, "evictions": self.evictions,
+                    "refused": self.refused,
+                    "refused_bytes": self.refused_bytes}
 
     def clear(self) -> None:
         with self._lock:

@@ -1351,16 +1351,27 @@ class _HopBatched:
             if cache is not None else None
         t0 = self.sw.t_prev
         if cp is not None and (t0 is None or cp.t_prev > t0):
-            sw = self.sw.fork(cp)
+            sw, seed = self.sw.fork(cp), "checkpoint"
         else:
             sw = self.sw.fork()
+            # "start": neither the cache nor the live builder holds a
+            # state, so this unit advances from the log's first event
+            seed = "start" if sw.t_prev is None else "live"
         if sw.t_prev is None or sw.t_prev < boundary:
             with TRACER.span("fold.checkpoint", time=int(boundary),
                                 seeded_from=(-1 if sw.t_prev is None
-                                             else int(sw.t_prev))):
+                                             else int(sw.t_prev)),
+                                seed=seed) as sp:
                 sw._advance(boundary)
-            if cache is not None:
-                cache.put_checkpoint(fp, sw.checkpoint())
+                if cache is not None:
+                    # inside the span, so its args say what became of
+                    # the state this advance reached: refused for size
+                    # (stored=False), or stored at nbytes so near the
+                    # bound that the next insert evicts it — either way
+                    # the NEXT request's units read seed="start" again
+                    cp = sw.checkpoint()
+                    stored = cache.put_checkpoint(fp, cp)
+                    sp.set(stored=stored, nbytes=cp.nbytes)
         return sw
 
     @staticmethod

@@ -201,7 +201,9 @@ def _compile_cache_sizes() -> dict:
 
 
 def _fold_cache_status() -> dict:
-    """Cross-request fold-cache occupancy + hit rates (core/sweep)."""
+    """Cross-request fold-cache occupancy, hit rates and what it refused
+    for size (``refused`` / ``refused_bytes``: above 0 means some log's
+    checkpoint or payload is larger than the whole bound) — core/sweep."""
     from ..core.sweep import fold_cache
 
     cache = fold_cache()

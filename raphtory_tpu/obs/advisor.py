@@ -293,6 +293,28 @@ def rule_fold_cache_thrash(sig: dict) -> dict | None:
                          "max_bytes", "entries")}})
 
 
+def rule_fold_cache_refused(sig: dict) -> dict | None:
+    """The fold cache refused an entry larger than its whole bound. A
+    refusal evicts nothing, so ``fold-cache-thrash`` never sees it: one
+    refused checkpoint is enough, because every later request over that
+    log finds none and folds from the log's first event."""
+    fc = sig.get("fold_cache") or {}
+    refused = int(fc.get("refused") or 0)
+    if refused <= 0:
+        return None
+    return _finding(
+        "fold-cache-refused",
+        f"a fold checkpoint or payload of {fc.get('refused_bytes')} bytes "
+        f"exceeds RTPU_FOLD_CACHE_MB ({fc.get('max_bytes')} bytes; "
+        f"{refused} refused): every request folds the log from its start",
+        "RTPU_FOLD_CACHE_MB",
+        "raise RTPU_FOLD_CACHE_MB above the refused size (several "
+        "checkpoints of it, to keep one per chunk boundary)",
+        {"fold_cache": {k: fc.get(k) for k in
+                        ("refused", "refused_bytes", "max_bytes", "bytes",
+                         "entries", "hits", "misses")}})
+
+
 def rule_watermark_stale(sig: dict) -> dict | None:
     """A live source has held the safe-time fence still past the
     staleness bar — every exact query behind the fence is waiting on it
@@ -655,6 +677,9 @@ RULES = (
     ("fold-cache-thrash", rule_fold_cache_thrash,
      "fold-cache hit/miss/eviction stats",
      "fold cache evicts more than it serves"),
+    ("fold-cache-refused", rule_fold_cache_refused,
+     "fold-cache refused / refused_bytes against its bound",
+     "a fold checkpoint larger than the whole cache: no request finds one"),
     ("watermark-stale", rule_watermark_stale,
      "watermark lag + source snapshot",
      "the safe-time fence stopped advancing past the staleness bar"),

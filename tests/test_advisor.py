@@ -527,6 +527,30 @@ def test_rule_h2d_stall_and_fold_cache_thrash():
     assert f["knob"] == "RTPU_FOLD_CACHE_MB"
 
 
+def test_rule_fold_cache_refused_fires_on_one_refusal_only():
+    """A refusal evicts nothing, so the thrash rule (10 evictions) never
+    sees a log whose checkpoint outgrew the bound; this one does, names
+    the size, and is silent on a cache that refused nothing."""
+    fc = {"hits": 0, "misses": 9, "evictions": 0, "bytes": 0, "entries": 0,
+          "max_bytes": 256 << 20, "refused": 5,
+          "refused_bytes": 301_234_567}
+    (f,) = evaluate_rules({"fold_cache": fc})
+    assert f["rule_id"] == "fold-cache-refused"
+    assert f["knob"] == "RTPU_FOLD_CACHE_MB"
+    assert "301234567 bytes exceeds RTPU_FOLD_CACHE_MB" in f["summary"]
+    assert "every request folds the log from its start" in f["summary"]
+    assert f["evidence"]["fold_cache"]["refused"] == 5
+    assert f["evidence"]["fold_cache"]["max_bytes"] == 256 << 20
+    for quiet in ({**fc, "refused": 0, "refused_bytes": 0},
+                  {k: v for k, v in fc.items() if "refused" not in k}, {}):
+        assert evaluate_rules({"fold_cache": quiet}) == []
+    # beside a thrashing cache both say so, each with its own id
+    both = evaluate_rules({"fold_cache": {**fc, "hits": 5, "misses": 50,
+                                          "evictions": 20}})
+    assert [f["rule_id"] for f in both] == ["fold-cache-thrash",
+                                            "fold-cache-refused"]
+
+
 def test_rule_watermark_stale_respects_bar(monkeypatch):
     monkeypatch.setenv("RTPU_ADVISOR_STALE_S", "5")
     sig = {"watermark_lag_seconds": 10.0,
