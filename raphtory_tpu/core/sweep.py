@@ -211,8 +211,17 @@ _STATE_COPIED = ("v_lat", "v_alive", "v_first", "v_seen",
                  "e_lat", "e_alive", "e_first", "e_seen")
 #: fold-state arrays only ever REBOUND by _advance (np.insert/concatenate
 #: build fresh arrays) — a checkpoint can hold the reference
-_STATE_SHARED = ("e_enc", "e_enc_dst", "dh_v", "dh_t",
-                 "_ea_rows", "_va_rows")
+_STATE_SHARED = ("dh_v", "dh_t", "_ea_rows", "_va_rows")
+#: the sorted pair tables. On a builder whose pairs are NOT preseeded
+#: they are fold state like ``_STATE_SHARED`` (they grow by np.insert as
+#: the fold meets fresh pairs) and a checkpoint carries them. On a
+#: PRESEEDED builder they hold every pair the log ever mentions from
+#: __init__ on and ``_advance`` never rebinds them: they are log-derived,
+#: owned by the builder every fork came from (the engines':
+#: ``engine/device_sweep.LogIndex.prototype``, counted in ``/statusz``
+#: ``log_index.bytes``), and a checkpoint neither holds nor is charged
+#: for them — ``fork(cp)`` takes them from the builder it is called on
+_PAIR_TABLES = ("e_enc", "e_enc_dst")
 
 
 class FoldCheckpoint:
@@ -221,7 +230,13 @@ class FoldCheckpoint:
     the same pinned log content are interchangeable (the dense spaces are
     content-determined), which is what lets the fold cache hand them
     across requests; ``config`` guards against mixing builders with
-    different emit/preseed settings."""
+    different emit/preseed settings.
+
+    ``state`` holds the copied per-vertex / per-pair arrays, the
+    rebind-only delete history and row lists and — only when the
+    builder's pairs were not preseeded — the pair tables
+    (``_PAIR_TABLES``). ``nbytes`` is the bytes of exactly those arrays:
+    the fold cache charges what holding the checkpoint keeps alive."""
 
     __slots__ = ("t_prev", "state", "config", "nbytes")
 
@@ -367,20 +382,26 @@ class SweepBuilder:
     def checkpoint(self) -> FoldCheckpoint:
         """Snapshot the fold state at the current ``t_prev``. Arrays that
         ``_advance`` mutates in place are copied; arrays it only ever
-        rebinds (the sorted pair/dst tables, delete history, row lists)
-        are shared by reference — a later advance builds fresh ones and
-        never touches the snapshot's."""
+        rebinds (delete history, row lists, and the sorted pair/dst
+        tables of a builder that is not preseeded) are shared by
+        reference — a later advance builds fresh ones and never touches
+        the snapshot's. A PRESEEDED builder's pair tables are left out
+        (``_PAIR_TABLES``): no reference kept, none counted in ``nbytes``."""
         state = {k: getattr(self, k).copy() for k in _STATE_COPIED}
-        state.update({k: getattr(self, k) for k in _STATE_SHARED})
+        shared = _STATE_SHARED if self._preseeded \
+            else _STATE_SHARED + _PAIR_TABLES
+        state.update({k: getattr(self, k) for k in shared})
         return FoldCheckpoint(self.t_prev, state, self._config())
 
     def fork(self, cp: FoldCheckpoint | None = None) -> "SweepBuilder":
         """An INDEPENDENT builder over the same pinned log, seeded from
         ``cp`` (or this builder's current state): log-derived arrays are
-        shared (immutable after __init__), fold state is copied — the
-        fork and the original advance without observing each other. This
-        is how a range sweep's chunks fold concurrently: each chunk forks
-        from the nearest checkpoint and folds its own hop window.
+        shared (immutable after __init__; a preseeded builder's pair
+        tables among them, taken from THIS builder whatever ``cp`` is),
+        fold state is copied — the fork and the original advance without
+        observing each other. This is how a range sweep's chunks fold
+        concurrently: each chunk forks from the nearest checkpoint and
+        folds its own hop window.
         Equivalence holds because the fold state at T is a function of
         (log, T) alone, not of the hop sequence that reached it (the
         ``view_at ≡ build_view`` contract, tested per hop batching)."""
@@ -397,10 +418,12 @@ class SweepBuilder:
         for k in _STATE_COPIED:
             setattr(sw, k, (src[k] if src is not None
                             else getattr(self, k)).copy())
-        for k in _STATE_SHARED:
+        for k in _STATE_SHARED + _PAIR_TABLES:
             # rebind-only arrays: the fork's first rebind leaves the
-            # source (live builder or cached checkpoint) untouched
-            setattr(sw, k, src[k] if src is not None else getattr(self, k))
+            # source (live builder or cached checkpoint) untouched. A
+            # preseeded builder's pair tables are in no checkpoint
+            setattr(sw, k, src[k] if src is not None and k in src
+                    else getattr(self, k))
         sw.t_prev = cp.t_prev if cp is not None else self.t_prev
         sw.last_delta = None
         return sw

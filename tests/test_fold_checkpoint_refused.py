@@ -2,12 +2,16 @@
 and the refusal is visible: ``FoldCache.stats()`` (so ``/statusz``
 ``fold_cache`` and the advisor), the ``fold.cache`` instant, the
 ``fold.checkpoint`` span. One that only just fits is stored and evicted
-by the next insert (what the default bound is to a log of 2^23 events);
+by the next insert (what the default bound was to a log of 2^23 events
+while a checkpoint was charged the index's pair tables, until PR 34);
 the span says that too (``stored``, ``nbytes``, ``seed="start"`` on the
 next request). And the regime both make — every request folds the log
 from its first event — serves the rows an ample cache serves, bit for
 bit, inside the benchmark configuration's limits of the plain
-reference."""
+reference. A bound that holds one checkpoint at what it OWNS plus a
+request's payload, and would not hold it at the old charge, keeps the
+newest checkpoint: the second request seeds from it (2^23 events under
+the default bound, in small)."""
 
 import argparse
 
@@ -17,7 +21,7 @@ import pytest
 import raphtory_tpu.core.sweep as cs
 from raphtory_tpu.core.sweep import (FoldCache, SweepBuilder,
                                      log_fingerprint)
-from raphtory_tpu.engine import hopbatch
+from raphtory_tpu.engine import device_sweep, hopbatch
 from raphtory_tpu.obs.trace import TRACER
 
 from benchmark import reference, run
@@ -99,6 +103,14 @@ def _serve_two_requests(monkeypatch, max_bytes, seed=2**31 + 33):
                          if s["name"] == "fold.checkpoint"]
         status = r.rest.get("/statusz")["fold_cache"]
         advice = r.rest.get("/advisez?cluster=0")["findings"]
+        # beside the cache's account: the payload entries' bytes, and
+        # the pair tables of the served log's index (the engines'
+        # preseeded builder, ``/statusz`` ``log_index``)
+        status["payload_bytes"] = [n for k, (_, n) in cache._entries.items()
+                                   if k[0] != "ckpt"]
+        proto = device_sweep._LOG_INDEXES[r.rt.graph.log].prototype
+        status["pair_table_bytes"] = (proto.e_enc.nbytes
+                                      + proto.e_enc_dst.nbytes)
     finally:
         r.stop()
     return r, reqs, status, advice
@@ -134,9 +146,9 @@ def test_refused_or_evicted_checkpoints_serve_the_rows_an_ample_cache_serves(
     assert str(st["refused_bytes"]) in f["summary"]
     nbytes = spans[0]["nbytes"]
 
-    # --- a bound just over one checkpoint (what 256 MB is to a log of
-    # 2^23 events: 262,361,722 B of 268,435,456): each is stored, then
-    # evicted by the next insert before any request looks for it
+    # --- a bound just over one checkpoint and under checkpoint +
+    # payload: each is stored, then evicted by the next insert before
+    # any request looks for it
     traced.clear()
     _, churn, st_c, _ = _serve_two_requests(monkeypatch, nbytes + nbytes // 50)
     spans_c = [a for q in churn for a in q["ckpt"]]
@@ -154,8 +166,12 @@ def test_refused_or_evicted_checkpoints_serve_the_rows_an_ample_cache_serves(
     first, second = (q["ckpt"] for q in warm)
     assert all(a["stored"] is True for a in first + second)
     assert {a["seed"] for a in first} == {"start"}
-    assert {(a["seeded_from"], a["seed"]) for a in second} == {
-        (max(a["time"] for a in first), "checkpoint")}
+    assert {a["seed"] for a in second} == {"checkpoint"}
+    # from the first request's newest (the second unit may find the
+    # first unit's own instead, stored a moment before it looked)
+    seeded = {a["seeded_from"] for a in second}
+    assert min(seeded) == max(a["time"] for a in first)
+    assert seeded <= {a["time"] for a in first + second}
     assert st_w["refused"] == 0 == st_w["evictions"]
     assert st_w["entries"] >= len(first)
     assert not [f for f in advice_w if f["rule_id"] == "fold-cache-refused"]
@@ -177,4 +193,49 @@ def test_refused_or_evicted_checkpoints_serve_the_rows_an_ample_cache_serves(
                                   cfg["algorithm"]),
             cfg["correct"]["limits"], cfg["algorithm"])
         assert got["ok"], (row["time"], row["windowsize"], got)
+    capsys.readouterr()         # the harness's phase lines
+
+
+def test_a_bound_over_what_a_checkpoint_owns_keeps_the_newest_one(
+        monkeypatch, traced, capsys):
+    # sizes at the rehearsal's size, from an ample cache
+    _, warm, st_w, _ = _serve_two_requests(monkeypatch, 64 << 20)
+    (owned,) = {a["nbytes"] for q in warm for a in q["ckpt"]}
+    payload = max(st_w["payload_bytes"])
+    tables = st_w["pair_table_bytes"]
+    assert 0 < payload < tables
+    # a checkpoint's bytes are the fold state alone: two int64 and two
+    # flags a pair and an id, where the two tables are 16 B a pair
+    pairs = tables // 16
+    assert owned > 18 * pairs and (owned - 18 * pairs) % 18 == 0
+    # between owned + payload and the old charge, owned + the two
+    # tables: charged those, every checkpoint here was refused (at 2^23
+    # events and the default bound, 262.4 + 53.7 MB against 268.4, it
+    # was stored and evicted); either way the next request found none.
+    # Charged what it owns: 140.6 + 53.7 = 194.3 MB there
+    bound = owned + payload + (tables - payload) // 4
+    assert owned + payload < bound < owned + tables
+    assert bound < 2 * owned        # two checkpoints never fit together
+    traced.clear()
+    _, kept, st, advice = _serve_two_requests(monkeypatch, bound)
+    first, second = (q["ckpt"] for q in kept)
+    assert first and second
+    assert all(a["stored"] is True and a["nbytes"] == owned
+               for a in first + second)
+    assert min(first, key=lambda a: a["time"])["seed"] == "start"
+    # the sibling's checkpoint evicted one of the first request's two;
+    # the payload, put last, left the other: the second request found it
+    # (its second unit may find the first unit's, stored a moment before)
+    assert {a["seed"] for a in second} == {"checkpoint"}
+    seeded = {a["seeded_from"] for a in second}
+    assert min(seeded) in {a["time"] for a in first}
+    assert seeded <= {a["time"] for a in first + second}
+    assert st["hits"] > 0 and st["refused"] == 0 and st["evictions"] > 0
+    assert st["bytes"] <= bound
+    assert not _refused(traced)
+    assert not [f for f in advice if f["rule_id"] == "fold-cache-refused"]
+    # bit for bit the rows of the ample cache
+    assert [row["time"] for q in kept for row in q["rows"]] \
+        == [row["time"] for q in warm for row in q["rows"]]
+    assert np.array_equal(_ranks(kept), _ranks(warm))
     capsys.readouterr()         # the harness's phase lines
