@@ -8,7 +8,7 @@ from .connected_components import ConnectedComponents
 from .degree import DegreeBasic
 from .diffusion import BinaryDiffusion
 from .flow import FlowGraph
-from .lpa import LabelPropagation
+from .lpa import CDLP, LabelPropagation
 from .pagerank import PageRank
 from .rankings import DegreeRanking, Density, StarNode
 from .taint import TaintTracking
@@ -23,6 +23,7 @@ __all__ = [
     "BinaryDiffusion",
     "FlowGraph",
     "LabelPropagation",
+    "CDLP",
     "PageRank",
     "TaintTracking",
     "BFS",

@@ -26,7 +26,8 @@ from ..core.snapshot import GraphView
 from ..obs import ledger as _ledger
 from ..obs.trace import TRACER, block_steps
 from ..ops.segment import segment_combine
-from .program import Context, Edges, VertexProgram
+from .program import (Context, Edges, VertexProgram, check_custom_direction,
+                      custom_exchange)
 
 _elem = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
 
@@ -144,25 +145,28 @@ def make_mask_runner(program: VertexProgram, n: int, m: int, k: int):
             return jax.tree_util.tree_map(
                 lambda a: a.reshape((k * n,) + a.shape[2:])[ids], state)
 
-        def custom_flat(tree_flat, ids):
-            agg = program.exchange(tree_flat, ids, k * n, em_flat)
-            return jax.tree_util.tree_map(
-                lambda a: a.reshape((k, n) + a.shape[1:]), agg)
-
         def step_all(st, step):
             ek = flat_edges(step)
             custom = program.combiner == "custom"
-            agg = None
+            agg, parts = None, []
             if program.direction in ("out", "both"):
                 payload = program.message(gather_flat(st, flat_src), ek)
-                agg = (custom_flat(payload, flat_dst) if custom
-                       else combine_flat(payload, flat_dst, True))
+                if custom:
+                    parts.append((payload, flat_dst, em_flat))
+                else:
+                    agg = combine_flat(payload, flat_dst, True)
             if program.direction in ("in", "both"):
                 payload = program.message(gather_flat(st, flat_dst), ek)
-                agg_in = (custom_flat(payload, flat_src) if custom
-                          else combine_flat(payload, flat_src, False))
-                agg = agg_in if agg is None else _merge_aggs(
-                    program.combiner, agg, agg_in)
+                if custom:
+                    parts.append((payload, flat_src, em_flat))
+                else:
+                    agg_in = combine_flat(payload, flat_src, False)
+                    agg = agg_in if agg is None else _merge_aggs(
+                        program.combiner, agg, agg_in)
+            if custom:
+                agg = jax.tree_util.tree_map(
+                    lambda a: a.reshape((k, n) + a.shape[1:]),
+                    custom_exchange(program, parts, k * n))
 
             def upd_k(kk, stk, aggk):
                 new, votes = program.update(stk, aggk, mk_ctx(kk, step))
@@ -244,10 +248,7 @@ def run_async(
                                 (BWindowed*; leading axis on the result).
     """
     batched = windows is not None
-    if program.combiner == "custom" and program.direction == "both":
-        raise ValueError(
-            "combiner='custom' requires direction 'out' or 'in' — merging "
-            "two custom aggregations is not well-defined")
+    check_custom_direction(program)
     if windows is not None and len(windows) == 0:
         raise ValueError("windows must be a non-empty list of window sizes")
     if windows is None:

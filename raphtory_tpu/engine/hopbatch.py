@@ -41,6 +41,7 @@ from ..core.sweep import (fold_cache, fold_pool, fold_workers,
                           log_fingerprint, prefetch_map)
 from ..obs import ledger as _ledger
 from ..obs.trace import TRACER
+from ..ops.segment import segment_counts, segment_mode
 from ..utils.transfer import _metrics
 from .device_sweep import (_device_edges, log_index, normalize_windows,
                            sweep_phase_summary)
@@ -283,7 +284,7 @@ def _compiled_delta(kind: str, n_pad: int, m_pad: int, H: int, W: int,
                     tile_budget: int | None = None):
     """Delta-fed columnar kernels: masks rebuilt on device from base state
     + per-hop deltas (``_masks_from_deltas``), then the shared algorithm
-    body. ``kind``: pagerank | cc | bfs (``weighted`` adds a per-pair
+    body. ``kind``: pagerank | cc | cdlp | bfs (``weighted`` adds a per-pair
     weight state rebuilt the same way); ``algo_args`` is the algorithm's
     static parameter tuple. ``h0=True`` is the resident-base variant: the
     base inputs are the previous dispatch's advanced state, delta[0] is
@@ -313,6 +314,11 @@ def _compiled_delta(kind: str, n_pad: int, m_pad: int, H: int, W: int,
             l0 = jnp.tile(rest[0][-W:], (H, 1)).T if warm else None
             out, steps = _cc_columns(me, mv, e_src, e_dst, n_pad, max_steps,
                                      tile_budget=tile_budget, l_init=l0)
+            return out, steps, adv
+        if kind == "cdlp":
+            (max_steps,) = algo_args
+            out, steps = _cdlp_columns(me, mv, e_src, e_dst, n_pad,
+                                       max_steps)
             return out, steps, adv
         max_steps, directed = algo_args
         ew = 1.0
@@ -360,7 +366,7 @@ def run_columns_delta(kind, tables, base, deltas_e, deltas_v, hop_times,
                       e_src_dev=None, e_dst_dev=None, r_init=None,
                       weight_base=None, weight_deltas=None,
                       h0_delta: bool = False, ship_counter=None):
-    """Dispatch a delta-fed columnar kernel (``kind``: pagerank|cc|bfs)
+    """Dispatch a delta-fed columnar kernel (``kind``: pagerank|cc|cdlp|bfs)
     over ``_HopBatched._fold_deltas`` output; returns ``(result, steps,
     advanced_base)``. ``weight_base`` + ``weight_deltas`` ([(pos, val)]
     per hop) turn bfs into weighted SSSP with the weight state rebuilt on
@@ -515,6 +521,48 @@ def _cc_columns(me, mv, e_src, e_dst, n_pad: int, max_steps: int,
         (jnp.int32(0) + (mv[0, 0] & False).astype(jnp.int32),
          lab0, mv[0] & False))
     return lab.T, steps   # [C, n_pad]
+
+
+def _cdlp_columns(me, mv, e_src, e_dst, n_pad: int, max_steps: int):
+    """Columnar CDLP (``algorithms/lpa.CDLP``: LDBC Graphalytics'
+    community detection) for every (hop, window) column at once: exactly
+    ``max_steps`` synchronous rounds in which a vertex takes the most
+    frequent label among its in- and out-neighbours, the smallest among
+    equals, and keeps its own without neighbours. Labels are global
+    padded indices.
+
+    The combine is a histogram, not an elementwise reduction: a round
+    hands the ``2 * m_pad * C`` (segment, label) rows of both directions
+    to ``ops.segment.segment_mode`` — the implementation ``bsp`` and the
+    mesh run — with segment ``vertex * C + column``, which is the
+    row-major order of the ``[m_pad, C]`` gathers. A masked row keeps its
+    segment, so a segment's rows are the vertex's in- plus out-degree in
+    the table, whatever the column: counted once a dispatch. No warm
+    start: a warm round is another computation, not a nearer start."""
+    C = me.shape[1]
+    I32_MAX = jnp.iinfo(jnp.int32).max
+    cols = jnp.arange(C, dtype=jnp.int32)[None, :]
+    # every [m_pad, C] block is flattened BEFORE the two directions are
+    # joined: the TPU pads C lanes to 128, so a [2 * m_pad, C] buffer is
+    # 21 times its bytes (3.8 GB at m_pad 3.7M), a flat one is not
+    seg = jnp.concatenate([(e_dst[:, None] * C + cols).reshape(-1),
+                           (e_src[:, None] * C + cols).reshape(-1)])
+    alive = jnp.tile(me.reshape(-1), 2)
+    counts = jnp.repeat(
+        segment_counts(jnp.concatenate([e_dst, e_src]), n_pad), C)
+    lab0 = jnp.where(mv, jnp.arange(n_pad, dtype=jnp.int32)[:, None],
+                     I32_MAX)
+
+    def body(_, lab):
+        with jax.named_scope("cdlp.gather"):
+            sent = jnp.concatenate([lab[e_src, :].reshape(-1),
+                                    lab[e_dst, :].reshape(-1)])
+        agg = segment_mode(sent, seg, n_pad * C, alive,
+                           default=-1, counts=counts).reshape(n_pad, C)
+        return jnp.where(mv, jnp.where(agg >= 0, agg, lab), I32_MAX)
+
+    lab = jax.lax.fori_loop(0, max_steps, body, lab0)
+    return lab.T, jnp.int32(max_steps)   # [C, n_pad]
 
 
 @functools.lru_cache(maxsize=64)
@@ -1950,6 +1998,40 @@ class HopBatchedCC(_HopBatched):
             self.tables, *cols, hop_times, windows,
             max_steps=self.max_steps,
             e_src_dev=self._e_src, e_dst_dev=self._e_dst)
+
+
+class HopBatchedCDLP(_HopBatched):
+    """Windowed CDLP (LDBC Graphalytics community detection, a fixed
+    number of rounds) over a full hop sweep in one call; labels decode
+    via ``tables.uv[label]``. Delta-fed only: the masks are rebuilt on
+    the device, there is no host-column variant to fall back to. No warm
+    start of either kind — a warm round is another computation."""
+
+    supports_delta_fold = True
+
+    def __init__(self, log: EventLog, max_steps: int = 10):
+        super().__init__(log)
+        self.max_steps = max_steps
+
+    def _use_delta_fold(self) -> bool:
+        return True
+
+    def _dispatch_deltas(self, payload, hop_times, windows, r_init=None):
+        assert r_init is None   # neither warm channel is declared
+        base, deltas_e, deltas_v = payload
+        base, h0 = self._delta_base_args(base)
+        led = _ledger.current()
+        if led is not None:
+            # rows handed to the mode's sort: both directions of every
+            # pair row, every column, every round — from the dispatch's
+            # shapes, no device read-back
+            led.count_mode_rows(2 * self.tables.m_pad * len(hop_times)
+                                * len(windows) * int(self.max_steps))
+        return self._run_delta(lambda: run_columns_delta(
+            "cdlp", self.tables, base, deltas_e, deltas_v,
+            hop_times, windows, algo_args=(int(self.max_steps),),
+            e_src_dev=self._e_src, e_dst_dev=self._e_dst, h0_delta=h0,
+            ship_counter=self._count_ship))
 
 
 def _dispatch_columns(runner, tables, cols, hop_of_col, T_col,

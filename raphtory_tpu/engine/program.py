@@ -153,6 +153,13 @@ class VertexProgram:
     # ConnectedComponents and SSSP/BFS satisfy this; PageRank-style dense
     # fixpoints must keep the default False.
     monotone_min: bool = False
+    # combiner='custom' with direction='both': True declares that
+    # ``exchange`` reduces the MULTISET of the payloads a vertex receives,
+    # whichever way each came along (a label histogram), so the engines
+    # hand it the out- and in-payloads concatenated, in ONE call
+    # (``custom_exchange``). False: the pair is refused — two custom
+    # aggregates have no merge.
+    exchange_joint: bool = False
 
     @property
     def cost_label(self) -> str:
@@ -182,8 +189,9 @@ class VertexProgram:
         segment; rows with ``mask`` False must not contribute. Runs inside
         the compiled superstep on every engine (single-chip and mesh) —
         use static-shape segment ops (``segment_combine``, ``segment_mode``)
-        only. Restricted to direction 'out' or 'in' (merging two custom
-        aggregations is not well-defined)."""
+        only. Direction 'both' needs ``exchange_joint`` (merging two
+        custom aggregations is not well-defined; one aggregation of both
+        directions' payloads is)."""
         raise NotImplementedError
 
     def update(self, state: Any, agg: Any, ctx: Context):
@@ -200,3 +208,31 @@ class VertexProgram:
         """Turn device results into the job-level answer (host code).
         Default: pass through."""
         return result
+
+
+def check_custom_direction(program: VertexProgram) -> None:
+    """The one refusal every engine makes of a custom combine."""
+    if (program.combiner == "custom" and program.direction == "both"
+            and not program.exchange_joint):
+        raise ValueError(
+            "combiner='custom' requires direction 'out' or 'in' — merging "
+            "two custom aggregations is not well-defined (a program whose "
+            "exchange reduces both directions' payloads as one multiset "
+            "declares exchange_joint)")
+
+
+def custom_exchange(program: VertexProgram, parts, num_segments: int):
+    """``program.exchange`` over ``parts`` = one ``(payload, seg_ids,
+    mask)`` per direction the program listens on. Two directions
+    (``exchange_joint``) are ONE exchange over both payloads together —
+    a histogram of in- and out-neighbours' messages, not two aggregates
+    to be merged."""
+    if len(parts) == 1:
+        payload, ids, mask = parts[0]
+    else:
+        def cat(*xs):
+            return jnp.concatenate(xs)
+
+        payload = jax.tree_util.tree_map(cat, *(p[0] for p in parts))
+        ids, mask = cat(*(p[1] for p in parts)), cat(*(p[2] for p in parts))
+    return program.exchange(payload, ids, num_segments, mask)

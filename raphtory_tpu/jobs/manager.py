@@ -555,12 +555,16 @@ class Job:
         vector and the power iteration warm-starts safely. CC: labels are
         global padded indices in both engines. SSSP/BFS: the columnar
         distances are exactly finalize's output; weighted traversal folds
-        per-hop weight columns (immutable weight keys raise)."""
+        per-hop weight columns (immutable weight keys raise). CDLP: labels
+        are global padded indices in both engines and the rounds are
+        fixed, so the columns are ``bsp``'s answer."""
+        from ..algorithms import CDLP as _CDLP
         from ..algorithms import ConnectedComponents as _CC
         from ..algorithms import PageRank as _PR
         from ..algorithms.traversal import SSSP as _SSSP
         from ..engine.hopbatch import (HopBatchedBFS, HopBatchedCC,
-                                       HopBatchedPageRank, HopBatchedSSSP)
+                                       HopBatchedCDLP, HopBatchedPageRank,
+                                       HopBatchedSSSP)
 
         p = self.program
         if type(p) is _PR:
@@ -568,6 +572,8 @@ class Job:
                                       tol=p.tol, max_steps=p.max_steps)
         if type(p) is _CC:
             return HopBatchedCC(self.graph.log, max_steps=p.max_steps)
+        if type(p) is _CDLP:
+            return HopBatchedCDLP(self.graph.log, max_steps=p.max_steps)
         if type(p) is _SSSP:
             if p.weight_prop:
                 return HopBatchedSSSP(self.graph.log, p.seeds,
@@ -620,8 +626,9 @@ class Job:
         the raw rank vector; the power iteration warm-starts safely),
         ConnectedComponents (labels are global padded indices in both
         engines; no warm start — min-propagation is not a contraction on a
-        changing edge set), and SSSP/BFS (unit or mutable-numeric-weighted;
-        no warm start)."""
+        changing edge set), SSSP/BFS (unit or mutable-numeric-weighted;
+        no warm start), and CDLP (a fixed number of rounds of a histogram
+        combine; no warm start)."""
         import numpy as np
 
         if self.mesh is not None or self.graph.safe_time() < q.end:
@@ -704,6 +711,10 @@ class Job:
         from ..parallel.columns import run_columns_sharded
 
         if self.mesh is None or self.graph.safe_time() < q.end:
+            return False
+        if self.program.combiner == "custom":
+            # the column-sharded runner has the elementwise kinds only; a
+            # histogram combine (CDLP) takes the vertex-sharded route
             return False
         prep = self._columnar_range_prep(q)
         if prep is None:

@@ -618,6 +618,10 @@ class Ledger:
         #: max device bytes-in-use observed at sampled timed dispatches
         #: (+ one read at finish) — 0 on backends without memory_stats
         self.peak_device_bytes = 0
+        #: rows handed to ``segment_mode``'s sort, summed over the rounds
+        #: of the query's dispatches (columnar CDLP; counted on the host
+        #: from the dispatch's shapes, no device read-back)
+        self.mode_rows = 0
         #: set by the serving scheduler when this query's views rode a
         #: COALESCED cross-request dispatch (jobs/scheduler.py): batch
         #: id, member count, this query's column share — the explain
@@ -717,6 +721,10 @@ class Ledger:
             self.peak_device_bytes = max(self.peak_device_bytes,
                                          int(bytes_in_use))
 
+    def count_mode_rows(self, n: int) -> None:
+        with self._lock:
+            self.mode_rows += int(n)
+
     def count_views(self, n: int = 1) -> None:
         with self._lock:
             self.views += int(n)
@@ -779,6 +787,7 @@ class Ledger:
             self.peak_device_bytes = max(
                 self.peak_device_bytes,
                 snap["device"].get("peak_device_bytes", 0))
+            self.mode_rows += snap["device"].get("mode_rows", 0)
         return self
 
     def absorb_share(self, batch_snap: dict, frac: float,
@@ -828,6 +837,8 @@ class Ledger:
                 mine["bound"] = k.get("bound", "unknown")
                 if k.get("bound_refined"):
                     mine["bound_refined"] = k["bound_refined"]
+            self.mode_rows += int(
+                batch_snap["device"].get("mode_rows", 0) * frac)
             self.sweeps += 1
             if coalesced is not None:
                 self.coalesced = dict(coalesced)
@@ -925,6 +936,7 @@ class Ledger:
                 "timed_dispatches": sum(k.get("timed_dispatches", 0)
                                         for k in self.kernels.values()),
                 "peak_device_bytes": int(self.peak_device_bytes),
+                "mode_rows": int(self.mode_rows),
                 "kernels": {n: dict(k) for n, k in self.kernels.items()},
             },
             "host": {"peak_rss_bytes": int(self.peak_rss_bytes)},

@@ -50,7 +50,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..analysis.sanitizer import mesh_active
 from ..core.snapshot import GraphView, INT64_MIN
 from ..engine.bsp import _elem, _merge_aggs
-from ..engine.program import Context, Edges, VertexProgram
+from ..engine.program import (Context, Edges, VertexProgram,
+                              check_custom_direction, custom_exchange)
 from ..obs.trace import TRACER
 from ..ops.segment import segment_combine
 
@@ -734,11 +735,6 @@ def _sharded_runner(program: VertexProgram, mesh: Mesh, n_loc: int,
                 (k_loc * m_loc_s,) + a.shape[1:])
 
         def combine_flat(tree_flat, ids, msk):
-            if program.combiner == "custom":
-                agg = program.exchange(tree_flat, ids, k_loc * n_loc, msk)
-                return jax.tree_util.tree_map(
-                    lambda a: a.reshape((k_loc, n_loc) + a.shape[1:]), agg)
-
             def leaf(x):
                 out = segment_combine(x, ids, k_loc * n_loc, program.combiner,
                                       msk, indices_are_sorted=True)
@@ -781,7 +777,8 @@ def _sharded_runner(program: VertexProgram, mesh: Mesh, n_loc: int,
                 st_full = gather_state(st)  # [k_loc, n_pad, ...]
                 pool_d = pool_s = lambda: st_full
                 width_d = width_s = n_pad
-            agg = None
+            custom = program.combiner == "custom"
+            agg, parts = None, []
             if program.direction in ("out", "both"):
                 # Edges contract: src/dst are GLOBAL padded indices
                 edges = Edges(src=tile_d(d_src_g), dst=tile_d(d_dst_l) + v_off,
@@ -791,7 +788,10 @@ def _sharded_runner(program: VertexProgram, mesh: Mesh, n_loc: int,
                               step=step)
                 payload = program.message(
                     gather_flat(pool_d(), fl_d_src, width_d), edges)
-                agg = combine_flat(payload, fl_d_dst, dm_flat)
+                if custom:
+                    parts.append((payload, fl_d_dst, dm_flat))
+                else:
+                    agg = combine_flat(payload, fl_d_dst, dm_flat)
             if program.direction in ("in", "both"):
                 edges = Edges(src=tile_s(s_src_l) + v_off, dst=tile_s(s_dst_g),
                               mask=sm_flat, time=tile_s(s_time),
@@ -800,9 +800,18 @@ def _sharded_runner(program: VertexProgram, mesh: Mesh, n_loc: int,
                               step=step)
                 payload = program.message(
                     gather_flat(pool_s(), fl_s_dst, width_s), edges)
-                agg_in = combine_flat(payload, fl_s_src, sm_flat)
-                agg = agg_in if agg is None else _merge_aggs(
-                    program.combiner, agg, agg_in)
+                if custom:
+                    parts.append((payload, fl_s_src, sm_flat))
+                else:
+                    agg_in = combine_flat(payload, fl_s_src, sm_flat)
+                    agg = agg_in if agg is None else _merge_aggs(
+                        program.combiner, agg, agg_in)
+            if custom:
+                # a vertex's owner holds its in-edges (d_*) and its
+                # out-edges (s_*): both payloads meet in one exchange
+                agg = jax.tree_util.tree_map(
+                    lambda a: a.reshape((k_loc, n_loc) + a.shape[1:]),
+                    custom_exchange(program, parts, k_loc * n_loc))
 
             def upd_k(kk, stk, aggk):
                 new_st, votes = program.update(stk, aggk, mk_ctx(kk, step))
@@ -936,10 +945,7 @@ def run(program: VertexProgram, view: GraphView, mesh: Mesh, *,
     so does the sparse route (its superstep loop is host-driven)."""
     batched = windows is not None
     occurrences = bool(getattr(program, "needs_occurrences", False))
-    if program.combiner == "custom" and program.direction == "both":
-        raise ValueError(
-            "combiner='custom' requires direction 'out' or 'in' — merging "
-            "two custom aggregations is not well-defined")
+    check_custom_direction(program)
     if windows is not None and len(windows) == 0:
         raise ValueError("windows must be a non-empty list of window sizes")
     if windows is None:

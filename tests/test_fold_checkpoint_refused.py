@@ -94,6 +94,9 @@ def _serve_two_requests(monkeypatch, max_bytes, seed=2**31 + 33):
                               trace=0, rehearsal=False)
     r = run.Run(args, loaded, "cpu",
                 {"platform": "cpu", "kind": "cpu", "count": 1})
+    # no trace is taken here, and ``stop`` empties the trace directory:
+    # not the one a traced rehearsal in another worker is reading
+    r.trace_dir += ".untraced"
     try:
         r.boot()
         reqs = [r.loop.request(k) for k in (0, 1)]
