@@ -25,7 +25,7 @@ import numpy as np
 from ..core.snapshot import GraphView
 from ..obs import ledger as _ledger
 from ..obs.trace import TRACER, block_steps
-from ..ops.segment import segment_combine
+from ..ops.segment import segment_combine, segment_ends_pos
 from .program import (Context, Edges, VertexProgram, check_custom_direction,
                       custom_exchange)
 
@@ -103,19 +103,29 @@ def make_mask_runner(program: VertexProgram, n: int, m: int, k: int):
             return jnp.broadcast_to(a[None, :], (k,) + a.shape).reshape(
                 (k * m,) + a.shape[1:])
 
+        # a sum over the dst-sorted rows is a segmented scan and one gather
+        # at the segments' last rows (ops/segment.sorted_segment_sum):
+        # where those lie depends on flat_dst alone — once, before the
+        # superstep loop, the way ``counts`` reaches ``segment_mode``
+        ends, pos = segment_ends_pos(flat_dst, k * n)
+
         def combine_flat(tree_flat, ids, sorted_):
             # No branch here may depend on the backend's name: the chip
             # must run the combine the CPU tests run.
+            # the plan is flat_dst's: the sorted ids are never any other
             def leaf(x):
                 out = segment_combine(x, ids, k * n, program.combiner,
-                                      em_flat, indices_are_sorted=sorted_)
+                                      em_flat, indices_are_sorted=sorted_,
+                                      ends=ends if sorted_ else None,
+                                      pos=pos if sorted_ else None)
                 return out.reshape((k, n) + x.shape[1:])
             return jax.tree_util.tree_map(leaf, tree_flat)
 
         # per-window degrees: one flat segment-sum over the masked edge set
         ones_flat = jnp.ones((k * m,), jnp.int32)
         in_deg = segment_combine(ones_flat, flat_dst, k * n, "sum",
-                                 em_flat, True).reshape(k, n)
+                                 em_flat, True, ends=ends,
+                                 pos=pos).reshape(k, n)
         out_deg = segment_combine(ones_flat, flat_src, k * n, "sum",
                                   em_flat, False).reshape(k, n)
 
@@ -150,7 +160,8 @@ def make_mask_runner(program: VertexProgram, n: int, m: int, k: int):
             custom = program.combiner == "custom"
             agg, parts = None, []
             if program.direction in ("out", "both"):
-                payload = program.message(gather_flat(st, flat_src), ek)
+                with jax.named_scope("combine.gather"):
+                    payload = program.message(gather_flat(st, flat_src), ek)
                 if custom:
                     parts.append((payload, flat_dst, em_flat))
                 else:

@@ -734,8 +734,12 @@ class DeviceSweep:
         _faults.fire("device.dispatch")
         runner = _compiled_run(program, self.n_pad, self.m_pad, len(wlist),
                                np.dtype(self.tdtype).name)
+        # a sum at the destination alone is a scan over the sorted rows;
+        # every other combine still scatters over them (docs/KERNELS.md)
+        scan = program.combiner == "sum" and program.direction == "out"
         with TRACER.span("hop.compute", time=int(T), windows=len(wlist),
-                            engine="device_sweep"):
+                            engine="device_sweep",
+                            combine="scan" if scan else "scatter"):
             result, steps = runner(
                 *self._bufs, self.vids, self.e_src, self.e_dst,
                 jnp.asarray(int(T), jnp.int64),

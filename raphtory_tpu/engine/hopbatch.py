@@ -41,7 +41,8 @@ from ..core.sweep import (fold_cache, fold_pool, fold_workers,
                           log_fingerprint, prefetch_map)
 from ..obs import ledger as _ledger
 from ..obs.trace import TRACER
-from ..ops.segment import segment_counts, segment_mode
+from ..ops.segment import (segment_counts, segment_ends_pos, segment_mode,
+                           sorted_segment_sum, sum_route)
 from ..utils.transfer import _metrics
 from .device_sweep import (_device_edges, log_index, normalize_windows,
                            sweep_phase_summary)
@@ -143,6 +144,33 @@ def _edge_tile_for(m_pad: int, C: int, budget_bytes: int) -> int | None:
     return min((target // step) * step, m_pad)
 
 
+# A gather table this small the compiler keeps in the chip's fast memory,
+# where a row gather costs 1.8 ns a row; out of it 9.9 (docs/KERNELS.md).
+_GATHER_TABLE_BYTES = 64 << 20
+_ROW_BYTES = 512    # a [rows, <= 128] f32 table is lane-padded to 128
+
+
+def _gather_pack(n_pad: int, C: int) -> int:
+    """Vertices a row of the superstep's gather table holds: the smallest
+    power of two that brings the lane-padded table under
+    ``_GATHER_TABLE_BYTES`` (1: the plain ``[n_pad, C]`` table), no more
+    than fit a row's 128 lanes."""
+    P = 1
+    while ((n_pad // P) * _ROW_BYTES > _GATHER_TABLE_BYTES
+           and 2 * P * C <= 128 and n_pad % (2 * P) == 0):
+        P *= 2
+    return P
+
+
+def _combine_route(m_pad: int, C: int, tile_budget: int) -> str:
+    """How a PageRank dispatch of ``C`` columns over ``m_pad`` rows sums at
+    the destination: ``scan`` (``ops/segment.sorted_segment_sum``) or
+    ``scatter`` (the edge-tiled path and the wide dispatches) —
+    ``hop.compute``'s ``combine``."""
+    tiled = _edge_tile_for(m_pad, C, tile_budget) is not None
+    return "scatter" if tiled else sum_route(C)
+
+
 def _pagerank_columns(me, mv, e_src, e_dst, n_pad: int, damping: float,
                       tol: float, max_steps: int, r_init=None,
                       tile_budget: int | None = None):
@@ -215,6 +243,16 @@ def _pagerank_columns(me, mv, e_src, e_dst, n_pad: int, damping: float,
         r0 = warm.astype(jnp.float32)
     inv_deg = 1.0 / jnp.maximum(out_deg, 1.0)
     dangling_mask = mv & (out_deg == 0)
+    ends, pos, P = None, None, 1
+    if _combine_route(e_src.shape[0], C, tile_budget) == "scan":
+        # where each destination's rows end and how far into its segment
+        # a row lies: functions of e_dst alone, once a dispatch, outside
+        # the superstep loop
+        ends, pos = segment_ends_pos(e_dst, n_pad)
+        # the gather table holds P vertices a row, so that it stays in
+        # fast memory: a pair reads row e_src // P and keeps slot e_src % P
+        P = _gather_pack(n_pad, C)
+    e_row, e_slot = e_src // P, e_src % P
 
     def body(carry):
         step, r, halted = carry
@@ -223,12 +261,21 @@ def _pagerank_columns(me, mv, e_src, e_dst, n_pad: int, damping: float,
             agg = tiled_sum(
                 lambda es, mk: jnp.where(mk, rd[es, :], 0.0), by_dst=True)
         else:
-            # row gather [m, C]; the bool mask gates via where — only the
-            # bool mask stays live across the loop
-            payload = jnp.where(me, rd[e_src, :], 0.0)
-            agg = jax.ops.segment_sum(
-                payload, e_dst, num_segments=n_pad,
-                indices_are_sorted=True)
+            with jax.named_scope("combine.gather"):
+                # a row gather [m, P * C], the row's slot [m, C]; the
+                # bool mask gates via where — only it stays live across
+                # the loop
+                rows = rd.reshape(n_pad // P, P * C)[e_row, :]
+                g = rows[:, :C]
+                for slot in range(1, P):
+                    g = jnp.where((e_slot == slot)[:, None],
+                                  rows[:, slot * C:(slot + 1) * C], g)
+                payload = jnp.where(me, g, 0.0)
+            # a segmented scan along the rows and one gather of n_pad
+            # rows, no scatter over the m rows; past SCAN_MAX_COLUMNS
+            # columns the scatter, which then costs less (docs/KERNELS.md)
+            agg = sorted_segment_sum(payload, e_dst, n_pad, ends=ends,
+                                     pos=pos)
         dangling = jnp.sum(jnp.where(dangling_mask, r, 0.0), axis=0)
         new = ((1.0 - damping) / n_act[None, :]
                + damping * (agg + dangling[None, :] / n_act[None, :]))
@@ -394,10 +441,11 @@ def run_columns_delta(kind, tables, base, deltas_e, deltas_v, hop_times,
             for h, (p, v) in enumerate(weight_deltas):
                 dw_pos[h, : len(p)] = p
                 dw_val[h, : len(v)] = v
+    tile_budget = _tile_budget_bytes()
     runner = _compiled_delta(kind, tables.n_pad, tables.m_pad, H, W,
                              U_e, U_v, np.dtype(tdt).name,
                              r_init is not None, tuple(algo_args),
-                             weighted, U_w, h0_delta, _tile_budget_bytes())
+                             weighted, U_w, h0_delta, tile_budget)
     if ship_counter is not None:
         # FOLD-STATE host→device payload of THIS dispatch (padded shapes;
         # device-resident inputs — h0 base, cached tables — ship nothing).
@@ -424,8 +472,12 @@ def run_columns_delta(kind, tables, base, deltas_e, deltas_v, hop_times,
     # errors (device-resident inputs pass through untouched)
     from ..utils.transfer import shared_engine
 
+    # how the dispatch combines at the destination: PageRank's sum is a
+    # scan or (tiled, wide) a scatter; min / max scatter; CDLP sorts
+    combine = {"pagerank": _combine_route(tables.m_pad, C, tile_budget),
+               "cdlp": "sort"}.get(kind, "scatter")
     with TRACER.span("hop.compute", kind=kind, hops=H, cols=H * W,
-                        resident_base=h0_delta):
+                        resident_base=h0_delta, combine=combine):
         return runner(*shared_engine().put_many([
             e_src_dev if e_src_dev is not None else tables.e_src,
             e_dst_dev if e_dst_dev is not None else tables.e_dst,
@@ -2035,15 +2087,17 @@ class HopBatchedCDLP(_HopBatched):
 
 
 def _dispatch_columns(runner, tables, cols, hop_of_col, T_col,
-                      w_col, e_src_dev, e_dst_dev, *extra):
+                      w_col, e_src_dev, e_dst_dev, *extra,
+                      combine: str = "scatter"):
     """Shared device dispatch for the columnar runners (`extra` appends
-    runner-specific trailing args, e.g. the BFS seed mask). The payload —
+    runner-specific trailing args, e.g. the BFS seed mask; ``combine`` is
+    how the program combines at the destination). The payload —
     on the host-column path the [H, m_pad] fold columns, the largest
     per-dispatch ship in the system — goes through the pipelined transfer
     engine: array k+1 stages while k is on the wire, per-slice retry."""
     from ..utils.transfer import shared_engine
 
-    with TRACER.span("hop.compute", cols=int(len(T_col))):
+    with TRACER.span("hop.compute", cols=int(len(T_col)), combine=combine):
         return runner(*shared_engine().put_many([
             e_src_dev if e_src_dev is not None else tables.e_src,
             e_dst_dev if e_dst_dev is not None else tables.e_dst,
@@ -2278,12 +2332,14 @@ def run_columns(tables, e_lat, e_alive, v_lat, v_alive, hop_times, windows,
     warm-starts the power iteration: the kernel slices its last hop's W
     rows and tiles them per hop IN-PROGRAM — see ``_compiled``."""
     H, C, hop_of_col, T_col, w_col = _column_layout(hop_times, windows)
+    tile_budget = _tile_budget_bytes()
     runner = _compiled(tables.n_pad, tables.m_pad, H, C, float(damping),
                        float(tol), int(max_steps),
                        np.dtype(tables.tdtype).name, r_init is not None,
-                       _tile_budget_bytes())
+                       tile_budget)
     extra = () if r_init is None else (r_init,)
     return _dispatch_columns(runner, tables,
                              (e_lat, e_alive, v_lat, v_alive),
                              hop_of_col, T_col, w_col, e_src_dev, e_dst_dev,
-                             *extra)
+                             *extra, combine=_combine_route(
+                                 tables.m_pad, C, tile_budget))

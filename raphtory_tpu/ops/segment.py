@@ -10,6 +10,8 @@ actor mailboxes.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -39,6 +41,8 @@ def segment_combine(
     op: str,
     mask: jnp.ndarray | None = None,
     indices_are_sorted: bool = True,
+    ends: jnp.ndarray | None = None,
+    pos: jnp.ndarray | None = None,
 ):
     """Combine per-edge payloads at their destination vertex.
 
@@ -46,16 +50,161 @@ def segment_combine(
     combiner's neutral element so padded edges are no-ops. `indices_are_sorted`
     may only be True when ids are sorted INCLUDING padding rows — the snapshot
     builder pads e_dst with n_pad-1 (the max id) to preserve the promise.
+    A sorted sum is ``sorted_segment_sum``: a caller whose ids do not change
+    between calls (the supersteps of one dispatch) computes ``ends`` / ``pos``
+    once (``segment_ends_pos``) and passes them, the way ``counts`` reaches
+    ``segment_mode``.
     """
     if op not in _SEG:
         raise ValueError(f"unknown combiner {op!r}; use one of {sorted(_SEG)}")
     if mask is not None:
         m = mask.reshape(mask.shape + (1,) * (data.ndim - mask.ndim))
         data = jnp.where(m, data, neutral(op, data.dtype))
+    if op == "sum" and indices_are_sorted:
+        return sorted_segment_sum(data, segment_ids, num_segments,
+                                  ends=ends, pos=pos)
     return _SEG[op](
         data, segment_ids, num_segments=num_segments,
         indices_are_sorted=indices_are_sorted,
     )
+
+
+# A scan costs per ELEMENT, XLA's scatter-add per ROW up to 128 lanes: past
+# this many columns the scatter wins (docs/KERNELS.md holds the readings).
+SCAN_MAX_COLUMNS = 16
+_SCAN_BLOCK = 128   # rows a block: one lane row
+
+
+def sum_route(cols: int) -> str:
+    """Which sum ``sorted_segment_sum`` runs for ``cols`` columns a row:
+    ``scan`` or ``scatter``."""
+    return "scan" if cols <= SCAN_MAX_COLUMNS else "scatter"
+
+
+def _rows_upto(ids: jnp.ndarray, queries: jnp.ndarray):
+    """For each query, how many of the SORTED ``ids`` are <= it
+    (``searchsorted(side="right")``) as a search tree 128 wide: the blocks
+    whose last id is <= the query count whole (the same question of the
+    blocks' last ids), then ONE row gather of the first block that is not
+    and a count along its lanes. Three levels at 4M ids where a binary
+    search is 22 flat gathers, which cost four times a row gather each."""
+    m = ids.shape[0]
+    B = _SCAN_BLOCK
+    if m <= 4 * B:
+        return jnp.sum(ids[None, :] <= queries[:, None], axis=1,
+                       dtype=jnp.int32)
+    nb = -(-m // B)
+    blocks = jnp.pad(ids, (0, nb * B - m),
+                     constant_values=jnp.iinfo(ids.dtype).max).reshape(nb, B)
+    whole = _rows_upto(blocks[:, -1], queries)      # blocks all <= query
+    part = jnp.sum(blocks[jnp.minimum(whole, nb - 1), :] <= queries[:, None],
+                   axis=1, dtype=jnp.int32)
+    return jnp.where(whole == nb, m, whole * B + part)
+
+
+# jitted, like ``_segmented_scan``: inside a caller's trace each is ONE
+# cached call, not a hundred jnp wrappers traced anew — a program that is
+# re-traced a request (the mesh column route) would otherwise fill the
+# flight recorder's ring with ``xla.trace`` events.
+@functools.partial(jax.jit, static_argnums=1)
+def segment_ends_pos(segment_ids: jnp.ndarray, num_segments: int):
+    """What ``sorted_segment_sum`` needs of the SORTED ids alone: ``ends
+    [num_segments]``, each segment's last row (-1 for an empty segment),
+    and ``pos [m]``, each row's offset inside its segment. With sorted ids
+    "row i - d is in row i's segment" is exactly ``pos[i] >= d``. No
+    scatter over the rows: a running maximum of the segment starts (the
+    scan below over one segment, with ``maximum``: ``lax.cummax`` computes
+    the same and takes the TPU's compiler 4.7 s at 4M rows where this
+    takes 0.6) and a search of the ids (``_rows_upto``)."""
+    m = segment_ids.shape[0]
+    idx = jnp.arange(m, dtype=jnp.int32)
+    start = jnp.concatenate(
+        [jnp.ones((1,), bool), segment_ids[1:] != segment_ids[:-1]])
+    pos = idx - _segmented_scan(jnp.where(start, idx, 0), idx, jnp.maximum)
+    upto = _rows_upto(segment_ids,
+                      jnp.arange(num_segments, dtype=segment_ids.dtype))
+    before = jnp.concatenate([jnp.zeros((1,), jnp.int32), upto[:-1]])
+    return jnp.where(upto > before, upto - 1, -1), pos
+
+
+def _shift(x: jnp.ndarray, d: int):
+    """``x`` moved ``d`` places up its last axis, zeros moving in."""
+    return jnp.pad(x[..., :-d], [(0, 0)] * (x.ndim - 1) + [(d, 0)])
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _segmented_scan(x: jnp.ndarray, pos: jnp.ndarray, op=jnp.add):
+    """Inclusive scan (``op``: add, or maximum over non-negative values —
+    0 is the neutral element) along the LAST axis of ``x [..., m]`` inside
+    the segments ``pos [m]`` describes. Contiguous shifted steps ``x[i] =
+    op(x[i], x[i - d])`` where ``pos[i] >= d``, d = 1, 2, 4, ...: a row
+    takes in rows of its own segment only, pairwise. Blocked: the steps
+    inside blocks of one lane row, the same scan over the block carries (a
+    block's last row lies ``pos // block`` blocks into its segment), one
+    pass to bring the carry to the rows whose segment began in an earlier
+    block."""
+    m = x.shape[-1]
+    B = _SCAN_BLOCK
+    if m <= B:
+        d = 1
+        while d < m:
+            x = op(x, jnp.where(pos >= d, _shift(x, d), 0))
+            d *= 2
+        return x
+    nb = -(-m // B)
+    if nb * B != m:   # a pad row is a segment of its own
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, nb * B - m)])
+        pos = jnp.pad(pos, (0, nb * B - m))
+    xb = x.reshape(x.shape[:-1] + (nb, B))
+    pb = pos.reshape(nb, B)
+    d = 1
+    while d < B:
+        xb = op(xb, jnp.where(pb >= d, _shift(xb, d), 0))
+        d *= 2
+    carry = _shift(_segmented_scan(xb[..., -1], pb[:, -1] // B, op), 1)
+    lane = jnp.arange(B, dtype=pos.dtype)
+    xb = op(xb, jnp.where(pb > lane, carry[..., None], 0))
+    return xb.reshape(x.shape)[..., :m]
+
+
+def sorted_segment_sum(
+    data: jnp.ndarray,
+    segment_ids: jnp.ndarray,
+    num_segments: int,
+    *,
+    ends: jnp.ndarray | None = None,
+    pos: jnp.ndarray | None = None,
+):
+    """``jax.ops.segment_sum`` for ids SORTED including padding rows, with
+    no scatter over the rows: a segmented inclusive scan along the rows,
+    then one gather of ``num_segments`` rows at the segments' last rows.
+
+    XLA's scatter-add walks the rows one by one whether or not the ids are
+    sorted (9.5 ns a row on a TPU v5e); the scan is about ten lane-dense
+    passes. Each segment adds only its own rows, pairwise — float32-accurate
+    with no cancellation (a global ``cumsum`` differenced at the segment ends
+    would carry the whole prefix's rounding into a small segment). ``data``
+    is ``[m]`` or ``[m, ...]``; with columns the scan runs with the rows on
+    the minor axis (``[C, m]``), never on the lane-padded ``[m, C]`` buffer.
+    Past ``SCAN_MAX_COLUMNS`` columns the scatter is the cheaper one and
+    serves. ``ends`` / ``pos`` (``segment_ends_pos``) depend on the ids
+    alone: a caller inside a loop computes them outside it."""
+    m = data.shape[0]
+    cols = data.size // max(m, 1)
+    if m == 0 or sum_route(cols) == "scatter":
+        return jax.ops.segment_sum(data, segment_ids,
+                                   num_segments=num_segments,
+                                   indices_are_sorted=True)
+    if ends is None or pos is None:
+        ends, pos = segment_ends_pos(segment_ids, num_segments)
+    with jax.named_scope("combine.scan"):
+        x = data if data.ndim == 1 else data.reshape(m, cols).T
+        x = _segmented_scan(x, pos)
+    with jax.named_scope("combine.pick"):
+        out = jnp.where(ends >= 0, x[..., jnp.maximum(ends, 0)], 0)
+        if data.ndim > 1:
+            out = out.T.reshape((num_segments,) + data.shape[1:])
+    return out
 
 
 _V_BITS = 31  # segment_mode value budget: non-negative ints < 2**31 - 1

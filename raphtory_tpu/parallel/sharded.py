@@ -53,7 +53,7 @@ from ..engine.bsp import _elem, _merge_aggs
 from ..engine.program import (Context, Edges, VertexProgram,
                               check_custom_direction, custom_exchange)
 from ..obs.trace import TRACER
-from ..ops.segment import segment_combine
+from ..ops.segment import segment_combine, segment_ends_pos
 
 V_AXIS = "vertices"
 W_AXIS = "windows"
@@ -734,19 +734,28 @@ def _sharded_runner(program: VertexProgram, mesh: Mesh, n_loc: int,
             return jnp.broadcast_to(a[None, :], (k_loc,) + a.shape).reshape(
                 (k_loc * m_loc_s,) + a.shape[1:])
 
-        def combine_flat(tree_flat, ids, msk):
+        # both id sets are sorted, so a sum over them is a segmented scan
+        # (ops/segment.sorted_segment_sum); where the segments lie depends
+        # on the ids alone: once, before the superstep loop
+        plan_d = segment_ends_pos(fl_d_dst, k_loc * n_loc)
+        plan_s = segment_ends_pos(fl_s_src, k_loc * n_loc)
+
+        def combine_flat(tree_flat, ids, msk, plan):
             def leaf(x):
                 out = segment_combine(x, ids, k_loc * n_loc, program.combiner,
-                                      msk, indices_are_sorted=True)
+                                      msk, indices_are_sorted=True,
+                                      ends=plan[0], pos=plan[1])
                 return out.reshape((k_loc, n_loc) + x.shape[1:])
             return jax.tree_util.tree_map(leaf, tree_flat)
 
         in_deg = segment_combine(
             jnp.ones((k_loc * m_loc_d,), jnp.int32), fl_d_dst,
-            k_loc * n_loc, "sum", dm_flat, True).reshape(k_loc, n_loc)
+            k_loc * n_loc, "sum", dm_flat, True,
+            ends=plan_d[0], pos=plan_d[1]).reshape(k_loc, n_loc)
         out_deg = segment_combine(
             jnp.ones((k_loc * m_loc_s,), jnp.int32), fl_s_src,
-            k_loc * n_loc, "sum", sm_flat, True).reshape(k_loc, n_loc)
+            k_loc * n_loc, "sum", sm_flat, True,
+            ends=plan_s[0], pos=plan_s[1]).reshape(k_loc, n_loc)
 
         def mk_ctx(kk, step):
             n_act = jnp.sum(v_mask[kk].astype(jnp.int32))
@@ -791,7 +800,7 @@ def _sharded_runner(program: VertexProgram, mesh: Mesh, n_loc: int,
                 if custom:
                     parts.append((payload, fl_d_dst, dm_flat))
                 else:
-                    agg = combine_flat(payload, fl_d_dst, dm_flat)
+                    agg = combine_flat(payload, fl_d_dst, dm_flat, plan_d)
             if program.direction in ("in", "both"):
                 edges = Edges(src=tile_s(s_src_l) + v_off, dst=tile_s(s_dst_g),
                               mask=sm_flat, time=tile_s(s_time),
@@ -803,7 +812,8 @@ def _sharded_runner(program: VertexProgram, mesh: Mesh, n_loc: int,
                 if custom:
                     parts.append((payload, fl_s_src, sm_flat))
                 else:
-                    agg_in = combine_flat(payload, fl_s_src, sm_flat)
+                    agg_in = combine_flat(payload, fl_s_src, sm_flat,
+                                          plan_s)
                     agg = agg_in if agg is None else _merge_aggs(
                         program.combiner, agg, agg_in)
             if custom:

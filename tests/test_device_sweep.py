@@ -9,15 +9,18 @@ label decodes to, which is the component's minimum id in both spaces).
 import dataclasses
 import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from raphtory_tpu.algorithms import ConnectedComponents, DegreeBasic, PageRank
 from raphtory_tpu.core.snapshot import build_view
-from raphtory_tpu.engine import bsp
+from raphtory_tpu.engine import bsp, hopbatch
 from raphtory_tpu.engine.device_sweep import (DeviceSweep, _compiled_run,
                                               supported)
+
+from raphtory_tpu.ops.segment import SCAN_MAX_COLUMNS
 
 from test_sweep import random_log
 
@@ -184,6 +187,96 @@ def _compiled_gathers(ds, program, k):
             jnp.asarray(0, jnp.int64), jnp.zeros((k,), jnp.int64))
     text = runner.fn.lower(*args).compile().as_text()
     return len(re.findall(r" gather\(", text))
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for j in (v if isinstance(v, (list, tuple)) else [v]):
+            j = getattr(j, "jaxpr", j)
+            if hasattr(j, "eqns"):
+                yield j
+
+
+def _walk(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in _sub_jaxprs(eqn):
+            yield from _walk(sub)
+
+
+def _superstep_loops(fn, *args):
+    """``(primitive names, combine.* scopes)`` of each ``while`` body of
+    the program ``fn(*args)`` traces to that holds a ``combine.*`` scope:
+    the superstep loops. What is traced is what every backend lowers."""
+    loops = []
+    for eqn in _walk(jax.make_jaxpr(fn)(*args).jaxpr):
+        if eqn.primitive.name != "while":
+            continue
+        inside = list(_walk(eqn.params["body_jaxpr"].jaxpr))
+        scopes = {m for e in inside for m in re.findall(
+            r"combine\.\w+", str(e.source_info.name_stack))}
+        if scopes:
+            loops.append(({e.primitive.name for e in inside}, scopes))
+    return loops
+
+
+def _delta_pagerank_args(n_pad, m_pad, H, W, U):
+    S, i32, tdt = jax.ShapeDtypeStruct, jnp.int32, jnp.int32
+    delta = (S((H, U), i32), S((H, U), tdt), S((H, U), bool))
+    return (S((m_pad,), i32), S((m_pad,), i32),
+            S((m_pad,), tdt), S((m_pad,), bool),
+            S((n_pad,), tdt), S((n_pad,), bool), *delta, *delta,
+            S((H * W,), jnp.int64), S((H * W,), jnp.int64))
+
+
+@pytest.mark.parametrize("H,W", [(2, 3), (1, 1)], ids=["C6", "C1"])
+def test_columnar_pagerank_superstep_scans_and_never_scatters(H, W):
+    """Inside the superstep loop of ``hopbatch.delta.pagerank`` the sum at
+    the destination is a segmented scan and one gather (the three
+    ``combine.*`` scopes): no scatter over the m rows, and what depends on
+    ``e_dst`` alone (``ends`` / ``pos``: a running maximum, a binary
+    search) is computed outside it. The one scatter a dispatch that is
+    left is ``out_deg`` at the source, before the loop."""
+    n_pad, m_pad, U = 256, 2048, 256
+    runner = hopbatch._compiled_delta(
+        "pagerank", n_pad, m_pad, H, W, U, U, "int32", False,
+        (0.85, 0.0, 20), tile_budget=hopbatch._tile_budget_bytes())
+    (prims, scopes), = _superstep_loops(
+        runner.fn, *_delta_pagerank_args(n_pad, m_pad, H, W, U))
+    assert scopes == {"combine.gather", "combine.scan", "combine.pick"}
+    assert not {p for p in prims if p.startswith("scatter")}, prims
+    assert not prims & {"cummax", "cumsum", "while", "sort"}, prims
+
+
+@pytest.mark.parametrize("windows", [[30], [100, 30, 7]], ids=["k1", "k3"])
+def test_resident_pagerank_superstep_scans_and_never_scatters(windows):
+    """The same for ``device_sweep.superstep.PageRank``: ``make_mask_runner``
+    computes ``ends`` / ``pos`` from ``flat_dst`` beside ``in_deg``, before
+    the loop, and hands them to ``segment_combine``."""
+    ds = _churned_sweep()
+    k = len(windows)
+    runner = _compiled_run(PageRank(max_steps=20, tol=0.0), ds.n_pad,
+                           ds.m_pad, k, np.dtype(ds.tdtype).name)
+    args = (*ds._bufs, ds.vids, ds.e_src, ds.e_dst,
+            jnp.asarray(0, jnp.int64), jnp.zeros((k,), jnp.int64))
+    (prims, scopes), = _superstep_loops(runner.fn, *args)
+    assert scopes == {"combine.gather", "combine.scan", "combine.pick"}
+    assert not {p for p in prims if p.startswith("scatter")}, prims
+    assert not prims & {"cummax", "cumsum", "while", "sort"}, prims
+
+
+def test_a_wide_columnar_dispatch_keeps_the_scatter():
+    """Past ``SCAN_MAX_COLUMNS`` columns the scatter costs less than the
+    scan (docs/KERNELS.md): the program adapts on the shape."""
+    H, W = 4, SCAN_MAX_COLUMNS // 4 + 1
+    n_pad, m_pad, U = 256, 2048, 256
+    runner = hopbatch._compiled_delta(
+        "pagerank", n_pad, m_pad, H, W, U, U, "int32", False,
+        (0.85, 0.0, 20), tile_budget=hopbatch._tile_budget_bytes())
+    (prims, scopes), = _superstep_loops(
+        runner.fn, *_delta_pagerank_args(n_pad, m_pad, H, W, U))
+    assert scopes == {"combine.gather"} and "scatter-add" in prims
 
 
 PER_EDGE_WINDOWS = [[30], [100, 30, 7]]

@@ -35,6 +35,9 @@ def _assert_columns_match_per_view(hb, cols, program, log, hops, windows,
 
 
 def _close(atol):
+    # columns against bsp: two different sums of the same ranks (a scan
+    # over [C, m] rows against a scan over the view's own flat rows), so
+    # the ranks agree to float32 reassociation, not bit for bit
     def agree(want, got, view, uv):
         np.testing.assert_allclose(got, want, atol=atol, rtol=0)
     return agree
@@ -400,6 +403,8 @@ def test_edge_tiled_pagerank_matches_single_shot(monkeypatch):
         tiled, s2 = HopBatchedPageRank(log, tol=0.0, max_steps=8).run(
             hops, windows)
         assert used and used[-1] is not None   # the tiled path really ran
+        # single-shot is the segmented scan, tiled the scatter a tile:
+        # another order of the same float32 adds, hence a tolerance
         np.testing.assert_allclose(one, np.asarray(tiled), atol=1e-6)
         assert int(s1) == int(s2)
         # min-combine kernels tile exactly (no reassociation concern)
@@ -684,6 +689,7 @@ def test_edge_tiled_matches_single_shot_at_cell_widths(monkeypatch,
                          for m, t in tiles)
     assert int(s1) == int(s2)
     if family == "pagerank":
+        # scan (single-shot) against scatter (tiled): the sum reassociates
         np.testing.assert_allclose(np.asarray(one), np.asarray(tiled),
                                    atol=1e-6, rtol=0)
     else:
@@ -826,3 +832,43 @@ def test_one_chip_default_route_is_the_mesh_routes_table():
     np.testing.assert_allclose(np.asarray(one), np.asarray(many),
                                rtol=1e-5, atol=1e-7)
     assert int(steps1) == int(steps2)
+
+
+@pytest.mark.parametrize("pack", [4, 16], ids=["P4", "P16"])
+def test_packed_gather_table_is_a_selection_bit_for_bit(monkeypatch, pack):
+    """Past ``_GATHER_TABLE_BYTES`` the superstep's gather table holds P
+    vertices a row (so that it stays in the chip's fast memory) and a pair
+    keeps its slot of the row it read: the same floats, so (on the CPU
+    backend, where no reduction's order depends on a layout) the same ranks
+    bit for bit, one route with itself — and the dispatch really packed."""
+    from raphtory_tpu.engine import hopbatch as hb_mod
+
+    log = random_log(np.random.default_rng(33), n_events=900, n_ids=60,
+                     t_span=100)
+    hops, windows = [40, 70, 99], [1000, 25]
+    plain = HopBatchedPageRank(log, tol=0.0, max_steps=20)
+    n_pad = plain.tables.n_pad
+    assert hb_mod._gather_pack(n_pad, 6) == 1
+    one, s1 = plain.run(hops, windows)
+    monkeypatch.setattr(hb_mod, "_GATHER_TABLE_BYTES", n_pad * 512 // pack)
+    assert hb_mod._gather_pack(n_pad, 6) == pack
+    hb_mod._compiled_delta.cache_clear()    # the pack is chosen at trace time
+    try:
+        packed, s2 = HopBatchedPageRank(log, tol=0.0, max_steps=20).run(
+            hops, windows)
+    finally:
+        hb_mod._compiled_delta.cache_clear()
+    assert int(s1) == int(s2) == 20
+    np.testing.assert_array_equal(np.asarray(one), np.asarray(packed))
+
+
+def test_gather_pack_follows_the_table_size_and_the_lane_width():
+    """The cells' shapes: the plain table (1 vertex a row) at 2^17 ids,
+    where it is 64 MiB, 2 vertices a row at 2^18; never more than fit 128
+    lanes."""
+    from raphtory_tpu.engine.hopbatch import _gather_pack
+
+    assert [_gather_pack(n, 6) for n in (1024, 65_536, 131_072, 262_144,
+                                         393_216)] == [1, 1, 1, 2, 4]
+    assert _gather_pack(1 << 22, 6) == 16 and _gather_pack(1 << 22, 16) == 8
+    assert _gather_pack(1 << 22, 1) == 32 and _gather_pack(131_072, 1) == 1

@@ -370,3 +370,30 @@ def test_tracez_returns_self_seconds_for_a_trace(traced, log):
               if s["ph"] == "X" and s["tid"] == tid
               and s["name"] != "job.publish")
     assert own == pytest.approx(root["dur"], rel=0.01)
+
+
+def test_a_retraced_program_traces_the_scanned_sum_as_cached_calls(traced):
+    """The mesh column route builds a NEW jit object a request, so JAX
+    traces its program again a request and every jnp wrapper traced is one
+    ``xla.trace`` event in the flight recorder's ring (4,096 events: the
+    benchmark reads a request's spans from it after the window). The
+    scanned sum's helpers are jitted, so a re-trace sees a few cached calls
+    where the unrolled scan would be some two hundred wrappers — which
+    pushed the window's first request out of the ring on the chip."""
+    from raphtory_tpu.ops.segment import sorted_segment_sum
+
+    assert obs_device.watch_jax_builds()
+    ids = jnp.sort(jnp.arange(5000, dtype=jnp.int32) % 37)
+    data = jnp.ones((5000, 6), jnp.float32)
+
+    def traces():
+        return obs_device.jax_builds_block()["stages"]["trace"]["count"]
+
+    def fresh():
+        return jax.jit(lambda d: sorted_segment_sum(d, ids, 37))(
+            data).block_until_ready()
+
+    fresh()                               # everything built once
+    before = traces()
+    fresh()                               # a new jit object: traced anew
+    assert 1 <= traces() - before <= 25, traces() - before
