@@ -911,4 +911,12 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    code = main()
+    sys.stdout.flush()
+    sys.stderr.flush()
+    # as benchmark/run.py leaves: everything the smoke started is stopped
+    # and its last line is out; the interpreter's teardown can abort the
+    # process where a daemon thread of the program is still inside C++
+    # ("FATAL: exception not rethrown", exit -6 after the last line — one
+    # rehearsal in four at the parent of PR 37, under load)
+    os._exit(code)

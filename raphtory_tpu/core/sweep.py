@@ -266,33 +266,38 @@ class SweepBuilder:
                  preseed_pairs: bool = False):
         if include_occurrences and not track_rows:
             raise ValueError("occurrence views need the add-row lists")
-        self.log = log.pin()
         self.include_occurrences = include_occurrences
         self.pad = pad
         self.track_rows = track_rows
-        self._t = self.log.column("time")
-        self._k = self.log.column("kind")
-        self._s = self.log.column("src")
-        self._d = self.log.column("dst")
-        # dense dictionary over every vertex id the log ever mentions. dst is
-        # only a vertex id on edge events — vertex events carry a -1 sentinel
-        # there, and REAL ids can be negative (assign_id hashes to signed
-        # int64), so select by kind, never by sign.
-        is_e = (self._k == EDGE_ADD) | (self._k == EDGE_DELETE)
-        d_real = self._d[is_e]
-        self.uv = np.unique(np.concatenate([self._s, d_real])) \
-            if len(self._s) else np.empty(0, np.int64)
-        self._ok = len(self.uv) < (1 << 31)
-        # per-row dense ids, computed ONCE: per-hop _advance slices these
-        # instead of re-running searchsorted over the dictionary for every
-        # delta (the dominant host cost of a columnar sweep). Skipped above
-        # 2^23 events, where the 16B/event would hurt more than it helps.
-        if self._ok and 0 < len(self._s) <= (1 << 23):
-            self._sd_all = np.searchsorted(self.uv, self._s)
-            self._dd_all = np.zeros(len(self._d), np.int64)
-            self._dd_all[is_e] = np.searchsorted(self.uv, d_real)
-        else:
-            self._sd_all = self._dd_all = None
+        # stage span (an ``engine.build`` is mostly this constructor): the
+        # pin, the id dictionary and the per-row dense ids
+        with _span("index.ids") as sp:
+            self.log = log.pin()
+            self._t = self.log.column("time")
+            self._k = self.log.column("kind")
+            self._s = self.log.column("src")
+            self._d = self.log.column("dst")
+            # dense dictionary over every vertex id the log ever mentions.
+            # dst is only a vertex id on edge events — vertex events carry
+            # a -1 sentinel there, and REAL ids can be negative (assign_id
+            # hashes to signed int64), so select by kind, never by sign.
+            is_e = (self._k == EDGE_ADD) | (self._k == EDGE_DELETE)
+            d_real = self._d[is_e]
+            self.uv = np.unique(np.concatenate([self._s, d_real])) \
+                if len(self._s) else np.empty(0, np.int64)
+            self._ok = len(self.uv) < (1 << 31)
+            # per-row dense ids, computed ONCE: per-hop _advance slices
+            # these instead of re-running searchsorted over the dictionary
+            # for every delta (the dominant host cost of a columnar
+            # sweep). Skipped above 2^23 events, where the 16B/event would
+            # hurt more than it helps.
+            if self._ok and 0 < len(self._s) <= (1 << 23):
+                self._sd_all = np.searchsorted(self.uv, self._s)
+                self._dd_all = np.zeros(len(self._d), np.int64)
+                self._dd_all[is_e] = np.searchsorted(self.uv, d_real)
+            else:
+                self._sd_all = self._dd_all = None
+            sp.set(events=len(self._t), ids=len(self.uv))
         nv = len(self.uv)
         # dense vertex fold state
         self.v_lat = np.full(nv, INT64_MIN, np.int64)
@@ -319,20 +324,22 @@ class SweepBuilder:
         self.e_seen = np.empty(0, bool)   # pair has real marks (firsts set)
         self._preseeded = False
         if preseed_pairs and self._ok and is_e.any():
-            sd_e = np.searchsorted(self.uv, self._s[is_e]) \
-                if self._sd_all is None else self._sd_all[is_e]
-            dd_e = np.searchsorted(self.uv, d_real) \
-                if self._dd_all is None else self._dd_all[is_e]
-            enc_all = np.unique(self._pack(sd_e, dd_e))
-            self.e_enc = enc_all
-            self.e_lat = np.full(len(enc_all), INT64_MIN, np.int64)
-            self.e_alive = np.zeros(len(enc_all), bool)
-            self.e_first = np.full(len(enc_all), INT64_MIN, np.int64)
-            self.e_seen = np.zeros(len(enc_all), bool)
-            self.e_enc_dst = np.sort(
-                ((enc_all & _ENC_MASK) << _ENC_SHIFT)
-                | (enc_all >> _ENC_SHIFT))
-            self._preseeded = True
+            with _span("index.pairs") as sp:
+                sd_e = np.searchsorted(self.uv, self._s[is_e]) \
+                    if self._sd_all is None else self._sd_all[is_e]
+                dd_e = np.searchsorted(self.uv, d_real) \
+                    if self._dd_all is None else self._dd_all[is_e]
+                enc_all = np.unique(self._pack(sd_e, dd_e))
+                self.e_enc = enc_all
+                self.e_lat = np.full(len(enc_all), INT64_MIN, np.int64)
+                self.e_alive = np.zeros(len(enc_all), bool)
+                self.e_first = np.full(len(enc_all), INT64_MIN, np.int64)
+                self.e_seen = np.zeros(len(enc_all), bool)
+                self.e_enc_dst = np.sort(
+                    ((enc_all & _ENC_MASK) << _ENC_SHIFT)
+                    | (enc_all >> _ENC_SHIFT))
+                self._preseeded = True
+                sp.set(pairs=len(enc_all))
         # delete history: (dense vertex, time), sorted by vertex
         self.dh_v = np.empty(0, np.int64)
         self.dh_t = np.empty(0, np.int64)
@@ -428,6 +435,11 @@ class SweepBuilder:
         sw.last_delta = None
         return sw
 
+    def fork_nbytes(self) -> int:
+        """Bytes a ``fork`` of this builder copies: the fold-state arrays
+        ``_advance`` mutates in place (18 B an id + 18 B a pair)."""
+        return int(sum(getattr(self, k).nbytes for k in _STATE_COPIED))
+
     # ---- incremental re-pin (live epoch serving) ----
 
     def repin(self, live_log) -> str:
@@ -521,6 +533,15 @@ class SweepBuilder:
         return self._emit(time)
 
     def _advance(self, time: int) -> None:
+        """Fold the log's rows in ``(t_prev, time]`` into the running
+        state — under one ``fold.advance`` span whoever calls (a View's, a
+        Range unit's, a Live epoch's or the mesh route's fold; the bulk
+        advance to a checkpoint boundary too)."""
+        with _span("fold.advance", time=int(time)) as sp:
+            sp.set(rows=self._fold_rows(time))
+
+    def _fold_rows(self, time: int) -> int:
+        """``_advance``'s work; returns how many log rows it folded."""
         t_prev = self.t_prev if self.t_prev is not None else np.iinfo(np.int64).min
         if self._t_sorted:
             lo = 0 if t_prev == np.iinfo(np.int64).min \
@@ -534,7 +555,7 @@ class SweepBuilder:
         self.t_prev = time
         if len(rows) == 0:
             self.last_delta = _EMPTY_DELTA
-            return
+            return 0
         t = self._t[rows]
         k = self._k[rows]
         s = self._s[rows]
@@ -707,6 +728,7 @@ class SweepBuilder:
             "e_enc": te, "e_lat": self.e_lat[epos],
             "e_alive": self.e_alive[epos], "e_first": self.e_first[epos],
         }
+        return len(rows)
 
     def _emit(self, time: int) -> GraphView:
         if not self.track_rows:
@@ -776,6 +798,14 @@ def _tracer():
         return TRACER
     except Exception:
         return None
+
+
+def _span(name: str, **attrs):
+    """A span of the process tracer (a shared no-op with tracing off).
+    ``obs/trace.py`` is stdlib-only; imported on use like ``_tracer``."""
+    from ..obs.trace import TRACER
+
+    return TRACER.span(name, **attrs)
 
 
 def log_fingerprint(log) -> tuple:

@@ -34,6 +34,7 @@ such programs fall back to the ``bsp`` path, see ``supported()``).
 
 from __future__ import annotations
 
+import contextlib
 import copy
 import functools
 import threading as _threading
@@ -264,7 +265,8 @@ class LogIndex:
         # vertex-side) — no add-row tracking
         self.prototype = SweepBuilder(log, track_rows=False,
                                       preseed_pairs=True)
-        self.tables = GlobalTables(self.prototype)
+        with TRACER.span("index.tables"):
+            self.tables = GlobalTables(self.prototype)
 
     @property
     def nbytes(self) -> int:
@@ -313,17 +315,29 @@ def log_index(log: EventLog):
     index once, and a fork never sees a half-rebound prototype. ``tables``
     is SHARED between engines and must not be written. A frozen log is
     its own pin, so an entry would keep its weak key alive: such a log
-    gets an index of its own every time (status ``"miss"``), uncached."""
-    with _LOG_INDEX_LOCK:
-        idx = _LOG_INDEXES.get(log)
-        status = "miss" if idx is None else idx.adopt(log)
-        if status == "miss":
-            _LOG_INDEXES.pop(log, None)   # free the stale one first
-            idx = LogIndex(log)
-            if idx.prototype.log is not log:
-                _LOG_INDEXES[log] = idx
-        _LOG_INDEX_COUNTS[status] += 1
-        return idx.prototype.fork(), idx.tables, status
+    gets an index of its own every time (status ``"miss"``), uncached.
+
+    The stages are spans (children of the caller's ``engine.build``,
+    whose ``index`` attribute is the status): ``index.lookup`` — the wait
+    for the lock, which another request's miss holds for its whole build,
+    and ``adopt``; on a miss the build's ``index.ids`` / ``index.pairs``
+    (``SweepBuilder.__init__``) and ``index.tables``; ``index.fork``. A
+    hit writes the first and the last only."""
+    with contextlib.ExitStack() as lookup:
+        lookup.enter_context(TRACER.span("index.lookup"))
+        with _LOG_INDEX_LOCK:
+            idx = _LOG_INDEXES.get(log)
+            status = "miss" if idx is None else idx.adopt(log)
+            lookup.close()      # the span ends here, the lock is kept
+            if status == "miss":
+                _LOG_INDEXES.pop(log, None)   # free the stale one first
+                idx = LogIndex(log)
+                if idx.prototype.log is not log:
+                    _LOG_INDEXES[log] = idx
+            _LOG_INDEX_COUNTS[status] += 1
+            with TRACER.span("index.fork",
+                             nbytes=idx.prototype.fork_nbytes()):
+                return idx.prototype.fork(), idx.tables, status
 
 
 def log_index_status() -> dict:

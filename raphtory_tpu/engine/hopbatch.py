@@ -1444,19 +1444,24 @@ class _HopBatched:
         """Fork the sweep's builder at ``boundary`` (exclusive upper time
         of every earlier unit's hops): nearest cached checkpoint when one
         is ahead of the live builder, else the live state, then one bulk
-        advance — recorded back as a checkpoint for the next request."""
+        advance — recorded back as a checkpoint for the next request.
+        The lookup and the fork are one ``fold.seed`` span (``nbytes``:
+        what the fork copied), the advance the ``fold.checkpoint`` beside
+        it."""
+        with TRACER.span("fold.seed") as ssp:
+            cp = cache.nearest_checkpoint(fp, cfg, boundary) \
+                if cache is not None and boundary is not None else None
+            t0 = self.sw.t_prev
+            if cp is not None and (t0 is None or cp.t_prev > t0):
+                sw, seed = self.sw.fork(cp), "checkpoint"
+            else:
+                sw = self.sw.fork()
+                # "start": neither the cache nor the live builder holds a
+                # state, so this unit advances from the log's first event
+                seed = "start" if sw.t_prev is None else "live"
+            ssp.set(seed=seed, nbytes=sw.fork_nbytes())
         if boundary is None:
-            return self.sw.fork()
-        cp = cache.nearest_checkpoint(fp, cfg, boundary) \
-            if cache is not None else None
-        t0 = self.sw.t_prev
-        if cp is not None and (t0 is None or cp.t_prev > t0):
-            sw, seed = self.sw.fork(cp), "checkpoint"
-        else:
-            sw = self.sw.fork()
-            # "start": neither the cache nor the live builder holds a
-            # state, so this unit advances from the log's first event
-            seed = "start" if sw.t_prev is None else "live"
+            return sw
         if sw.t_prev is None or sw.t_prev < boundary:
             with TRACER.span("fold.checkpoint", time=int(boundary),
                                 seeded_from=(-1 if sw.t_prev is None
@@ -1502,31 +1507,37 @@ class _HopBatched:
         bit-identical to the serial ``_fold_columns``."""
         t = self.tables
         e_lat, e_alive, v_lat, v_alive = out
+        row_bytes = sum(a[0].nbytes for a in out)
         for j, T in enumerate(group):
             sw._advance(T)
             if hop_callback is not None:
                 hop_callback(T, sw)
             r = off + j
-            if j == 0:
-                pos = t.eng_pos(sw.e_enc)
-                e_lat[r, pos] = t.cast_times(sw.e_lat)
-                e_alive[r, pos] = sw.e_alive
-                nv = len(sw.uv)
-                v_lat[r, :nv] = t.cast_times(sw.v_lat)
-                v_alive[r, :nv] = sw.v_alive
-                continue
-            e_lat[r] = e_lat[r - 1]
-            e_alive[r] = e_alive[r - 1]
-            v_lat[r] = v_lat[r - 1]
-            v_alive[r] = v_alive[r - 1]
-            d = sw.last_delta
-            if len(d["e_enc"]):
-                dpos = t.eng_pos(d["e_enc"])
-                e_lat[r, dpos] = t.cast_times(d["e_lat"])
-                e_alive[r, dpos] = d["e_alive"]
-            if len(d["v_idx"]):
-                v_lat[r, d["v_idx"]] = t.cast_times(d["v_lat"])
-                v_alive[r, d["v_idx"]] = d["v_alive"]
+            # hop 0 writes the full fold state, every later hop memcpys
+            # the previous row (contiguous in this layout) and scatters
+            # only the hop's exact touched-entity delta
+            # (``sweep.last_delta``)
+            with TRACER.span("fold.payload", base=j == 0, bytes=row_bytes):
+                if j == 0:
+                    pos = t.eng_pos(sw.e_enc)
+                    e_lat[r, pos] = t.cast_times(sw.e_lat)
+                    e_alive[r, pos] = sw.e_alive
+                    nv = len(sw.uv)
+                    v_lat[r, :nv] = t.cast_times(sw.v_lat)
+                    v_alive[r, :nv] = sw.v_alive
+                else:
+                    e_lat[r] = e_lat[r - 1]
+                    e_alive[r] = e_alive[r - 1]
+                    v_lat[r] = v_lat[r - 1]
+                    v_alive[r] = v_alive[r - 1]
+                    d = sw.last_delta
+                    if len(d["e_enc"]):
+                        dpos = t.eng_pos(d["e_enc"])
+                        e_lat[r, dpos] = t.cast_times(d["e_lat"])
+                        e_alive[r, dpos] = d["e_alive"]
+                    if len(d["v_idx"]):
+                        v_lat[r, d["v_idx"]] = t.cast_times(d["v_lat"])
+                        v_alive[r, d["v_idx"]] = d["v_alive"]
 
     def _fold_deltas_fork(self, sw, group, ship_base: bool, hop_callback):
         """Delta fold of one unit on a FORKED builder — the parallel twin
@@ -1545,12 +1556,14 @@ class _HopBatched:
             sw._advance(T)
             if hop_callback is not None:
                 hop_callback(T, sw)
-            if j == 0 and ship_base:
-                base = self._materialise_base(sw)
-                deltas_e.append(empty)
-                deltas_v.append(empty)
-            else:
-                de, dv = self._delta_eng(sw.last_delta)
+            with TRACER.span("fold.payload") as sp:
+                if j == 0 and ship_base:
+                    base = self._materialise_base(sw)
+                    de = dv = empty
+                    sp.set(base=True, bytes=_payload_nbytes(base))
+                else:
+                    de, dv = self._delta_eng(sw.last_delta)
+                    sp.set(base=False, bytes=_payload_nbytes((de, dv)))
                 deltas_e.append(de)
                 deltas_v.append(dv)
         return (base, deltas_e, deltas_v)
@@ -1579,7 +1592,6 @@ class _HopBatched:
         # it would scatter one hop's delta onto a stale base
         self._delta_base = None
         self._drop_residency()
-        t = self.tables
         hop_times = [int(x) for x in hop_times]
         if sorted(hop_times) != hop_times:
             raise ValueError("hop_times must ascend")
@@ -1591,49 +1603,15 @@ class _HopBatched:
                 f"hop_times must continue forward from the previous batch "
                 f"(got {hop_times[0]} < {self.sw.t_prev}); build a fresh "
                 f"{type(self).__name__} to go back in history")
-        H = len(hop_times)
-
-        # host fold -> hop-major state columns [H, m_pad]/[H, n_pad]: hop 0
-        # writes the full fold state, every later hop memcpys the previous
-        # row (contiguous in this layout) and scatters only the hop's exact
-        # touched-entity delta (``sweep.last_delta``) — one O(m) scatter,
-        # then an O(m) contiguous memcpy plus an O(delta) scatter per hop,
-        # instead of an O(m) scattered write per hop
-        tdt = t.tdtype
-        e_lat = np.full((H, t.m_pad), t.tmin, tdt)
-        e_alive = np.zeros((H, t.m_pad), bool)
-        v_lat = np.full((H, t.n_pad), t.tmin, tdt)
-        v_alive = np.zeros((H, t.n_pad), bool)
-
-        for j, T in enumerate(hop_times):
-            self.sw._advance(T)
-            if hop_callback is not None:
-                # post-advance fold state, e.g. for per-hop reducer shells
-                hop_callback(T, self.sw)
-            if j == 0:
-                pos = t.eng_pos(self.sw.e_enc)
-                e_lat[0, pos] = t.cast_times(self.sw.e_lat)
-                e_alive[0, pos] = self.sw.e_alive
-                nv = len(self.sw.uv)
-                v_lat[0, :nv] = t.cast_times(self.sw.v_lat)
-                v_alive[0, :nv] = self.sw.v_alive
-                continue
-            e_lat[j] = e_lat[j - 1]
-            e_alive[j] = e_alive[j - 1]
-            v_lat[j] = v_lat[j - 1]
-            v_alive[j] = v_alive[j - 1]
-            d = self.sw.last_delta
-            if len(d["e_enc"]):
-                dpos = t.eng_pos(d["e_enc"])
-                e_lat[j, dpos] = t.cast_times(d["e_lat"])
-                e_alive[j, dpos] = d["e_alive"]
-            if len(d["v_idx"]):
-                v_lat[j, d["v_idx"]] = t.cast_times(d["v_lat"])
-                v_alive[j, d["v_idx"]] = d["v_alive"]
+        # host fold -> hop-major state columns [H, m_pad]/[H, n_pad], on
+        # the engine's own builder: one O(m) scatter, then an O(m)
+        # contiguous memcpy plus an O(delta) scatter per hop, instead of
+        # an O(m) scattered write per hop
+        cols = self._alloc_columns(len(hop_times))
+        self._fold_columns_fork(self.sw, hop_times, hop_callback, cols, 0)
         self.fold_seconds += _time.perf_counter() - f0
-        self.ship_bytes += (e_lat.nbytes + e_alive.nbytes
-                            + v_lat.nbytes + v_alive.nbytes)
-        return hop_times, (e_lat, e_alive, v_lat, v_alive)
+        self.ship_bytes += sum(a.nbytes for a in cols)
+        return hop_times, cols
 
     def _delta_eng(self, d):
         """``sweep.last_delta`` → engine-coordinate (pos, lat, alive)
@@ -1693,7 +1671,9 @@ class _HopBatched:
             # running base — rebuild it at the adopted clock (the same
             # time the device-resident state sits at) so the resident
             # all-delta contract survives across batch styles
-            self._delta_base = list(self._materialise_base(self.sw))
+            with TRACER.span("fold.payload", base=True) as sp:
+                self._delta_base = list(self._materialise_base(self.sw))
+                sp.set(bytes=_payload_nbytes(self._delta_base))
         resident = resident and self._delta_base is not None
         empty = (np.empty(0, np.int32), np.empty(0, tdt),
                  np.empty(0, bool))
@@ -1701,21 +1681,27 @@ class _HopBatched:
             self.sw._advance(T)
             if hop_callback is not None:
                 hop_callback(T, self.sw)
-            if self._delta_base is None:
-                # first batch, first hop: materialise from the full fold
-                self._delta_base = list(self._materialise_base(self.sw))
-            else:
-                de, dv = self._apply_delta_to_base()
-                if j > 0 or resident:
-                    deltas_e.append(de)
-                    deltas_v.append(dv)
-            if j == 0 and not resident:
-                # snapshot the running base as this batch's upload (the
-                # arrays keep mutating through later hops; jnp.asarray is
-                # async, so the copy must be taken now)
-                ship_base = tuple(a.copy() for a in self._delta_base)
-                deltas_e.append(empty)
-                deltas_v.append(empty)
+            with TRACER.span("fold.payload") as sp:
+                de = dv = empty
+                if self._delta_base is None:
+                    # first batch, first hop: materialise from the full
+                    # fold
+                    self._delta_base = list(
+                        self._materialise_base(self.sw))
+                else:
+                    de, dv = self._apply_delta_to_base()
+                if j == 0 and not resident:
+                    # snapshot the running base as this batch's upload
+                    # (the arrays keep mutating through later hops;
+                    # jnp.asarray is async, so the copy must be taken
+                    # now); hop 0's delta is in it already
+                    ship_base = tuple(a.copy() for a in self._delta_base)
+                    de = dv = empty
+                    sp.set(base=True, bytes=_payload_nbytes(ship_base))
+                else:
+                    sp.set(base=False, bytes=_payload_nbytes((de, dv)))
+                deltas_e.append(de)
+                deltas_v.append(dv)
         self.fold_seconds += _time.perf_counter() - f0
         return hop_times, (ship_base, deltas_e, deltas_v)
 

@@ -49,7 +49,9 @@ Knobs
 * ``RTPU_TRACE`` — enable tracing at import (default off). Runtime
   toggles: ``TRACER.enable()`` / ``TRACER.disable()`` or the REST
   ``/tracez?enable=1`` endpoint.
-* ``RTPU_TRACE_RING`` — flight-recorder capacity in spans (default 4096).
+* ``RTPU_TRACE_RING`` — flight-recorder capacity in spans (default
+  32768: about 1 KB an event, so 32 MB at most — a benchmark window's
+  requests whole, at ten times the request rates PR 36 measured).
 * ``RTPU_TRACE_DUMP`` — a file path; implies tracing on, and the ring is
   written there at interpreter exit (the CI failure-artifact hook).
 """
@@ -66,7 +68,7 @@ import time
 
 from . import journal as _journal
 
-DEFAULT_RING = 4096
+DEFAULT_RING = 32768
 
 
 class _NullSpan:

@@ -146,11 +146,21 @@ def run_columns_sharded(tables, e_lat, e_alive, v_lat, v_alive, hop_times,
                      direction="columns", process=proc,
                      shards=n_dev, rows=repl_rows * max(1, n_dev - 1),
                      bytes=repl_bytes * max(1, n_dev - 1)):
-        result, steps = shard(
-            put(tables.e_src), put(tables.e_dst), put(e_lat), put(e_alive),
-            put(v_lat), put(v_alive),
-            jnp.asarray(hop_of_col), jnp.asarray(T_col), jnp.asarray(w_col),
-            *(put(a) for a in extra_host))
+        # the job thread's DISPATCH of the puts of the replicated tables
+        # and the column descriptors: ``device_put`` returns before the
+        # bytes have moved, so the transfer is not in this span — its
+        # seconds fall where the host next waits for the chips
+        # (``comm.block_wait`` at the latest). The call's own seconds are
+        # ``comm.exchange``'s self time: what is left of it beside this
+        # span and the ``xla.*`` events (trace, lower, compile or cache
+        # read) recorded under it
+        with TRACER.span("comm.put", arrays=len(repl_arrays) + 3,
+                         bytes=repl_bytes):
+            args = [put(a) for a in repl_arrays]
+            # the sharded column descriptors sit after the six tables
+            args[6:6] = [jnp.asarray(hop_of_col), jnp.asarray(T_col),
+                         jnp.asarray(w_col)]
+        result, steps = shard(*args)
         barrier_wait = 0.0
         # rtpulint: spmd-uniform — `multi` derives from the mesh's device set, which every process builds from the same global device list; all processes take the same arm
         if multi:

@@ -450,3 +450,42 @@ def test_sweep_phase_histogram_observed(global_trace):
     ds.run_sweep(PageRank(max_steps=5), [400, 800], windows=[10_000])
     for ph, prev in before.items():
         assert hist_count(ph) == prev + 1, ph
+
+
+# ---- the recorder's budget (ISSUE 37): a default that holds a benchmark
+# window whole; past it, ``dropped`` says how many events went
+
+
+def test_default_ring_holds_32768_events(monkeypatch):
+    from raphtory_tpu.obs import trace as obs_trace
+
+    monkeypatch.delenv("RTPU_TRACE_RING", raising=False)
+    assert obs_trace.DEFAULT_RING == 32_768
+    assert Tracer(enabled=True).ring_size == 32_768
+    monkeypatch.setenv("RTPU_TRACE_RING", "4096")       # the knob stays
+    assert Tracer(enabled=True).ring_size == 4096
+
+
+@pytest.mark.parametrize("ring,dropped,first,wrapped,last", [
+    # 4,096 events are the newest 68 traces and 16 events of the 69th:
+    # the window's first request is gone, and ``dropped`` says so
+    (4096, 200 * 60 - 4096, 0, 16, 60),
+    # the default holds the 200 requests whole, with nothing dropped
+    (None, 0, 60, 60, 60),
+])
+def test_dropped_says_whether_the_ring_still_holds_the_window(
+        monkeypatch, ring, dropped, first, wrapped, last):
+    monkeypatch.delenv("RTPU_TRACE_RING", raising=False)
+    tr = Tracer(enabled=True, ring=ring, annotate=False)
+    ids = []
+    for _ in range(200):
+        with tr.span("request") as root:
+            for i in range(58):
+                with tr.span("stage", i=i):
+                    pass
+            tr.instant("mark")
+        ids.append(root.trace)
+    assert tr.status()["recorded"] == 200 * 60
+    assert tr.status()["dropped"] == dropped
+    assert [len(tr.for_trace(ids[k])) for k in (0, -69, -1)] \
+        == [first, wrapped, last]
