@@ -957,6 +957,14 @@ class _HopBatched:
     def _dispatch_deltas(self, payload, hop_times, windows, r_init=None):
         raise NotImplementedError
 
+    def _reset_run_counters(self) -> None:
+        """The per-call accounting ("the LAST run()'s ...") starts over."""
+        self.fold_seconds = 0.0
+        self.fold_mode_seconds = {}
+        self.fold_stall_seconds = 0.0
+        self.fold_inline_seconds = 0.0
+        self.ship_bytes = 0
+
     def run(self, hop_times, windows, chunks: int = 1,
             warm_start: bool = False, hop_callback=None, warm_state=None):
         """``chunks=k`` pipelines the sweep; ``warm_start=True``
@@ -982,11 +990,7 @@ class _HopBatched:
         cross-request fold cache (``RTPU_FOLD_CACHE_MB``); on a hit the
         callback replays from cached per-hop vertex state and
         ``fold_seconds`` stays ~0."""
-        self.fold_seconds = 0.0
-        self.fold_mode_seconds = {}
-        self.fold_stall_seconds = 0.0
-        self.fold_inline_seconds = 0.0
-        self.ship_bytes = 0
+        self._reset_run_counters()
         if warm_start and not self.supports_warm_start:
             raise ValueError(
                 f"{type(self).__name__} cannot warm-start: its superstep "
@@ -1130,8 +1134,10 @@ class _HopBatched:
         outs.append(out)
         steps_box[0] = jnp.maximum(steps_box[0], st)
 
-    def _run_chunks(self, hop_times, windows, chunks, warm_start,
-                    hop_callback):
+    def _check_forward(self, hop_times) -> None:
+        """The incremental fold only moves forward: a backward batch on
+        the advanced clock would silently fold nothing (DeviceSweep
+        raises for the same reason)."""
         if sorted(hop_times) != hop_times:
             raise ValueError("hop_times must ascend")
         if self.sw.t_prev is not None and hop_times[0] < self.sw.t_prev:
@@ -1139,6 +1145,10 @@ class _HopBatched:
                 f"hop_times must continue forward from the previous batch "
                 f"(got {hop_times[0]} < {self.sw.t_prev}); build a fresh "
                 f"{type(self).__name__} to go back in history")
+
+    def _run_chunks(self, hop_times, windows, chunks, warm_start,
+                    hop_callback):
+        self._check_forward(hop_times)
         delta = self._use_delta_fold()
         if chunks == 1 or len(hop_times) % chunks:
             # unequal groups would compile one program per distinct size —
@@ -1223,20 +1233,8 @@ class _HopBatched:
         outs, steps_box = [], [jnp.int32(0)]
 
         def fold(c, group, lookahead: bool):
-            # a lookahead fold runs BEFORE the previous group's delta
-            # dispatch is issued — it must assume that dispatch will leave
-            # a device-resident base (assume_resident), or chunk 2+ would
-            # re-ship a full base snapshot the serial loop never ships
-            with TRACER.span("hop.fold", hops=len(group),
-                                engine=type(self).__name__):
-                t0 = _time.perf_counter()
-                if delta:
-                    _, p = self._fold_deltas(group, cb,
-                                             assume_resident=lookahead)
-                else:
-                    _, p = self._fold_columns(group, cb)
-                self._observe_fold(_time.perf_counter() - t0, "serial")
-            return c, group, p
+            return c, group, self._fold_group_serial(group, cb, delta,
+                                                     lookahead)
 
         def dispatch(fold_out, stall):
             c, group, payload = fold_out
@@ -1265,44 +1263,79 @@ class _HopBatched:
         self._maybe_cache(cache, key, payloads, cap, delta)
         return jnp.concatenate(outs, axis=0), steps_box[0]
 
-    def fold_payloads(self, hop_times, chunks: int = 1):
-        """Fold the sweep's chunk payloads WITHOUT dispatching — the
-        serial/parallel fold A/B surface (``bench.py --config
-        fold_parallel`` and the equivalence tests). Honours
-        ``RTPU_FOLD_WORKERS`` exactly like ``run()`` (serial pipeline at
-        1, forked parallel folds above); the fold cache is never
-        consulted — this measures/exercises folding itself. Returns
-        ``(groups, payloads)``, one payload per dispatch group, identical
-        to what ``run(hop_times, ..., chunks=chunks)`` would dispatch."""
+    def _fold_group_serial(self, group, hop_callback, delta: bool,
+                           lookahead: bool):
+        """One dispatch group folded on the engine's OWN builder, on the
+        calling thread, under a ``hop.fold`` span. A lookahead fold runs
+        BEFORE the previous group's delta dispatch is issued — it must
+        assume that dispatch will leave a device-resident base
+        (assume_resident), or chunk 2+ would re-ship a full base
+        snapshot the serial loop never ships."""
+        with TRACER.span("hop.fold", hops=len(group),
+                            engine=type(self).__name__):
+            t0 = _time.perf_counter()
+            if delta:
+                _, p = self._fold_deltas(group, hop_callback,
+                                         assume_resident=lookahead)
+            else:
+                _, p = self._fold_columns(group, hop_callback)
+            self._observe_fold(_time.perf_counter() - t0, "serial")
+        return p
+
+    def fold_payloads(self, hop_times, chunks: int = 1, *, delta=None,
+                      hop_callback=None):
+        """Fold the sweep's chunk payloads WITHOUT dispatching: what
+        ``run(hop_times, ..., chunks=chunks)`` folds, minus the fold
+        cache's whole-payload entries and the dispatch. Returns
+        ``(groups, payloads)``, one payload per dispatch group.
+
+        Two callers. The serial/parallel fold A/B (``bench.py --config
+        fold_parallel`` and the equivalence tests) takes the engine's own
+        payload kind (``delta=None``: ``RTPU_FOLD``). The column-sharded
+        mesh route (``jobs/manager._try_range_mesh_columns``) asks for
+        full host columns (``delta=False``: what ``parallel/columns.py``
+        replicates) and hands in the reducer shells' ``hop_callback``,
+        which — as in ``run()`` — may fire from worker threads in any
+        hop order.
+
+        Honours ``RTPU_FOLD_WORKERS`` exactly like ``run()``: above 1 (and
+        more than one hop, on an engine that ``supports_parallel_fold``)
+        the fold units run on forked builders on ``fold_pool()``, seeded
+        from the fold cache's nearest checkpoint and recording theirs
+        back, while this thread waits (``fold_stall_seconds``); otherwise
+        the groups fold on this thread from the engine's live builder
+        (``fold_inline_seconds``). Payload entries stay out of the cache
+        (``key=None``): a caller of this surface never repeats a hop
+        grid, and a mesh request's columns (hops x (m_pad + n_pad) x 5 B)
+        would push the checkpoints out of the bound."""
+        self._reset_run_counters()
         hop_times = [int(x) for x in hop_times]
+        self._check_forward(hop_times)
         chunks = max(1, min(int(chunks), len(hop_times)))
         if chunks > 1 and len(hop_times) % chunks:
             chunks = 1
         per = len(hop_times) // chunks
         groups = [hop_times[c * per:(c + 1) * per] for c in range(chunks)]
-        delta = self._use_delta_fold()
+        if delta is None:
+            delta = self._use_delta_fold()
         workers = fold_workers()
         if (workers > 1 and self.supports_parallel_fold
                 and len(hop_times) > 1):
-            # checkpoints participate (key=None keeps payload entries
-            # out): repeated folds seed their forks at the boundaries
-            # and skip the prefix re-fold — the serving steady state
+            # checkpoints participate: repeated folds seed their forks at
+            # the boundaries and skip the prefix re-fold — the serving
+            # steady state
             payloads, _ = self._fold_groups_parallel(
-                groups, None, delta, fold_cache(), None, workers,
+                groups, hop_callback, delta, fold_cache(), None, workers,
                 lambda c, p: None)
             return groups, payloads
         payloads = []
         for c, g in enumerate(groups):
-            t0 = _time.perf_counter()
-            if delta:
-                # chunks 1+ fold all-delta exactly like the pipelined
-                # run (the previous chunk's dispatch leaves a resident
-                # base); chunk 0 ships the base snapshot
-                _, p = self._fold_deltas(g, None, assume_resident=c > 0)
-            else:
-                _, p = self._fold_columns(g, None)
-            self._observe_fold(_time.perf_counter() - t0, "serial")
-            payloads.append(p)
+            # chunks 1+ fold all-delta exactly like the pipelined run
+            # (the previous chunk's dispatch leaves a resident base);
+            # chunk 0 ships the base snapshot
+            payloads.append(self._fold_group_serial(g, hop_callback, delta,
+                                                    lookahead=c > 0))
+        self.fold_inline_seconds = self.fold_seconds
         return groups, payloads
 
     def _fold_dispatch_parallel(self, groups, windows, warm_start,
@@ -1593,16 +1626,7 @@ class _HopBatched:
         self._delta_base = None
         self._drop_residency()
         hop_times = [int(x) for x in hop_times]
-        if sorted(hop_times) != hop_times:
-            raise ValueError("hop_times must ascend")
-        if self.sw.t_prev is not None and hop_times[0] < self.sw.t_prev:
-            # the incremental fold only moves forward; a backward batch on
-            # the advanced clock would silently fold nothing (DeviceSweep
-            # raises for the same reason)
-            raise ValueError(
-                f"hop_times must continue forward from the previous batch "
-                f"(got {hop_times[0]} < {self.sw.t_prev}); build a fresh "
-                f"{type(self).__name__} to go back in history")
+        self._check_forward(hop_times)
         # host fold -> hop-major state columns [H, m_pad]/[H, n_pad], on
         # the engine's own builder: one O(m) scatter, then an O(m)
         # contiguous memcpy plus an O(delta) scatter per hop, instead of
@@ -1652,13 +1676,7 @@ class _HopBatched:
         f0 = _time.perf_counter()
         t = self.tables
         hop_times = [int(x) for x in hop_times]
-        if sorted(hop_times) != hop_times:
-            raise ValueError("hop_times must ascend")
-        if self.sw.t_prev is not None and hop_times[0] < self.sw.t_prev:
-            raise ValueError(
-                f"hop_times must continue forward from the previous batch "
-                f"(got {hop_times[0]} < {self.sw.t_prev}); build a fresh "
-                f"{type(self).__name__} to go back in history")
+        self._check_forward(hop_times)
         tdt = t.tdtype
         deltas_e, deltas_v = [], []
         ship_base = None

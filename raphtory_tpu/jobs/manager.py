@@ -703,7 +703,12 @@ class Job:
         (hop, window) columns spread COLLECTIVE-FREE over every device of
         the mesh (``parallel/columns.py``) — the graph tables replicate,
         so this route takes ranges whose graph fits one chip; bigger
-        graphs fall through to the vertex-sharded ``_try_range_mesh``."""
+        graphs fall through to the vertex-sharded ``_try_range_mesh``.
+        The full host columns come from the engine's own fold
+        (``fold_payloads``): forked units seeded from the fold cache's
+        checkpoints on the fold pool, as the one-chip route folds, while
+        this thread waits; an engine whose fold is sequential (weighted
+        SSSP) or ``RTPU_FOLD_WORKERS=1`` folds them here, inline."""
         import numpy as np
 
         from ..engine.hopbatch import (HopBatchedCC, HopBatchedPageRank,
@@ -738,12 +743,12 @@ class Job:
             shells[int(T)] = _shell_from_fold(hb.tables, sw, int(T))
 
         t0 = _time.perf_counter()
-        # the mesh route folds full host columns on THIS thread (no
-        # prefetch lane): the same span the columnar engine's folds carry
-        with TRACER.span("hop.fold", hops=len(hops),
-                         engine=type(hb).__name__, mode="columns"):
-            _, cols = hb._fold_columns(hops, grab_shell)
-        self.ledger.add_phase("fold", hb.fold_seconds)
+        _, (cols,) = hb.fold_payloads(hops, delta=False,
+                                      hop_callback=grab_shell)
+        # the phase is what THIS thread waited for the units or folded
+        # inline; ``hb.fold_seconds`` is the units' worker seconds
+        self.ledger.add_phase(
+            "fold", hb.fold_stall_seconds + hb.fold_inline_seconds)
         if isinstance(hb, HopBatchedSSSP):
             *cols, kw["weight_cols"] = cols
         try:

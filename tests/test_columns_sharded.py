@@ -6,6 +6,7 @@ import pytest
 
 import jax
 
+from test_stage_spans import _log, _named, _pagerank, _spans
 from test_sweep import random_log
 
 from raphtory_tpu.engine.hopbatch import HopBatchedPageRank
@@ -177,3 +178,177 @@ def test_mesh_cc_range_job_rides_column_sharding(monkeypatch):
                         if r["time"] == t
                         and r["windowsize"] == vrow["windowsize"])
             assert rrow["result"] == vrow["result"]
+
+
+# ------------------------------------------- the mesh route's host fold
+
+
+@pytest.fixture
+def traced(monkeypatch):
+    from raphtory_tpu.core.sweep import fold_cache
+    from raphtory_tpu.obs.trace import TRACER
+
+    monkeypatch.setenv("RTPU_FOLD_CACHE_MB", "64")
+    monkeypatch.setenv("RTPU_BATCH_WINDOW_MS", "0")    # no collect window
+    fold_cache().clear()
+    was = TRACER.enabled
+    TRACER.enable()
+    yield
+    (TRACER.enable if was else TRACER.disable)()
+
+
+def _done(job):
+    assert job.wait(300) and job.status == "done", job.error
+    return job
+
+
+def _range_graph(seed):
+    from raphtory_tpu.core.service import TemporalGraph
+
+    return TemporalGraph(_log(seed))
+
+
+@pytest.mark.parametrize("workers", [2, 4])
+def test_second_mesh_range_job_folds_from_the_first_ones_checkpoint(
+        traced, monkeypatch, workers):
+    """The mesh route folds as the one-chip route does: a request that
+    starts where the last one ended seeds every fold unit from a cached
+    checkpoint — none advances from the log's first event — on the fold
+    pool, and the rows are the one-chip route's."""
+    from raphtory_tpu.jobs.manager import AnalysisManager, RangeQuery
+    from raphtory_tpu.parallel import sharded
+
+    monkeypatch.setenv("RTPU_FOLD_WORKERS", str(workers))
+    g = _range_graph(51)
+    mgr = AnalysisManager(g, mesh=sharded.make_mesh(4, 2))
+    windows = (1000, 300)
+    first = _spans(mgr.submit(_pagerank(), RangeQuery(
+        start=300, end=600, jump=100, windows=windows)))
+    assert {s["args"]["seed"] for s in _named(first, "fold.seed")} \
+        == {"start"}                        # nothing cached yet
+    q = RangeQuery(start=600, end=900, jump=100, windows=windows)
+    job = mgr.submit(_pagerank(), q)
+    spans = _spans(job)
+    (root,) = _named(spans, "job")
+    folds = _named(spans, "hop.fold")
+    assert len(folds) == min(workers, 4)    # one dispatch group, sub-split
+    assert {f["args"]["mode"] for f in folds} == {"parallel"}
+    assert all(f["tid"] != root["tid"] for f in folds)
+    assert sum(f["args"]["hops"] for f in folds) == 4
+    seeds = _named(spans, "fold.seed")
+    assert len(seeds) == len(folds)
+    assert {s["args"]["seed"] for s in seeds} == {"checkpoint"}
+    cps = _named(spans, "fold.checkpoint")
+    assert cps and all(c["args"]["seed"] == "checkpoint"
+                       and c["args"]["seeded_from"] >= 300
+                       and c["args"]["stored"] for c in cps)
+    # the job thread only waits
+    stalls = _named(spans, "fold.stall")
+    assert stalls and {s["tid"] for s in stalls} == {root["tid"]}
+    assert _named(spans, "comm.exchange")   # and then the mesh served
+
+    ref = _done(AnalysisManager(g).submit(_pagerank(), q))
+    assert len(job.results) == len(ref.results) == 4 * len(windows)
+    for vrow in ref.results:
+        rrow = next(r for r in job.results
+                    if r["time"] == vrow["time"]
+                    and r["windowsize"] == vrow["windowsize"])
+        assert rrow["result"]["sum"] == pytest.approx(
+            vrow["result"]["sum"], abs=1e-4)
+        ra, rb = dict(rrow["result"]["top10"]), \
+            dict(vrow["result"]["top10"])
+        assert set(ra) == set(rb)
+        for k in ra:
+            assert ra[k] == pytest.approx(rb[k], abs=1e-5)
+
+
+@pytest.mark.parametrize("fold", ["inline", "workers"])
+def test_mesh_range_job_phases_partition_its_wall(traced, monkeypatch,
+                                                  fold):
+    """The ``fold`` phase of a mesh job is what the job thread folded
+    inline or waited for the units — never the workers' seconds, which
+    would overlap the wall."""
+    from raphtory_tpu.jobs.manager import AnalysisManager, RangeQuery
+    from raphtory_tpu.parallel import sharded
+
+    monkeypatch.setenv("RTPU_FOLD_WORKERS", "1" if fold == "inline" else "4")
+    mgr = AnalysisManager(_range_graph(52), mesh=sharded.make_mesh(4, 2))
+    _done(mgr.submit(_pagerank(), RangeQuery(
+        start=300, end=600, jump=100, windows=(1000, 300))))
+    job = mgr.submit(_pagerank(), RangeQuery(
+        start=600, end=900, jump=100, windows=(1000, 300)))
+    spans = _spans(job)
+    led = job.ledger.as_dict()
+    ph = led["phase_seconds"]
+    assert led["phase_overlap_seconds"] == 0.0
+    assert 0.0 < ph["fold"] <= led["wall_seconds"]
+    assert led["queue_wait_seconds"] + sum(ph.values()) == pytest.approx(
+        led["wall_seconds"], rel=0.01)
+    (root,) = _named(spans, "job")
+    folds = _named(spans, "hop.fold")
+    if fold == "inline":
+        assert [f["tid"] for f in folds] == [root["tid"]]
+        assert not _named(spans, "fold.stall")
+        assert ph["fold"] == pytest.approx(folds[0]["dur"] / 1e6,
+                                           rel=0.05, abs=2e-3)
+    else:
+        assert len(folds) == 4
+        assert all(f["tid"] != root["tid"] for f in folds)
+        stall = sum(s["dur"] for s in _named(spans, "fold.stall")) / 1e6
+        assert ph["fold"] == pytest.approx(stall, abs=2e-3)
+        # the units' seconds are cost; side by side they may exceed it
+        assert ph["fold"] <= sum(f["dur"] for f in folds) / 1e6 + 2e-3
+
+
+@pytest.mark.parametrize("directed", [False, True])
+def test_mesh_weighted_sssp_range_job_still_folds_serially(
+        traced, monkeypatch, directed):
+    """Weighted SSSP threads a weight cursor through its fold
+    (``supports_parallel_fold = False``): on the mesh route it keeps the
+    inline fold on the job thread whatever the pool's size, and answers
+    as the one-chip route does."""
+    from raphtory_tpu.core.events import EventLog
+    from raphtory_tpu.core.service import TemporalGraph
+    from raphtory_tpu.engine import hopbatch
+    from raphtory_tpu.jobs import registry
+    from raphtory_tpu.jobs.manager import AnalysisManager, RangeQuery
+    from raphtory_tpu.parallel import sharded
+
+    monkeypatch.setenv("RTPU_FOLD_WORKERS", "4")
+    rng = np.random.default_rng(53)
+    n = 700
+    log = EventLog()
+    log.append_batch(
+        np.sort(rng.integers(0, 100, n)), np.full(n, 2, np.uint8),
+        rng.integers(0, 40, n).astype(np.int64),
+        rng.integers(0, 40, n).astype(np.int64),
+        props=[(i, {"weight": float(rng.uniform(0.5, 3.0))})
+               for i in range(n)])
+    g = TemporalGraph(log)
+
+    def sssp():
+        return registry.resolve("SSSP", {
+            "seeds": [0, 1, 2], "weight_prop": "weight",
+            "directed": directed, "max_steps": 50})
+
+    q = RangeQuery(start=40, end=99, jump=20, windows=(1000, 30))
+    ref = _done(AnalysisManager(g).submit(sssp(), q))
+
+    def boom(*a, **k):
+        raise AssertionError("a weighted-SSSP fold went to the pool")
+
+    monkeypatch.setattr(hopbatch._HopBatched, "_fold_groups_parallel", boom)
+    job = AnalysisManager(g, mesh=sharded.make_mesh(4, 2)).submit(sssp(), q)
+    spans = _spans(job)
+    (root,) = _named(spans, "job")
+    (fold,) = _named(spans, "hop.fold")
+    assert fold["tid"] == root["tid"] and fold["args"]["hops"] == 3
+    assert fold["args"]["engine"] == "HopBatchedSSSP"
+    assert not _named(spans, "fold.seed")
+    assert _named(spans, "comm.exchange")
+    assert len(job.results) == len(ref.results) == 3 * 2
+    for vrow in ref.results:
+        rrow = next(r for r in job.results
+                    if r["time"] == vrow["time"]
+                    and r["windowsize"] == vrow["windowsize"])
+        assert rrow["result"] == vrow["result"]

@@ -112,6 +112,60 @@ def test_parallel_fold_payloads_bit_identical(monkeypatch, seed, mode):
         assert _payloads_equal(p1, p2), f"chunks={chunks}"
 
 
+@pytest.mark.parametrize("warm", [False, True],
+                         ids=["cold_cache", "warm_checkpoint"])
+@pytest.mark.parametrize("workers", [1, 2, 4])
+@pytest.mark.parametrize("seed", [5, 11, 21])
+def test_host_column_entry_bit_identical_to_fold_columns(monkeypatch, seed,
+                                                         workers, warm):
+    """``fold_payloads(delta=False, hop_callback=...)`` — the mesh Range
+    route's fold — hands out the serial ``_fold_columns``' columns bit
+    for bit: inline at one worker, on forked units above, from the log's
+    first event or seeded from an earlier request's checkpoint; and the
+    callback sees every hop's fold state once."""
+    from raphtory_tpu.engine.hopbatch import HopBatchedPageRank
+
+    monkeypatch.setenv("RTPU_FOLD", "delta")    # the entry overrides it
+    monkeypatch.setenv("RTPU_FOLD_CACHE_MB", "64")
+    fold_cache().clear()
+    log = random_log(np.random.default_rng(seed), n_events=900, n_ids=40,
+                     t_span=1000)
+    hops = [600, 700, 800, 900]
+    monkeypatch.setenv("RTPU_FOLD_WORKERS", "1")
+    ref_shells = {}
+    _, want = HopBatchedPageRank(log)._fold_columns(
+        hops, lambda T, sw: ref_shells.__setitem__(
+            T, (sw.v_lat.copy(), sw.v_alive.copy())))
+    monkeypatch.setenv("RTPU_FOLD_WORKERS", str(workers))
+    if warm:
+        # an earlier request that ended where this one starts leaves its
+        # units' checkpoints behind (none at one worker: the inline fold
+        # never looks into the cache)
+        HopBatchedPageRank(log).fold_payloads([300, 400, 500, 600],
+                                              delta=False)
+        assert (fold_cache().stats()["entries"] > 0) == (workers > 1)
+    hits0 = fold_cache().stats()["hits"]
+    shells = {}
+    hb = HopBatchedPageRank(log)
+    groups, (got,) = hb.fold_payloads(
+        hops, delta=False, hop_callback=lambda T, sw: shells.__setitem__(
+            T, (sw.v_lat.copy(), sw.v_alive.copy())))
+    assert groups == [hops]
+    assert _payloads_equal(tuple(got), tuple(want))
+    assert sorted(shells) == hops
+    assert _payloads_equal([shells[T] for T in hops],
+                           [ref_shells[T] for T in hops])
+    assert hb.sw.t_prev == hops[-1]     # the engine's clock moved on
+    # the job thread's part is a wait or an inline fold, never both
+    if workers == 1:
+        assert hb.fold_stall_seconds == 0.0
+        assert hb.fold_inline_seconds == hb.fold_seconds > 0.0
+    else:
+        assert hb.fold_inline_seconds == 0.0 and hb.fold_seconds > 0.0
+        # (cold, a unit may still find a faster sibling's checkpoint)
+        assert fold_cache().stats()["hits"] > hits0 or not warm
+
+
 def test_parallel_run_matches_serial_and_reuses(monkeypatch):
     """run() under parallel folds matches RTPU_FOLD_WORKERS=1 bitwise,
     and the engine stays reusable for a follow-on batch."""

@@ -189,20 +189,79 @@ def test_range_on_two_fold_workers_writes_the_fold_stages(traced,
     assert all(r >= 0 for r in rows) and max(rows) > 0
 
 
-def test_inline_column_fold_writes_an_advance_and_a_payload_a_hop(traced):
-    """The mesh route's fold (``_fold_columns`` on the job thread)."""
+def test_inline_column_fold_writes_an_advance_and_a_payload_a_hop(
+        traced, monkeypatch):
+    """The serial arm of the mesh route's fold (``fold_payloads(delta=
+    False)`` at one fold worker: ``_fold_columns`` on the calling
+    thread, under its own ``hop.fold``)."""
+    monkeypatch.setenv("RTPU_FOLD_WORKERS", "1")
     hb = HopBatchedPageRank(_log(40), tol=0, max_steps=5)
     hops = [400, 600, 800]
-    with TRACER.span("hop.fold", mode="columns") as fold:
-        hb._fold_columns(hops)
-    kids = [e for e in TRACER.for_trace(fold.trace)
-            if e.get("parent") == fold.sid]
+    with TRACER.span("job") as root:
+        hb.fold_payloads(hops, delta=False)
+    spans = [e for e in TRACER.for_trace(root.trace) if e["ph"] == "X"]
+    (fold,) = _named(spans, "hop.fold")
+    assert fold["args"]["hops"] == 3 and "mode" not in fold["args"]
+    kids = _children(spans, fold)
+    assert all(_inside(fold, k) for k in kids)
     assert [k["name"] for k in kids] == ["fold.advance", "fold.payload"] * 3
     assert [k["args"]["time"] for k in kids[::2]] == hops
     assert [k["args"]["base"] for k in kids[1::2]] == [True, False, False]
     t = hb.tables
     row = (t.m_pad + t.n_pad) * (np.dtype(t.tdtype).itemsize + 1)
     assert {k["args"]["bytes"] for k in kids[1::2]} == {row}
+
+
+@pytest.mark.parametrize("workers", [1, 4])
+def test_mesh_range_job_writes_the_fold_stages_where_it_folds(
+        traced, monkeypatch, workers):
+    """A mesh Range job follows the one-chip route's fold: its stages lie
+    under a worker's ``hop.fold mode=parallel`` and the job thread's wait
+    is a ``fold.stall``; at one fold worker they lie under the job
+    thread's own ``hop.fold``."""
+    from raphtory_tpu.parallel import sharded
+
+    monkeypatch.setenv("RTPU_FOLD_WORKERS", str(workers))
+    mgr = AnalysisManager(TemporalGraph(_log(44)),
+                          mesh=sharded.make_mesh(4, 2))
+    hops = [300, 500, 700, 900]
+    spans = _spans(mgr.submit(_pagerank(), RangeQuery(
+        start=hops[0], end=hops[-1], jump=200, windows=(1000, 300))))
+    (job,) = _named(spans, "job")
+    folds = _named(spans, "hop.fold")
+    assert len(folds) == workers and \
+        sum(f["args"]["hops"] for f in folds) == 4
+    for fold in folds:
+        kids = _children(spans, fold)
+        assert all(_inside(fold, k) for k in kids)
+        adv, pay = (_named(kids, n) for n in ("fold.advance",
+                                              "fold.payload"))
+        assert len(adv) == len(pay) == fold["args"]["hops"]
+        # every unit's first row is the full fold state, the rest copies
+        assert [p["args"]["base"] for p in pay] \
+            == [True] + [False] * (len(pay) - 1)
+        if workers == 1:
+            assert fold["tid"] == job["tid"] and "mode" not in fold["args"]
+            assert {k["name"] for k in kids} == {"fold.advance",
+                                                 "fold.payload"}
+        else:
+            assert fold["tid"] != job["tid"]
+            assert fold["args"]["mode"] == "parallel"
+            assert fold["args"]["worker"].startswith("sweep-fold")
+            (seed,) = _named(kids, "fold.seed")
+            assert seed["args"]["nbytes"] > 0
+            for cp in _named(kids, "fold.checkpoint"):
+                (bulk,) = _named(_children(spans, cp), "fold.advance")
+                assert bulk["args"]["time"] == cp["args"]["time"]
+    stalls = _named(spans, "fold.stall")
+    assert (len(stalls) > 0) == (workers > 1)
+    assert {s["tid"] for s in stalls} <= {job["tid"]}
+    assert sorted(a["args"]["time"] for f in folds
+                  for a in _named(_children(spans, f), "fold.advance")) \
+        == hops
+    # the exchange starts when the last unit has been waited for
+    (xchg,) = _named(spans, "comm.exchange")
+    assert xchg["ts"] >= max(f["ts"] + f["dur"] for f in folds) - 1.0
 
 
 # -------------------------------------------------------- comm.exchange
