@@ -2,8 +2,15 @@
 ``core/analysis/Algorithms/`` plus the example-space analysers (SURVEY §2.8):
 ConnectedComponents, DegreeBasic/DegreeRanking, PageRank, BinaryDiffusion,
 FlowGraph, Density, temporal TaintTracking (EthereumTaintTracking),
-BFS/SSSP (LDBC bar)."""
+BFS/SSSP (LDBC bar), and LDBC Graphalytics' CDLP and LCC.
 
+Six of them have a columnar kind (``engine/hopbatch.py``: every (hop,
+window) view of a Range a column of one dispatch): PageRank
+(``pagerank``), ConnectedComponents (``cc``), BFS and unit-weight SSSP
+(``bfs``), weighted SSSP (``bfs`` with a weight state), CDLP (``cdlp``)
+and LCC (``lcc``), which has no other engine."""
+
+from .clustering import LCC
 from .connected_components import ConnectedComponents
 from .degree import DegreeBasic
 from .diffusion import BinaryDiffusion
@@ -24,6 +31,7 @@ __all__ = [
     "FlowGraph",
     "LabelPropagation",
     "CDLP",
+    "LCC",
     "PageRank",
     "TaintTracking",
     "BFS",

@@ -258,7 +258,7 @@ class LogIndex:
     here is read-only to its users: a fork shares the log-derived arrays
     by reference and copies the fold state (``SweepBuilder.fork``)."""
 
-    __slots__ = ("prototype", "tables")
+    __slots__ = ("prototype", "tables", "triangles")
 
     def __init__(self, log: EventLog):
         # fold state only (the engines never emit GraphViews, shells are
@@ -267,6 +267,10 @@ class LogIndex:
                                       preseed_pairs=True)
         with TRACER.span("index.tables"):
             self.tables = GlobalTables(self.prototype)
+        #: the pair table's triangles (``ops/triangles.TriangleTable``):
+        #: built by the first engine that intersects neighbour sets
+        #: (``log_triangles``), never for another program
+        self.triangles = None
 
     @property
     def nbytes(self) -> int:
@@ -277,7 +281,8 @@ class LogIndex:
         arrays = {id(a): a for a in (*own.values(),
                                      *vars(self.tables).values())
                   if isinstance(a, np.ndarray)}
-        return int(sum(a.nbytes for a in arrays.values()))
+        return int(sum(a.nbytes for a in arrays.values())) + (
+            self.triangles.nbytes if self.triangles is not None else 0)
 
     def adopt(self, log: EventLog) -> str:
         """Bring the index up to ``log``'s current pin, exactly:
@@ -338,6 +343,57 @@ def log_index(log: EventLog):
             with TRACER.span("index.fork",
                              nbytes=idx.prototype.fork_nbytes()):
                 return idx.prototype.fork(), idx.tables, status
+
+
+def log_triangles(log: EventLog, tables: GlobalTables):
+    """``(table, status)``: the triangle table
+    (``ops/triangles.TriangleTable``) of ``tables``, the pair table an
+    engine got from ``log_index(log)``: kept on the log's index
+    (``"held"``), built here the first time an engine asks (``"built"``)
+    — span ``index.triangles``, a child of that engine's
+    ``engine.build``, absent when the index holds the table already. The
+    pairs of an index never change (a log that gains one gets a new
+    index: ``LogIndex.adopt``), so neither does the table. An uncached
+    index (a frozen log's) gets a table of its own every time. Holds the
+    index lock for the build, as an index miss does."""
+    from ..ops.triangles import build_table
+
+    with _LOG_INDEX_LOCK:
+        idx = _LOG_INDEXES.get(log)
+        if idx is not None and idx.tables is not tables:
+            idx = None      # the engine's tables outlived their index
+        if idx is not None and idx.triangles is not None:
+            return idx.triangles, "held"
+        with TRACER.span("index.triangles", pairs=tables.m) as sp:
+            tt = build_table(tables.e_src, tables.e_dst, tables.m,
+                             tables.n, tables.n_pad, tables.m_pad)
+            sp.set(triangles=tt.triangles, rows=tt.walked_rows,
+                   nbytes=tt.nbytes)
+        if idx is not None:
+            idx.triangles = tt
+        return tt, "built"
+
+
+#: per-log cache of the device copy of the table above, as ``_DEVICE_EDGES``
+#: is of the pair table: a new engine over an unchanged log ships nothing
+_DEVICE_TRIANGLES = weakref.WeakKeyDictionary()
+
+
+def _device_triangles(log, tt) -> tuple:
+    """Device arrays of ``tt`` in ``ops/triangles.lcc_columns``'s order,
+    cached per log while the index keeps that table."""
+    ent = _DEVICE_TRIANGLES.get(log)
+    if ent is not None and ent[0] is tt:
+        return ent[1]
+    from ..obs import device as _obs_device
+    from ..utils.transfer import device_put_chunked
+
+    dev = tuple(device_put_chunked(a) for a in tt.device_args())
+    _DEVICE_TRIANGLES[log] = (tt, dev)
+    _obs_device.RESIDENT.track(log, "triangle_table",
+                               _obs_device.nbytes_tree(dev),
+                               triangles=tt.triangles)
+    return dev
 
 
 def log_index_status() -> dict:

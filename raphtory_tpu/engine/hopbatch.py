@@ -43,8 +43,10 @@ from ..obs import ledger as _ledger
 from ..obs.trace import TRACER
 from ..ops.segment import (segment_counts, segment_ends_pos, segment_mode,
                            sorted_segment_sum, sum_route)
+from ..ops.triangles import lcc_columns
 from ..utils.transfer import _metrics
-from .device_sweep import (_device_edges, log_index, normalize_windows,
+from .device_sweep import (_device_edges, _device_triangles, log_index,
+                           log_triangles, normalize_windows,
                            sweep_phase_summary)
 
 _log = logging.getLogger(__name__)
@@ -331,12 +333,14 @@ def _compiled_delta(kind: str, n_pad: int, m_pad: int, H: int, W: int,
                     tile_budget: int | None = None):
     """Delta-fed columnar kernels: masks rebuilt on device from base state
     + per-hop deltas (``_masks_from_deltas``), then the shared algorithm
-    body. ``kind``: pagerank | cc | cdlp | bfs (``weighted`` adds a per-pair
-    weight state rebuilt the same way); ``algo_args`` is the algorithm's
-    static parameter tuple. ``h0=True`` is the resident-base variant: the
-    base inputs are the previous dispatch's advanced state, delta[0] is
-    applied before hop 0. Every variant returns ``(result, steps,
-    advanced_base)`` so the caller can keep the fold state on device."""
+    body. ``kind``: pagerank | cc | cdlp | lcc | bfs (``weighted`` adds a
+    per-pair weight state rebuilt the same way); ``algo_args`` is the
+    algorithm's static parameter tuple (``lcc``: its triangle table's
+    ``tile_edges``, the table's arrays follow the column descriptors).
+    ``h0=True`` is the resident-base variant: the base inputs are the
+    previous dispatch's advanced state, delta[0] is applied before hop 0.
+    Every variant returns ``(result, steps, advanced_base)`` so the
+    caller can keep the fold state on device."""
     tdt_ = jnp.dtype(tdt)
 
     def run(e_src, e_dst, be_lat, be_alive, bv_lat, bv_alive,
@@ -367,6 +371,12 @@ def _compiled_delta(kind: str, n_pad: int, m_pad: int, H: int, W: int,
             out, steps = _cdlp_columns(me, mv, e_src, e_dst, n_pad,
                                        max_steps)
             return out, steps, adv
+        if kind == "lcc":
+            # one pass, no rounds: a view is a mask over the log's
+            # triangle table as it is over its pair table
+            (tile_edges,) = algo_args
+            return (lcc_columns(me, n_pad, tile_edges, *rest),
+                    jnp.int32(1), adv)
         max_steps, directed = algo_args
         ew = 1.0
         nxt = 1   # rest[0] is the seed mask; weights then warm follow
@@ -412,15 +422,19 @@ def run_columns_delta(kind, tables, base, deltas_e, deltas_v, hop_times,
                       windows, *, algo_args: tuple, seed_mask=None,
                       e_src_dev=None, e_dst_dev=None, r_init=None,
                       weight_base=None, weight_deltas=None,
-                      h0_delta: bool = False, ship_counter=None):
-    """Dispatch a delta-fed columnar kernel (``kind``: pagerank|cc|cdlp|bfs)
-    over ``_HopBatched._fold_deltas`` output; returns ``(result, steps,
-    advanced_base)``. ``weight_base`` + ``weight_deltas`` ([(pos, val)]
-    per hop) turn bfs into weighted SSSP with the weight state rebuilt on
-    device too. ``h0_delta=True`` means ``base`` (and ``weight_base``)
-    are the previous dispatch's device-resident advanced state and
-    delta[0] carries the inter-batch catch-up — the sweep then ships
-    O(Σ delta) bytes with no full-table upload at all."""
+                      h0_delta: bool = False, ship_counter=None,
+                      static_tables: tuple = ()):
+    """Dispatch a delta-fed columnar kernel (``kind``:
+    pagerank|cc|cdlp|lcc|bfs) over ``_HopBatched._fold_deltas`` output;
+    returns ``(result, steps, advanced_base)``. ``static_tables`` are
+    device-resident per-log arrays the kind's body takes after the
+    column descriptors (``lcc``: the triangle table). ``weight_base`` +
+    ``weight_deltas`` ([(pos, val)] per hop) turn bfs into weighted SSSP
+    with the weight state rebuilt on device too. ``h0_delta=True`` means
+    ``base`` (and ``weight_base``) are the previous dispatch's
+    device-resident advanced state and delta[0] carries the inter-batch
+    catch-up — the sweep then ships O(Σ delta) bytes with no full-table
+    upload at all."""
     H, C, _, T_col, w_col = _column_layout(hop_times, windows)
     W = C // H
     be_lat, be_alive, bv_lat, bv_alive = base
@@ -467,15 +481,17 @@ def run_columns_delta(kind, tables, base, deltas_e, deltas_v, hop_times,
         extra.extend((weight_base, dw_pos, dw_val))
     if r_init is not None:
         extra.append(r_init)
+    extra.extend(static_tables)
     # the whole dispatch payload ships through the pipelined engine: array
     # k+1 stages while k is on the wire, each slice retried on transport
     # errors (device-resident inputs pass through untouched)
     from ..utils.transfer import shared_engine
 
     # how the dispatch combines at the destination: PageRank's sum is a
-    # scan or (tiled, wide) a scatter; min / max scatter; CDLP sorts
+    # scan or (tiled, wide) a scatter; min / max scatter; CDLP sorts; LCC
+    # intersects neighbour sets over the triangle table
     combine = {"pagerank": _combine_route(tables.m_pad, C, tile_budget),
-               "cdlp": "sort"}.get(kind, "scatter")
+               "cdlp": "sort", "lcc": "intersect"}.get(kind, "scatter")
     with TRACER.span("hop.compute", kind=kind, hops=H, cols=H * W,
                         resident_base=h0_delta, combine=combine):
         return runner(*shared_engine().put_many([
@@ -2088,6 +2104,47 @@ class HopBatchedCDLP(_HopBatched):
             hop_times, windows, algo_args=(int(self.max_steps),),
             e_src_dev=self._e_src, e_dst_dev=self._e_dst, h0_delta=h0,
             ship_counter=self._count_ship))
+
+
+class HopBatchedLCC(_HopBatched):
+    """Windowed local clustering coefficient (LDBC Graphalytics LCC) over
+    a full hop sweep in one call: per column ``[2, n_pad]`` int32, ``tri``
+    (the directed pairs among a vertex's neighbours) and ``deg`` (its
+    undirected neighbours), in the global dense space. One pass over the
+    log's triangle table (``ops/triangles``), which this engine asks the
+    log's index for — built on the first ask, resident on the device with
+    the pair table. Delta-fed only; nothing to warm-start."""
+
+    supports_delta_fold = True
+
+    def __init__(self, log: EventLog):
+        super().__init__(log)
+        #: ``triangles_status``: "built" (this engine's build made the
+        #: table) or "held" (the log's index had it) — ``engine.build``'s
+        #: ``triangles`` attribute
+        self.triangles, self.triangles_status = log_triangles(
+            log, self.tables)
+
+    def _use_delta_fold(self) -> bool:
+        return True
+
+    def _dispatch_deltas(self, payload, hop_times, windows, r_init=None):
+        assert r_init is None   # neither warm channel is declared
+        base, deltas_e, deltas_v = payload
+        base, h0 = self._delta_base_args(base)
+        tt = self.triangles
+        columns = len(hop_times) * len(windows)
+        led = _ledger.current()
+        if led is not None:
+            # triangle rows the dispatch walks, padding included, for each
+            # of its columns — from the shapes, no device read-back
+            led.count_triangle_rows(tt.walked_rows * columns)
+        return self._run_delta(lambda: run_columns_delta(
+            "lcc", self.tables, base, deltas_e, deltas_v,
+            hop_times, windows, algo_args=(int(tt.tile_edges),),
+            e_src_dev=self._e_src, e_dst_dev=self._e_dst, h0_delta=h0,
+            ship_counter=self._count_ship,
+            static_tables=_device_triangles(self._log, tt)))
 
 
 def _dispatch_columns(runner, tables, cols, hop_of_col, T_col,

@@ -160,6 +160,12 @@ class VertexProgram:
     # (``custom_exchange``). False: the pair is refused — two custom
     # aggregates have no merge.
     exchange_joint: bool = False
+    # True for an algorithm that is no message along an edge (it
+    # intersects neighbour sets: ``algorithms/clustering.LCC``): it has no
+    # init / message / update, the columnar engine is its only engine,
+    # and the job layer serves it there or fails the job by name
+    # (``jobs/manager.Job._run_columnar_only``), never through ``bsp``.
+    columnar_only: bool = False
 
     @property
     def cost_label(self) -> str:

@@ -336,7 +336,9 @@ class Job:
             if self._coalesce is not None and self._run_coalesced(q):
                 return   # status set by the coalesced path
             self._coalesce = None   # declined/timed out: own path
-            if isinstance(q, ViewQuery):
+            if self.program.columnar_only:
+                self._run_columnar_only(q)
+            elif isinstance(q, ViewQuery):
                 self._run_at(q.timestamp, q)
             elif isinstance(q, RangeQuery):
                 # When the whole range is already safe, sweep incrementally
@@ -548,23 +550,49 @@ class Job:
         self._range_amortised(q, sweep.advance, run, sweep.reduce_view)
         return True
 
+    def _run_columnar_only(self, q) -> None:
+        """A program with no engine but the columnar one (``LCC``: it is
+        no message along an edge). A Range is ``_try_range_hopbatch``'s;
+        a View is the same engine at one hop, one column a window. What
+        that route does not take — a mesh, a time past the watermark's
+        fence, a Live subscription — fails the job with the route's name
+        instead of falling to ``bsp``, which cannot run the program."""
+        name = type(self.program).__name__
+        if isinstance(q, ViewQuery):
+            q = RangeQuery(int(q.timestamp), int(q.timestamp), 1,
+                           window=q.window, windows=q.windows)
+        if not isinstance(q, RangeQuery):
+            raise NotImplementedError(
+                f"{name} is served by the columnar Range route "
+                f"(hopbatch.delta.{name.lower()}) as a Range or a View; "
+                "a Live subscription of it is not")
+        if not self._try_range_hopbatch(q):
+            raise NotImplementedError(
+                f"{name} is served by the columnar Range route "
+                f"(hopbatch.delta.{name.lower()}) alone: one chip, a "
+                "range behind the watermark's fence, at most 1024 views")
+
     def _columnar_builder(self):
         """Construct the hop-batched columnar engine for this job's
         program (raises for programs without one — the caller treats any
-        failure as \'route declined\'). PageRank: finalize is the raw rank
-        vector and the power iteration warm-starts safely. CC: labels are
-        global padded indices in both engines. SSSP/BFS: the columnar
-        distances are exactly finalize's output; weighted traversal folds
-        per-hop weight columns (immutable weight keys raise). CDLP: labels
-        are global padded indices in both engines and the rounds are
-        fixed, so the columns are ``bsp``'s answer."""
+        failure as \'route declined\'). Six kinds: PageRank
+        (``pagerank``: finalize is the raw rank vector and the power
+        iteration warm-starts safely); ConnectedComponents (``cc``: labels
+        are global padded indices in both engines); BFS and SSSP (``bfs``,
+        and ``bfs`` with a weight state: the columnar distances are
+        exactly finalize's output; weighted traversal folds per-hop weight
+        columns, immutable weight keys raise); CDLP (``cdlp``: labels are
+        global padded indices in both engines and the rounds are fixed,
+        so the columns are ``bsp``'s answer); LCC (``lcc``: ``tri`` and
+        ``deg`` per vertex, which no other engine computes)."""
         from ..algorithms import CDLP as _CDLP
         from ..algorithms import ConnectedComponents as _CC
+        from ..algorithms import LCC as _LCC
         from ..algorithms import PageRank as _PR
         from ..algorithms.traversal import SSSP as _SSSP
         from ..engine.hopbatch import (HopBatchedBFS, HopBatchedCC,
-                                       HopBatchedCDLP, HopBatchedPageRank,
-                                       HopBatchedSSSP)
+                                       HopBatchedCDLP, HopBatchedLCC,
+                                       HopBatchedPageRank, HopBatchedSSSP)
 
         p = self.program
         if type(p) is _PR:
@@ -574,6 +602,8 @@ class Job:
             return HopBatchedCC(self.graph.log, max_steps=p.max_steps)
         if type(p) is _CDLP:
             return HopBatchedCDLP(self.graph.log, max_steps=p.max_steps)
+        if type(p) is _LCC:
+            return HopBatchedLCC(self.graph.log)
         if type(p) is _SSSP:
             if p.weight_prop:
                 return HopBatchedSSSP(self.graph.log, p.seeds,
@@ -627,8 +657,9 @@ class Job:
         ConnectedComponents (labels are global padded indices in both
         engines; no warm start — min-propagation is not a contraction on a
         changing edge set), SSSP/BFS (unit or mutable-numeric-weighted;
-        no warm start), and CDLP (a fixed number of rounds of a histogram
-        combine; no warm start)."""
+        no warm start), CDLP (a fixed number of rounds of a histogram
+        combine; no warm start), and LCC (one pass over the log's
+        triangle table; nothing to warm-start)."""
         import numpy as np
 
         if self.mesh is not None or self.graph.safe_time() < q.end:

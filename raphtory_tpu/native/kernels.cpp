@@ -11,6 +11,7 @@
 // Build: g++ -O3 -shared -fPIC (see native/build.py). Plain C ABI.
 
 #include <algorithm>
+#include <atomic>
 #include <cstdint>
 #include <cstring>
 #include <thread>
@@ -260,5 +261,56 @@ void rtpu_searchsorted_u64(int64_t nb, const uint64_t* base,
     for (auto& x : th) x.join();
 }
 
-}  // extern "C"
+// Triangles of an undirected graph whose every edge points from its end of
+// lower rank to the other: CSR ``off[n + 1]`` / ``nbr[U]``, a vertex's
+// out-neighbours ascending, an edge's id its CSR position. Each triangle
+// v1 < v2 < v3 is found once, from v1, by merging what is left of N+(v1)
+// after v2 with N+(v2): e1 = (v1, v2), e2 = (v1, v3), e3 = (v2, v3), rows
+// ascending by (e1, e2). ``row_off == nullptr`` is the counting pass
+// (``cnt[v1]`` = triangles whose lowest vertex is v1); with ``row_off`` (the
+// exclusive running sum of ``cnt``) the rows are written. Vertices are handed
+// to the threads in small blocks, so a hub's block stalls nobody.
+void rtpu_triangles(int64_t n, const int64_t* off, const int32_t* nbr,
+                    int64_t* cnt, const int64_t* row_off,
+                    int32_t* e1, int32_t* e2, int32_t* e3) {
+    int nt = (int)std::thread::hardware_concurrency();
+    if (nt < 1) nt = 1;
+    if (nt > 32) nt = 32;
+    if (off[n] < (1 << 18)) nt = 1;   // a small graph is done before a thread starts
+    const int64_t block = 64;
+    std::atomic<int64_t> next(0);
+    auto work = [&]() {
+        for (;;) {
+            int64_t lo = next.fetch_add(block), hi = std::min(n, lo + block);
+            if (lo >= n) return;
+            for (int64_t v1 = lo; v1 < hi; ++v1) {
+                const int64_t end1 = off[v1 + 1];
+                int64_t found = 0, at = row_off ? row_off[v1] : 0;
+                for (int64_t i = off[v1]; i < end1; ++i) {
+                    const int32_t v2 = nbr[i];
+                    int64_t j = i + 1, k = off[v2];
+                    const int64_t end2 = off[v2 + 1];
+                    while (j < end1 && k < end2) {
+                        const int32_t a = nbr[j], b = nbr[k];
+                        if (a < b) ++j;
+                        else if (b < a) ++k;
+                        else {
+                            if (row_off) {
+                                e1[at] = (int32_t)i; e2[at] = (int32_t)j;
+                                e3[at] = (int32_t)k; ++at;
+                            }
+                            ++found; ++j; ++k;
+                        }
+                    }
+                }
+                if (!row_off) cnt[v1] = found;
+            }
+        }
+    };
+    std::vector<std::thread> th;
+    for (int t = 1; t < nt; ++t) th.emplace_back(work);
+    work();
+    for (auto& x : th) x.join();
+}
 
+}  // extern "C"

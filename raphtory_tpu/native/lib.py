@@ -85,6 +85,10 @@ def _build_and_bind():
     lib.rtpu_searchsorted_u64.restype = None
     lib.rtpu_searchsorted_u64.argtypes = [
         ctypes.c_int64, _u64p, ctypes.c_int64, _u64p, ctypes.c_int32, _i64p]
+    _i32p = ctypes.POINTER(ctypes.c_int32)
+    lib.rtpu_triangles.restype = None
+    lib.rtpu_triangles.argtypes = [
+        ctypes.c_int64, _i64p, _i32p, _i64p, _i64p, _i32p, _i32p, _i32p]
     return lib
 
 
@@ -217,3 +221,32 @@ def parse_int_csv(data: bytes, sep: str, cols: tuple) -> np.ndarray | None:
         data, len(data), ctypes.c_char(sep_b), _p64(cols_a),
         len(cols), _p64(out), max_rows)
     return np.ascontiguousarray(out[:, :rows])
+
+
+def triangles(offsets: np.ndarray, nbr: np.ndarray, row_off=None,
+              out=None):
+    """Triangles of a rank-oriented CSR (``ops/triangles.py`` names the
+    orientation). Without ``row_off``: ``cnt [n]``, the triangles whose
+    lowest vertex is each vertex. With it: the rows of vertex ``v`` —
+    three edge ids (CSR positions) each, ascending by (e1, e2) — written
+    from ``row_off[v]`` on into ``out = (e1, e2, e3)``, the caller's
+    int32 arrays (its threads touch the pages: a fresh 2 GB array costs
+    seconds to fault in from one). None when the native lib is
+    unavailable."""
+    lib = _load()
+    if lib is None:
+        return None
+    i32p = ctypes.POINTER(ctypes.c_int32)
+    off = _c64(offsets)
+    nbr = np.ascontiguousarray(nbr, np.int32)
+    n = len(off) - 1
+    if row_off is None:
+        cnt = np.zeros(n, np.int64)
+        lib.rtpu_triangles(n, _p64(off), nbr.ctypes.data_as(i32p),
+                           _p64(cnt), None, None, None, None)
+        return cnt
+    assert all(a.dtype == np.int32 and a.flags.c_contiguous for a in out)
+    lib.rtpu_triangles(n, _p64(off), nbr.ctypes.data_as(i32p), None,
+                       _p64(_c64(row_off)),
+                       *(a.ctypes.data_as(i32p) for a in out))
+    return out

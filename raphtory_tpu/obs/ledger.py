@@ -567,10 +567,16 @@ def built(engine) -> dict:
     """``engine.build`` span attributes of a finished engine: its class,
     the padded sizes of its global tables, and what its lookup of the
     log's index cost (``engine/device_sweep.log_index``): ``hit`` (a
-    fork), ``extended`` (a suffix adopted first) or ``miss`` (built)."""
+    fork), ``extended`` (a suffix adopted first) or ``miss`` (built). An
+    engine over the log's triangle table (``HopBatchedLCC``) adds
+    ``triangles``: ``built`` (by this build: span ``index.triangles``) or
+    ``held`` (the index had it)."""
     t = engine.tables
-    return {"engine": type(engine).__name__, "n_pad": int(t.n_pad),
-            "m_pad": int(t.m_pad), "index": engine.index_status}
+    out = {"engine": type(engine).__name__, "n_pad": int(t.n_pad),
+           "m_pad": int(t.m_pad), "index": engine.index_status}
+    if getattr(engine, "triangles_status", None):
+        out["triangles"] = engine.triangles_status
+    return out
 
 
 
@@ -622,6 +628,10 @@ class Ledger:
         #: of the query's dispatches (columnar CDLP; counted on the host
         #: from the dispatch's shapes, no device read-back)
         self.mode_rows = 0
+        #: triangle-table rows walked, padding included, times the columns
+        #: served, summed over the query's dispatches (columnar LCC;
+        #: counted on the host from the dispatch's shapes)
+        self.triangle_rows = 0
         #: set by the serving scheduler when this query's views rode a
         #: COALESCED cross-request dispatch (jobs/scheduler.py): batch
         #: id, member count, this query's column share — the explain
@@ -725,6 +735,10 @@ class Ledger:
         with self._lock:
             self.mode_rows += int(n)
 
+    def count_triangle_rows(self, n: int) -> None:
+        with self._lock:
+            self.triangle_rows += int(n)
+
     def count_views(self, n: int = 1) -> None:
         with self._lock:
             self.views += int(n)
@@ -788,6 +802,7 @@ class Ledger:
                 self.peak_device_bytes,
                 snap["device"].get("peak_device_bytes", 0))
             self.mode_rows += snap["device"].get("mode_rows", 0)
+            self.triangle_rows += snap["device"].get("triangle_rows", 0)
         return self
 
     def absorb_share(self, batch_snap: dict, frac: float,
@@ -839,6 +854,8 @@ class Ledger:
                     mine["bound_refined"] = k["bound_refined"]
             self.mode_rows += int(
                 batch_snap["device"].get("mode_rows", 0) * frac)
+            self.triangle_rows += int(
+                batch_snap["device"].get("triangle_rows", 0) * frac)
             self.sweeps += 1
             if coalesced is not None:
                 self.coalesced = dict(coalesced)
@@ -937,6 +954,7 @@ class Ledger:
                                         for k in self.kernels.values()),
                 "peak_device_bytes": int(self.peak_device_bytes),
                 "mode_rows": int(self.mode_rows),
+                "triangle_rows": int(self.triangle_rows),
                 "kernels": {n: dict(k) for n, k in self.kernels.items()},
             },
             "host": {"peak_rss_bytes": int(self.peak_rss_bytes)},

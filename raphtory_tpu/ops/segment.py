@@ -197,14 +197,53 @@ def sorted_segment_sum(
                                    indices_are_sorted=True)
     if ends is None or pos is None:
         ends, pos = segment_ends_pos(segment_ids, num_segments)
+    if data.ndim == 1:
+        return segment_sums_at(data, ends, pos)
+    # the two layout changes keep the scopes they had inside the scan
+    # and the pick: a device op's ``op_name`` says which pass it is
     with jax.named_scope("combine.scan"):
-        x = data if data.ndim == 1 else data.reshape(m, cols).T
+        x = data.reshape(m, cols).T
+    out = segment_sums_at(x, ends, pos)
+    with jax.named_scope("combine.pick"):
+        return out.T.reshape((num_segments,) + data.shape[1:])
+
+
+def rows_upto(segment_ids: jnp.ndarray, num_segments: int):
+    """``upto [num_segments]``: how many of the SORTED ids are <= each
+    segment — its rows end there and the next segment's begin
+    (``segment_ends_pos`` reads its ``ends`` off the same search)."""
+    return _rows_upto(segment_ids,
+                      jnp.arange(num_segments, dtype=segment_ids.dtype))
+
+
+def integer_segment_sums(x: jnp.ndarray, upto: jnp.ndarray):
+    """``sorted_segment_sum`` for INTEGER data with the rows on the minor
+    axis, ``x [..., m]`` -> ``[..., num_segments]``, ``upto`` from
+    ``rows_upto``: one running sum along the rows, differenced at the
+    segments' ends. Exact: integer addition wraps, so the difference of
+    two prefix sums is the segment's sum whatever the prefix grew to, as
+    long as the segment's own sum fits the type — the rounding that makes
+    a float sum scan inside its segments (``_segmented_scan``) does not
+    exist here. On a TPU v5e a running sum costs 0.13 ns an element where
+    the segmented scan costs 0.5 (PERF.md section 6, PR 39)."""
+    assert jnp.issubdtype(x.dtype, jnp.integer)
+    with jax.named_scope("combine.scan"):
+        total = jnp.cumsum(x, axis=-1)
+    with jax.named_scope("combine.pick"):
+        at_end = jnp.where(upto > 0, total[..., jnp.maximum(upto - 1, 0)], 0)
+        return at_end - _shift(at_end, 1)
+
+
+def segment_sums_at(x: jnp.ndarray, ends: jnp.ndarray, pos: jnp.ndarray):
+    """``sorted_segment_sum``'s scan and pick for data that already lies
+    with the rows on the minor axis: ``x [..., m]`` -> ``[...,
+    num_segments]``, ``ends`` / ``pos`` from ``segment_ends_pos``. A
+    caller that builds ``[cols, m]`` itself (``ops/triangles``: 16
+    million rows a tile) never holds the lane-padded ``[m, cols]``."""
+    with jax.named_scope("combine.scan"):
         x = _segmented_scan(x, pos)
     with jax.named_scope("combine.pick"):
-        out = jnp.where(ends >= 0, x[..., jnp.maximum(ends, 0)], 0)
-        if data.ndim > 1:
-            out = out.T.reshape((num_segments,) + data.shape[1:])
-    return out
+        return jnp.where(ends >= 0, x[..., jnp.maximum(ends, 0)], 0)
 
 
 _V_BITS = 31  # segment_mode value budget: non-negative ints < 2**31 - 1
