@@ -928,6 +928,12 @@ class _HopBatched:
     #: warm-start from the previous chunk's solution)
     supports_warm_start = False
 
+    #: whether a warm-started chunk can halt in fewer supersteps than a
+    #: cold one: never without ``supports_warm_start``, and PageRank only
+    #: with ``tol > 0`` — with a fixed superstep count chunking buys no
+    #: superstep (``jobs/manager._range_chunks``)
+    warm_start_saves_steps = False
+
     #: subclasses whose kernel has a delta-fed variant (device-side mask
     #: rebuild, ``_masks_from_deltas``; SSSP additionally rebuilds its
     #: weight state from base + per-hop deltas)
@@ -967,6 +973,14 @@ class _HopBatched:
         kernel holds across its superstep loop."""
         return (self.tables.m_pad + self.tables.n_pad) * n_cols
 
+    def dispatch_columns_ok(self, C: int) -> bool:
+        """Whether ONE dispatch of ``C`` columns stays on this engine's
+        fast path: its ``[m_pad, C]`` payload untiled under the tile
+        budget. The job layer sizes a Range's dispatches by this
+        (``jobs/manager._range_chunks``)."""
+        return _edge_tile_for(self.tables.m_pad, C,
+                              _tile_budget_bytes()) is None
+
     def _dispatch_cols(self, cols, hop_times, windows, r_init=None):
         raise NotImplementedError
 
@@ -982,7 +996,8 @@ class _HopBatched:
         self.ship_bytes = 0
 
     def run(self, hop_times, windows, chunks: int = 1,
-            warm_start: bool = False, hop_callback=None, warm_state=None):
+            warm_start: bool = False, hop_callback=None, warm_state=None,
+            chunk_rule: str = "caller"):
         """``chunks=k`` pipelines the sweep; ``warm_start=True``
         additionally initialises each chunk's columns from the previous
         chunk's LAST-hop ranks (same fixed point, reached in far fewer
@@ -1005,7 +1020,12 @@ class _HopBatched:
         exact (log, hop grid) repeat serves its fold from the bounded
         cross-request fold cache (``RTPU_FOLD_CACHE_MB``); on a hit the
         callback replays from cached per-hop vertex state and
-        ``fold_seconds`` stays ~0."""
+        ``fold_seconds`` stays ~0.
+
+        ``chunk_rule`` says who chose ``chunks`` and why (the job layer's
+        ``_range_chunks``: ``one_dispatch`` / ``fit`` / ``warm_start`` /
+        ``ladder``); it rides the ``sweep.columnar`` span and the
+        ledger's ``device`` block beside ``columns``, a dispatch's C."""
         self._reset_run_counters()
         if warm_start and not self.supports_warm_start:
             raise ValueError(
@@ -1019,6 +1039,13 @@ class _HopBatched:
             self._epoch_seed = warm_state
         hop_times = [int(x) for x in hop_times]
         chunks = max(1, min(int(chunks), len(hop_times)))
+        # C of one dispatch; an unequal split runs as one group
+        # (``_run_chunks``)
+        groups = 1 if len(hop_times) % chunks else chunks
+        columns = len(hop_times) // groups * len(windows)
+        led = _ledger.current()
+        if led is not None:
+            led.note_chunks(chunks, columns, chunk_rule)
         from ..utils.transfer import shared_engine
 
         before = shared_engine().stats.as_dict()
@@ -1026,7 +1053,9 @@ class _HopBatched:
         try:
             with TRACER.span("sweep.columnar",
                                 engine=type(self).__name__,
-                                hops=len(hop_times), chunks=chunks) as sp:
+                                hops=len(hop_times), chunks=chunks,
+                                columns=columns,
+                                chunk_rule=chunk_rule) as sp:
                 out = self._run_chunks(hop_times, windows, chunks,
                                        warm_start, hop_callback)
                 self.last_phase_seconds = sweep_phase_summary(
@@ -1755,6 +1784,18 @@ class HopBatchedPageRank(_HopBatched):
                  tol: float = 1e-7, max_steps: int = 20):
         super().__init__(log)
         self.damping, self.tol, self.max_steps = damping, tol, max_steps
+
+    @property
+    def warm_start_saves_steps(self) -> bool:
+        # with tol == 0 no column halts before max_steps, whatever it
+        # starts from
+        return self.tol > 0
+
+    def dispatch_columns_ok(self, C: int) -> bool:
+        """Untiled AND narrow enough for the segmented scan: a tiled or
+        wide dispatch sums by the scatter (``_combine_route``)."""
+        return _combine_route(self.tables.m_pad, C,
+                              _tile_budget_bytes()) == "scan"
 
     def _dispatch_cols(self, cols, hop_times, windows, r_init=None):
         return run_columns(

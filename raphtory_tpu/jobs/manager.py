@@ -650,7 +650,8 @@ class Job:
     def _try_range_hopbatch(self, q: RangeQuery) -> bool:
         """Whole-range columnar dispatch for qualifying Range queries:
         every (hop, window) view of the range is a COLUMN of one compiled
-        program (``engine/hopbatch``), pipelined in equal hop chunks —
+        program (``engine/hopbatch``), in as few dispatches as
+        ``_range_chunks`` finds worth their pass over the table —
         against the reference's full per-hop actor handshake
         (``RangeAnalysisTask.scala:18-35``). Routes: PageRank (finalize is
         the raw rank vector; the power iteration warm-starts safely),
@@ -679,14 +680,13 @@ class Job:
         def grab_shell(T, sw):
             shells[int(T)] = _shell_from_fold(hb.tables, sw, int(T))
 
-        chunks = next((k for k in (4, 3, 2)
-                       if len(hops) >= 2 * k and len(hops) % k == 0), 1)
+        chunks, rule = _range_chunks(hb, len(hops), len(windows))
         t0 = _time.perf_counter()
         try:
             ranks, steps = hb.run(hops, windows, chunks=chunks,
                                   warm_start=chunks > 1
                                   and hb.supports_warm_start,
-                                  hop_callback=grab_shell)
+                                  hop_callback=grab_shell, chunk_rule=rule)
             b0 = _time.perf_counter()
             ranks, steps = _block_steps(
                 lambda: (np.asarray(ranks), steps))
@@ -1071,6 +1071,32 @@ class Job:
                 self.results_dropped += drop
         if self.sink is not None:
             self.sink.write(row)
+
+
+def _range_chunks(hb, n_hops: int, n_windows: int) -> tuple[int, str]:
+    """How many dispatches a columnar Range of ``n_hops`` x ``n_windows``
+    views is, and why: ``(chunks, chunk_rule)``. Chunks hide the host
+    fold behind the device and warm-start the next chunk's iteration;
+    each is another pass over the pair table and pays the dispatch's
+    fixed work again. So:
+
+    - ``warm_start``: the engine's warm-started chunks can halt in fewer
+      supersteps (PageRank with ``tol > 0``) — the ladder, equal chunks
+      of at least two hops, most first;
+    - ``one_dispatch``: they cannot, and all the columns in one dispatch
+      stay on the engine's fast path (``dispatch_columns_ok``);
+    - ``fit``: the fewest chunks (of 2, 3, 4, dividing the hops) whose
+      columns do;
+    - ``ladder``: none does."""
+    ladder = next((k for k in (4, 3, 2)
+                   if n_hops >= 2 * k and n_hops % k == 0), 1)
+    if hb.warm_start_saves_steps:
+        return ladder, "warm_start"
+    C = n_hops * n_windows
+    for k in (1, 2, 3, 4):
+        if n_hops % k == 0 and hb.dispatch_columns_ok(C // k):
+            return k, "one_dispatch" if k == 1 else "fit"
+    return ladder, "ladder"
 
 
 def _shell_from_fold(tables, sw, T):

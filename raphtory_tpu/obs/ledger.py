@@ -632,6 +632,10 @@ class Ledger:
         #: served, summed over the query's dispatches (columnar LCC;
         #: counted on the host from the dispatch's shapes)
         self.triangle_rows = 0
+        #: how the LAST columnar sweep of the query was cut into
+        #: dispatches: ``chunks``, ``columns`` (C of one dispatch) and
+        #: ``chunk_rule`` (``jobs/manager._range_chunks``, or ``caller``)
+        self.chunking: dict | None = None
         #: set by the serving scheduler when this query's views rode a
         #: COALESCED cross-request dispatch (jobs/scheduler.py): batch
         #: id, member count, this query's column share — the explain
@@ -738,6 +742,11 @@ class Ledger:
     def count_triangle_rows(self, n: int) -> None:
         with self._lock:
             self.triangle_rows += int(n)
+
+    def note_chunks(self, chunks: int, columns: int, rule: str) -> None:
+        with self._lock:
+            self.chunking = {"chunks": int(chunks), "columns": int(columns),
+                             "chunk_rule": str(rule)}
 
     def count_views(self, n: int = 1) -> None:
         with self._lock:
@@ -955,6 +964,7 @@ class Ledger:
                 "peak_device_bytes": int(self.peak_device_bytes),
                 "mode_rows": int(self.mode_rows),
                 "triangle_rows": int(self.triangle_rows),
+                **(self.chunking or {}),
                 "kernels": {n: dict(k) for n, k in self.kernels.items()},
             },
             "host": {"peak_rss_bytes": int(self.peak_rss_bytes)},
