@@ -1414,81 +1414,6 @@ def bench_scale_pagerank():
     }
 
 
-def bench_scale_features():
-    """Windowed 128-d feature aggregation (temporal GNN mean-aggregate) —
-    the scale workload the TPU memory system is FOR: every edge moves a
-    128-lane feature row, so the engine streams at HBM bandwidth instead of
-    the per-element gather rate. The reference has no analogue (scalar actor
-    messages only)."""
-    import os
-
-    import jax
-
-    from raphtory_tpu.engine.device_sweep import DeviceSweep
-    from raphtory_tpu.engine.features import FeatureAggregator
-    from raphtory_tpu.utils.synth import twitter_like_log
-
-    n_v = int(os.environ.get("RTPU_FEAT_V", 1 << 22))   # 4.2M
-    n_e = int(os.environ.get("RTPU_FEAT_E", 1 << 25))   # 33.5M
-    t_span = 2_600_000
-    log = twitter_like_log(n_vertices=n_v, n_edges=n_e, t_span=t_span)
-
-    rounds, F = 2, 128
-    # feature storage dtype: bf16 on the accelerator (halves the HBM-bound
-    # row traffic; f32 accumulation), f32 on host where bf16 is emulated —
-    # each backend's NATIVE dtype, disclosed in the row; an explicit
-    # RTPU_FEAT_DTYPE pins both (it propagates to the crosscheck child).
-    fdt = os.environ.get(
-        "RTPU_FEAT_DTYPE",
-        "bfloat16" if jax.default_backend() == "tpu" else "float32")
-    T0 = int(0.8 * t_span)
-    s0 = _time.perf_counter()
-    ds = DeviceSweep(log)
-    fa = FeatureAggregator(ds, feature_dim=F, dtype=fdt)
-    X = fa.random_features()
-    H = fa.propagate(X, T0, window=t_span, rounds=rounds)   # compile+upload
-    _sync(H)
-    setup_s = _time.perf_counter() - s0
-
-    calls = [(T0 + 3_600, t_span), (T0 + 3_600, 86_400),
-             (T0 + 7_200, t_span), (T0 + 7_200, 86_400)]
-    t0 = _time.perf_counter()
-    outs = [fa.propagate(X, T, window=w, rounds=rounds) for T, w in calls]
-    _sync(outs)
-    elapsed = _time.perf_counter() - t0
-    vps = len(calls) / elapsed
-
-    bytes_moved = len(calls) * fa.traffic_bytes(rounds)
-    flops = len(calls) * fa.flops(rounds)
-    peak_tflops, peak_gbps = _device_peaks()
-    return {
-        "metric": (f"scale windowed {F}-d feature aggregation views/sec "
-                   f"({n_v / 1e6:.1f}M v / {n_e / 1e6:.1f}M edges, "
-                   f"{rounds} rounds)"),
-        "value": round(vps, 3),
-        "unit": "views/sec",
-        "vs_baseline": None,   # no reference analogue exists (not "0x" —
-        # detail.baseline carries the explanation)
-        "detail": {
-            "n_views": len(calls),
-            "n_vertices": n_v,
-            "n_edges": n_e,
-            "feature_dtype": fdt,
-            "sweep_seconds": round(elapsed, 2),
-            "seconds_per_view": round(elapsed / len(calls), 3),
-            "setup_seconds": round(setup_s, 2),
-            "unique_pairs": int(ds.m),
-            "achieved_GBps": round(bytes_moved / elapsed / 1e9, 1),
-            "achieved_GFLOPs": round(flops / elapsed / 1e9, 1),
-            "hbm_peak_GBps": peak_gbps,
-            "bf16_peak_TFLOPS": peak_tflops,
-            "bandwidth_util_pct": round(
-                100 * bytes_moved / elapsed / 1e9 / peak_gbps, 2),
-            "baseline": "no reference analogue (scalar actor messages only)",
-        },
-    }
-
-
 def _arrays_equal(a, b) -> bool:
     """Recursive bitwise equality of nested payload structures."""
     if a is None or b is None:
@@ -3203,7 +3128,6 @@ CONFIGS = {
     "ingest_obs_overhead": bench_ingest_obs_overhead,
     "live_stream": bench_live_stream,
     "scale_pagerank": bench_scale_pagerank,
-    "scale_features": bench_scale_features,
 }
 
 
@@ -3354,18 +3278,6 @@ def main() -> int:
                     "scale_pagerank", timeout=1200.0,
                     env={"RTPU_SCALE_V": str(row["detail"]["n_vertices"]),
                          "RTPU_SCALE_E": str(row["detail"]["n_edge_events"]),
-                         "RTPU_CROSSCHECK": "1"})
-            if (name == "scale_features" and row["device"] != "cpu"
-                    and not args.no_crosscheck):
-                # same element count; each backend keeps its NATIVE storage
-                # dtype (bf16 on the chip, f32 on host where bf16 is
-                # emulated) — handicapping the host would inflate the
-                # chip-vs-host proof. An explicit RTPU_FEAT_DTYPE in the
-                # environment propagates to the subprocess and pins both.
-                row["detail"]["cpu_same_size_crosscheck"] = _cpu_crosscheck(
-                    "scale_features", timeout=1200.0,
-                    env={"RTPU_FEAT_V": str(row["detail"]["n_vertices"]),
-                         "RTPU_FEAT_E": str(row["detail"]["n_edges"]),
                          "RTPU_CROSSCHECK": "1"})
         except Exception as e:
             row = {

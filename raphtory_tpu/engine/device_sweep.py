@@ -396,6 +396,55 @@ def _device_triangles(log, tt) -> tuple:
     return dev
 
 
+#: per-log cache of what the columnar kind ``sgc`` keeps resident beside
+#: the pair table: the propagation table (a function of the pair table
+#: alone) and ONE feature block, the last (dim, seed) asked for — an
+#: immutable ``(key, table, of, X)`` swapped whole, as ``_DEVICE_TRIANGLES``
+_DEVICE_FEATURES = weakref.WeakKeyDictionary()
+
+
+def _device_features(log, tables, dim: int, seed: int):
+    """``(X, table, status)`` for ``tables``: the device feature block
+    ``[n_pad, dim]`` of the log's vertex ids (``ops/propagate.features``)
+    made ON the device, and the propagation table of its pair table
+    (``ops/propagate.build_table``: a host sort, put on the device), once
+    a log:
+    ``status`` is ``"built"`` when this call made either, ``"held"`` when
+    the cache had both. Keyed like ``_device_edges`` (equal counts mean
+    the identical table). What is returned is what this call read or
+    made, never re-read from the cache: two jobs with different seeds on
+    one log each serve their own features, whoever publishes last."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..obs import device as _obs_device
+    from ..ops import propagate
+
+    key, of = (tables.m, tables.n), (int(dim), int(seed))
+    ent = _DEVICE_FEATURES.get(log)
+    _, table, held_of, X = ent if ent is not None and ent[0] == key \
+        else (key, None, None, None)
+    if table is not None and held_of == of:
+        return X, table, "held"
+    if table is None:
+        from ..utils.transfer import device_put_chunked
+
+        table = propagate.PropagationTable(*(
+            device_put_chunked(a) for a in propagate.build_table(
+                tables.e_src, tables.e_dst, int(tables.n_pad))))
+    if held_of != of:
+        # from ``uv``: a DeviceSweep over the same tables frees ``vids``
+        vids = np.full(tables.n_pad, -1, np.int64)
+        vids[: tables.n] = tables.uv
+        X = jax.jit(propagate.features, static_argnums=(1, 2))(
+            jnp.asarray(vids), *of)
+    _DEVICE_FEATURES[log] = (key, table, of, X)
+    _obs_device.RESIDENT.track(
+        log, "feature_tables",
+        _obs_device.nbytes_tree((X, tuple(table))), dim=int(dim))
+    return X, table, "built"
+
+
 def log_index_status() -> dict:
     """The ``log_index`` block of ``/statusz``: lookups by outcome since
     start, and the host bytes the live indexes hold."""

@@ -43,11 +43,12 @@ from ..obs import ledger as _ledger
 from ..obs.trace import TRACER
 from ..ops.segment import (segment_counts, segment_ends_pos, segment_mode,
                            sorted_segment_sum, sum_route)
+from ..ops import propagate as _propagate
 from ..ops.triangles import lcc_columns
 from ..utils.transfer import _metrics
-from .device_sweep import (_device_edges, _device_triangles, log_index,
-                           log_triangles, normalize_windows,
-                           sweep_phase_summary)
+from .device_sweep import (_device_edges, _device_features,
+                           _device_triangles, log_index, log_triangles,
+                           normalize_windows, sweep_phase_summary)
 
 _log = logging.getLogger(__name__)
 
@@ -333,10 +334,12 @@ def _compiled_delta(kind: str, n_pad: int, m_pad: int, H: int, W: int,
                     tile_budget: int | None = None):
     """Delta-fed columnar kernels: masks rebuilt on device from base state
     + per-hop deltas (``_masks_from_deltas``), then the shared algorithm
-    body. ``kind``: pagerank | cc | cdlp | lcc | bfs (``weighted`` adds a
-    per-pair weight state rebuilt the same way); ``algo_args`` is the
-    algorithm's static parameter tuple (``lcc``: its triangle table's
-    ``tile_edges``, the table's arrays follow the column descriptors).
+    body. ``kind``: pagerank | cc | cdlp | lcc | sgc | bfs (``weighted``
+    adds a per-pair weight state rebuilt the same way); ``algo_args`` is
+    the algorithm's static parameter tuple (``lcc``: its triangle table's
+    ``tile_edges``, the table's arrays follow the column descriptors;
+    ``sgc``: its rounds, the feature block and the propagation table
+    follow them).
     ``h0=True`` is the resident-base variant: the base inputs are the
     previous dispatch's advanced state, delta[0] is applied before hop 0.
     Every variant returns ``(result, steps, advanced_base)`` so the
@@ -377,6 +380,14 @@ def _compiled_delta(kind: str, n_pad: int, m_pad: int, H: int, W: int,
             (tile_edges,) = algo_args
             return (lcc_columns(me, n_pad, tile_edges, *rest),
                     jnp.int32(1), adv)
+        if kind == "sgc":
+            # a column is an [n_pad, F] block: the columns are walked,
+            # and what comes back of each is its summary, not the block
+            (rounds,) = algo_args
+            X, *table = rest
+            return (_propagate.sgc_columns(
+                me, mv, rounds, X, _propagate.PropagationTable(*table)),
+                jnp.int32(rounds), adv)
         max_steps, directed = algo_args
         ew = 1.0
         nxt = 1   # rest[0] is the seed mask; weights then warm follow
@@ -425,10 +436,11 @@ def run_columns_delta(kind, tables, base, deltas_e, deltas_v, hop_times,
                       h0_delta: bool = False, ship_counter=None,
                       static_tables: tuple = ()):
     """Dispatch a delta-fed columnar kernel (``kind``:
-    pagerank|cc|cdlp|lcc|bfs) over ``_HopBatched._fold_deltas`` output;
-    returns ``(result, steps, advanced_base)``. ``static_tables`` are
-    device-resident per-log arrays the kind's body takes after the
-    column descriptors (``lcc``: the triangle table). ``weight_base`` +
+    pagerank|cc|cdlp|lcc|sgc|bfs) over ``_HopBatched._fold_deltas``
+    output; returns ``(result, steps, advanced_base)``. ``static_tables``
+    are device-resident per-log arrays the kind's body takes after the
+    column descriptors (``lcc``: the triangle table; ``sgc``: the feature
+    block and the propagation table). ``weight_base`` +
     ``weight_deltas`` ([(pos, val)] per hop) turn bfs into weighted SSSP
     with the weight state rebuilt on device too. ``h0_delta=True`` means
     ``base`` (and ``weight_base``) are the previous dispatch's
@@ -489,9 +501,11 @@ def run_columns_delta(kind, tables, base, deltas_e, deltas_v, hop_times,
 
     # how the dispatch combines at the destination: PageRank's sum is a
     # scan or (tiled, wide) a scatter; min / max scatter; CDLP sorts; LCC
-    # intersects neighbour sets over the triangle table
+    # intersects neighbour sets over the triangle table; SGC sums F-wide
+    # rows a step of the walked table at a time (ops/propagate)
     combine = {"pagerank": _combine_route(tables.m_pad, C, tile_budget),
-               "cdlp": "sort", "lcc": "intersect"}.get(kind, "scatter")
+               "cdlp": "sort", "lcc": "intersect",
+               "sgc": "rows"}.get(kind, "scatter")
     with TRACER.span("hop.compute", kind=kind, hops=H, cols=H * W,
                         resident_base=h0_delta, combine=combine):
         return runner(*shared_engine().put_many([
@@ -783,6 +797,13 @@ def _payload_nbytes(obj) -> int:
     return 8   # scalars (hop times in vshell rows)
 
 
+def _join_columns(outs):
+    """The chunks' results as one, hop-major: each is an array whose
+    leading axis is its columns, or (``sgc``) a pytree of such."""
+    return jax.tree_util.tree_map(
+        lambda *xs: jnp.concatenate(xs, axis=0), *outs)
+
+
 class _HopBatched:
     """Shared incremental fold → per-hop state columns (deletes included).
 
@@ -980,6 +1001,10 @@ class _HopBatched:
         (``jobs/manager._range_chunks``)."""
         return _edge_tile_for(self.tables.m_pad, C,
                               _tile_budget_bytes()) is None
+
+    def count_result(self, out) -> None:
+        """Ledger counters that only a result knows (``out``: ``run``'s,
+        fetched to the host) — none but ``sgc``'s rows walked."""
 
     def _dispatch_cols(self, cols, hop_times, windows, r_init=None):
         raise NotImplementedError
@@ -1247,7 +1272,7 @@ class _HopBatched:
                     # residency: the next batch ships a base from the
                     # host clock, which is always consistent.
                     self._drop_residency()
-                    return jnp.concatenate(outs, axis=0), steps_box[0]
+                    return _join_columns(outs), steps_box[0]
                 # cached without shells but this job needs them: refold
             led = _ledger.current()
             if led is not None:
@@ -1306,7 +1331,7 @@ class _HopBatched:
                 self.fold_inline_seconds += self.fold_seconds - f0
                 dispatch(folded, 0.0)
         self._maybe_cache(cache, key, payloads, cap, delta)
-        return jnp.concatenate(outs, axis=0), steps_box[0]
+        return _join_columns(outs), steps_box[0]
 
     def _fold_group_serial(self, group, hop_callback, delta: bool,
                            lookahead: bool):
@@ -1394,7 +1419,7 @@ class _HopBatched:
         payloads, cap = self._fold_groups_parallel(
             groups, hop_callback, delta, cache, key, workers, on_payload)
         self._maybe_cache(cache, key, payloads, cap, delta)
-        return jnp.concatenate(outs, axis=0), steps_box[0]
+        return _join_columns(outs), steps_box[0]
 
     def _fold_groups_parallel(self, groups, hop_callback, delta, cache,
                               key, workers, on_payload):
@@ -2186,6 +2211,75 @@ class HopBatchedLCC(_HopBatched):
             e_src_dev=self._e_src, e_dst_dev=self._e_dst, h0_delta=h0,
             ship_counter=self._count_ship,
             static_tables=_device_triangles(self._log, tt)))
+
+
+class HopBatchedSGC(_HopBatched):
+    """Windowed SGC feature propagation (``algorithms/propagation.SGC``:
+    ``Y = S^K X``, a row of ``dim`` features a vertex) over a full hop
+    sweep in one call. A column is an ``[n_pad, dim]`` block, so the
+    columns of a dispatch are walked over one feature block, one
+    propagation table and one accumulator (``ops/propagate``), which
+    this engine keeps resident per log beside the pair table; per column
+    the result is ``ops/propagate.summarise``'s small pytree (leading
+    axis the columns, hop-major), never the block. Delta-fed only;
+    nothing to warm-start."""
+
+    supports_delta_fold = True
+
+    def __init__(self, log: EventLog, rounds: int = 2, dim: int = 602,
+                 feature_seed: int = 0):
+        super().__init__(log)
+        self.rounds, self.dim = int(rounds), int(dim)
+        self.feature_seed = int(feature_seed)
+        #: ``features_status``: "built" (this engine's build made the
+        #: log's feature block or propagation table) or "held" —
+        #: ``engine.build``'s ``features`` attribute
+        self._features, self._table, self.features_status = \
+            _device_features(log, self.tables, self.dim, self.feature_seed)
+
+    def _use_delta_fold(self) -> bool:
+        return True
+
+    def _row_bytes(self) -> int:
+        """Device bytes of one vertex's ``dim`` float32 features, padded
+        to whole 128-lane tiles."""
+        return -(-self.dim // 128) * 512
+
+    def device_mask_bytes(self, n_cols: int) -> int:
+        """The masks and what a dispatch holds whatever its columns, four
+        ``[n_pad, dim]`` blocks: the features, a round's two states and
+        the accumulator."""
+        return super().device_mask_bytes(n_cols) \
+            + 4 * self.tables.n_pad * self._row_bytes()
+
+    def dispatch_columns_ok(self, C: int) -> bool:
+        """Always: the columns are walked, so nothing F-wide grows with
+        C — a dispatch holds the feature block, a round's two states,
+        the accumulator and one gathered step whatever it serves
+        (``device_mask_bytes``; the step is a constant of the table's
+        size, ``ops/propagate.step_rows``, which no budget sizes). A
+        Range is one dispatch."""
+        return True
+
+    def count_result(self, out) -> None:
+        """``device.feature_rows``: the F-wide rows the dispatch's
+        columns moved — each column's ``walked`` rows of ``A + A^T + I``
+        once a round, as the device counted them."""
+        led = _ledger.current()
+        if led is not None:
+            led.count_feature_rows(self.rounds * int(
+                np.sum(out["walked"], dtype=np.int64)))
+
+    def _dispatch_deltas(self, payload, hop_times, windows, r_init=None):
+        assert r_init is None   # neither warm channel is declared
+        base, deltas_e, deltas_v = payload
+        base, h0 = self._delta_base_args(base)
+        return self._run_delta(lambda: run_columns_delta(
+            "sgc", self.tables, base, deltas_e, deltas_v,
+            hop_times, windows, algo_args=(self.rounds,),
+            e_src_dev=self._e_src, e_dst_dev=self._e_dst, h0_delta=h0,
+            ship_counter=self._count_ship,
+            static_tables=(self._features, *self._table)))
 
 
 def _dispatch_columns(runner, tables, cols, hop_of_col, T_col,

@@ -160,11 +160,14 @@ class VertexProgram:
     # (``custom_exchange``). False: the pair is refused — two custom
     # aggregates have no merge.
     exchange_joint: bool = False
-    # True for an algorithm that is no message along an edge (it
-    # intersects neighbour sets: ``algorithms/clustering.LCC``): it has no
-    # init / message / update, the columnar engine is its only engine,
-    # and the job layer serves it there or fails the job by name
-    # (``jobs/manager.Job._run_columnar_only``), never through ``bsp``.
+    # True for a program the job layer serves on the columnar engine or
+    # fails by the route's name (``jobs/manager.Job._run_columnar_only``),
+    # never through ``bsp``: an algorithm that is no message along an
+    # edge and has no init / message / update
+    # (``algorithms/clustering.LCC`` intersects neighbour sets), or one
+    # whose state is a row of features a vertex
+    # (``algorithms/propagation.SGC``: ``bsp`` runs it as a library call
+    # at a small size and would gather ``[pairs, dim]`` at a served one).
     columnar_only: bool = False
 
     @property

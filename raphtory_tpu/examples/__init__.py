@@ -12,9 +12,6 @@ analysers built on the core algorithm library.
 | ``examples/citationNetwork`` | :mod:`.citations` |
 | ``examples/trackAndTrace``   | :mod:`.track_and_trace` |
 | ``examples/twitterRumour``   | :mod:`.twitter_rumour` |
-
-Plus :mod:`.embeddings` — temporal vertex embeddings over windowed feature
-propagation, a workload class the reference has no analogue for.
 """
 
 from .blockchain import (
@@ -27,7 +24,6 @@ from .blockchain import (
     LitecoinBlockParser,
 )
 from .citations import CitationParser
-from .embeddings import TemporalEmbeddings
 from .gab import (GabMostUsedTopics, GabPostGraphParser,
                   GabRawPostParser, GabUserGraphParser)
 from .ldbc import LDBCParser

@@ -551,12 +551,14 @@ class Job:
         return True
 
     def _run_columnar_only(self, q) -> None:
-        """A program with no engine but the columnar one (``LCC``: it is
-        no message along an edge). A Range is ``_try_range_hopbatch``'s;
-        a View is the same engine at one hop, one column a window. What
-        that route does not take — a mesh, a time past the watermark's
-        fence, a Live subscription — fails the job with the route's name
-        instead of falling to ``bsp``, which cannot run the program."""
+        """A program the columnar engine alone serves (``LCC``: it is no
+        message along an edge; ``SGC``: its state is a row of features a
+        vertex, and ``bsp`` would gather one such row a pair). A Range is
+        ``_try_range_hopbatch``'s; a View is the same engine at one hop,
+        one column a window. What that route does not take — a mesh, a
+        time past the watermark's fence, a Live subscription — fails the
+        job with the route's name instead of falling to ``bsp``, which
+        cannot run the program, or not at a deployment's size."""
         name = type(self.program).__name__
         if isinstance(q, ViewQuery):
             q = RangeQuery(int(q.timestamp), int(q.timestamp), 1,
@@ -575,7 +577,7 @@ class Job:
     def _columnar_builder(self):
         """Construct the hop-batched columnar engine for this job's
         program (raises for programs without one — the caller treats any
-        failure as \'route declined\'). Six kinds: PageRank
+        failure as \'route declined\'). Seven kinds: PageRank
         (``pagerank``: finalize is the raw rank vector and the power
         iteration warm-starts safely); ConnectedComponents (``cc``: labels
         are global padded indices in both engines); BFS and SSSP (``bfs``,
@@ -584,15 +586,19 @@ class Job:
         columns, immutable weight keys raise); CDLP (``cdlp``: labels are
         global padded indices in both engines and the rounds are fixed,
         so the columns are ``bsp``'s answer); LCC (``lcc``: ``tri`` and
-        ``deg`` per vertex, which no other engine computes)."""
+        ``deg`` per vertex, which no other engine computes); SGC
+        (``sgc``: per column the small pytree ``SGC.finalize`` returns
+        on ``bsp``, never the ``[n_pad, dim]`` block)."""
         from ..algorithms import CDLP as _CDLP
+        from ..algorithms import SGC as _SGC
         from ..algorithms import ConnectedComponents as _CC
         from ..algorithms import LCC as _LCC
         from ..algorithms import PageRank as _PR
         from ..algorithms.traversal import SSSP as _SSSP
         from ..engine.hopbatch import (HopBatchedBFS, HopBatchedCC,
                                        HopBatchedCDLP, HopBatchedLCC,
-                                       HopBatchedPageRank, HopBatchedSSSP)
+                                       HopBatchedPageRank, HopBatchedSGC,
+                                       HopBatchedSSSP)
 
         p = self.program
         if type(p) is _PR:
@@ -604,6 +610,9 @@ class Job:
             return HopBatchedCDLP(self.graph.log, max_steps=p.max_steps)
         if type(p) is _LCC:
             return HopBatchedLCC(self.graph.log)
+        if type(p) is _SGC:
+            return HopBatchedSGC(self.graph.log, rounds=p.rounds,
+                                 dim=p.dim, feature_seed=p.feature_seed)
         if type(p) is _SSSP:
             if p.weight_prop:
                 return HopBatchedSSSP(self.graph.log, p.seeds,
@@ -659,8 +668,11 @@ class Job:
         engines; no warm start — min-propagation is not a contraction on a
         changing edge set), SSSP/BFS (unit or mutable-numeric-weighted;
         no warm start), CDLP (a fixed number of rounds of a histogram
-        combine; no warm start), and LCC (one pass over the log's
-        triangle table; nothing to warm-start)."""
+        combine; no warm start), LCC (one pass over the log's triangle
+        table; nothing to warm-start), and SGC (a fixed number of rounds
+        over F-wide rows, the columns walked; per column a small pytree,
+        not an array)."""
+        import jax
         import numpy as np
 
         if self.mesh is not None or self.graph.safe_time() < q.end:
@@ -688,10 +700,11 @@ class Job:
                                   and hb.supports_warm_start,
                                   hop_callback=grab_shell, chunk_rule=rule)
             b0 = _time.perf_counter()
-            ranks, steps = _block_steps(
-                lambda: (np.asarray(ranks), steps))
+            ranks, steps = _block_steps(lambda: (
+                jax.tree_util.tree_map(np.asarray, ranks), steps))
             self.ledger.add_phase("device_wait",
                                   _time.perf_counter() - b0)
+            hb.count_result(ranks)
         except Exception as e:
             # a transport failure or OOM mid-dispatch falls back to the
             # O(1)-memory-per-hop device-resident route (which rebuilds
@@ -713,7 +726,11 @@ class Job:
         dispatch: viewTime is the AMORTISED share of the dispatch (plus
         that row's own reduce), snapshot-build is the per-hop share of the
         measured incremental fold. ``shells`` is keyed by hop time (the
-        fold callback may fire out of hop order under parallel folds)."""
+        fold callback may fire out of hop order under parallel folds).
+        ``ranks``: an array whose leading axis is the columns, or a
+        pytree of such (``sgc``)."""
+        import jax
+
         W = len(windows)
         per_row = elapsed / max(len(hops) * W, 1)
         for _ in hops:
@@ -726,7 +743,9 @@ class Job:
                 if self._kill.is_set():
                     return
                 for i, w in enumerate(windows):
-                    self._emit(T, w, ranks[j * W + i], shells[int(T)],
+                    col = jax.tree_util.tree_map(
+                        lambda a: a[j * W + i], ranks)
+                    self._emit(T, w, col, shells[int(T)],
                                steps, _time.perf_counter() - per_row)
 
     def _try_range_mesh_columns(self, q: RangeQuery) -> bool:

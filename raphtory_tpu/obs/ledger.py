@@ -570,12 +570,14 @@ def built(engine) -> dict:
     fork), ``extended`` (a suffix adopted first) or ``miss`` (built). An
     engine over the log's triangle table (``HopBatchedLCC``) adds
     ``triangles``: ``built`` (by this build: span ``index.triangles``) or
-    ``held`` (the index had it)."""
+    ``held`` (the index had it); one over the log's feature block and
+    propagation table (``HopBatchedSGC``) adds ``features`` likewise."""
     t = engine.tables
     out = {"engine": type(engine).__name__, "n_pad": int(t.n_pad),
            "m_pad": int(t.m_pad), "index": engine.index_status}
-    if getattr(engine, "triangles_status", None):
-        out["triangles"] = engine.triangles_status
+    for attr in ("triangles", "features"):
+        if getattr(engine, attr + "_status", None):
+            out[attr] = getattr(engine, attr + "_status")
     return out
 
 
@@ -632,6 +634,11 @@ class Ledger:
         #: served, summed over the query's dispatches (columnar LCC;
         #: counted on the host from the dispatch's shapes)
         self.triangle_rows = 0
+        #: F-wide rows moved along a pair: pair-table rows, padding
+        #: included, x 2 directions x rounds x the columns served, summed
+        #: over the query's dispatches (columnar SGC; counted on the host
+        #: from the dispatch's shapes)
+        self.feature_rows = 0
         #: how the LAST columnar sweep of the query was cut into
         #: dispatches: ``chunks``, ``columns`` (C of one dispatch) and
         #: ``chunk_rule`` (``jobs/manager._range_chunks``, or ``caller``)
@@ -743,6 +750,10 @@ class Ledger:
         with self._lock:
             self.triangle_rows += int(n)
 
+    def count_feature_rows(self, n: int) -> None:
+        with self._lock:
+            self.feature_rows += int(n)
+
     def note_chunks(self, chunks: int, columns: int, rule: str) -> None:
         with self._lock:
             self.chunking = {"chunks": int(chunks), "columns": int(columns),
@@ -812,6 +823,7 @@ class Ledger:
                 snap["device"].get("peak_device_bytes", 0))
             self.mode_rows += snap["device"].get("mode_rows", 0)
             self.triangle_rows += snap["device"].get("triangle_rows", 0)
+            self.feature_rows += snap["device"].get("feature_rows", 0)
         return self
 
     def absorb_share(self, batch_snap: dict, frac: float,
@@ -865,6 +877,8 @@ class Ledger:
                 batch_snap["device"].get("mode_rows", 0) * frac)
             self.triangle_rows += int(
                 batch_snap["device"].get("triangle_rows", 0) * frac)
+            self.feature_rows += int(
+                batch_snap["device"].get("feature_rows", 0) * frac)
             self.sweeps += 1
             if coalesced is not None:
                 self.coalesced = dict(coalesced)
@@ -964,6 +978,7 @@ class Ledger:
                 "peak_device_bytes": int(self.peak_device_bytes),
                 "mode_rows": int(self.mode_rows),
                 "triangle_rows": int(self.triangle_rows),
+                "feature_rows": int(self.feature_rows),
                 **(self.chunking or {}),
                 "kernels": {n: dict(k) for n, k in self.kernels.items()},
             },

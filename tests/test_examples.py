@@ -252,38 +252,6 @@ def test_malformed_records_never_kill_the_source():
     assert BitcoinBlockParser()({"time": "x"}) == []
 
 
-def test_temporal_embeddings_nearest_and_drift():
-    """Embeddings example: structurally-close vertices score similar, and
-    drift spikes exactly for the vertex whose neighbourhood changed."""
-    import numpy as np
-
-    from raphtory_tpu.core.events import EventLog
-    from raphtory_tpu.examples import TemporalEmbeddings
-
-    log = EventLog()
-    # two cliques {1,2,3} and {10,11,12} wired early; vertex 3 defects to
-    # the second clique late
-    for t, (a, b) in enumerate([(1, 2), (2, 3), (3, 1), (10, 11), (11, 12),
-                                (12, 10)]):
-        log.add_edge(10 + t, a, b)
-        log.add_edge(10 + t, b, a)
-    for t, (a, b) in enumerate([(3, 10), (3, 11), (3, 12)]):
-        log.add_edge(100 + t, a, b)
-        log.add_edge(100 + t, b, a)
-
-    emb = TemporalEmbeddings(log, dim=32, rounds=2, seed=3)
-    near = emb.nearest(1, time=50, window=100, k=2)
-    assert {v for v, _ in near} == {2, 3}   # its clique, pre-defection
-
-    drift = emb.drift(50, 200, window=60)
-    uv = emb.ds.uv.tolist()
-    # vertex 3's neighbourhood flipped cliques -> it drifts far more than
-    # the untouched clique-1 anchor (its old neighbours drift some too —
-    # they lost a member)
-    d = {int(v): float(drift[i]) for i, v in enumerate(uv)}
-    assert d[3] > d[1] and d[3] > 0.1
-
-
 def test_gab_raw_post_parser_unfolds_hetero_graph():
     """One raw JSON post → post/user/topic vertices, the four typed edges,
     and a single-level parent unfold (GabRawRouter.scala:28-130)."""

@@ -12,7 +12,7 @@ import pytest
 from raphtory_tpu.core.service import TemporalGraph
 from raphtory_tpu.engine.hopbatch import (HopBatchedBFS, HopBatchedCC,
                                           HopBatchedCDLP, HopBatchedLCC,
-                                          HopBatchedPageRank)
+                                          HopBatchedPageRank, HopBatchedSGC)
 from raphtory_tpu.jobs.manager import (AnalysisManager, Job, RangeQuery,
                                        _range_chunks)
 
@@ -70,6 +70,42 @@ def test_the_chunk_rule_as_a_table(monkeypatch, hops, windows, m_pad,
                                    budget_mb, tol, engine, want):
     monkeypatch.setenv("RTPU_TILE_BUDGET_MB", str(budget_mb))
     assert _range_chunks(_engine(engine, m_pad, tol), hops, windows) == want
+
+
+@pytest.mark.parametrize("hops,windows,dim,budget_mb,want", [
+    # the benchmark's cell: six walked columns of 602 features
+    (2, 3, 602, 256, (1, "one_dispatch")),
+    # the columns are walked over one block set: no width of a Range
+    # makes a block grow, so none chunks
+    (4, 3, 602, 256, (1, "one_dispatch")),
+    (16, 3, 602, 256, (1, "one_dispatch")),
+    (341, 3, 602, 256, (1, "one_dispatch")),
+    # the tile budget sizes ``[m_pad, C]`` payloads, which this engine
+    # has none of: its gathered step (65,536 rows x 640 lanes x 4 B =
+    # 168 MB) is a constant of the table's size, so no budget sends a
+    # Range to the ladder, where every chunk would gather that step too
+    (4, 3, 602, 128, (1, "one_dispatch")),
+    (2, 3, 602, 1, (1, "one_dispatch")),
+    # nor does the width
+    (4, 3, 128, 64, (1, "one_dispatch")),
+    (4, 3, 129, 63, (1, "one_dispatch")),
+])
+def test_the_chunk_rule_on_an_f_wide_engine(monkeypatch, hops, windows, dim,
+                                            budget_mb, want):
+    """ISSUE 44: an engine whose column is an ``[n_pad, F]`` block
+    answers ``dispatch_columns_ok`` from what grows with its columns —
+    here nothing, since the columns are walked."""
+    monkeypatch.setenv("RTPU_TILE_BUDGET_MB", str(budget_mb))
+    hb = object.__new__(HopBatchedSGC)
+    hb.tables = SimpleNamespace(m_pad=M_RANGE, n_pad=131_072)
+    hb.dim = dim
+    assert hb.warm_start_saves_steps is False
+    assert _range_chunks(hb, hops, windows) == want
+    # what the memory guard of the route counts: the masks and four
+    # lane-padded blocks, whatever the columns
+    lanes = -(-dim // 128) * 128
+    assert hb.device_mask_bytes(6) == (M_RANGE + 131_072) * 6 \
+        + 4 * 131_072 * lanes * 4
 
 
 HOPS = [500, 600, 700, 800]
