@@ -48,6 +48,21 @@ _ENC_SHIFT = 32
 _ENC_MASK = (1 << _ENC_SHIFT) - 1
 
 
+def _through(shift: np.ndarray, enc: np.ndarray) -> np.ndarray:
+    """Packed keys with both halves taken through the rank map ``shift``
+    (monotone: sorted keys stay sorted) — a new array, written a block
+    at a time so that the halves' temporaries stay in cache."""
+    out = np.empty(len(enc), np.int64)
+    block = 1 << 18
+    for lo in range(0, len(enc), block):
+        part = enc[lo:lo + block]
+        hi = np.take(shift, part >> _ENC_SHIFT, mode="clip")
+        hi <<= _ENC_SHIFT
+        hi |= np.take(shift, part & _ENC_MASK, mode="clip")
+        out[lo:lo + block] = hi
+    return out
+
+
 def fold_workers() -> int:
     """Size of the chunk-fold worker pool (``RTPU_FOLD_WORKERS``). The
     default scales with the host — half the cores, capped at 8 — because
@@ -202,10 +217,11 @@ def prefetch_map(fold_fns, body, *, depth: int | None = None,
         raise
 
 #: SweepBuilder attributes that are pure functions of the pinned log —
-#: forks SHARE them (never mutated after __init__)
+#: forks SHARE them (never written after __init__; ``repin`` REBINDS them
+#: on the builder it is called on, so a fork keeps the arrays it took)
 _LOG_DERIVED = ("log", "include_occurrences", "pad", "track_rows",
                 "_t", "_k", "_s", "_d", "uv", "_ok", "_sd_all", "_dd_all",
-                "_t_sorted", "_preseeded")
+                "_t_sorted", "_preseed_asked", "_preseeded")
 #: fold-state arrays mutated IN PLACE by _advance — checkpoint/fork copy
 _STATE_COPIED = ("v_lat", "v_alive", "v_first", "v_seen",
                  "e_lat", "e_alive", "e_first", "e_seen")
@@ -252,6 +268,25 @@ _EMPTY_DELTA = {
     "e_enc": np.empty(0, np.int64), "e_lat": np.empty(0, np.int64),
     "e_alive": np.empty(0, bool), "e_first": np.empty(0, np.int64),
 }
+
+
+class _Suffix:
+    """The rows a log appended since a builder's pin
+    (``SweepBuilder.suffix``): the new pin, the suffix's columns, the
+    ids the builder's ``uv`` lacks, whether adopting it ``grows`` the
+    dictionaries, and the suffix's per-row dense ids (``sd`` / ``dd``:
+    set by ``suffix`` when nothing grows, by ``_grow`` otherwise)."""
+
+    __slots__ = ("log", "n_old", "t", "s", "d", "is_e", "new_ids",
+                 "grows", "sd", "dd")
+
+    def __init__(self, log, n_old: int):
+        self.log, self.n_old = log, n_old
+        self.t = log.column("time")[n_old:]
+        self.s = log.column("src")[n_old:]
+        self.d = log.column("dst")[n_old:]
+        k = log.column("kind")[n_old:]
+        self.is_e = (k == EDGE_ADD) | (k == EDGE_DELETE)
 
 
 class SweepBuilder:
@@ -322,6 +357,7 @@ class SweepBuilder:
         # historical join it replaces). The columnar engines opt in;
         # semantics stay bit-identical (tested against build_view).
         self.e_seen = np.empty(0, bool)   # pair has real marks (firsts set)
+        self._preseed_asked = bool(preseed_pairs)
         self._preseeded = False
         if preseed_pairs and self._ok and is_e.any():
             with _span("index.pairs") as sp:
@@ -444,31 +480,47 @@ class SweepBuilder:
 
     def repin(self, live_log) -> str:
         """Adopt rows appended to the LIVE log since this builder's pin,
-        without refolding history. Returns:
+        without refolding history (``suffix`` then ``adopt``). Returns:
 
         * ``"noop"``     — nothing new; the pin already covers the log.
         * ``"extended"`` — the suffix was adopted in place: fold state,
           ``t_prev`` and the dense vertex/pair dictionaries all remain
           valid, and the next ``_advance`` folds exactly the new rows.
+        * ``"grown"``    — the suffix names a vertex id outside ``uv``
+          or, on a preseeded builder, a pair outside ``e_enc``: the
+          dictionaries GREW to take it in (``_grow``). Fold state and
+          ``t_prev`` are carried, but every dense index and pair key a
+          caller holds is stale — whatever it derived from the old
+          dictionaries (global tables, device buffers, a warm seed, the
+          last delta) must be derived again.
         * ``"rebuild"``  — the suffix cannot be adopted; the caller must
           construct a fresh builder (and refold from scratch).
 
-        Extension is only sound when the pinned snapshot is still a
-        PREFIX of the live log and the frozen dictionaries still cover
-        it, so ``"rebuild"`` is returned when any of these hold:
+        Adoption is only sound when the pinned snapshot is still a
+        PREFIX of the live log, so ``"rebuild"`` is returned when any of
+        these hold:
 
         * the log was compacted (history rewritten — the pin is no
-          longer a prefix; detected via ``EventLog.compactions``);
-        * the suffix mentions a vertex id outside ``uv`` (the dense
-          dictionary, and every per-row dense id derived from it, is
-          frozen at pin time);
-        * a preseeded builder sees a (src, dst) pair outside ``e_enc``
-          (the preseed invariant is "every pair the log ever mentions");
+          longer a prefix; detected via ``EventLog.compactions``), or
+          shrank;
         * a suffix event lands at or below ``t_prev`` — the watermark
           contract says events at or below the served fence never
           arrive late, so such a row means the fence was not honoured
-          and already-folded state is stale.
+          and already-folded state is stale;
+        * the dictionary cannot hold the suffix: the pin was empty, the
+          ids would pass the 31 bits of the pair pack, or pairs were to
+          be preseeded and the pin had no edge event to preseed from.
         """
+        suffix = self.suffix(live_log)
+        return suffix if isinstance(suffix, str) else self.adopt(suffix)
+
+    def suffix(self, live_log) -> "str | _Suffix":
+        """Read what ``live_log`` appended since this builder's pin and
+        change nothing: ``"noop"`` / ``"rebuild"`` (``repin`` names the
+        causes), or the suffix for ``adopt``, whose ``grows`` says
+        whether adopting it grows the dictionaries — a caller that
+        cannot follow a growth discards the builder before paying for
+        one. O(suffix)."""
         new = live_log.pin()
         n_old = len(self._t)
         if (getattr(new, "compactions", 0)
@@ -479,46 +531,179 @@ class SweepBuilder:
             return "rebuild"
         if new.n == n_old:
             return "noop"
-        if new.n < n_old or not self._ok:
+        if new.n < n_old or not self._ok or not len(self.uv):
             return "rebuild"
-        t_new = new.column("time")[n_old:]
-        k_new = new.column("kind")[n_old:]
-        s_new = new.column("src")[n_old:]
-        d_new = new.column("dst")[n_old:]
-        if self.t_prev is not None and len(t_new) \
-                and int(t_new.min()) <= self.t_prev:
+        sfx = _Suffix(new, n_old)
+        if self.t_prev is not None and int(sfx.t.min()) <= self.t_prev:
             return "rebuild"
-        is_e = (k_new == EDGE_ADD) | (k_new == EDGE_DELETE)
-        d_real = d_new[is_e]
-        ids = np.concatenate([s_new, d_real])
+        if self._preseed_asked and not self._preseeded and sfx.is_e.any():
+            return "rebuild"   # the log's first edges: nothing was preseeded
+        ids = np.concatenate([sfx.s, sfx.d[sfx.is_e]])
         pos = np.searchsorted(self.uv, ids)
-        pos_c = np.clip(pos, 0, max(len(self.uv) - 1, 0))
-        if not len(self.uv) or not bool((self.uv[pos_c] == ids).all()):
-            return "rebuild"   # new vertex id: dense dictionary is stale
-        sd_new = pos[: len(s_new)]
-        dd_new = np.zeros(len(d_new), np.int64)
-        dd_new[is_e] = pos[len(s_new):]
-        if self._preseeded and is_e.any():
-            enc = self._pack(sd_new[is_e], dd_new[is_e])
-            epos = np.clip(np.searchsorted(self.e_enc, enc), 0,
-                           max(len(self.e_enc) - 1, 0))
-            if not len(self.e_enc) \
-                    or not bool((self.e_enc[epos] == enc).all()):
-                return "rebuild"   # new pair: preseeded table is stale
-        # adopt: rebind the log-derived views; everything else is valid
+        known = self.uv[np.minimum(pos, len(self.uv) - 1)] == ids
+        sfx.new_ids = np.unique(ids[~known])
+        if len(self.uv) + len(sfx.new_ids) >= (1 << 31):
+            return "rebuild"   # the pair pack's 31 bits an id
+        sfx.grows = bool(len(sfx.new_ids))
+        if not sfx.grows:
+            sfx.sd = pos[: len(sfx.s)]
+            sfx.dd = np.zeros(len(sfx.d), np.int64)
+            sfx.dd[sfx.is_e] = pos[len(sfx.s):]
+            if self._preseeded and sfx.is_e.any():
+                enc = self._pack(sfx.sd[sfx.is_e], sfx.dd[sfx.is_e])
+                sfx.grows = not bool(self._has_pairs(self.e_enc, enc).all())
+        return sfx
+
+    @staticmethod
+    def _has_pairs(table: np.ndarray, enc: np.ndarray) -> np.ndarray:
+        """Which of the packed keys ``enc`` the sorted ``table`` holds."""
+        if not len(table):
+            return np.zeros(len(enc), bool)
+        return table[np.minimum(np.searchsorted(table, enc),
+                                len(table) - 1)] == enc
+
+    def adopt(self, sfx: "_Suffix") -> str:
+        """Adopt a suffix ``suffix`` read (nothing appended to this
+        builder since): ``"extended"`` or ``"grown"``."""
+        if sfx.grows:
+            self._grow(sfx)
+        else:
+            self._append_rows(sfx)
+        old_pin, new = self.log, sfx.log
+        # rebind the log-derived views; everything else is valid
         self.log = new
         self._t = new.column("time")
         self._k = new.column("kind")
         self._s = new.column("src")
         self._d = new.column("dst")
-        if self._sd_all is not None:
-            self._sd_all = np.concatenate([self._sd_all, sd_new])
-            self._dd_all = np.concatenate([self._dd_all, dd_new])
         self._t_sorted = bool(
             self._t_sorted
-            and (not len(t_new) or bool((t_new[:-1] <= t_new[1:]).all()))
-            and (n_old == 0 or int(t_new[0]) >= int(self._t[n_old - 1])))
-        return "extended"
+            and bool((sfx.t[:-1] <= sfx.t[1:]).all())
+            and int(sfx.t[0]) >= int(self._t[sfx.n_old - 1]))
+        # the fold-cache key of the new pin, in O(suffix)
+        extend_fingerprint(old_pin, new)
+        return "grown" if sfx.grows else "extended"
+
+    def _append_rows(self, sfx: "_Suffix", shift=None) -> None:
+        """The per-row dense ids with the suffix's appended, the old
+        pin's taken through ``shift`` where the ranks moved — each into
+        one new array (the old pin's columns are still bound)."""
+        if self._sd_all is None:
+            return
+        n_old = sfx.n_old
+        for name, new in (("_sd_all", sfx.sd), ("_dd_all", sfx.dd)):
+            old = getattr(self, name)
+            out = np.empty(n_old + len(new), np.int64)
+            if shift is None:
+                out[:n_old] = old
+            else:
+                np.take(shift, old, out=out[:n_old], mode="clip")
+            out[n_old:] = new
+            setattr(self, name, out)
+        if shift is not None and shift[0]:
+            # a vertex event's row holds 0, not the rank of ``uv[0]``
+            self._dd_all[:n_old][(self._k != EDGE_ADD)
+                                 & (self._k != EDGE_DELETE)] = 0
+
+    def _grow(self, sfx: "_Suffix") -> None:
+        """Grow the dense dictionaries to hold ``sfx``'s new ids and (on
+        a preseeded builder) new pairs, and carry the fold state across.
+
+        Dense ids are ranks in the sorted ``uv``, so inserting the new
+        ids moves every old rank up by the count of new ids below it: a
+        MONOTONE map (``shift``). Every sorted structure stays sorted
+        under it — the per-row dense ids, the pair keys packed either
+        way, the delete history — so each is one gather through
+        ``shift``, never a sort or a ``unique`` over the log; the new
+        entities take blank slots (what ``__init__`` gives one no
+        event has reached) at their insertion points. ``t_prev`` and the
+        state at ``t_prev`` stay valid: every suffix event is later
+        (``suffix``), so a new id has no event at or before ``t_prev``,
+        and a new pair none of its own — only its endpoints' deletes,
+        which ``_killed_before`` joins in as the preseeded fold would
+        have. The result is bit for bit what ``SweepBuilder`` over the
+        grown log builds and advances to ``t_prev``.
+
+        Every grown array is a NEW array, rebound: forks share the
+        log-derived arrays and the preseeded pair tables by reference
+        and go on reading the ones they took. ``last_delta`` spoke the
+        old coordinates and is dropped."""
+        uv, n_new = self.uv, len(sfx.new_ids)
+        with _span("index.ids", grow=True, events=len(sfx.t)) as sp:
+            shift = None
+            if n_new:
+                v_at = np.searchsorted(uv, sfx.new_ids)
+                self.uv = np.insert(uv, v_at, sfx.new_ids)
+                # old rank -> new rank: + the new ids inserted at or below
+                shift = np.arange(len(uv), dtype=np.int64)
+                shift += np.searchsorted(v_at, shift, side="right")
+                self.dh_v = shift[self.dh_v]
+                blank_t = np.full(n_new, INT64_MIN, np.int64)
+                blank_b = np.zeros(n_new, bool)
+                self.v_lat = np.insert(self.v_lat, v_at, blank_t)
+                self.v_alive = np.insert(self.v_alive, v_at, blank_b)
+                self.v_first = np.insert(self.v_first, v_at, blank_t)
+                self.v_seen = np.insert(self.v_seen, v_at, blank_b)
+                sfx.sd = np.searchsorted(self.uv, sfx.s)
+                sfx.dd = np.zeros(len(sfx.d), np.int64)
+                sfx.dd[sfx.is_e] = np.searchsorted(self.uv,
+                                                   sfx.d[sfx.is_e])
+            self._append_rows(sfx, shift)
+            sp.set(new_ids=n_new, ids=len(self.uv))
+        with _span("index.pairs", grow=True) as sp:
+            e_enc, e_enc_dst = self.e_enc, self.e_enc_dst
+            if shift is not None:
+                e_enc = _through(shift, e_enc)
+                e_enc_dst = _through(shift, e_enc_dst)
+            n_pairs = 0
+            if self._preseeded:
+                enc = np.unique(self._pack(sfx.sd[sfx.is_e],
+                                           sfx.dd[sfx.is_e]))
+                enc = enc[~self._has_pairs(e_enc, enc)]
+                n_pairs = len(enc)
+            if n_pairs:
+                e_at = np.searchsorted(e_enc, enc)
+                e_enc = np.insert(e_enc, e_at, enc)
+                enc_dst = np.sort(
+                    ((enc & _ENC_MASK) << _ENC_SHIFT) | (enc >> _ENC_SHIFT))
+                e_enc_dst = np.insert(
+                    e_enc_dst, np.searchsorted(e_enc_dst, enc_dst), enc_dst)
+                lat, alive, first, seen = self._killed_before(enc, enc_dst)
+                self.e_lat = np.insert(self.e_lat, e_at, lat)
+                self.e_alive = np.insert(self.e_alive, e_at, alive)
+                self.e_first = np.insert(self.e_first, e_at, first)
+                self.e_seen = np.insert(self.e_seen, e_at, seen)
+            self.e_enc, self.e_enc_dst = e_enc, e_enc_dst
+            sp.set(new_pairs=n_pairs, pairs=len(e_enc))
+        self.last_delta = None
+
+    def _killed_before(self, enc: np.ndarray, enc_dst: np.ndarray):
+        """Fold state at ``t_prev`` of pairs ``enc`` (sorted; ``enc_dst``
+        the same pairs dst-major, sorted) that no event of their own has
+        reached: blank, but for the dead marks of their endpoints'
+        deletes at or before ``t_prev`` — the all-pairs x all-deletes
+        killList join (``_fold_rows``) gives a preseeded pair those from
+        the log's first event on, so a pair that joins the table later
+        is owed them. Keys are in the GROWN dictionary; the rows read
+        are the old pin's."""
+        lat = np.full(len(enc), INT64_MIN, np.int64)
+        alive = np.zeros(len(enc), bool)
+        first = lat.copy()
+        seen = alive.copy()
+        rows = np.flatnonzero(self._k == VERTEX_DELETE)
+        if self.t_prev is not None and len(rows):
+            rows = rows[self._t[rows] <= self.t_prev]
+            dv = self._dense(self._s[rows])
+            marks = [self._incident(tab, dv, self._t[rows], flip)
+                     for tab, flip in ((enc, False), (enc_dst, True))]
+            (u,), ulat, ualive, ufirst = _fold_latest(
+                (np.concatenate([m[0] for m in marks]),),
+                np.concatenate([m[1] for m in marks]),
+                np.zeros(sum(len(m[0]) for m in marks), bool))
+            at = np.searchsorted(enc, u)
+            lat[at], alive[at], first[at], seen[at] = \
+                ulat, ualive, ufirst, True
+        return lat, alive, first, seen
 
     # ---- the sweep ----
 
@@ -832,12 +1017,15 @@ def extend_fingerprint(old_pin, new_pin) -> None:
     """Carry a cached fingerprint from ``old_pin`` to ``new_pin``, a pin
     of the same log that extends it by a suffix (no compaction between
     them): each checksum is an xor-reduce keyed by the ABSOLUTE row
-    index, so the suffix's rows fold onto the prefix's in O(suffix). A
-    no-op when ``old_pin`` never computed one — ``log_fingerprint``
-    then computes the new pin's on first use."""
+    index, so the suffix's rows fold onto the prefix's in O(suffix) —
+    under a ``fold.fingerprint`` span of its own (``extend``, ``events``
+    the suffix's rows). A no-op when ``old_pin`` never computed one —
+    ``log_fingerprint`` then computes the new pin's on first use."""
     fp = getattr(old_pin, "_rtpu_fold_fp", None)
     if fp is not None:
-        _fingerprint(new_pin, prefix=fp)
+        with _span("fold.fingerprint", extend=True,
+                   events=int(new_pin.n) - int(fp[0])):
+            _fingerprint(new_pin, prefix=fp)
 
 
 def _fingerprint(log, prefix: tuple | None = None) -> tuple:

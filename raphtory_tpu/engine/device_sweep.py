@@ -47,8 +47,7 @@ import numpy as np
 
 from ..core.events import EDGE_ADD, EDGE_DELETE, EventLog
 from ..core.snapshot import INT64_MIN, _pad_bucket
-from ..core.sweep import (_ENC_MASK, _ENC_SHIFT, SweepBuilder,
-                          extend_fingerprint)
+from ..core.sweep import _ENC_MASK, _ENC_SHIFT, SweepBuilder
 from ..native import lib as _native
 from ..obs import ledger as _ledger
 from ..obs.trace import TRACER
@@ -289,19 +288,26 @@ class LogIndex:
         ``"hit"`` — same ``(n, compactions)``, so the pin's content is
         the index's (rows below ``n`` never mutate); ``"extended"`` — the
         log grew by a suffix with no new id or pair and no time past the
-        tables' dtype, adopted in O(suffix) with the fingerprint carried
-        over; ``"miss"`` — anything else (new id or pair, compaction,
-        shrink): the caller builds a new index. Caller holds the lock."""
+        tables' dtype, adopted in O(suffix); ``"grown"`` — the suffix
+        brought new ids or pairs and the prototype's dictionaries grew to
+        hold them (``SweepBuilder.repin``), the tables made anew over it
+        and the triangle table, a function of the pairs, dropped;
+        ``"miss"`` — anything else (compaction, shrink, an empty pin):
+        the caller builds a new index. The fingerprint is carried over
+        a suffix either way. Caller holds the lock."""
         sw = self.prototype
-        old_pin, n_old = sw.log, len(sw._t)
+        n_old = len(sw._t)
         status = sw.repin(log)
         if status == "noop":
             return "hit"
-        if status != "extended" \
+        if status == "grown":
+            with TRACER.span("index.tables", grow=True):
+                self.tables = GlobalTables(sw)
+            self.triangles = None
+        elif status != "extended" \
                 or not self.tables.holds_times(sw._t[n_old:]):
             return "miss"   # the prototype may be rebound: discard it
-        extend_fingerprint(old_pin, sw.log)
-        return "extended"
+        return status
 
 
 #: per-log cache of the index above, keyed by the CALLER's live log the
@@ -309,16 +315,20 @@ class LogIndex:
 #: accumulated) when it goes stale, freed with the log
 _LOG_INDEXES = weakref.WeakKeyDictionary()
 _LOG_INDEX_LOCK = _threading.Lock()
-_LOG_INDEX_COUNTS = {"hit": 0, "extended": 0, "miss": 0}
+#: lookups by outcome, and ``grown``: the lookups among ``extended`` whose
+#: suffix brought new ids or pairs, so the index's dictionaries grew
+_LOG_INDEX_COUNTS = {"hit": 0, "extended": 0, "miss": 0, "grown": 0}
 
 
 def log_index(log: EventLog):
     """``(builder, tables, status)`` for a new engine over ``log``: a
     private fork of the log's cached index, built here on a ``"miss"``
-    (``LogIndex.adopt`` names the three statuses). One lock covers the
-    lookup, a build and the fork — two jobs arriving together build the
-    index once, and a fork never sees a half-rebound prototype. ``tables``
-    is SHARED between engines and must not be written. A frozen log is
+    (``LogIndex.adopt`` names the statuses; a lookup that grew the index
+    reads ``"extended"`` here and is counted as ``grown`` besides). One
+    lock covers the lookup, a build and the fork — two jobs arriving
+    together build the index once, and a fork never sees a half-rebound
+    prototype. ``tables`` is SHARED between engines and must not be
+    written. A frozen log is
     its own pin, so an entry would keep its weak key alive: such a log
     gets an index of its own every time (status ``"miss"``), uncached.
 
@@ -327,7 +337,10 @@ def log_index(log: EventLog):
     for the lock, which another request's miss holds for its whole build,
     and ``adopt``; on a miss the build's ``index.ids`` / ``index.pairs``
     (``SweepBuilder.__init__``) and ``index.tables``; ``index.fork``. A
-    hit writes the first and the last only."""
+    hit writes the first and the last only. A growth writes the three
+    build stages too, inside ``index.lookup``, each with ``grow=True``
+    (``index.ids``: ``new_ids``; ``index.pairs``: ``new_pairs``), and a
+    carried fingerprint a ``fold.fingerprint`` with ``extend=True``."""
     with contextlib.ExitStack() as lookup:
         lookup.enter_context(TRACER.span("index.lookup"))
         with _LOG_INDEX_LOCK:
@@ -339,6 +352,9 @@ def log_index(log: EventLog):
                 idx = LogIndex(log)
                 if idx.prototype.log is not log:
                     _LOG_INDEXES[log] = idx
+            if status == "grown":
+                _LOG_INDEX_COUNTS["grown"] += 1
+                status = "extended"
             _LOG_INDEX_COUNTS[status] += 1
             with TRACER.span("index.fork",
                              nbytes=idx.prototype.fork_nbytes()):
@@ -352,8 +368,8 @@ def log_triangles(log: EventLog, tables: GlobalTables):
     (``"held"``), built here the first time an engine asks (``"built"``)
     — span ``index.triangles``, a child of that engine's
     ``engine.build``, absent when the index holds the table already. The
-    pairs of an index never change (a log that gains one gets a new
-    index: ``LogIndex.adopt``), so neither does the table. An uncached
+    table goes with the pairs it was built from (a log that gains one
+    gets new tables, or a new index: ``LogIndex.adopt``). An uncached
     index (a frozen log's) gets a table of its own every time. Holds the
     index lock for the build, as an index miss does."""
     from ..ops.triangles import build_table
@@ -447,11 +463,14 @@ def _device_features(log, tables, dim: int, seed: int):
 
 def log_index_status() -> dict:
     """The ``log_index`` block of ``/statusz``: lookups by outcome since
-    start, and the host bytes the live indexes hold."""
+    start (``grown``: the lookups among ``extends`` whose suffix brought
+    new ids or pairs, so the index's dictionaries grew where they were
+    rebuilt, a miss, until PR 45), and the host bytes the live indexes
+    hold."""
     with _LOG_INDEX_LOCK:
         c = _LOG_INDEX_COUNTS
         return {"hits": c["hit"], "extends": c["extended"],
-                "misses": c["miss"],
+                "grown": c["grown"], "misses": c["miss"],
                 "bytes": sum(i.nbytes for i in _LOG_INDEXES.values())}
 
 
@@ -613,17 +632,18 @@ class DeviceSweep:
         same coordinate space, and the next ``advance`` folds exactly
         the appended suffix as one delta instead of a from-scratch
         rebuild. Returns ``"noop"`` / ``"extended"`` / ``"rebuild"``;
-        after ``"rebuild"`` the sweep must be DISCARDED (its pin may
-        already be rebound past the decision point)."""
+        after ``"rebuild"`` the sweep must be DISCARDED."""
         if self._stale:
             return "rebuild"   # buffers behind the clock: re-pin fresh
-        n_old = len(self.sw._t)
-        status = self.sw.repin(live_log)
-        if status != "extended":
-            return status
-        if not self.tables.holds_times(self.sw._t[n_old:]):
-            return "rebuild"   # suffix overflows the narrowed time dtype
-        return "extended"
+        sfx = self.sw.suffix(live_log)
+        if isinstance(sfx, str):
+            return sfx
+        if sfx.grows or not self.tables.holds_times(sfx.t):
+            # new ids or pairs (the device buffers sit in the old dense
+            # space: the re-pin's engine is built over the GROWN index,
+            # ``log_index``), or a time past the narrowed dtype
+            return "rebuild"
+        return self.sw.adopt(sfx)
 
     # ---- sweep driving ----
 

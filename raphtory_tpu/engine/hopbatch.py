@@ -46,7 +46,7 @@ from ..ops.segment import (segment_counts, segment_ends_pos, segment_mode,
 from ..ops import propagate as _propagate
 from ..ops.triangles import lcc_columns
 from ..utils.transfer import _metrics
-from .device_sweep import (_device_edges, _device_features,
+from .device_sweep import (GlobalTables, _device_edges, _device_features,
                            _device_triangles, log_index, log_triangles,
                            normalize_windows, sweep_phase_summary)
 
@@ -926,24 +926,64 @@ class _HopBatched:
                                    _obs_device.nbytes_tree(adv))
         return out, steps
 
+    #: whether ``repin`` can follow a suffix that grows the dense
+    #: dictionaries. Set False by subclasses that hold more in the old
+    #: dense space than ``_forget_tables`` forgets (SSSP's weight stream,
+    #: LCC's triangle table, SGC's feature block) — for them a growth is
+    #: a rebuild
+    supports_growth = True
+
     def repin(self) -> str:
         """Adopt rows appended to the live log since this engine's pin
-        (``SweepBuilder.repin``): on ``"extended"`` every piece of engine
-        state stays valid — the dense dictionaries and pair table are
-        unchanged, so ``GlobalTables``, the cached device edge tables,
-        the host delta base AND the device-resident advanced base all
-        keep describing the same coordinate space, and the next ``run``
-        folds exactly the appended suffix. Returns ``"noop"`` /
-        ``"extended"`` / ``"rebuild"``; after ``"rebuild"`` the engine
-        must be DISCARDED and rebuilt over the live log (its pin may
-        already be rebound past the decision point)."""
-        n_old = len(self.sw._t)
-        status = self.sw.repin(self._log)
-        if status != "extended":
-            return status
-        if not self.tables.holds_times(self.sw._t[n_old:]):
-            return "rebuild"   # suffix overflows the narrowed time dtype
-        return "extended"
+        (``SweepBuilder.repin``). Returns:
+
+        * ``"noop"``.
+        * ``"extended"`` — every piece of engine state stays valid: the
+          dense dictionaries and pair table are unchanged, so
+          ``GlobalTables``, the cached device edge tables, the host delta
+          base AND the device-resident advanced base all keep describing
+          the same coordinate space, and the next ``run`` folds exactly
+          the appended suffix.
+        * ``"grown"`` — the suffix brought new ids or pairs and the
+          engine followed IN PLACE: its builder's dictionaries grew with
+          the fold state and ``t_prev`` carried, ``tables`` is made anew
+          over them (this engine's own: the log's index is not asked and
+          not touched — the next request's lookup grows it by whatever
+          the log holds then), and what sat in the old dense space is
+          forgotten (``_forget_tables``) — the next ``run`` folds the
+          suffix, puts the edge tables and ships a base snapshot. The
+          padded sizes may have stepped up: a caller that guards memory
+          by them checks again. A result row of before the growth is in
+          the OLD space: the caller drops its warm seed. One
+          ``engine.build`` span (``reason`` ``growth``) holds the stages
+          and counts the growth; its seconds are the ledger's ``build``
+          phase.
+        * ``"rebuild"`` — the engine must be DISCARDED and rebuilt over
+          the live log."""
+        sfx = self.sw.suffix(self._log)
+        if isinstance(sfx, str):
+            return sfx
+        if not sfx.grows:
+            if not self.tables.holds_times(sfx.t):
+                return "rebuild"   # suffix overflows the narrowed dtype
+            return self.sw.adopt(sfx)
+        if not self.supports_growth:
+            return "rebuild"
+        with _ledger.engine_build("growth", sfx.log) as sp:
+            self.sw.adopt(sfx)
+            with TRACER.span("index.tables", grow=True):
+                self.tables = GlobalTables(self.sw)
+            self._forget_tables()
+            sp.set(**_ledger.built(self))
+        return "grown"
+
+    def _forget_tables(self) -> None:
+        """Forget what was derived from the tables a growth replaced:
+        the device edge tables, the host delta base and the resident
+        advanced base (subclasses: what else they hold)."""
+        self._edges = None
+        self._delta_base = None
+        self._drop_residency()
 
     #: set True by subclasses whose iteration is a contraction (safe to
     #: warm-start from the previous chunk's solution)
@@ -1863,6 +1903,10 @@ class HopBatchedBFS(_HopBatched):
         is cached; build a new engine for different seeds)."""
         return self._seeds
 
+    def _forget_tables(self) -> None:
+        super()._forget_tables()
+        self._seed_dev = None   # the seeds' rows moved
+
     @property
     def _seed(self):
         if self._seed_dev is None:
@@ -1902,6 +1946,7 @@ class HopBatchedSSSP(HopBatchedBFS):
     (earliest-wins) are refused — the ascending fold is last-wins."""
 
     supports_delta_fold = True   # weights rebuild on device too
+    supports_growth = False      # the weight stream holds pair positions
     #: a weight update can RAISE a pair's weight — old distances become
     #: stale under-estimates, so SSSP never takes a cross-epoch seed
     supports_epoch_warm = False
@@ -2182,6 +2227,7 @@ class HopBatchedLCC(_HopBatched):
     the pair table. Delta-fed only; nothing to warm-start."""
 
     supports_delta_fold = True
+    supports_growth = False      # the triangle table is the old pairs'
 
     def __init__(self, log: EventLog):
         super().__init__(log)
@@ -2225,6 +2271,7 @@ class HopBatchedSGC(_HopBatched):
     nothing to warm-start."""
 
     supports_delta_fold = True
+    supports_growth = False      # feature block, propagation table
 
     def __init__(self, log: EventLog, rounds: int = 2, dim: int = 602,
                  feature_seed: int = 0):

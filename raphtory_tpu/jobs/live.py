@@ -10,7 +10,10 @@ tick ("epoch") by:
 * adopting the log suffix appended since the last epoch in place
   (``SweepBuilder.repin`` — same coordinate space, so fold state, the
   device-resident advanced base and the host delta base all stay
-  valid),
+  valid; a suffix that brings new vertex ids or pairs GROWS the dense
+  dictionaries under the standing engine instead, the fold state
+  carried: that epoch re-ships the base and solves cold, and folds no
+  more than any other),
 * folding ONLY the events in ``(t_prev, t]`` and shipping O(Σdelta)
   bytes through ``run_columns_delta``'s delta path, and
 * warm-starting the solve from the previous epoch's output — PageRank
@@ -33,9 +36,13 @@ Epoch modes (the ``raphtory_live_epochs_total{algorithm,mode}`` label
 set, closed):
 
 * ``incremental`` — suffix adopted, delta folded, warm-seeded solve
+                    (span attribute ``repin``: ``grown`` when the suffix
+                    grew the dictionaries — a cold solve on a re-shipped
+                    base, the fold still the delta's)
 * ``rebase``      — fresh engine built (first epoch, or repin refused:
-                    compaction / new vertex / new pair / out-of-order
-                    / dtype overflow); full base ships once
+                    compaction / out-of-order / dtype overflow / an
+                    engine that cannot follow a growth, weighted SSSP);
+                    full base ships once
 * ``resync``      — scheduled residency + warm-seed drop (drift bound)
 * ``resweep``     — legacy full re-sweep fallback
 * ``skipped``     — wall-clock mode, neither safe_time nor the log
@@ -163,16 +170,31 @@ class LiveEpochState:
         if self.hb is not None:
             r0 = _time.perf_counter()
             status = self.hb.repin()
-            # adopting the appended suffix is the incremental fold's
-            # first half (the log scan that decides what to fold)
-            led.add_phase("fold", _time.perf_counter() - r0)
+            if status == "grown":
+                # the suffix brought new ids or pairs and the engine grew
+                # its dictionaries to hold them, fold state and t_prev
+                # carried (an ``engine.build``: the seconds are in the
+                # build phase already). The previous output's rows are
+                # the OLD dense space's: this epoch solves cold
+                self.last_out = None
+            else:
+                # adopting the appended suffix is the incremental fold's
+                # first half (the log scan that decides what to fold)
+                led.add_phase("fold", _time.perf_counter() - r0)
+            sp.set(repin=status)
             if status == "rebuild":
-                # the adopted-suffix invariants broke (compaction, new
-                # vertex/pair, out-of-order arrival past t_prev, dtype
-                # overflow): the engine's pin may be rebound past the
-                # decision point — discard it wholesale and rebase
+                # the adopted-suffix invariants broke (compaction,
+                # out-of-order arrival past t_prev, dtype overflow, an
+                # engine that cannot follow a growth): discard it
+                # wholesale and rebase
                 self.hb = None
                 self.last_out = None    # n_pad may change under a rebuild
+            elif status == "grown" and self._over_guard(self.hb, q):
+                # the padded sizes stepped up past a memory guard: what
+                # a rebase over this log would be refused for
+                sp.set(declined="memory_guard")
+                self.hb = None
+                self._builder_failed = True
         if self.hb is None:
             if self._builder_failed:
                 return self._resweep(q, t, alg, t0)
@@ -187,12 +209,7 @@ class LiveEpochState:
                     hb = None
                 else:
                     bsp.set(**_ledger.built(hb))
-                    windows = (list(q.windows) if q.windows is not None
-                               else [q.window])
-                    if (hb.device_mask_bytes(len(windows))
-                            > MAX_DEVICE_MASK_BYTES
-                            or hb.host_column_bytes(1)
-                            > MAX_HOST_COLUMN_BYTES):
+                    if self._over_guard(hb, q):
                         bsp.set(declined="memory_guard")
                         hb = None   # a guard is a property of the
                         #             graph's size
@@ -308,6 +325,15 @@ class LiveEpochState:
         return max(floor, float(q.repeat))
 
     # ---- internals ----
+
+    @staticmethod
+    def _over_guard(hb, q) -> bool:
+        """Whether ``hb``'s padded sizes put an epoch's device masks or
+        host columns past the memory guards — asked wherever the sizes
+        can have changed: a build, and a growth."""
+        windows = list(q.windows) if q.windows is not None else [q.window]
+        return (hb.device_mask_bytes(len(windows)) > MAX_DEVICE_MASK_BYTES
+                or hb.host_column_bytes(1) > MAX_HOST_COLUMN_BYTES)
 
     def _delta_stats(self, hb, t: int):
         """(rows folded this epoch, add-only?) — BY TIME over the full

@@ -236,7 +236,9 @@ def _statusz(manager: AnalysisManager,
         "scheduler": manager.scheduler.status_block(),
         "compile_caches": _compile_cache_sizes(),
         # the per-log engine index (engine/device_sweep.log_index):
-        # lookups by outcome, and the host bytes the live indexes hold
+        # lookups by outcome (hits / extends / misses; grown = those of
+        # extends whose suffix brought new ids or pairs, so the index
+        # grew), and the host bytes the live indexes hold
         "log_index": log_index_status(),
         "fold_cache": _fold_cache_status(),
         "trace": TRACER.status(),

@@ -550,8 +550,10 @@ def engine_build(reason: str, log, ledger: "Ledger | None" = None):
     is derived from the log alone comes from the log's cached index, so
     only the request that finds the log changed builds it: ``index`` on
     the span, from ``built``), ``rebase`` (a Live epoch whose pin could
-    not be extended) or ``pin`` (the resident View sweep's first pin, or
-    its re-pin)."""
+    not be extended), ``pin`` (the resident View sweep's first pin, or
+    its re-pin) or ``growth`` (no construction: a standing engine whose
+    ``repin`` grew its dictionaries in place to hold a suffix's new ids
+    or pairs, ``engine/hopbatch._HopBatched.repin``)."""
     t0 = time.perf_counter()
     try:
         with TRACER.span("engine.build", reason=reason,

@@ -37,9 +37,10 @@ N_IDS = 24
 
 def _make_pool(rng, n_pairs=60):
     """The (src, dst) universe a stream draws from. The columnar
-    engines preseed the pair table from the pinned log, so an adoptable
-    suffix must reuse pairs the seed segment already introduced — a
-    genuinely new pair is a REBUILD (covered separately)."""
+    engines preseed the pair table from the pinned log, so a suffix that
+    is adopted with the dense space unchanged (``extended``) must reuse
+    pairs the seed segment already introduced — a genuinely new pair
+    GROWS the dictionaries (``tests/test_index_growth.py``)."""
     return [(int(a), int(b))
             for a, b in rng.integers(0, N_IDS, (n_pairs, 2))]
 
@@ -136,15 +137,24 @@ def test_epochs_match_scratch_on_adversarial_stream(kind):
         assert inc_ship < base_ship, (inc_ship, base_ship)
 
 
-def test_repin_rebuilds_on_new_vertex_out_of_order_and_compaction():
+def test_repin_grows_on_new_vertex_rebuilds_out_of_order_and_compaction():
     rng = np.random.default_rng(3)
     pool = _make_pool(rng)
     log = _seed_log(rng, pool)
     hb = HopBatchedCC(log, max_steps=60)
     hb.run([40], [None])
-    # a vertex outside the pinned id space cannot be adopted
+    # a vertex outside the pinned id space grows the dictionaries under
+    # the standing engine (ISSUE 45): the next run is a fresh engine's
     log.add_edge(50, 0, N_IDS + 5)
-    assert hb.repin() == "rebuild"
+    assert hb.repin() == "grown"
+    got, _ = hb.run([55], [None])
+    want, _ = HopBatchedCC(log, max_steps=60).run([55], [None])
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # weighted SSSP keeps pair positions in its weight stream: a rebuild
+    sssp = HopBatchedSSSP(log, seeds=(0,), weight_prop="w", max_steps=60)
+    sssp.run([55], [None])
+    log.add_edge(60, 1, N_IDS + 6)
+    assert sssp.repin() == "rebuild"
 
     rng2 = np.random.default_rng(4)
     log2 = _seed_log(rng2, _make_pool(rng2))

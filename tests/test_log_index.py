@@ -3,8 +3,9 @@
 What a columnar engine derives from the log alone (the preseeded fold
 builder, the global tables, the fold-cache fingerprint) is kept per live
 log by ``engine/device_sweep.log_index`` and forked per engine. A fork is
-what a fresh build is; validity is exact (``n``, ``compactions``, the
-suffix's ids and pairs); two jobs arriving together build it once; it
+what a fresh build is; validity is exact (``n``, ``compactions``; a
+suffix's new ids and pairs grow the index: ISSUE 45,
+``tests/test_index_growth.py``); two jobs arriving together build it once; it
 dies with the log. An engine over a FROZEN log never uses the cache, so
 ``Engine(log.freeze())`` is this file's freshly built reference."""
 
@@ -50,6 +51,10 @@ def fold_for_real(monkeypatch):
 def _counts():
     c = ds.log_index_status()
     return np.array([c["hits"], c["extends"], c["misses"]])
+
+
+def _grown():
+    return ds.log_index_status()["grown"]
 
 
 # ------------------------------------------------- a fork is a fresh build
@@ -140,8 +145,8 @@ CASES = {
     "unchanged": (_nothing, "hit", "noop"),
     "suffix_among_existing_pairs": (_suffix_existing_pairs, "extended",
                                     "extended"),
-    "suffix_with_a_new_id": (_suffix_new_id, "miss", "rebuild"),
-    "suffix_with_a_new_pair": (_suffix_new_pair, "miss", "rebuild"),
+    "suffix_with_a_new_id": (_suffix_new_id, "extended", "grown"),
+    "suffix_with_a_new_pair": (_suffix_new_pair, "extended", "grown"),
     "event_at_or_below_a_served_t_prev": (_suffix_late_event, "extended",
                                           "rebuild"),
     "compaction_to_the_same_row_count": (_compaction_same_rows, "miss",
@@ -160,14 +165,27 @@ def test_index_validity(case):
     served.run([50], [None])
     cs.log_fingerprint(served.sw.log)        # so an extension carries it
     n_before = log.n
-    before = _counts()
+    before, grown_before = _counts(), _grown()
 
     mutate(log)
     nxt = HopBatchedCC(log, max_steps=60)
     assert nxt.index_status == want_index
     assert (_counts() - before).tolist() == [
         int(want_index == s) for s in ("hit", "extended", "miss")]
+    # a lookup whose suffix grew the dictionaries is an ``extended`` one,
+    # counted as ``grown`` besides; the serving engine's own repin then
+    # grows ITS builder and tables: no lookup, so the index counts none
+    assert _grown() - grown_before == int(want_repin == "grown")
     assert served.repin() == want_repin
+    assert _grown() - grown_before == int(want_repin == "grown")
+    if want_repin == "grown":
+        np.testing.assert_array_equal(served.tables.eng_of_rank,
+                                      nxt.tables.eng_of_rank)
+        got, _ = served.run([65], [None, 25])
+        want, _ = HopBatchedCC(log.freeze(), max_steps=60).run(
+            [20, 50, 65], [None, 25])
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(want)[4:])
     if case == "compaction_to_the_same_row_count":
         assert log.n == n_before
 
@@ -303,14 +321,15 @@ def test_statusz_log_index_counts_match():
     mgr = AnalysisManager(TemporalGraph(log))
     q = RangeQuery(start=300, end=600, jump=100, windows=(500,))
     was = _statusz(mgr)["log_index"]
-    assert set(was) == {"hits", "extends", "misses", "bytes"}
+    assert set(was) == {"hits", "extends", "grown", "misses", "bytes"}
     _range_rows(mgr, q)                 # miss
     _range_rows(mgr, q)                 # hit
     log.add_vertex(900, int(log.column("src")[0]))
     _range_rows(mgr, q)                 # extended: a known id, no pair
     log.add_edge(950, 10_001, 10_002)
-    _range_rows(mgr, q)                 # miss: new ids
+    _range_rows(mgr, q)                 # extended, grown: new ids
     now = _statusz(mgr)["log_index"]
-    assert [now[k] - was[k] for k in ("hits", "extends", "misses")] \
-        == [1, 1, 2]
+    assert [now[k] - was[k]
+            for k in ("hits", "extends", "grown", "misses")] \
+        == [1, 2, 1, 1]
     assert now["bytes"] - was["bytes"] == ds._LOG_INDEXES[log].nbytes > 0
