@@ -25,6 +25,7 @@ import numpy as np
 from ..core.snapshot import GraphView
 from ..obs import ledger as _ledger
 from ..obs.trace import TRACER, block_steps
+from ..ops.gather import gather_pack, packed_elements, row_and_slot
 from ..ops.segment import segment_combine, segment_ends_pos
 from .program import (Context, Edges, VertexProgram, check_custom_direction,
                       custom_exchange)
@@ -63,6 +64,29 @@ def make_runner(program: VertexProgram, n: int, m: int, k: int):
                     time, windows, eprops, vprops)
 
     return run
+
+
+@functools.lru_cache(maxsize=256)
+def state_pack(program: VertexProgram, n: int, k: int) -> int:
+    """Vertices a row of the table a ``make_mask_runner(program, n, _, k)``
+    program gathers its one-element state leaves from (``ops/gather``),
+    read off the shapes ``program.init`` gives: 1 where it has none, and
+    nothing packs. ``hop.compute`` and ``bsp.dispatch`` report it as
+    ``gather_pack``."""
+    def init(v_mask, i64, i32, t, c, vprops):
+        return program.init(Context(
+            n=n, time=t, window=t, v_mask=v_mask, vids=i64,
+            v_latest_time=i64, v_first_time=i64, out_deg=i32, in_deg=i32,
+            n_active=c, step=c, vprops=vprops))
+
+    S = jax.ShapeDtypeStruct
+    state = jax.eval_shape(
+        init, S((n,), bool), S((n,), jnp.int64), S((n,), jnp.int32),
+        S((), jnp.int64), S((), jnp.int32),
+        {name: S((n,), jnp.float32) for name in program.vertex_props})
+    # init's leaves are one window's: [n] here is [k, n] in the runner
+    one = any(a.ndim == 1 for a in jax.tree_util.tree_leaves(state))
+    return gather_pack(k * n, 1) if one else 1
 
 
 def make_mask_runner(program: VertexProgram, n: int, m: int, k: int):
@@ -108,6 +132,12 @@ def make_mask_runner(program: VertexProgram, n: int, m: int, k: int):
         # where those lie depends on flat_dst alone — once, before the
         # superstep loop, the way ``counts`` reaches ``segment_mode``
         ends, pos = segment_ends_pos(flat_dst, k * n)
+        # a state leaf of one element a vertex is gathered P vertices a
+        # row, never element by element (ops/gather): which row and slot a
+        # pair reads depends on its id alone — once, here, like the plan
+        P = state_pack(program, n, k)
+        at_src = (flat_src, *row_and_slot(flat_src, P))
+        at_dst = (flat_dst, *row_and_slot(flat_dst, P))
 
         def combine_flat(tree_flat, ids, sorted_):
             # No branch here may depend on the backend's name: the chip
@@ -151,9 +181,14 @@ def make_mask_runner(program: VertexProgram, n: int, m: int, k: int):
                          props=jax.tree_util.tree_map(tile_e, eprops),
                          step=step)
 
-        def gather_flat(state, ids):
-            return jax.tree_util.tree_map(
-                lambda a: a.reshape((k * n,) + a.shape[2:])[ids], state)
+        def gather_flat(state, at):
+            ids, row, slot = at
+
+            def leaf(a):
+                if a.ndim == 2 and P > 1:
+                    return packed_elements(a.reshape(k * n), row, slot, P)
+                return a.reshape((k * n,) + a.shape[2:])[ids]
+            return jax.tree_util.tree_map(leaf, state)
 
         def step_all(st, step):
             ek = flat_edges(step)
@@ -161,13 +196,13 @@ def make_mask_runner(program: VertexProgram, n: int, m: int, k: int):
             agg, parts = None, []
             if program.direction in ("out", "both"):
                 with jax.named_scope("combine.gather"):
-                    payload = program.message(gather_flat(st, flat_src), ek)
+                    payload = program.message(gather_flat(st, at_src), ek)
                 if custom:
                     parts.append((payload, flat_dst, em_flat))
                 else:
                     agg = combine_flat(payload, flat_dst, True)
             if program.direction in ("in", "both"):
-                payload = program.message(gather_flat(st, flat_dst), ek)
+                payload = program.message(gather_flat(st, at_dst), ek)
                 if custom:
                     parts.append((payload, flat_src, em_flat))
                 else:
@@ -309,7 +344,8 @@ def run_async(
     dummy64 = jnp.zeros((1,), jnp.int64)
     with TRACER.span("bsp.dispatch", n=int(view.n_pad), m=int(m_pad),
                         windows=k, time=int(view.time),
-                        program=type(program).__name__):
+                        program=type(program).__name__,
+                        gather_pack=state_pack(program, view.n_pad, k)):
         result, steps = runner(
             jnp.asarray(np.packbits(v_masks, axis=1, bitorder="little")),
             jnp.asarray(np.packbits(e_masks, axis=1, bitorder="little")),

@@ -53,7 +53,7 @@ from ..obs import ledger as _ledger
 from ..obs.trace import TRACER
 from ..resilience import faults as _faults
 from ..utils.transfer import _metrics
-from .bsp import make_mask_runner
+from .bsp import make_mask_runner, state_pack
 from .program import VertexProgram
 
 
@@ -878,7 +878,9 @@ class DeviceSweep:
         scan = program.combiner == "sum" and program.direction == "out"
         with TRACER.span("hop.compute", time=int(T), windows=len(wlist),
                             engine="device_sweep",
-                            combine="scan" if scan else "scatter"):
+                            combine="scan" if scan else "scatter",
+                            gather_pack=state_pack(program, self.n_pad,
+                                                   len(wlist))):
             result, steps = runner(
                 *self._bufs, self.vids, self.e_src, self.e_dst,
                 jnp.asarray(int(T), jnp.int64),

@@ -41,6 +41,8 @@ from ..core.sweep import (fold_cache, fold_pool, fold_workers,
                           log_fingerprint, prefetch_map)
 from ..obs import ledger as _ledger
 from ..obs.trace import TRACER
+from ..ops.gather import (gather_pack as _gather_pack, packed_rows,
+                          row_and_slot)
 from ..ops.segment import (segment_counts, segment_ends_pos, segment_mode,
                            sorted_segment_sum, sum_route)
 from ..ops import propagate as _propagate
@@ -147,24 +149,6 @@ def _edge_tile_for(m_pad: int, C: int, budget_bytes: int) -> int | None:
     return min((target // step) * step, m_pad)
 
 
-# A gather table this small the compiler keeps in the chip's fast memory,
-# where a row gather costs 1.8 ns a row; out of it 9.9 (docs/KERNELS.md).
-_GATHER_TABLE_BYTES = 64 << 20
-_ROW_BYTES = 512    # a [rows, <= 128] f32 table is lane-padded to 128
-
-
-def _gather_pack(n_pad: int, C: int) -> int:
-    """Vertices a row of the superstep's gather table holds: the smallest
-    power of two that brings the lane-padded table under
-    ``_GATHER_TABLE_BYTES`` (1: the plain ``[n_pad, C]`` table), no more
-    than fit a row's 128 lanes."""
-    P = 1
-    while ((n_pad // P) * _ROW_BYTES > _GATHER_TABLE_BYTES
-           and 2 * P * C <= 128 and n_pad % (2 * P) == 0):
-        P *= 2
-    return P
-
-
 def _combine_route(m_pad: int, C: int, tile_budget: int) -> str:
     """How a PageRank dispatch of ``C`` columns over ``m_pad`` rows sums at
     the destination: ``scan`` (``ops/segment.sorted_segment_sum``) or
@@ -172,6 +156,15 @@ def _combine_route(m_pad: int, C: int, tile_budget: int) -> str:
     ``hop.compute``'s ``combine``."""
     tiled = _edge_tile_for(m_pad, C, tile_budget) is not None
     return "scatter" if tiled else sum_route(C)
+
+
+def _pagerank_pack(n_pad: int, m_pad: int, C: int, tile_budget: int) -> int:
+    """Vertices a row of that dispatch's gather table (``ops/gather``; 1:
+    the plain ``[n_pad, C]`` table, all the scatter's arms read) —
+    ``hop.compute``'s ``gather_pack``."""
+    if _combine_route(m_pad, C, tile_budget) != "scan":
+        return 1
+    return _gather_pack(n_pad, C)
 
 
 def _pagerank_columns(me, mv, e_src, e_dst, n_pad: int, damping: float,
@@ -246,16 +239,17 @@ def _pagerank_columns(me, mv, e_src, e_dst, n_pad: int, damping: float,
         r0 = warm.astype(jnp.float32)
     inv_deg = 1.0 / jnp.maximum(out_deg, 1.0)
     dangling_mask = mv & (out_deg == 0)
-    ends, pos, P = None, None, 1
+    ends, pos = None, None
     if _combine_route(e_src.shape[0], C, tile_budget) == "scan":
         # where each destination's rows end and how far into its segment
         # a row lies: functions of e_dst alone, once a dispatch, outside
         # the superstep loop
         ends, pos = segment_ends_pos(e_dst, n_pad)
-        # the gather table holds P vertices a row, so that it stays in
-        # fast memory: a pair reads row e_src // P and keeps slot e_src % P
-        P = _gather_pack(n_pad, C)
-    e_row, e_slot = e_src // P, e_src % P
+    # the gather table holds P vertices a row, so that it stays in fast
+    # memory and is never a table of single elements: a pair reads row
+    # e_src // P and keeps slot e_src % P
+    P = _pagerank_pack(n_pad, e_src.shape[0], C, tile_budget)
+    e_row, e_slot = row_and_slot(e_src, P)
 
     def body(carry):
         step, r, halted = carry
@@ -268,12 +262,8 @@ def _pagerank_columns(me, mv, e_src, e_dst, n_pad: int, damping: float,
                 # a row gather [m, P * C], the row's slot [m, C]; the
                 # bool mask gates via where — only it stays live across
                 # the loop
-                rows = rd.reshape(n_pad // P, P * C)[e_row, :]
-                g = rows[:, :C]
-                for slot in range(1, P):
-                    g = jnp.where((e_slot == slot)[:, None],
-                                  rows[:, slot * C:(slot + 1) * C], g)
-                payload = jnp.where(me, g, 0.0)
+                payload = jnp.where(
+                    me, packed_rows(rd, e_row, e_slot, P), 0.0)
             # a segmented scan along the rows and one gather of n_pad
             # rows, no scatter over the m rows; past SCAN_MAX_COLUMNS
             # columns the scatter, which then costs less (docs/KERNELS.md)
@@ -506,8 +496,11 @@ def run_columns_delta(kind, tables, base, deltas_e, deltas_v, hop_times,
     combine = {"pagerank": _combine_route(tables.m_pad, C, tile_budget),
                "cdlp": "sort", "lcc": "intersect",
                "sgc": "rows"}.get(kind, "scatter")
+    pack = (_pagerank_pack(tables.n_pad, tables.m_pad, C, tile_budget)
+            if kind == "pagerank" else 1)
     with TRACER.span("hop.compute", kind=kind, hops=H, cols=H * W,
-                        resident_base=h0_delta, combine=combine):
+                        resident_base=h0_delta, combine=combine,
+                        gather_pack=pack):
         return runner(*shared_engine().put_many([
             e_src_dev if e_src_dev is not None else tables.e_src,
             e_dst_dev if e_dst_dev is not None else tables.e_dst,
@@ -2331,7 +2324,7 @@ class HopBatchedSGC(_HopBatched):
 
 def _dispatch_columns(runner, tables, cols, hop_of_col, T_col,
                       w_col, e_src_dev, e_dst_dev, *extra,
-                      combine: str = "scatter"):
+                      combine: str = "scatter", gather_pack: int = 1):
     """Shared device dispatch for the columnar runners (`extra` appends
     runner-specific trailing args, e.g. the BFS seed mask; ``combine`` is
     how the program combines at the destination). The payload —
@@ -2340,7 +2333,8 @@ def _dispatch_columns(runner, tables, cols, hop_of_col, T_col,
     engine: array k+1 stages while k is on the wire, per-slice retry."""
     from ..utils.transfer import shared_engine
 
-    with TRACER.span("hop.compute", cols=int(len(T_col)), combine=combine):
+    with TRACER.span("hop.compute", cols=int(len(T_col)), combine=combine,
+                        gather_pack=gather_pack):
         return runner(*shared_engine().put_many([
             e_src_dev if e_src_dev is not None else tables.e_src,
             e_dst_dev if e_dst_dev is not None else tables.e_dst,
@@ -2585,4 +2579,6 @@ def run_columns(tables, e_lat, e_alive, v_lat, v_alive, hop_times, windows,
                              (e_lat, e_alive, v_lat, v_alive),
                              hop_of_col, T_col, w_col, e_src_dev, e_dst_dev,
                              *extra, combine=_combine_route(
-                                 tables.m_pad, C, tile_budget))
+                                 tables.m_pad, C, tile_budget),
+                             gather_pack=_pagerank_pack(
+                                 tables.n_pad, tables.m_pad, C, tile_budget))

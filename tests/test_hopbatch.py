@@ -834,41 +834,80 @@ def test_one_chip_default_route_is_the_mesh_routes_table():
     assert int(steps1) == int(steps2)
 
 
-@pytest.mark.parametrize("pack", [4, 16], ids=["P4", "P16"])
-def test_packed_gather_table_is_a_selection_bit_for_bit(monkeypatch, pack):
-    """Past ``_GATHER_TABLE_BYTES`` the superstep's gather table holds P
-    vertices a row (so that it stays in the chip's fast memory) and a pair
-    keeps its slot of the row it read: the same floats, so (on the CPU
+@pytest.mark.parametrize("pack,hops,windows,tile", [
+    (4, [40, 70, 99], [1000, 25], None), (16, [40, 70, 99], [1000, 25], None),
+    (128, [99], [25], None), (128, [99], [25], 300)],
+    ids=["P4", "P16", "one-column", "one-column-tiled"])
+def test_packed_gather_table_is_a_selection_bit_for_bit(monkeypatch, pack,
+                                                        hops, windows, tile):
+    """Past ``TABLE_BYTES`` the superstep's gather table holds P vertices
+    a row (so that it stays in the chip's fast memory), a table of one
+    column always does (its plain form is the flat gather), and a pair
+    keeps its slot of the row it read (all the ids at once, or ``tile`` of
+    them at a time): the same floats, so (on the CPU
     backend, where no reduction's order depends on a layout) the same ranks
     bit for bit, one route with itself — and the dispatch really packed."""
     from raphtory_tpu.engine import hopbatch as hb_mod
+    from raphtory_tpu.obs.trace import TRACER
+    from raphtory_tpu.ops import gather as gather_ops
 
-    log = random_log(np.random.default_rng(33), n_events=900, n_ids=60,
+    log = random_log(np.random.default_rng(33), n_events=2000, n_ids=300,
                      t_span=100)
-    hops, windows = [40, 70, 99], [1000, 25]
-    plain = HopBatchedPageRank(log, tol=0.0, max_steps=20)
-    n_pad = plain.tables.n_pad
-    assert hb_mod._gather_pack(n_pad, 6) == 1
-    one, s1 = plain.run(hops, windows)
-    monkeypatch.setattr(hb_mod, "_GATHER_TABLE_BYTES", n_pad * 512 // pack)
-    assert hb_mod._gather_pack(n_pad, 6) == pack
-    hb_mod._compiled_delta.cache_clear()    # the pack is chosen at trace time
-    try:
-        packed, s2 = HopBatchedPageRank(log, tol=0.0, max_steps=20).run(
-            hops, windows)
-    finally:
-        hb_mod._compiled_delta.cache_clear()
-    assert int(s1) == int(s2) == 20
-    np.testing.assert_array_equal(np.asarray(one), np.asarray(packed))
+    C = len(hops) * len(windows)
+
+    def run():
+        hb_mod._compiled_delta.cache_clear()    # the pack is chosen at trace time
+        was = TRACER.enabled
+        TRACER.enable()
+        try:
+            before = TRACER.recorded
+            out, steps = HopBatchedPageRank(log, tol=0.0, max_steps=20).run(
+                hops, windows)
+            events = TRACER.recent(TRACER.recorded - before)
+        finally:
+            (TRACER.enable if was else TRACER.disable)()
+            hb_mod._compiled_delta.cache_clear()
+        (span,) = [e["args"] for e in events if e["name"] == "hop.compute"]
+        return np.asarray(out), int(steps), span["gather_pack"]
+
+    n_pad = HopBatchedPageRank(log).tables.n_pad
+    monkeypatch.setattr(gather_ops, "ONE_COLUMN_PACK", 1)
+    assert hb_mod._gather_pack(n_pad, C) == 1
+    one, s1, p1 = run()
+    if C == 1:
+        monkeypatch.undo()    # the rule as it stands: as many as divide n
+        if tile:
+            monkeypatch.setattr(gather_ops, "PACKED_ROWS_BYTES", tile * 512)
+    else:
+        monkeypatch.setattr(gather_ops, "TABLE_BYTES", n_pad * 512 // pack)
+    assert hb_mod._gather_pack(n_pad, C) == pack
+    packed, s2, p2 = run()
+    assert (s1, s2, p1, p2) == (20, 20, 1, pack)
+    assert one.any()
+    np.testing.assert_array_equal(one, packed)
 
 
 def test_gather_pack_follows_the_table_size_and_the_lane_width():
     """The cells' shapes: the plain table (1 vertex a row) at 2^17 ids,
     where it is 64 MiB, 2 vertices a row at 2^18; never more than fit 128
-    lanes."""
+    lanes. A table of ONE column is never plain — its row gather is the
+    flat gather, the dear form (docs/KERNELS.md): a full row of lanes (as
+    many as divide the ids), however many ids read it — the picked rows,
+    128 elements an id read, are taken ``rows_tile`` ids at a time past
+    ``PACKED_ROWS_BYTES``: the cells' one-window dispatches whole, an
+    int64 leaf at half the ids of a float."""
     from raphtory_tpu.engine.hopbatch import _gather_pack
+    from raphtory_tpu.ops.gather import PACKED_ROWS_BYTES, rows_tile
 
-    assert [_gather_pack(n, 6) for n in (1024, 65_536, 131_072, 262_144,
-                                         393_216)] == [1, 1, 1, 2, 4]
+    sizes = (1024, 65_536, 131_072, 262_144, 393_216)
+    assert [_gather_pack(n, 6) for n in sizes] == [1, 1, 1, 2, 4]
     assert _gather_pack(1 << 22, 6) == 16 and _gather_pack(1 << 22, 16) == 8
-    assert _gather_pack(1 << 22, 1) == 32 and _gather_pack(131_072, 1) == 1
+    assert [_gather_pack(n, 1) for n in sizes + (1 << 22,)] == [128] * 6
+    assert [_gather_pack(n, 1) for n in (192, 96, 6, 7)] == [64, 32, 2, 1]
+    whole = PACKED_ROWS_BYTES // 512
+    assert whole == 1 << 22
+    assert [rows_tile(ids, 4) for ids in (3_735_552, whole, whole + 1,
+                                          3 * 3_735_552, 3 * 7_667_712)
+            ] == [3_735_552, whole, whole, whole, whole]
+    assert [rows_tile(3 * 4_200_000, size) for size in (8, 4, 1)
+            ] == [whole // 2, whole, 3 * 4_200_000]
