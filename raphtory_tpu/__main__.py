@@ -8,7 +8,6 @@ analysis + REST + metrics + archivist) from the same env-var ergonomics
 
     python -m raphtory_tpu serve --csv edges.csv
     python -m raphtory_tpu serve --random 100000
-    python -m raphtory_tpu bench            # delegates to bench.py configs
 
 ``serve`` starts the REST job API (:8081) and Prometheus metrics (:11600),
 ingests the given sources, and then keeps serving queries until SIGINT.
@@ -101,27 +100,7 @@ def main(argv=None) -> int:
                     help="exit after sources drain (batch import mode)")
     sv.add_argument("--platform", default=None,
                     help="force a JAX platform (e.g. cpu) before backend init")
-    # add_help=False so `bench -h` forwards to bench.py's own parser
-    sub.add_parser("bench", add_help=False,
-                   help="run the benchmark suite; extra arguments are "
-                        "forwarded to bench.py "
-                        "(e.g. --config headline --device cpu)")
-    # bench flags (--config, --suite, ...) pass through untouched —
-    # argparse's REMAINDER is unreliable for option-like tokens after a
-    # subcommand, so unknowns are collected instead
-    args, extra = ap.parse_known_args(argv)
-    if extra and args.cmd != "bench":
-        ap.error(f"unrecognized arguments: {' '.join(extra)}")
-
-    if args.cmd == "bench":
-        import pathlib
-        import runpy
-
-        sys.argv = ["bench.py"] + extra
-        runpy.run_path(str(pathlib.Path(__file__).resolve().parent.parent
-                           / "bench.py"), run_name="__main__")
-        return 0
-    return _serve(args)
+    return _serve(ap.parse_args(argv))
 
 
 if __name__ == "__main__":

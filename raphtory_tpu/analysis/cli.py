@@ -23,7 +23,7 @@ DEFAULT_DOCS = os.path.join("docs", "OPERATIONS.md")
 
 def _is_python_script(path: str) -> bool:
     """Extensionless executables with a python shebang (tools/rtpulint,
-    tools/perfwatch) are source too — the tools/ scan must not skip the
+    tools/rtpu-postmortem) are source too — the tools/ scan must not skip the
     linter's own drivers."""
     try:
         with open(path, "rb") as fh:

@@ -1392,10 +1392,10 @@ class _HopBatched:
         cache's whole-payload entries and the dispatch. Returns
         ``(groups, payloads)``, one payload per dispatch group.
 
-        Two callers. The serial/parallel fold A/B (``bench.py --config
-        fold_parallel`` and the equivalence tests) takes the engine's own
-        payload kind (``delta=None``: ``RTPU_FOLD``). The column-sharded
-        mesh route (``jobs/manager._try_range_mesh_columns``) asks for
+        Two callers. The serial/parallel fold equivalence tests take the
+        engine's own payload kind (``delta=None``: ``RTPU_FOLD``). The
+        column-sharded mesh route
+        (``jobs/manager._try_range_mesh_columns``) asks for
         full host columns (``delta=False``: what ``parallel/columns.py``
         replicates) and hands in the reducer shells' ``hop_callback``,
         which — as in ``run()`` — may fire from worker threads in any
@@ -2341,174 +2341,6 @@ def _dispatch_columns(runner, tables, cols, hop_of_col, T_col,
             *cols, hop_of_col, T_col, w_col, *extra]))
 
 
-@functools.lru_cache(maxsize=16)
-def _compiled_scale(n_pad: int, m_pad: int, H: int, W: int, U_e: int,
-                    U_v: int, damping: float, tol: float, max_steps: int,
-                    scan_masks: bool = False,
-                    tile_budget: int | None = None):
-    """Scale variant of the columnar PageRank: per-hop fold state is
-    REBUILT ON DEVICE from the base state plus per-hop update lists, so a
-    sweep ships O(base + deltas) bytes instead of O(m_pad * H) — at
-    10^8-edge scale the ``[H, m_pad]`` columns cannot cross the host link.
-
-    Add-only streams only (``core/bulk.py`` contract): alive == ever-seen,
-    so every mask is ONE threshold compare ``lat >= thr`` with
-    ``thr = max(T - w, 0)`` (windowed) or 0 (unwindowed), and hop state is
-    a running scatter-max of update times. Update lists are (pos, t) pairs
-    padded with (0, INT32_MIN) — a max no-op.
-
-    ``scan_masks=True`` builds the hop rebuild as a ``lax.scan`` over hops
-    instead of an H-way unrolled block — an HLO ~H times smaller, kept as
-    the fallback shape for remote compilers that choke on the unrolled
-    program (RTPU_SCALE_MASKS=scan); results are identical (tested)."""
-
-    def run(e_src, e_dst, base_e, base_v, de_pos, de_t, dv_pos, dv_t, thr):
-        thr_hw = thr.reshape(H, W)
-
-        def hop_masks(base, d_pos, d_t):
-            def col_of(cur, th):
-                return cur[:, None] >= th[None, :]
-
-            if scan_masks:
-                def step(cur, inp):
-                    pos, tt, th = inp
-                    cur = cur.at[pos].max(tt)
-                    return cur, col_of(cur, th)               # [len, W]
-
-                _, cols = jax.lax.scan(step, base, (d_pos, d_t, thr_hw))
-                # [H, len, W] -> [len, H*W] hop-major
-                return jnp.swapaxes(cols, 0, 1).reshape(
-                    cols.shape[1], H * W)
-            cur, cols = base, []
-            for h in range(H):     # H static and small: unrolled
-                cur = cur.at[d_pos[h]].max(d_t[h])
-                cols.append(col_of(cur, thr[h * W:(h + 1) * W]))
-            return jnp.concatenate(cols, axis=1)   # [len, H*W] hop-major
-        me = hop_masks(base_e, de_pos, de_t)
-        mv = hop_masks(base_v, dv_pos, dv_t)
-        return _pagerank_columns(me, mv, e_src, e_dst, n_pad,
-                                 damping, tol, max_steps,
-                                 tile_budget=tile_budget)
-
-    return _ledger.instrument(
-        "hopbatch.pagerank_scale", jax.jit(run),
-        traffic=_ledger.edge_traffic_model(m_pad, H * W, n_pad))
-
-
-def _delta_fingerprint(deltas_e, deltas_v) -> tuple:
-    """Cheap identity of the delta lists a scale payload was built from:
-    per-hop lengths plus an xor checksum over BOTH the pos and time
-    arrays (same positions with different update times are different
-    deltas). O(Σ delta) memory-bandwidth work — a payload built from
-    DIFFERENT deltas must fail loudly in ``run_scale_columns`` instead of
-    returning mislabelled results."""
-    def xor(a):
-        a = np.asarray(a)
-        if not len(a):
-            return 0
-        return int(np.bitwise_xor.reduce(a.astype(np.int64, copy=False)))
-
-    def fp(deltas):
-        return tuple((int(len(p)), xor(p) ^ (xor(t) << 1))
-                     for p, t in deltas)
-
-    return fp(deltas_e), fp(deltas_v)
-
-
-def prepare_scale_payload(deltas_e, deltas_v, hop_times, windows):
-    """Pad the per-hop update lists and compute the column thresholds ONCE
-    for repeated ``run_scale_columns`` calls over the same sweep: the
-    padded delta arrays are the largest per-call ship (256 MB at 134M
-    events) and re-padding + re-uploading them per timed sweep would put
-    host→device transfer inside the measured loop. Returns
-    ``(U_e, U_v, de_pos, de_t, dv_pos, dv_t, thr)`` with the big arrays
-    moved via the chunked resilient path."""
-    from ..utils.transfer import device_put_chunked
-
-    H = len(hop_times)
-    wlist = normalize_windows(windows)
-    W = len(wlist)
-    thr = np.zeros(H * W, np.int32)
-    for j, T in enumerate(int(x) for x in hop_times):
-        for i, w in enumerate(wlist):
-            thr[j * W + i] = 0 if w < 0 else max(int(T) - int(w), 0)
-
-    def pad_for(deltas):
-        longest = max((len(p) for p, _ in deltas), default=1)
-        return max(1024, 1 << int(np.ceil(np.log2(max(longest, 1)))))
-
-    def pad_deltas(deltas, U):
-        pos = np.zeros((H, U), np.int32)
-        t = np.full((H, U), np.iinfo(np.int32).min, np.int32)
-        for h, (p, tt) in enumerate(deltas):
-            if len(p) > U:
-                raise ValueError(f"delta {h} exceeds pad {U}")
-            pos[h, : len(p)] = p
-            t[h, : len(p)] = tt
-        return pos, t
-
-    U_e, U_v = pad_for(deltas_e), pad_for(deltas_v)
-    de_pos, de_t = pad_deltas(deltas_e, U_e)
-    dv_pos, dv_t = pad_deltas(deltas_v, U_v)
-    # fingerprint: (hop_times, windows) grid AND the delta lists (per-hop
-    # lengths + pos checksums) — a payload prepared for one sweep must not
-    # silently relabel another same-shape sweep's results
-    fp = (tuple(int(x) for x in hop_times), tuple(wlist),
-          _delta_fingerprint(deltas_e, deltas_v))
-    return (U_e, U_v, device_put_chunked(de_pos), device_put_chunked(de_t),
-            device_put_chunked(dv_pos), device_put_chunked(dv_t),
-            jnp.asarray(thr), fp)
-
-
-def run_scale_columns(bulk, base_e, base_v, deltas_e, deltas_v, hop_times,
-                      windows, *, damping: float = 0.85, tol: float = 0.0,
-                      max_steps: int = 20, e_src_dev=None, e_dst_dev=None,
-                      prepared=None):
-    """Columnar PageRank over ``core.bulk.bulk_hop_deltas`` output: uploads
-    the base fold rows and per-hop update lists, rebuilds hop state on
-    device, runs every (hop, window) view as one column. Returns
-    ``(ranks [H*W, n_pad] hop-major, steps)``; unwindowed views use a
-    negative window (same convention as ``run_columns``). ``prepared``
-    (from ``prepare_scale_payload``) supplies pre-uploaded delta pads so
-    repeated sweeps ship nothing."""
-    H = len(hop_times)
-    wlist = normalize_windows(windows)
-    W = len(wlist)
-    if prepared is None:
-        prepared = prepare_scale_payload(deltas_e, deltas_v, hop_times,
-                                         windows)
-        U_e, U_v, de_pos, de_t, dv_pos, dv_t, thr, fp = prepared
-    else:
-        # caller-supplied payload: verify it was built from THESE deltas
-        # and THIS grid (the fresh-built branch above trivially was —
-        # don't re-walk O(Σ delta) bytes to prove it)
-        U_e, U_v, de_pos, de_t, dv_pos, dv_t, thr, fp = prepared
-        want = (tuple(int(x) for x in hop_times), tuple(wlist),
-                _delta_fingerprint(deltas_e, deltas_v))
-        if fp[:2] != want[:2]:
-            raise ValueError(
-                "prepared payload was built for a different sweep grid "
-                f"(prepared {fp[0][:2]}.../{fp[1]}, called with "
-                f"{want[0][:2]}.../{want[1]}) — prepare_scale_payload must "
-                "see the SAME hop_times/windows (and the same deltas)")
-        if len(fp) > 2 and fp[2] != want[2]:
-            raise ValueError(
-                "prepared payload was built from DIFFERENT delta lists "
-                "(per-hop length/checksum mismatch) — results would be "
-                "mislabelled; re-run prepare_scale_payload on these deltas")
-    import os
-
-    scan_masks = os.environ.get("RTPU_SCALE_MASKS", "unroll") == "scan"
-    runner = _compiled_scale(bulk.n_pad, bulk.m_pad, H, W, U_e, U_v,
-                             float(damping), float(tol), int(max_steps),
-                             scan_masks, _tile_budget_bytes())
-    return runner(
-        e_src_dev if e_src_dev is not None else jnp.asarray(bulk.e_src),
-        e_dst_dev if e_dst_dev is not None else jnp.asarray(bulk.e_dst),
-        jnp.asarray(base_e), jnp.asarray(base_v),
-        de_pos, de_t, dv_pos, dv_t, thr)
-
-
 def _column_layout(hop_times, windows):
     """Hop-major (hop 0's windows first) column layout shared by every
     columnar runner — the ONE place the ordering is defined."""
@@ -2562,9 +2394,9 @@ def run_columns(tables, e_lat, e_alive, v_lat, v_alive, hop_times, windows,
                 max_steps: int = 20, e_src_dev=None, e_dst_dev=None,
                 r_init=None):
     """Dispatch the columnar PageRank over prebuilt per-hop fold columns —
-    shared by the incremental-fold class above and the add-only bulk loader
-    (``core/bulk.bulk_hop_columns``). `tables` needs the GlobalTables /
-    BulkGraph surface (n_pad, m_pad, e_src, e_dst, tdtype). ``r_init``
+    shared by the incremental-fold class above, the serving scheduler and
+    the mesh route. `tables` needs the GlobalTables surface (n_pad, m_pad,
+    e_src, e_dst, tdtype). ``r_init``
     (the previous chunk's full ``[C, n_pad]`` hop-major output, device)
     warm-starts the power iteration: the kernel slices its last hop's W
     rows and tiles them per hop IN-PROGRAM — see ``_compiled``."""

@@ -180,7 +180,7 @@ def _compile_cache_sizes() -> dict:
     for mod, names in ((_bsp, ("_compiled_runner",)),
                        (_ds, ("_compiled_run", "_compiled_apply")),
                        (_hb, ("_compiled", "_compiled_delta", "_compiled_cc",
-                              "_compiled_bfs", "_compiled_scale"))):
+                              "_compiled_bfs"))):
         short = mod.__name__.rsplit(".", 1)[-1]
         for nm in names:
             fn = getattr(mod, nm, None)

@@ -33,7 +33,7 @@ Design constraints, in order:
   exists and every hook returns after that single check.
 * **Standalone-importable.** stdlib only, no relative imports required
   at module load — ``tools/rtpu-postmortem`` loads THIS file by path
-  (the rtpulint/perfwatch idiom) so the reader and writer can never
+  (the rtpulint idiom) so the reader and writer can never
   drift apart.
 
 Record schema (JSON payload, compact keys — docs/OBSERVABILITY.md):

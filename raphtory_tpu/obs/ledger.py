@@ -73,7 +73,7 @@ from . import device as _device
 from .trace import TRACER
 
 #: (peak FLOP/s, peak memory bandwidth B/s) per jax ``device_kind`` — the
-#: ONE table (bench.py reads it too). "TPU v5 lite" is one v5e chip:
+#: ONE table. "TPU v5 lite" is one v5e chip:
 #: 197 TFLOP/s bf16, 819 GB/s HBM (Google Cloud documentation, "TPU
 #: v5e"). "cpu" is an order-of-magnitude anchor for the CPU backend the
 #: tests run on, not a calibration. A device that is not in the table is

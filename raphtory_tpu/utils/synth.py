@@ -38,29 +38,6 @@ def random_update_stream(
     return times, kinds, src, dst
 
 
-def bitcoin_like_log(
-    n_addresses: int = 20_000,
-    n_txs: int = 200_000,
-    seed: int = 11,
-    t_span: int = 2_600_000,
-) -> EventLog:
-    """Bitcoin-style transaction graph (``BitcoinRouter`` workload shape):
-    address→address payment edges, heavy-tailed sender distribution
-    (exchanges / mixers dominate), timestamps over ~a month so hour/day/week
-    batched windows are all non-trivial."""
-    rng = np.random.default_rng(seed)
-    # heavy-tailed senders: Zipf-ish via pareto index into the address pool
-    ranks = np.minimum(
-        (rng.pareto(1.2, n_txs) * 50).astype(np.int64), n_addresses - 1)
-    src = ranks
-    dst = rng.integers(0, n_addresses, n_txs).astype(np.int64)
-    times = np.sort(rng.integers(0, t_span, n_txs)).astype(np.int64)
-    kinds = np.full(n_txs, EDGE_ADD, np.uint8)
-    log = EventLog()
-    log.append_batch(times, kinds, src, dst)
-    return log
-
-
 def ldbc_like_log(
     n_persons: int = 10_000,
     n_knows: int = 120_000,
@@ -102,15 +79,15 @@ def ldbc_like_log(
     return log
 
 
-def gab_like_arrays(
+def gab_like_log(
     n_vertices: int = 30_000,
     n_edges: int = 300_000,
     seed: int = 7,
-    t_span: int = 2_600_000,
-):
-    """(src, dst, times) arrays of the GAB-style preferential-attachment
-    stream — the raw form the bulk loader (core/bulk.py) ingests without an
-    EventLog round-trip."""
+    t_span: int = 2_600_000,  # ~a month of seconds
+) -> EventLog:
+    """GAB-style social graph: preferential attachment (heavy-tailed in-degree,
+    one giant component ~ the README demo's 22k-vertex biggest cluster),
+    timestamps spread over the span so windowed views are non-trivial."""
     rng = np.random.default_rng(seed)
     # preferential attachment via repeated-endpoint sampling trick: draw dst
     # from previously used endpoints with prob p, else uniform
@@ -123,36 +100,7 @@ def gab_like_arrays(
     dst[~reuse] = pool[~reuse]
     dst[reuse] = src[earlier[reuse]]
     times = np.sort(rng.integers(0, t_span, n_edges)).astype(np.int64)
-    return src, dst, times
-
-
-def gab_like_log(
-    n_vertices: int = 30_000,
-    n_edges: int = 300_000,
-    seed: int = 7,
-    t_span: int = 2_600_000,  # ~a month of seconds
-) -> EventLog:
-    """GAB-style social graph: preferential attachment (heavy-tailed in-degree,
-    one giant component ~ the README demo's 22k-vertex biggest cluster),
-    timestamps spread over the span so windowed views are non-trivial."""
-    src, dst, times = gab_like_arrays(n_vertices, n_edges, seed, t_span)
     kinds = np.full(n_edges, EDGE_ADD, np.uint8)
     log = EventLog()
     log.append_batch(times, kinds, src, dst)
     return log
-
-
-def twitter_like_log(
-    n_vertices: int = 5_300_000,
-    n_edges: int = 100_000_000,
-    seed: int = 11,
-    t_span: int = 2_600_000,
-) -> EventLog:
-    """Twitter-2010-class synthetic follow graph (the BASELINE.md scale
-    config shape): tens of millions of preferential-attachment edges over a
-    month of timestamps. Same generator as ``gab_like_log`` — heavy-tailed
-    degrees, one giant component — at a scale where the vertex state stops
-    fitting any host cache and the accelerator's memory system is the
-    ceiling."""
-    return gab_like_log(n_vertices=n_vertices, n_edges=n_edges, seed=seed,
-                        t_span=t_span)

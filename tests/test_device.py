@@ -15,6 +15,7 @@ on this healthy rig).
 import gc
 import itertools
 import json
+import os
 import urllib.request
 
 import numpy as np
@@ -26,6 +27,8 @@ import jax.numpy as jnp
 from raphtory_tpu.obs import advisor as advisor_mod
 from raphtory_tpu.obs import device, ledger
 from raphtory_tpu.obs.trace import TRACER
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -464,3 +467,40 @@ def test_devicez_rest_and_statusz_device_block(monkeypatch):
         assert me["device"]["timing"]["kernels_measured"] >= 1
     finally:
         srv.stop()
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` set: jax owns the directory and the
+    code assigns none. Unset: ONE fixed path inside the checkout — never
+    a temp name, pid or timestamp (the path is part of the cache key)."""
+    import jax
+
+    from raphtory_tpu.utils import config
+
+    old = jax.config.jax_compilation_cache_dir
+    platforms = jax.config.jax_platforms
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", "/sentinel/untouched")
+        assert config.configure_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == "/sentinel/untouched"
+
+        # unset, on a process pinned to the CPU (this one): no cache —
+        # XLA:CPU executables do not round-trip reliably
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert platforms == "cpu"
+        assert config.configure_compile_cache() is None
+        assert jax.config.jax_compilation_cache_dir is None
+
+        # unset, platform not pinned to the CPU (the chip): the fixed path
+        jax.config.update("jax_platforms", None)   # a flag; backends stay
+        fixed = os.path.join(ROOT, ".jax_cache")
+        assert config.configure_compile_cache() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+        assert config.configure_compile_cache() == fixed   # every call
+        # even sub-second compiles persist
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+        assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
+    finally:
+        jax.config.update("jax_platforms", platforms)
+        jax.config.update("jax_compilation_cache_dir", old)

@@ -1,13 +1,17 @@
 """Golden-value algorithm tests against NetworkX (SURVEY §4: the test
 pyramid the reference lacks needs external oracles, not just
-engine-vs-engine equivalence — all our engines could share one bug)."""
+engine-vs-engine equivalence — all our engines could share one bug), and
+on windowed views with deletes against the benchmark's plain fold."""
 
 import networkx as nx
 import numpy as np
 import pytest
 
+from benchmark import reference
+from benchmark.algorithms import pagerank as ref_pagerank
 from raphtory_tpu.algorithms import (BFS, SSSP, ConnectedComponents,
                                      DegreeBasic, PageRank)
+from raphtory_tpu.core import events as ev
 from raphtory_tpu.core.events import EventLog
 from raphtory_tpu.core.snapshot import build_view
 from raphtory_tpu.engine import bsp
@@ -106,3 +110,78 @@ def test_degrees_match_networkx(graph):
         vid = int(view.vids[i])
         assert int(np.asarray(got["in"])[i]) == G.in_degree(vid)
         assert int(np.asarray(got["out"])[i]) == G.out_degree(vid)
+
+
+def _adversarial_stream(seed, n_events=600, n_ids=14, t_span=60):
+    """Time-sorted stream with heavy id reuse, duplicate timestamps,
+    vertex/edge deletes and re-adds (revivals): the engine's log and the
+    benchmark's plain reference of the same events."""
+    rng = np.random.default_rng(seed)
+    code = np.array([ev.VERTEX_ADD, ev.VERTEX_DELETE, ev.EDGE_ADD,
+                     ev.EDGE_DELETE], np.uint8)     # RefEvents' 0..3
+    k = rng.choice(4, n_events, p=[0.2, 0.1, 0.5, 0.2])
+    t = np.sort(rng.integers(0, t_span, n_events)).astype(np.int64)
+    s = rng.integers(0, n_ids, n_events).astype(np.int64)
+    d = rng.integers(0, n_ids, n_events).astype(np.int64)
+    d[k < 2] = -1
+    log = EventLog()
+    log.append_batch(t, code[k], s, d)
+    return log, reference.RefEvents(t, k, s, np.maximum(d, 0), n_ids)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_reference_fold_and_algorithms_match_engine(seed):
+    """Windowed views with deletes: the engine's fold against a fold that
+    is not its own, and on that fold's alive sets PageRank against the
+    benchmark's float64 power iteration, components and degrees against
+    NetworkX."""
+    log, ref = _adversarial_stream(seed)
+    saw_dead_edge = False
+    for T in (15, 33, 59):
+        view = build_view(log, T)
+        for w in (None, 20, 4):
+            vm, src, dst = ref.fold(T, w)
+            if w is None:
+                v_mask, e_mask = view.v_mask, view.e_mask
+            else:
+                (v_mask,), (e_mask,) = view.window_masks([w])
+            assert sorted(view.vids[v_mask]) == \
+                np.flatnonzero(vm).tolist()
+            got_e = sorted(zip(view.vids[view.e_src[e_mask]].tolist(),
+                               view.vids[view.e_dst[e_mask]].tolist()))
+            assert got_e == sorted(zip(src.tolist(), dst.tolist()))
+            saw_dead_edge |= len(src) < len(ref.us)
+            G = nx.DiGraph()
+            G.add_nodes_from(np.flatnonzero(vm).tolist())
+            G.add_edges_from(zip(src.tolist(), dst.tolist()))
+
+            ranks, _ = bsp.run(PageRank(tol=1e-9, max_steps=200), view,
+                               window=w)
+            got = np.zeros(ref.n_ids)
+            got[view.vids[view.v_mask]] = np.asarray(ranks)[view.v_mask]
+            np.testing.assert_allclose(
+                got, ref_pagerank.pagerank(vm, src, dst, 200),
+                atol=6e-9, rtol=2e-4)
+
+            cc = ConnectedComponents()
+            labels, _ = bsp.run(cc, view, window=w)
+            sizes = sorted((len(c) for c in nx.connected_components(
+                G.to_undirected())), reverse=True)
+            got_cc = cc.reduce(labels, view, window=w)
+            assert got_cc == {
+                "vertices": len(G), "clusters": len(sizes),
+                "biggest": sizes[0] if sizes else 0,
+                "islands": sizes.count(1),
+                "proportion": sizes[0] / len(G) if sizes else 0.0,
+                "top5": sizes[:5]}
+
+            deg = DegreeBasic()
+            res, _ = bsp.run(deg, view, window=w)
+            ind = [x for _, x in G.in_degree()]
+            outd = [x for _, x in G.out_degree()]
+            assert deg.reduce(res, view, window=w) == {
+                "vertices": len(G), "total_in": sum(ind),
+                "total_out": sum(outd), "max_in": max(ind, default=0),
+                "max_out": max(outd, default=0),
+                "avg_degree": (sum(ind) + sum(outd)) / max(len(G), 1)}
+    assert saw_dead_edge, "the stream never killed an edge"

@@ -303,3 +303,22 @@ def test_staged_writer_failure_poisons_source():
     assert pipe.log.n <= 512                           # nothing past the hole
     assert pipe.watermarks.safe_time() >= 2**62        # fence released
     assert pipe.backlog() == 0 or pipe._q_done
+
+
+def test_late_source_joins_without_replaying_the_drained_one():
+    from raphtory_tpu.cluster.runtime import NodeRuntime
+    from raphtory_tpu.ingestion.source import IterableSource
+    from raphtory_tpu.ingestion.updates import EdgeAdd
+    from raphtory_tpu.utils.config import Settings
+
+    rt = NodeRuntime(settings=Settings(rest_port=0, metrics_port=0))
+    rt.add_source(IterableSource(
+        [EdgeAdd(t, t, t + 1) for t in range(1, 6)], name="first"))
+    rt.ingest(wait=True)
+    assert len(rt.graph.log) == 5
+    rt.add_source(IterableSource(
+        [EdgeAdd(t, t, t + 1) for t in range(6, 9)], name="late"))
+    rt.ingest(wait=True)
+    assert rt.pipeline.counts == {"first": 5, "late": 3}
+    assert len(rt.graph.log) == 8          # the first was not replayed
+    assert not rt.pipeline.errors

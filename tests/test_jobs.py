@@ -443,3 +443,68 @@ def test_range_weighted_sssp_rides_hopbatch_and_matches_view_jobs(
     assert calls, "hopbatch weighted-SSSP route was not taken"
     assert len(job.results) == 8 * 2
     _assert_range_rows_match_view_jobs(job, sssp, mgr)
+
+
+def test_declinable_is_transport_or_oom_only():
+    from raphtory_tpu.jobs.manager import declinable
+    from raphtory_tpu.resilience.faults import FaultError
+
+    class XlaRuntimeError(RuntimeError):
+        pass
+
+    assert declinable(XlaRuntimeError("UNAVAILABLE: connection lost"))
+    assert declinable(FaultError("UNAVAILABLE: injected fault"))
+    assert declinable(MemoryError())
+    assert declinable(XlaRuntimeError("RESOURCE_EXHAUSTED: out of HBM"))
+    assert not declinable(XlaRuntimeError(
+        "INTERNAL: Mosaic failed to compile TPU kernel"))
+    assert not declinable(TypeError("a bug"))
+
+
+def _small_graph():
+    from raphtory_tpu.core.events import EventLog
+
+    log = EventLog()
+    rng = np.random.default_rng(7)
+    for t in range(1, 80):
+        a, b = (int(x) for x in rng.integers(0, 12, 2))
+        log.add_edge(t, a, b)
+    return TemporalGraph(log)
+
+
+@pytest.mark.parametrize("route", ["range_columnar", "live_epoch",
+                                   "range_mesh_columns"])
+@pytest.mark.parametrize("error, status", [
+    ("INTERNAL: compiler refused the program", "failed"),
+    ("UNAVAILABLE: connection lost", "done")])
+def test_device_error_fails_the_job_transport_error_declines(
+        monkeypatch, route, error, status):
+    """A columnar Range, a Live epoch or a column-sharded mesh Range that
+    hits a device error ends ``failed`` with that error; a transport
+    error still declines to the next rung (the mesh: vertex sharding)
+    and the job ends ``done``."""
+    from raphtory_tpu.engine.hopbatch import HopBatchedCC
+    from raphtory_tpu.parallel import columns, sharded
+
+    class XlaRuntimeError(RuntimeError):
+        pass
+
+    def boom(*a, **k):
+        raise XlaRuntimeError(error)
+
+    mesh = None
+    if route == "range_mesh_columns":
+        monkeypatch.setattr(columns, "run_columns_sharded", boom)
+        mesh = sharded.make_mesh(4, 2)
+    else:
+        monkeypatch.setattr(HopBatchedCC, "run", boom)
+    mgr = AnalysisManager(_small_graph(), mesh=mesh)
+    q = (LiveQuery(repeat=20, event_time=True, max_runs=2)
+         if route == "live_epoch" else RangeQuery(20, 60, 20))
+    job = mgr.submit(registry.resolve("ConnectedComponents"), q)
+    assert job.wait(120)
+    assert job.status == status, job.error
+    if status == "failed":
+        assert error in job.error and not job.results
+    else:
+        assert len(job.results) == (2 if route == "live_epoch" else 3)

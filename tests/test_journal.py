@@ -1,7 +1,7 @@
 """Durable telemetry journal + postmortem plane: CRC framing and torn
 tails, segment rotation under the byte cap, concurrent non-blocking
 writers, zero-overhead-off, /journalz + /clusterz surfaces, exitdump
-consolidation, rtpu-postmortem replay, perfwatch ingestion (ISSUE 18)."""
+consolidation, rtpu-postmortem replay (ISSUE 18)."""
 
 import json
 import threading
@@ -9,7 +9,7 @@ import threading
 import numpy as np
 import pytest
 
-from raphtory_tpu.analysis import perfwatch, postmortem
+from raphtory_tpu.analysis import postmortem
 from raphtory_tpu.core.service import TemporalGraph
 from raphtory_tpu.ingestion.pipeline import IngestionPipeline
 from raphtory_tpu.ingestion.source import IterableSource
@@ -388,18 +388,6 @@ def test_postmortem_cli_subcommands(tmp_path, capsys):
     assert postmortem.main(["diff", str(d), str(d)]) == 0   # self-clean
     capsys.readouterr()
     assert postmortem.main(["status", str(tmp_path / "empty")]) == 2
-
-
-# ---- perfwatch ingestion ----
-
-def test_perfwatch_ingests_journal_directory(tmp_path):
-    d = _synthetic_run(tmp_path, "run")
-    rows = perfwatch.load_rows(str(d))
-    by_config = {r["config"]: r for r in rows}
-    assert by_config["journal_phase:PageRank/fold"]["value"] \
-        == pytest.approx(0.02)
-    assert by_config["journal_span:sweep"]["value"] == pytest.approx(0.005)
-    assert all(r["unit"] == "seconds" for r in rows)
 
 
 # ---- end to end: a real job's evidence reaches disk ----

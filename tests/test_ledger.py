@@ -1,14 +1,11 @@
-"""Resource ledger (obs/ledger.py) + perfwatch sentinel.
+"""Resource ledger (obs/ledger.py).
 
 Covers the ISSUE 6 satellites: accumulation/merge across threads (the
 parallel fold workers' shape), the instrument()/registry harvest path
 with its CPU/capability fallback (degrade to host-side accounting, never
-fail a sweep), the fold-cache-hit hop.fold span + ledger entry, and the
-perfwatch noise-band judgement over synthetic and real trajectories.
+fail a sweep), and the fold-cache-hit hop.fold span + ledger entry.
 """
 
-import glob
-import json
 import threading
 
 import numpy as np
@@ -311,116 +308,3 @@ def test_concurrent_jobs_never_share_a_ledger():
         total = d["queue_wait_seconds"] + sum(d["phase_seconds"].values())
         assert abs(total - d["wall_seconds"]) <= \
             0.05 * d["wall_seconds"] + 1e-6
-
-
-# ------------------------------------------------------------- perfwatch
-
-
-from raphtory_tpu.analysis import perfwatch  # noqa: E402
-
-
-def _write_round(tmp_path, rnd, rows):
-    p = tmp_path / f"BENCH_r{rnd:02d}.json"
-    p.write_text(json.dumps({"n": rnd, "rows": rows}))
-    return str(p)
-
-
-def test_perfwatch_flags_synthetic_2x_slowdown(tmp_path):
-    hist_rows = [{"config": "headline", "metric": "m", "value": v,
-                  "unit": "views/sec"} for v in (10.0, 10.4, 9.8)]
-    paths = [_write_round(tmp_path, i + 1, [r])
-             for i, r in enumerate(hist_rows)]
-    head = tmp_path / "head.json"
-    head.write_text(json.dumps(
-        {"config": "headline", "metric": "m", "value": 5.0,
-         "unit": "views/sec"}))
-    out = perfwatch.check(paths, head_path=str(head))
-    assert out["regressions"] == ["headline"]
-    assert not out["ok"]
-    j = out["judgements"]["headline"]
-    assert j["regressed"] and j["worse_by_rel"] > j["band_rel"]
-
-
-def test_perfwatch_passes_noise_and_improvements(tmp_path):
-    paths = [_write_round(tmp_path, i + 1, [
-        {"config": "headline", "value": v, "unit": "views/sec"},
-        {"config": "overhead", "value": o,
-         "unit": "percent_slower_with_ledger"},
-    ]) for i, (v, o) in enumerate(((10.0, 1.2), (10.4, 3.8), (9.8, -2.0)))]
-    head = tmp_path / "head.json"
-    head.write_text(json.dumps({"rows": [
-        {"config": "headline", "value": 12.5, "unit": "views/sec"},
-        {"config": "overhead", "value": 6.0,
-         "unit": "percent_slower_with_ledger"},
-    ]}))
-    out = perfwatch.check(paths, head_path=str(head))
-    assert out["ok"], out["judgements"]
-    # ... but a 2x-slowdown percent arm (the ledger left on a hot path,
-    # say) blows the absolute percentage-point band
-    head.write_text(json.dumps({"rows": [
-        {"config": "overhead", "value": 100.0,
-         "unit": "percent_slower_with_ledger"}]}))
-    out = perfwatch.check(paths, head_path=str(head))
-    assert out["regressions"] == ["overhead"]
-
-
-def test_perfwatch_tolerates_every_committed_format(tmp_path):
-    # {row}, {parsed}, {rows}, bare row, JSONL — one of each
-    p1 = tmp_path / "BENCH_r01.json"
-    p1.write_text(json.dumps({"row": {"config": "a", "value": 1.0,
-                                      "unit": "views/sec"}}))
-    p2 = tmp_path / "BENCH_r02.json"
-    p2.write_text(json.dumps({"parsed": {"config": "a", "value": 1.1,
-                                         "unit": "views/sec"}}))
-    p3 = tmp_path / "BENCH_r03.json"
-    p3.write_text(json.dumps({"rows": [{"config": "a", "value": 0.9,
-                                        "unit": "views/sec"}]}))
-    p4 = tmp_path / "BENCH_r04.json"
-    p4.write_text(json.dumps({"config": "a", "value": 1.05,
-                              "unit": "views/sec"}))
-    p5 = tmp_path / "head.jsonl"
-    p5.write_text('not json\n'
-                  + json.dumps({"config": "a", "value": 1.0,
-                                "unit": "views/sec"}) + "\n")
-    series = perfwatch.collect_series(map(str, (p1, p2, p3, p4)))
-    assert len(series["a"]) == 4
-    out = perfwatch.check([str(p) for p in (p1, p2, p3, p4)],
-                          head_path=str(p5))
-    assert out["ok"]
-
-
-def test_perfwatch_selftest_and_real_trajectory():
-    """The CI gate's two halves, run over the repo itself: the built-in
-    calibration behaves, and the committed BENCH_* trajectory passes
-    clean (a red here means a committed artifact ALREADY regressed)."""
-    assert perfwatch.selftest() == 0
-    paths = sorted(glob.glob("BENCH_*.json"))
-    if not paths:   # running outside the repo root
-        pytest.skip("no committed trajectory visible from cwd")
-    out = perfwatch.check(paths)
-    assert out["ok"], out["regressions"]
-
-
-def test_perfwatch_empty_head_fails_the_gate(tmp_path):
-    """A crashed bench (empty/error-only head file) must fail perfwatch,
-    not sail through with zero judgements."""
-    hist = _write_round(tmp_path, 1, [
-        {"config": "a", "value": 1.0, "unit": "views/sec"}])
-    empty = tmp_path / "head.jsonl"
-    empty.write_text("")
-    with pytest.raises(ValueError, match="no judgeable bench rows"):
-        perfwatch.check([hist], head_path=str(empty))
-    errors_only = tmp_path / "err.jsonl"
-    errors_only.write_text(json.dumps(
-        {"config": "a", "value": 0.0, "unit": "error"}))
-    with pytest.raises(ValueError):
-        perfwatch.check([hist], head_path=str(errors_only))
-    assert perfwatch.main([str(hist), "--head", str(empty)]) == 2
-
-
-def test_perfwatch_unit_rules():
-    assert perfwatch.judge([], 1.0, "views/sec")["skipped"]
-    assert perfwatch.judge([1.0], 1.0, "error")["skipped"]
-    # lower-better seconds: faster head passes, slower flags
-    assert not perfwatch.judge([1.0, 1.1], 0.5, "seconds")["regressed"]
-    assert perfwatch.judge([1.0, 1.1], 2.2, "seconds")["regressed"]

@@ -1300,8 +1300,45 @@ def test_knob_table_rows_and_code_agree_both_ways():
     package = names_under("raphtory_tpu")
     assert package - rows == set(), "knobs without a row"
     # rows may also name the knobs of the drivers around the package
-    drivers = names_under("tools", "tests", "bench.py", "chip_smoke.py")
+    drivers = names_under("tools", "tests")
     assert rows - package - drivers == set(), "rows without a reader"
+
+
+DOCUMENTS = ["README.md", ".claude/skills/verify/SKILL.md"] + sorted(
+    "docs/" + f for f in os.listdir(os.path.join(REPO, "docs"))
+    if f.endswith(".md"))
+
+
+@pytest.mark.parametrize("document", DOCUMENTS)
+def test_every_path_a_document_names_exists(document):
+    """The knob-table rule, for paths: every back-quoted ``*.py``,
+    ``*.md``, ``*.json``, ``*.yml`` or ``*.cpp`` path of a document
+    resolves from the root, the package, ``docs/``, ``tests/``, ``tools/``
+    or ``benchmark/`` (a bare module name: one file of that name in the
+    package) — a path outliving its file sends a reader to code that is
+    gone. Fenced blocks (a user's own files in an example command) and
+    absolute paths (outside the checkout) are not the repo's to hold.
+    PERF.md, ROADMAP.md and CHANGES.md are history and are not scanned."""
+    import glob
+    import re
+
+    path = re.compile(r"(?<![\w./*<>{}$-])([\w.*/-]+\.(?:py|md|json|yml|cpp))"
+                      r"(?![\w/*-])")
+    roots = ("", "raphtory_tpu", "docs", "tests", "tools", "benchmark")
+
+    def resolves(p):
+        if any(glob.glob(os.path.join(REPO, r, p)) for r in roots):
+            return True
+        return "/" not in p and len(glob.glob(
+            os.path.join(REPO, "raphtory_tpu", "**", p),
+            recursive=True)) == 1
+
+    with open(os.path.join(REPO, document)) as fh:
+        text = re.sub(r"^```.*?^```", "", fh.read(), flags=re.M | re.S)
+    dead = sorted({p for span in re.findall(r"`([^`]+)`", text)
+                   for p in path.findall(span)
+                   if not p.startswith("/") and not resolves(p)})
+    assert dead == [], f"{document} names files that are not there"
 
 
 # ---------------------------------------------------------------------------

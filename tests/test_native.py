@@ -219,3 +219,22 @@ def test_device_put_chunked_matches_device_put(monkeypatch):
     a = rng.integers(0, 255, 5000, np.uint8)
     got = transfer.device_put_chunked(a, chunk_bytes=1 << 10, backoff=0.0)
     np.testing.assert_array_equal(np.asarray(got), a)
+
+
+def test_native_radix_and_searchsorted_match_numpy():
+    rng = np.random.default_rng(4)
+    keys = rng.integers(0, 2**63, 50_000, dtype=np.uint64)
+    order = native.radix_argsort_u64(keys)
+    np.testing.assert_array_equal(keys[order], np.sort(keys))
+    # stability on heavy duplicates
+    dup = (rng.integers(0, 7, 20_000).astype(np.uint64) << np.uint64(32))
+    o = native.radix_argsort_u64(dup)
+    for b in range(7):
+        idx = o[dup[o] == (np.uint64(b) << np.uint64(32))]
+        assert np.all(np.diff(idx) > 0)
+    base = np.sort(keys)
+    q = rng.integers(0, 2**63, 10_000, dtype=np.uint64)
+    for side in ("left", "right"):
+        np.testing.assert_array_equal(
+            native.searchsorted_u64(base, q, side),
+            np.searchsorted(base, q, side=side))

@@ -85,7 +85,7 @@ def test_transport_failure_resumes_mid_array(monkeypatch):
 def test_programming_error_raises_immediately(monkeypatch):
     """A shape/dtype bug must NOT be retried — no backoff sleeps, no
     retry counter, original exception type surfaces (the ~70 s/chunk
-    pathology ADVICE.md flagged)."""
+    pathology a round-5 review flagged)."""
     import jax
 
     def broken(a, device=None):
@@ -282,7 +282,7 @@ def test_hopbatch_prefetch_failure_drops_residency():
 def test_tile_budget_part_of_compiled_cache_key():
     """Changing RTPU_TILE_BUDGET_MB mid-process must produce a DIFFERENT
     compiled program object — the budget is in the lru_cache key, not
-    read once at first trace (ADVICE.md round 5)."""
+    read once at first trace (a round-5 review finding)."""
     from raphtory_tpu.engine import hopbatch as hb
 
     args = (1 << 10, 1 << 10, 2, 4, 0.85, 1e-7, 20, "int32", False)
@@ -299,42 +299,3 @@ def test_tile_budget_part_of_compiled_cache_key():
         assert hb._tile_budget_bytes() == 17 << 20
     finally:
         del os.environ["RTPU_TILE_BUDGET_MB"]
-
-
-def test_scale_payload_fingerprint_rejects_different_deltas():
-    """A prepared scale payload passed alongside DIFFERENT delta lists
-    must fail loudly (mislabelled results otherwise)."""
-    from raphtory_tpu.core.bulk import bulk_hop_deltas
-    from raphtory_tpu.engine.hopbatch import (prepare_scale_payload,
-                                              run_scale_columns)
-
-    rng = np.random.default_rng(3)
-    n = 4000
-    src = rng.integers(0, 200, n)
-    dst = rng.integers(0, 200, n)
-    times = np.sort(rng.integers(0, 1000, n))
-    hops = [400, 600, 800, 999]
-    windows = [1000, 50]
-    bulk, base_e, base_v, d_e, d_v = bulk_hop_deltas(src, dst, times, hops)
-    prepared = prepare_scale_payload(d_e, d_v, hops, windows)
-
-    # same deltas: runs
-    ranks, _ = run_scale_columns(bulk, base_e, base_v, d_e, d_v, hops,
-                                 windows, max_steps=5, prepared=prepared)
-    assert np.asarray(ranks).shape[0] == len(hops) * len(windows)
-
-    # tampered pos array in one hop: loud failure, not silent relabelling
-    d_e_bad = [(p.copy(), t) for p, t in d_e]
-    if len(d_e_bad[1][0]):
-        d_e_bad[1][0][0] ^= 1
-    else:
-        d_e_bad[1] = (np.array([3], np.int32),
-                      np.array([500], bulk.tdtype))
-    with pytest.raises(ValueError, match="DIFFERENT delta lists"):
-        run_scale_columns(bulk, base_e, base_v, d_e_bad, d_v, hops,
-                          windows, max_steps=5, prepared=prepared)
-
-    # different grid still caught by the original guard
-    with pytest.raises(ValueError, match="different sweep grid"):
-        run_scale_columns(bulk, base_e, base_v, d_e, d_v, hops,
-                          [1000], max_steps=5, prepared=prepared)

@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""N-process localhost cluster smoke + observability-overhead bench.
+"""N-process localhost cluster smoke.
 
 Driver (default mode) spawns ``RTPU_SMOKE_N`` worker processes
 (default 2; CI also runs the 4-process leg) that form a real
@@ -33,7 +33,7 @@ the ISSUE-10 acceptance path end to end:
   processes' safe times + watermark spread, and the delayed worker's
   source must MOVE the merged min-watermark to its stalled fence;
 * finally the mesh-divergence leg (ISSUE 19, ``RTPU_SMOKE_DIVERGE``,
-  on by default outside bench mode): both workers issue one more sweep
+  on by default): both workers issue one more sweep
   at the same dispatch seq with DIFFERENT window sets, and the merged
   ``/clusterz`` mesh block must report the injected divergence naming
   that exact superstep with both processes' fingerprints
@@ -48,12 +48,6 @@ handshake exits 0 with SKIPPED (the capability under test is the
 observability plane, not the collectives — each process sweeps its own
 LOCAL 2-device mesh, so cross-process device collectives are not
 required; on jaxes that lack them the smoke still proves everything).
-
-``--pairs N`` adds the ``multichip_obs_overhead`` measurement on worker
-0: N interleaved telemetry-off/on pairs of a jobs-layer sharded range
-sweep (median per-pair ratio — the shared-box protocol), with worker 1
-alive and serving its REST plane throughout so the federation surface is
-real. bench.py wraps this mode as ``--config multichip_obs_overhead``.
 """
 
 from __future__ import annotations
@@ -112,7 +106,7 @@ def _wait_done(base, job_id, timeout_s=300.0):
 
 
 def worker(idx: int, n: int, coord_port: int, rest_base: int, tmpdir: str,
-           pairs: int, cheap: bool, out: str | None) -> None:
+           cheap: bool, out: str | None) -> None:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
@@ -353,56 +347,6 @@ def worker(idx: int, n: int, coord_port: int, rest_base: int, tmpdir: str,
     assert tenants["smoke-w0"]["queries"] >= 1, tenants
     assert tenants["smoke-w0"]["cost_seconds"] > 0, tenants
 
-    # ---- optional bench mode: interleaved telemetry off/on pairs ----
-    if pairs > 0:
-        from raphtory_tpu.jobs.manager import RangeQuery
-
-        n_hops = 12 if cheap else 16
-        times = np.linspace(0.4 * latest, latest, n_hops).astype(np.int64)
-        q = RangeQuery(int(times[0]), int(times[-1]),
-                       int(times[1] - times[0]) or 1,
-                       windows=(800, 400, 200, 100))
-        from raphtory_tpu.jobs import registry
-
-        def once():
-            # the timed unit is a multi-second sharded range job: per-pair
-            # ratio cancellation only works when the unit outlasts the
-            # shared box's drift bursts (sub-second units read pure noise)
-            t0 = time.perf_counter()
-            job = mgr.submit(registry.resolve(
-                "PageRank", {"max_steps": 25, "tol": 0.0}), q)
-            ok = job.wait(600)
-            dt = time.perf_counter() - t0
-            if not ok or job.status != "done":
-                raise RuntimeError(f"bench job {job.status}: {job.error}")
-            return dt
-
-        def arm(on: bool):
-            os.environ["RTPU_SLO"] = "1" if on else "0"
-            os.environ["RTPU_LEDGER"] = "1" if on else "0"
-            (TRACER.enable if on else TRACER.disable)()
-
-        arm(True)
-        once()                         # warm: compiles + caches, untimed
-        ab = []
-        for i in range(pairs):
-            # ABBA: alternate which arm leads — a monotonic drift across
-            # the run then biases half the pairs each way instead of
-            # reading uniformly as overhead
-            order = (False, True) if i % 2 == 0 else (True, False)
-            t = {}
-            for on in order:
-                arm(on)
-                t[on] = once()
-            ab.append((t[False], t[True]))
-        arm(True)
-        t0 = time.perf_counter()
-        _http_json(f"{me}/clusterz?refresh=1")
-        scrape_s = time.perf_counter() - t0
-        print("BENCH_PAIRS " + json.dumps(
-            {"pairs": ab, "clusterz_scrape_seconds": round(scrape_s, 4),
-             "n_views": n_hops * 4}), flush=True)
-
     # ---- straggler injection (ISSUE-11): worker 1 delays — its
     # watermark fence stops advancing — and a federated /advisez pass
     # HERE must fire the cluster-straggler rule naming process 1. The
@@ -458,14 +402,12 @@ def worker(idx: int, n: int, coord_port: int, rest_base: int, tmpdir: str,
                       default=str)
     print("FRESHNESS_OK", flush=True)
 
-    # ---- mesh-divergence leg (ISSUE 19): on by default for the plain
-    # smoke, disabled by RTPU_SMOKE_DIVERGE=0 or bench mode (the driver
-    # keeps the sanitizer off while measuring overhead). Both workers
-    # issue one more sweep at the same dispatch seq but with different
-    # window sets — different compile shapes, so the /clusterz prefix
-    # cross-check must name that seq as the first divergent superstep.
-    if pairs == 0 and os.environ.get(
-            "RTPU_SMOKE_DIVERGE", "1") not in ("", "0", "false"):
+    # ---- mesh-divergence leg (ISSUE 19): on by default, disabled by
+    # RTPU_SMOKE_DIVERGE=0. Both workers issue one more sweep at the same
+    # dispatch seq but with different window sets — different compile
+    # shapes, so the /clusterz prefix cross-check must name that seq as
+    # the first divergent superstep.
+    if os.environ.get("RTPU_SMOKE_DIVERGE", "1") not in ("", "0", "false"):
         mz = cz2.get("mesh") or {}
         assert mz.get("processes_enabled") == n, (
             f"mesh sanitizer not armed on all workers: {mz}")
@@ -539,12 +481,11 @@ def _free_port_run(n: int) -> int:
     raise RuntimeError(f"no free run of {n} adjacent ports")
 
 
-def run_cluster(out: str | None = None, pairs: int = 0,
-                cheap: bool = False, timeout_s: float = 600.0,
-                n: int | None = None) -> dict:
+def run_cluster(out: str | None = None, cheap: bool = False,
+                timeout_s: float = 600.0, n: int | None = None) -> dict:
     """Spawn the N-worker cluster (``n`` or RTPU_SMOKE_N, default 2);
-    returns {skipped, outputs, pairs...}. Raises on real failures
-    (assertions inside a worker, timeouts)."""
+    returns {skipped, outputs}. Raises on real failures (assertions
+    inside a worker, timeouts)."""
     if n is None:
         try:
             n = int(os.environ.get("RTPU_SMOKE_N", "2"))
@@ -567,14 +508,13 @@ def run_cluster(out: str | None = None, pairs: int = 0,
     # CI-sized staleness bar for the straggler phase: worker 1's stalled
     # fence must clear it in smoke time, not the 30 s production default
     env["RTPU_ADVISOR_STALE_S"] = "2"
-    # mesh-divergence leg (ISSUE 19): on by default for the plain smoke
-    # (RTPU_SMOKE_DIVERGE=0 disables); bench runs (pairs > 0) keep the
-    # sanitizer OFF so the overhead measurement stays uncontaminated.
-    # The workers' local meshes never span processes, so the injected
-    # divergence cannot hang a collective — the fingerprint prefix
-    # check is the detector, and the barrier watchdog rides along armed.
-    diverge = (pairs == 0 and os.environ.get(
-        "RTPU_SMOKE_DIVERGE", "1") not in ("", "0", "false"))
+    # mesh-divergence leg (ISSUE 19): on by default (RTPU_SMOKE_DIVERGE=0
+    # disables). The workers' local meshes never span processes, so the
+    # injected divergence cannot hang a collective — the fingerprint
+    # prefix check is the detector, and the barrier watchdog rides along
+    # armed.
+    diverge = os.environ.get("RTPU_SMOKE_DIVERGE", "1") not in (
+        "", "0", "false")
     if diverge:
         env["RTPU_SANITIZE"] = "1"
         env.setdefault("RTPU_SANITIZE_BARRIER_S", "5")
@@ -586,8 +526,7 @@ def run_cluster(out: str | None = None, pairs: int = 0,
         cmd = [sys.executable, os.path.abspath(__file__),
                "--worker", str(i), "--n", str(n),
                "--coord-port", str(coord),
-               "--rest-base", str(rest_base), "--tmpdir", tmpdir,
-               "--pairs", str(pairs)]
+               "--rest-base", str(rest_base), "--tmpdir", tmpdir]
         if cheap:
             cmd.append("--cheap")
         if out and i == 0:
@@ -622,11 +561,7 @@ def run_cluster(out: str | None = None, pairs: int = 0,
     if diverge and "DIVERGENCE_OK" not in outs[0]:
         raise RuntimeError(f"worker 0 missing DIVERGENCE_OK:\n"
                            f"{outs[0][-4000:]}")
-    res: dict = {"skipped": False, "outputs": outs}
-    for line in outs[0].splitlines():
-        if line.startswith("BENCH_PAIRS "):
-            res.update(json.loads(line[len("BENCH_PAIRS "):]))
-    return res
+    return {"skipped": False, "outputs": outs}
 
 
 def main(argv=None) -> int:
@@ -637,25 +572,20 @@ def main(argv=None) -> int:
     ap.add_argument("--coord-port", type=int, default=0)
     ap.add_argument("--rest-base", type=int, default=0)
     ap.add_argument("--tmpdir", default="")
-    ap.add_argument("--pairs", type=int, default=0,
-                    help="bench mode: N interleaved off/on pairs")
     ap.add_argument("--cheap", action="store_true")
     ap.add_argument("--out", default=None,
                     help="write the federated snapshot JSON here")
     args = ap.parse_args(argv)
     if args.worker is not None:
         worker(args.worker, max(2, args.n), args.coord_port,
-               args.rest_base, args.tmpdir, args.pairs, args.cheap,
-               args.out)
+               args.rest_base, args.tmpdir, args.cheap, args.out)
         return 0
-    res = run_cluster(out=args.out, pairs=args.pairs, cheap=args.cheap,
-                      n=args.n or None)
+    res = run_cluster(out=args.out, cheap=args.cheap, n=args.n or None)
     if res["skipped"]:
         print("SKIPPED: this jax cannot form a localhost "
               "jax.distributed cluster")
         return 0
-    print("cluster smoke ok" + (
-        f"; pairs={res['pairs']}" if args.pairs else ""))
+    print("cluster smoke ok")
     return 0
 
 
