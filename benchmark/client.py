@@ -11,6 +11,12 @@ import urllib.request
 ACTIVE = ("pending", "running")
 
 
+class ScheduleUsedUp(ValueError):
+    """The traffic file's schedule holds no request ``k``: its last hop
+    would lie past the end of the log's span. A window that gets there
+    closes (``loops/closed.py``); it is no fault of the run."""
+
+
 class Rest:
     def __init__(self, port: int):
         self.base = f"http://127.0.0.1:{port}"
@@ -36,18 +42,34 @@ class Rest:
         return self.get(f"/KillTask?jobID={job_id}")
 
 
+def _grid(cfg: dict, traffic: dict) -> tuple[int, int, int]:
+    """Request 0's first hop time, the hops a request, a hop's seconds."""
+    return (int(traffic["start_frac"] * cfg["graph"]["t_span"]),
+            int(traffic["hops_per_request"]), int(cfg["hop_s"]))
+
+
 def hop_times(cfg: dict, traffic: dict, k: int) -> list[int]:
     """Hop times of request ``k`` (negative k: warm-up requests, which
     end where request 0 starts): consecutive hops, ascending."""
-    h, jump = int(traffic["hops_per_request"]), int(cfg["hop_s"])
-    t0 = int(traffic["start_frac"] * cfg["graph"]["t_span"])
+    t0, h, jump = _grid(cfg, traffic)
     times = [t0 + (k * h + j) * jump for j in range(h)]
     if times[-1] > cfg["graph"]["t_span"]:
-        raise ValueError(
+        raise ScheduleUsedUp(
             f"request {k} would ask for T={times[-1]}, past the end of the "
             f"log's span {cfg['graph']['t_span']}: the traffic file's "
             "schedule is used up (a faster system needs a longer one)")
     return times
+
+
+def schedule_requests(cfg: dict, traffic: dict):
+    """How many requests (k = 0, 1, ...) the traffic file's schedule
+    holds on this configuration before ``hop_times`` raises; None for
+    traffic that has no schedule (a subscription)."""
+    if "hops_per_request" not in traffic:
+        return None
+    t0, h, jump = _grid(cfg, traffic)
+    hops = (int(cfg["graph"]["t_span"]) - t0) // jump + 1   # hop times <= span
+    return max(hops // h, 0)
 
 
 def request_body(cfg: dict, traffic: dict, k: int) -> dict:

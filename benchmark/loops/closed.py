@@ -23,11 +23,13 @@ class Loop:
 
     def request(self, k: int, deadline=None) -> dict:
         """Request ``k``, POST to the read that sees it ended. Past
-        ``deadline`` the job is killed and waited for: ``t_done`` None."""
+        ``deadline`` the job is killed and waited for: ``t_done`` None.
+        Raises ``client.ScheduleUsedUp``, with nothing posted, where the
+        schedule holds no request ``k``."""
         run = self.run
         t0 = time.perf_counter()
-        job_id = run.rest.post(run.traffic["endpoint"], client.request_body(
-            run.cfg, run.traffic, k))["jobID"]
+        body = client.request_body(run.cfg, run.traffic, k)
+        job_id = run.rest.post(run.traffic["endpoint"], body)["jobID"]
         job, cut = run.rt.manager.get(job_id), False
         while True:
             job.wait(None if deadline is None or cut
@@ -62,16 +64,24 @@ class Loop:
     def window(self):
         """Issue requests until the window closes; only requests that
         completed inside it count. The one in flight at the close is
-        killed and waited for."""
+        killed and waited for. A schedule that holds no request ``k``
+        closes the window too, before anything is posted: the rate runs
+        to the last completion, so a faster system is measured over the
+        same requests in less time."""
         run, rec = self.run, self.run.rec
         t_w = rec["t_window"] = time.perf_counter()
         deadline = t_w + run.args.seconds
         done, traced, attempted, failed, k = [], [], 0, 0, 0
         trace_left = int(run.traffic.get("trace_requests", 1))
+        rec["schedule_used_up"] = False
         while time.perf_counter() < deadline:
             if run.args.trace and k == 1:          # request 0 runs untraced
                 run.trace_start()
-            req = self.request(k, deadline)
+            try:
+                req = self.request(k, deadline)
+            except client.ScheduleUsedUp:      # nothing is in flight
+                rec["schedule_used_up"] = True
+                break
             k += 1
             if run.tracing:
                 traced += [req] if req["ok"] else []
@@ -117,7 +127,11 @@ class Loop:
 
     def work(self) -> dict:
         done = self.done()
-        return {"requests_completed": len(done),
+        run = self.run
+        return {"schedule_requests": client.schedule_requests(
+                    run.cfg, run.traffic),
+                "schedule_used_up": run.rec["schedule_used_up"],
+                "requests_completed": len(done),
                 "views_completed": sum(r["views"] for r in done),
                 "result_reads": self.reads, "poll_period_ms": None,
                 "request_seconds": [round(r["latency_s"], 4)

@@ -153,7 +153,10 @@ class Loop:
 
     def collect(self):
         rec, doc = self.run.rec, self.doc
-        every = self.run.rest.spans(doc["traceID"])
+        # a job has no trace where the recorder is off (RTPU_TRACE=0):
+        # then no span is read, and every span metric is left out
+        every = self.run.rest.spans(doc["traceID"]) \
+            if doc.get("traceID") else []
         want = {int(e["row"]["time"]) for e in rec["epochs"]}
         ep = [s for s in every if s["name"] == "live.epoch"
               and int(s["args"].get("time", -1)) in want]
@@ -190,9 +193,21 @@ class Loop:
         tt, tk, ts, td = (c[:self.tail.sent] for c in self.cols)
         return tt, tk, ts, np.maximum(td, 0)
 
+    def span_medians(self) -> dict:
+        """Median seconds of each span name the window's epochs wrote:
+        where a run's level comes from, on the work line of every run."""
+        by_name: dict = {}
+        for s in self.run.rec.get("spans") or []:
+            if "dur" in s:                      # an instant has none
+                by_name.setdefault(s["name"], []).append(s["dur"] / 1e6)
+        return {n: round(layers.quantile(v, 50), 4)
+                for n, v in sorted(by_name.items())
+                if len(v) >= len(self.done()) // 2}
+
     def work(self) -> dict:
         done = self.done()
         return {"epochs_completed": len(done),
+                "span_median_seconds": self.span_medians(),
                 "views_completed": len(done),
                 "epoch_modes": [e.get("mode") for e in done],
                 "result_reads": self.reads,

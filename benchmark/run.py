@@ -14,7 +14,11 @@ sample of the served rows against the plain numpy reference
 stdout is the result object of the builder's contract. ``--trace 0``
 reports the cell's end-to-end metrics, ``--trace 1`` its per-layer
 metrics (a profiler trace is taken over whole requests or epochs inside
-the window).
+the window). A window ends at ``--seconds`` (the request in flight is
+killed and not counted) or, in a closed loop, where the traffic file's
+schedule holds no further request (``schedule_used_up`` on the work
+line; nothing is in flight): either way only what completed counts, and
+a rate runs to the last completion.
 
 Without a TPU (or with fewer chips than the cell asks for) it exits
 non-zero and prints no result. ``--rehearsal`` runs a tiny size on the
@@ -72,14 +76,19 @@ from benchmark import (BenchFailure, algorithms, client, gen,  # noqa: E402
                        layers, loops, reference, xplane)
 
 #: the host spans a device idle gap is named by (the innermost one that
-#: covers it, ``xplane.gaps_by_span``); no metric reads the names
+#: covers it, ``xplane.gaps_by_span``); no metric reads the names. From
+#: ``fold.seed`` on: the stages the program writes under ``hop.fold``,
+#: ``engine.build`` and ``comm.exchange``.
 HOST_SPANS = ("rest.request", "job", "sweep.columnar", "hop.fold",
               "hop.ship", "hop.compute", "ship.stage", "ship.wire",
               "superstep.block", "snapshot.fold", "bsp.dispatch",
               "live.epoch", "comm.exchange", "xla.compile", "fold.stall",
               "ingest.append", "engine.build", "engine.layout",
               "comm.block_wait", "job.emit", "job.publish",
-              "fold.fingerprint")
+              "fold.fingerprint",
+              "fold.seed", "fold.advance", "fold.payload", "fold.checkpoint",
+              "index.ids", "index.pairs", "index.tables", "index.fork",
+              "index.lookup", "index.triangles", "comm.put")
 
 
 def load_json(*parts):
@@ -140,7 +149,9 @@ class Run:
         self.rec: dict = {"setup": {}}
         self.algo = algorithms.load(self.cfg["algorithm"]["module"])
         self.loop = loops.load(self.traffic["loop"])(self)
-        self.trace_dir = os.path.join(ROOT, ".bench_trace")
+        # a traced run's profile: a directory of this process's own, so
+        # two traced runs of one checkout never empty each other's
+        self.trace_dir = os.path.join(ROOT, ".bench_trace", str(os.getpid()))
 
     def say(self, phase, **kw):
         say(self.label, phase, **kw)
@@ -222,7 +233,6 @@ class Run:
     def trace_start(self):
         import jax
 
-        shutil.rmtree(self.trace_dir, ignore_errors=True)
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         opts.host_tracer_level = 2
@@ -260,11 +270,9 @@ class Run:
             self.say("trace_not_reduced", trace_bytes=os.path.getsize(path),
                      traced_s=round(tr["window_s"], 3),
                      traced_items=len(tr["items"]), **least)
-            shutil.rmtree(self.trace_dir, ignore_errors=True)
             return
         self.rec["xplane"] = {
             **xplane.reduce_trace(path, HOST_SPANS, tr["window_s"]), **least}
-        shutil.rmtree(self.trace_dir, ignore_errors=True)
 
     # -- after the window ------------------------------------------------------
 
@@ -276,6 +284,13 @@ class Run:
         rec["window_compiles"] = after["compiles"] \
             - compiles_at_setup["compiles"]
         self.loop.collect()
+        ring = self.rest.get("/statusz").get("trace") or {}
+        if ring.get("dropped", 0) > 0:
+            raise BenchFailure(
+                f"the flight recorder's ring wrapped: /statusz trace.dropped "
+                f"is {ring['dropped']} of {ring.get('recorded')} events "
+                f"(ring_size {ring.get('ring_size')}), so spans of the "
+                "window are lost and every span metric would read low")
         rec["shapes"] = shapes_of(self.rest.get("/costz")["kernels"],
                                   self.route_kernels())
         mem = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
@@ -371,12 +386,18 @@ class Run:
         return {"ok": ok, "rows": out, "limits": limits,
                 "reference_s": time.perf_counter() - t0}
 
-    def stop(self, keep_trace: bool = False):
+    def stop(self):
         self.loop.stop()
         if self.rt is not None:
             self.rt.stop()
-        if not keep_trace:
-            shutil.rmtree(self.trace_dir, ignore_errors=True)
+
+    def drop_trace(self):
+        """The profile goes with the run that took it, however it ends."""
+        shutil.rmtree(self.trace_dir, ignore_errors=True)
+        try:
+            os.rmdir(os.path.dirname(self.trace_dir))   # if no other run's
+        except OSError:
+            pass
 
 
 def shapes_of(costz_kernels: list, want: dict) -> dict:
@@ -447,6 +468,16 @@ def run_cell(args, *, require_chip: bool = True, tiny: bool = False) -> int:
         raise BenchFailure("native C++ fold kernels unavailable (g++ build "
                            "failed): the numpy fallback is another program")
     run = Run(args, loaded, label, device)
+    try:
+        return measure(run, loaded, require_chip, t_setup)
+    finally:
+        run.drop_trace()
+
+
+def measure(run: Run, loaded: dict, require_chip: bool,
+            t_setup: float) -> int:
+    """Set-up, the window, the check and the result line of one run."""
+    args, device = run.args, run.device
     peaks = load_json(HERE, "peaks.json")["by_device_kind"]
     if device["platform"] == "tpu":
         if device["kind"] not in peaks:
@@ -467,7 +498,7 @@ def run_cell(args, *, require_chip: bool = True, tiny: bool = False) -> int:
         run.collect(at_setup)
         routes_bad = run.route_failures()
     finally:
-        run.stop(keep_trace=True)
+        run.stop()
     rec = run.rec
     done = run.loop.done()
     if len(done) < 2:
@@ -520,6 +551,19 @@ def run_cell(args, *, require_chip: bool = True, tiny: bool = False) -> int:
                                "idle_gaps": x["idle_gaps"]}
     if args.rehearsal:
         result["rehearsal"] = "cpu: a plumbing check, never a result"
+    # each number `correct` compared, the worst over the rows, beside its
+    # limit: last on standard error and last in the result's line
+    rows = chk["rows"]
+    result["compared"] = {
+        **{k: {"value": max((r[k] for r in rows if k in r), default=None),
+               "limit": lim} for k, lim in chk["limits"].items()},
+        "steps": {"value": sorted({r["steps"] for r in rows}),
+                  "limit": run.cfg["algorithm"]["iterations"]},
+        "requests_failed": {"value": rec["failed"], "limit": 0},
+        "route_failures": {"value": len(routes_bad), "limit": 0}}
+    for name, c in result["compared"].items():
+        sys.stderr.write(f"compared {name} {c['value']} limit {c['limit']}\n")
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

@@ -4,11 +4,14 @@
     python3 benchmark/tools/spread.py chiprun_out/live
 
 reads ``<prefix>_A_<seed>.out`` and ``<prefix>_B_<seed>.out`` (what
-``two_sets.sh`` wrote), and prints for every metric each set's median
+``sets.sh`` wrote), and prints for every metric each set's median
 and spread (inter-quartile distance over the median), the wider of the
-two, the bound that would follow (five times it, never under 1 %), how
-far the second set's median lies from the first's, and whether every
-run was correct.
+two, the bound that would follow (five times it, never under 1 %), the
+check's own two statistics (``tight``: each set's run farthest from its
+median left out, the two sets' mean — a bound under twice it is too
+tight; ``loose``: the spread of all the runs — a bound over eight times
+it is too loose), how far the second set's median lies from the
+first's, and whether every run was correct.
 """
 
 import glob
@@ -33,6 +36,12 @@ def last_line(path):
     return out if "metrics" in out else None
 
 
+def without_farthest(values):
+    med = statistics.median(values)
+    far = max(range(len(values)), key=lambda i: abs(values[i] - med))
+    return [v for i, v in enumerate(values) if i != far]
+
+
 def main(prefix: str) -> int:
     sets = {}
     for s in "AB":
@@ -55,6 +64,9 @@ def main(prefix: str) -> int:
             widest = max(row["spread_A"], row["spread_B"])
             row["widest"] = widest
             row["bound_5x"] = round(max(0.01, 5 * widest), 4)
+            row["tight"] = round(statistics.mean(
+                spread(without_farthest(v)) for v in vals.values()), 5)
+            row["loose"] = round(spread(vals["A"] + vals["B"]), 5)
             row["median_B_over_A"] = round(
                 row["median_B"] / row["median_A"] - 1.0, 5)
         print(json.dumps(row))

@@ -28,14 +28,17 @@ def _cli(*argv, timeout=240):
 @pytest.mark.parametrize("trace", [0, 1])
 @pytest.mark.parametrize("cell", CELLS)
 def test_rehearsal_runs_every_cell_and_never_passes(cell, trace):
-    p = _cli("--workload", cell, "--seed", str(2**31 + 11), "--seconds", "4",
+    chips = next(w["chips"] for w in BENCH["workloads"] if w["name"] == cell)
+    # two requests must end inside the window with every worker of the
+    # test run busy: the mesh's four virtual devices take the longest
+    p = _cli("--workload", cell, "--seed", str(2**31 + 11),
+             "--seconds", "10" if chips == 4 else "4",
              "--trace", str(trace), "--rehearsal")
     assert p.returncode == 0, p.stderr[-2000:]
     lines = p.stdout.strip().splitlines()
     out = json.loads(lines[-1])
     assert set(out) >= {"correct", "attempted", "failed", "metrics", "device"}
     assert out["correct"] is False and "rehearsal" in out
-    chips = next(w["chips"] for w in BENCH["workloads"] if w["name"] == cell)
     assert out["device"]["platform"] == "cpu"
     assert out["device"]["count"] == chips
     assert out["attempted"] >= 2 and out["failed"] == 0
@@ -51,6 +54,10 @@ def test_rehearsal_runs_every_cell_and_never_passes(cell, trace):
         assert not any("device_idle" in n or "roofline" in n
                        for n in out["metrics"])
         assert "busy_s" not in out["device"]
+        # the fold's stage spans are written in every Range cell listed
+        for name in ("range.fold_seed_share", "range.fold_advance_share",
+                     "range.fold_payload_share"):
+            assert (name in known) == (name in out["metrics"]), name
         traced = next(json.loads(ln) for ln in lines
                       if '"trace_not_reduced"' in ln)
         assert traced["least_bytes"] > 0 < traced["traced_views"]
@@ -60,6 +67,11 @@ def test_rehearsal_runs_every_cell_and_never_passes(cell, trace):
     # the cell's own configuration says how many supersteps a row takes
     alg = run.load_cell(cell)["config"]["algorithm"]
     assert work["supersteps"] == [alg["iterations"]] and work["failed"] == 0
+    if "epochs_completed" in work:      # a subscription: where a run's
+        med = work["span_median_seconds"]       # level comes from
+        assert med["live.epoch"] >= med["engine.build"] > 0
+    else:
+        assert work["schedule_used_up"] is False
     summary = next(ph for ph in phases if ph["phase"] == "check_summary")
     assert summary["ok"] and summary["rows_compared"] >= 3
     assert not summary["route_failures"]

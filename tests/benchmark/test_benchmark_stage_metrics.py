@@ -26,7 +26,8 @@ ROOT = run.ROOT
 BENCH = rules.load_bench(ROOT)
 
 RANGE = ["twitter_wpr.range_windows", "twitter_wpr_big.range_windows",
-         "graph500_cdlp.range_communities"]
+         "graph500_cdlp.range_communities", "graph500_lcc.range_clustering",
+         "reddit_sgc.range_propagation"]
 LIVE = ["twitter_wpr.live_tail"]
 MESH = ["twitter_wpr_x4.range_windows"]
 
@@ -88,7 +89,7 @@ def test_stage_metric_is_one_span_share_file_in_its_layer(name):
     assert set(spec) == {"reducer", "span", "what"}
     assert (spec["reducer"], spec["span"]) == ("span_share", span)
     assert span in spec["what"]
-    if name.startswith("range.fold_"):
+    if name.startswith(("range.fold_", "mesh_range.fold_")):
         # worker seconds over the client's wall: the file says so
         assert "worker seconds" in spec["what"]
     with open(f"{ROOT}/PERF.md") as f:
@@ -117,15 +118,12 @@ def test_stage_metric_reduces_a_hand_made_record(name):
 
 
 @pytest.fixture(scope="module")
-def rehearsed(tmp_path_factory):
-    """One ``--trace 1`` rehearsal a cell, run when first asked for —
-    from a tree of links to the checkout: ``run.py`` keeps a traced run's
-    profile under ``<its root>/.bench_trace`` and empties that first, and
-    another worker runs ``test_benchmark_run.py``'s traced rehearsals of
-    the checkout itself at the same time."""
-    root = tmp_path_factory.mktemp("checkout")
-    for name in ("BENCHMARK.json", "benchmark", "raphtory_tpu"):
-        os.symlink(os.path.join(ROOT, name), root / name)
+def rehearsed():
+    """One ``--trace 1`` rehearsal a cell, run when first asked for, from
+    the checkout itself: ``run.py`` keeps a traced run's profile in a
+    directory of the process's own (``.bench_trace/<pid>``), so another
+    worker's traced rehearsals (``test_benchmark_run.py``) do not meet
+    these."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     lines = {}
@@ -133,10 +131,11 @@ def rehearsed(tmp_path_factory):
     def line(cell):
         if cell not in lines:
             p = subprocess.run(
-                [sys.executable, str(root / "benchmark" / "run.py"),
+                [sys.executable, os.path.join(run.HERE, "run.py"),
                  "--workload", cell, "--seed", str(2**31 + 37),
-                 "--seconds", "4", "--trace", "1", "--rehearsal"],
-                cwd=root, env=env, capture_output=True, text=True,
+                 "--seconds", "10" if cell in MESH else "4",
+                 "--trace", "1", "--rehearsal"],
+                cwd=ROOT, env=env, capture_output=True, text=True,
                 timeout=240)
             assert p.returncode == 0, p.stderr[-2000:]
             lines[cell] = json.loads(p.stdout.strip().splitlines()[-1])
@@ -157,8 +156,8 @@ def test_stage_metric_comes_out_a_number_on_a_traced_rehearsal(rehearsed,
 @pytest.mark.parametrize("cell", REHEARSED)
 def test_stages_stay_inside_what_times_them_from_outside(rehearsed, cell):
     """The shares a parent span's stages add up to do not pass the share
-    the parent is read by (job-thread spans only: a Range's fold units
-    run on workers, beside the wall)."""
+    the parent is read by (job-thread spans only: a Range's fold units,
+    one chip or mesh, run on workers, beside the wall)."""
     m = {k: v["value"] for k, v in rehearsed(cell)["metrics"].items()}
     if cell in LIVE:
         assert m["live.fold_advance_share"] + m["live.fold_payload_share"] \
@@ -168,9 +167,10 @@ def test_stages_stay_inside_what_times_them_from_outside(rehearsed, cell):
     elif cell in MESH:
         assert m["mesh_range.table_put_share"] \
             <= m["mesh_range.comm_exchange_share"]
-        assert m["mesh_range.fold_advance_share"] \
-            + m["mesh_range.fold_payload_share"] \
-            <= m["mesh_range.fold_share"] * 1.05
+        # since PR 38 the mesh route's fold units are worker seconds
+        # too: the one-chip arm's rule, each alone under the wall
+        assert max(m["mesh_range.fold_advance_share"],
+                   m["mesh_range.fold_payload_share"]) < 100.0
     else:
         # worker seconds beside the wall, so no sum is bounded by the
         # job thread's ``range.fold_share``; each alone is under the wall
