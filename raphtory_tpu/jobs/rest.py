@@ -176,11 +176,13 @@ def _compile_cache_sizes() -> dict:
     from ..engine import bsp as _bsp
     from ..engine import device_sweep as _ds
     from ..engine import hopbatch as _hb
+    from ..parallel import columns as _cols
 
     for mod, names in ((_bsp, ("_compiled_runner",)),
                        (_ds, ("_compiled_run", "_compiled_apply")),
                        (_hb, ("_compiled", "_compiled_delta", "_compiled_cc",
-                              "_compiled_bfs"))):
+                              "_compiled_bfs")),
+                       (_cols, ("_compiled_columns",))):
         short = mod.__name__.rsplit(".", 1)[-1]
         for nm in names:
             fn = getattr(mod, nm, None)

@@ -11,6 +11,14 @@ replicated-table broadcast. This is the temporal analogue of batch data
 parallelism, complementing ``parallel/sharded.py``'s vertex sharding
 (which exists for graphs too big for one chip's HBM).
 
+The program is built once a process and key: ``_compiled_columns`` is an
+``lru_cache``d factory, as every one-chip engine's runner is, keyed on
+the kind, the devices in mesh order and every static the traced block
+reads. A key's first request traces, lowers and loads it (the ``xla.*``
+events under that request's ``comm.exchange``); every later request of
+the key calls the same jit object (``/statusz``
+``compile_caches.columns._compiled_columns``: ``misses`` / ``hits``).
+
 Reference contrast: the reference cannot parallelise ACROSS the hops of a
 Range query at all — each hop is a fresh sequential actor handshake
 (``RangeAnalysisTask.scala:18-35``); here hops*windows spread over the
@@ -19,6 +27,7 @@ whole mesh.
 
 from __future__ import annotations
 
+import functools
 import time as _time
 
 import numpy as np
@@ -30,8 +39,54 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..engine.hopbatch import (_bfs_columns, _cc_columns, _column_layout,
                                _column_masks, _pagerank_columns, _seed_mask,
                                _tile_budget_bytes)
+from ..obs import ledger as _ledger
+from ..obs.trace import TRACER
+from .sharded import COLLECTIVES, _shard_map
 
 C_AXIS = "columns"
+
+
+@functools.lru_cache(maxsize=64)
+def _compiled_columns(kind: str, devices: tuple, n_pad: int, tdt: str,
+                      damping: float, tol: float, max_steps: int,
+                      directed: bool, n_extra: int, tile_budget: int):
+    """``(mesh, runner)`` of the column-sharded program, built once a key.
+
+    The key holds every value the traced ``block`` reads and the mesh it
+    is mapped over — ``devices`` in mesh order, the time dtype's name,
+    ``n_extra`` 0 / 1 / 2 (none, the BFS seed mask, seed mask + weight
+    columns) and the resolved ``RTPU_TILE_BUDGET_MB`` — so a process
+    traces, lowers and loads the program once per key and argument
+    shapes (``m_pad``, ``C`` and ``H`` are shapes: ``jax.jit`` keys on
+    them itself); every later request calls the same jit object."""
+    if kind not in ("pagerank", "cc", "bfs"):
+        raise ValueError(f"unknown columnar kind {kind!r}")
+    tdt = jnp.dtype(tdt)
+
+    def block(e_src, e_dst, el, ea, vl, va, hoc, tc, wc, *extra):
+        me, mv = _column_masks(tdt, el, ea, vl, va, hoc, tc, wc)
+        if kind == "pagerank":
+            out, steps = _pagerank_columns(me, mv, e_src, e_dst, n_pad,
+                                           damping, tol, max_steps,
+                                           tile_budget=tile_budget)
+        elif kind == "cc":
+            out, steps = _cc_columns(me, mv, e_src, e_dst, n_pad,
+                                     max_steps, tile_budget=tile_budget)
+        else:
+            ew = extra[1][hoc].T if n_extra > 1 else 1.0
+            out, steps = _bfs_columns(me, mv, e_src, e_dst, n_pad,
+                                      max_steps, directed, extra[0], ew,
+                                      tile_budget=tile_budget)
+        return out, steps[None]   # scalar -> [1] so steps concatenates
+
+    mesh = Mesh(np.asarray(devices), (C_AXIS,))
+    # the registry wrapper gives the route its kernel-table row and
+    # dispatch count; it harvests once per (kind, shapes)
+    return mesh, _ledger.instrument(f"columns.{kind}", jax.jit(_shard_map(
+        block, mesh=mesh,
+        in_specs=(P(), P(), P(), P(), P(), P(),   # tables replicate
+                  P(C_AXIS), P(C_AXIS), P(C_AXIS), *([P()] * n_extra)),
+        out_specs=(P(C_AXIS), P(C_AXIS)))))
 
 
 def run_columns_sharded(tables, e_lat, e_alive, v_lat, v_alive, hop_times,
@@ -59,57 +114,24 @@ def run_columns_sharded(tables, e_lat, e_alive, v_lat, v_alive, hop_times,
         T_col = np.concatenate([T_col, np.repeat(T_col[:1], pad)])
         w_col = np.concatenate([w_col, np.repeat(w_col[:1], pad)])
 
-    mesh = Mesh(np.asarray(devices), (C_AXIS,))
-    tdt = jnp.dtype(np.dtype(tables.tdtype).name)
     n_pad = tables.n_pad
     extra_host = []
-    extra_specs = []
     if kind == "bfs":
         extra_host.append(_seed_mask(tables, seeds))
-        extra_specs.append(P())
         if weight_cols is not None:
             extra_host.append(weight_cols)
-            extra_specs.append(P())
 
-    # resolved HERE, outside the traced block — an env read at trace time
-    # would bake a budget the cache key doesn't carry (rtpulint RT001)
-    tile_budget = _tile_budget_bytes()
-
-    def block(e_src, e_dst, el, ea, vl, va, hoc, tc, wc, *extra):
-        me, mv = _column_masks(tdt, el, ea, vl, va, hoc, tc, wc)
-        if kind == "pagerank":
-            out, steps = _pagerank_columns(me, mv, e_src, e_dst, n_pad,
-                                           float(damping), float(tol),
-                                           int(max_steps),
-                                           tile_budget=tile_budget)
-        elif kind == "cc":
-            out, steps = _cc_columns(me, mv, e_src, e_dst, n_pad,
-                                     int(max_steps),
-                                     tile_budget=tile_budget)
-        elif kind == "bfs":
-            ew = extra[1][hoc].T if len(extra) > 1 else 1.0
-            out, steps = _bfs_columns(me, mv, e_src, e_dst, n_pad,
-                                      int(max_steps), bool(directed),
-                                      extra[0], ew,
-                                      tile_budget=tile_budget)
-        else:
-            raise ValueError(f"unknown columnar kind {kind!r}")
-        return out, steps[None]   # scalar -> [1] so steps concatenates
-
-    from ..obs import ledger as _ledger
-    from ..obs.trace import TRACER
-    from .sharded import COLLECTIVES, _shard_map
-
-    # a NEW jit object on every call (not cached here): what JAX does
-    # with it — trace, lower, ask the backend or the persistent cache —
-    # shows as xla.* events in the request's trace (obs/device.py). The
-    # registry wrapper gives the route its kernel-table row and dispatch
-    # count; it harvests once per (kind, shapes), not per object.
-    shard = _ledger.instrument(f"columns.{kind}", jax.jit(_shard_map(
-        block, mesh=mesh,
-        in_specs=(P(), P(), P(), P(), P(), P(),   # tables replicate
-                  P(C_AXIS), P(C_AXIS), P(C_AXIS), *extra_specs),
-        out_specs=(P(C_AXIS), P(C_AXIS)))))
+    # the key holds every value the traced block reads, so only a key's
+    # first request builds the program (the xla.* events under its
+    # comm.exchange, obs/device.py); every later one is JAX's cached
+    # call. The budget is resolved HERE, outside the traced block: an env
+    # read at trace time would bake a budget the key doesn't carry
+    # (rtpulint RT001)
+    tdt = np.dtype(tables.tdtype).name
+    mesh, shard = _compiled_columns(
+        kind, tuple(devices), int(n_pad), tdt, float(damping), float(tol),
+        int(max_steps), bool(directed), len(extra_host),
+        _tile_budget_bytes())
 
     repl = NamedSharding(mesh, P())
     put = lambda a: jax.device_put(jnp.asarray(a), repl)

@@ -10,7 +10,8 @@ from test_stage_spans import _log, _named, _pagerank, _spans
 from test_sweep import random_log
 
 from raphtory_tpu.engine.hopbatch import HopBatchedPageRank
-from raphtory_tpu.parallel.columns import run_columns_sharded
+from raphtory_tpu.parallel.columns import (_compiled_columns,
+                                           run_columns_sharded)
 
 
 @pytest.mark.parametrize("n_dev,windows", [
@@ -38,24 +39,30 @@ def test_column_sharded_matches_single_device(n_dev, windows):
     assert int(steps1) == steps2
 
 
+def _weighted_log(rng, n=700):
+    """``n`` edge adds over 40 ids, each with a ``weight`` property."""
+    from raphtory_tpu.core.events import EventLog
+
+    src = rng.integers(0, 40, n)
+    dst = rng.integers(0, 40, n)
+    times = np.sort(rng.integers(0, 100, n))
+    log = EventLog()
+    log.append_batch(
+        times, np.full(n, 2, np.uint8), src.astype(np.int64),
+        dst.astype(np.int64),
+        props=[(i, {"weight": float(rng.uniform(0.5, 3.0))})
+               for i in range(n)])
+    return log
+
+
 @pytest.mark.parametrize("kind", ["cc", "bfs", "sssp"])
 def test_column_sharded_cc_bfs_match_single_device(kind):
-    from raphtory_tpu.core.events import EventLog
     from raphtory_tpu.engine.hopbatch import (HopBatchedBFS, HopBatchedCC,
                                               HopBatchedSSSP)
 
     rng = np.random.default_rng(7)
     if kind == "sssp":
-        n = 700
-        src = rng.integers(0, 40, n)
-        dst = rng.integers(0, 40, n)
-        times = np.sort(rng.integers(0, 100, n))
-        log = EventLog()
-        log.append_batch(
-            times, np.full(n, 2, np.uint8), src.astype(np.int64),
-            dst.astype(np.int64),
-            props=[(i, {"weight": float(rng.uniform(0.5, 3.0))})
-                   for i in range(n)])
+        log = _weighted_log(rng)
     else:
         log = random_log(rng, n_events=900, n_ids=50, t_span=100)
     hops = [20, 40, 60, 80, 99]
@@ -352,3 +359,141 @@ def test_mesh_weighted_sssp_range_job_still_folds_serially(
                     if r["time"] == vrow["time"]
                     and r["windowsize"] == vrow["windowsize"])
         assert rrow["result"] == vrow["result"]
+
+
+# ------------------------------------- the program is built once a key
+
+
+KINDS = ["pagerank", "cc", "bfs", "sssp"]     # sssp = weighted bfs
+BUILD_EVENTS = ("xla.trace", "xla.lower", "xla.backend_compile")
+
+
+def _kind_case(kind, max_steps=12, damping=0.85):
+    """``(args, kw, one, steps)``: the positional arguments and keywords
+    of a ``run_columns_sharded`` call of that kind over five hops and two
+    windows, and what the one-device ``hopbatch`` runner of the same
+    statics gives for it."""
+    from raphtory_tpu.engine.hopbatch import (HopBatchedBFS, HopBatchedCC,
+                                              HopBatchedSSSP)
+
+    rng = np.random.default_rng(11)
+    hops, windows, seeds = [20, 40, 60, 80, 99], [1000, 30], (0, 1, 2)
+    if kind == "sssp":
+        log = _weighted_log(rng)
+    else:
+        log = random_log(rng, n_events=900, n_ids=50, t_span=100)
+    if kind == "pagerank":
+        make = lambda: HopBatchedPageRank(log, damping=damping, tol=1e-7,
+                                          max_steps=max_steps)
+        kw = dict(kind="pagerank", tol=1e-7)
+    elif kind == "cc":
+        make = lambda: HopBatchedCC(log, max_steps=max_steps)
+        kw = dict(kind="cc")
+    elif kind == "bfs":
+        make = lambda: HopBatchedBFS(log, seeds, max_steps=max_steps)
+        kw = dict(kind="bfs", seeds=seeds)
+    else:
+        make = lambda: HopBatchedSSSP(log, seeds, "weight",
+                                      max_steps=max_steps)
+        kw = dict(kind="bfs", seeds=seeds)
+    kw.update(max_steps=max_steps, damping=damping)
+    one, steps = make().run(hops, windows)
+    hb = make()
+    _, cols = hb._fold_columns(hops)
+    if kind == "sssp":
+        *cols, kw["weight_cols"] = cols
+    return (hb.tables, *cols, hops, windows), kw, np.asarray(one), int(steps)
+
+
+def _same_values(kind, one, many):
+    if kind == "pagerank":
+        # another partition of the same f32 sums (see the first test)
+        np.testing.assert_allclose(one, np.asarray(many), rtol=1e-5,
+                                   atol=1e-7)
+    else:
+        np.testing.assert_array_equal(one, np.asarray(many))
+
+
+def _exchange_builds(root):
+    """The program-build events inside the one ``comm.exchange`` of the
+    trace under ``root``."""
+    from raphtory_tpu.obs.trace import TRACER
+
+    spans = [e for e in TRACER.for_trace(root.trace) if e["ph"] == "X"]
+    (xchg,) = _named(spans, "comm.exchange")
+    return [e for e in _named(spans, *BUILD_EVENTS)
+            if xchg["ts"] <= e["ts"] <= xchg["ts"] + xchg["dur"]]
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_column_program_is_built_by_a_keys_first_call_only(traced, kind):
+    from raphtory_tpu.obs.trace import TRACER
+
+    args, kw, one, steps1 = _kind_case(kind)
+    devices = jax.devices()[:4]
+    _compiled_columns.cache_clear()
+    with TRACER.span("job") as first:
+        a, steps_a = run_columns_sharded(*args, devices, **kw)
+    assert {e["name"] for e in _exchange_builds(first)} \
+        == set(BUILD_EVENTS)
+    info = _compiled_columns.cache_info()
+    assert (info.misses, info.hits, info.currsize) == (1, 0, 1)
+
+    with TRACER.span("job") as second:
+        b, steps_b = run_columns_sharded(*args, devices, **kw)
+    assert _exchange_builds(second) == []
+    info = _compiled_columns.cache_info()
+    assert (info.misses, info.hits, info.currsize) == (1, 1, 1)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))  # bitwise
+    assert steps_a == steps_b == steps1
+    _same_values(kind, one, a)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("static", ["max_steps", "damping", "devices",
+                                    "tile_budget"])
+def test_column_program_of_another_static_is_another_key(monkeypatch,
+                                                         static, kind):
+    """Every value the traced block reads is in the factory's key: a
+    request that differs in one of them builds its own program (a miss),
+    and its values are the one-device runner's of the same statics."""
+    args, kw, _, _ = _kind_case(kind)
+    devices = jax.devices()[:4]
+    _compiled_columns.cache_clear()
+    run_columns_sharded(*args, devices, **kw)
+    if static == "max_steps":
+        args, kw, one, steps1 = _kind_case(kind, max_steps=3)
+    elif static == "damping":
+        args, kw, one, steps1 = _kind_case(kind, damping=0.5)
+    else:
+        args, kw, one, steps1 = _kind_case(kind)
+        if static == "devices":
+            devices = jax.devices()[4:8]
+        else:
+            # nothing tiles at this size: the budget keys all the same
+            monkeypatch.setenv("RTPU_TILE_BUDGET_MB", "1")
+    many, steps2 = run_columns_sharded(*args, devices, **kw)
+    info = _compiled_columns.cache_info()
+    assert (info.misses, info.hits, info.currsize) == (2, 0, 2)
+    _same_values(kind, one, many)
+    assert steps2 == steps1
+
+
+def test_statusz_counts_one_build_and_one_hit_a_mesh_request(traced):
+    """``/statusz`` ``compile_caches`` lists the factory beside the
+    one-chip engines': the first served mesh Range of a key is its one
+    miss, every request after it a hit with no build in its trace."""
+    from raphtory_tpu.jobs.manager import AnalysisManager, RangeQuery
+    from raphtory_tpu.jobs.rest import _compile_cache_sizes
+    from raphtory_tpu.parallel import sharded
+
+    _compiled_columns.cache_clear()
+    mgr = AnalysisManager(_range_graph(53), mesh=sharded.make_mesh(
+        4, 1, devices=jax.devices()[:4]))
+    for n, start in enumerate((300, 400, 500)):
+        spans = _spans(mgr.submit(_pagerank(), RangeQuery(
+            start=start, end=start + 300, jump=100, windows=(1000, 300))))
+        assert _named(spans, "comm.exchange")
+        assert bool(_named(spans, *BUILD_EVENTS)) == (n == 0)
+        assert _compile_cache_sizes()["columns._compiled_columns"] == {
+            "size": 1, "misses": 1, "hits": n}

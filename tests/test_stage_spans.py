@@ -22,7 +22,8 @@ from raphtory_tpu.jobs.manager import (AnalysisManager, LiveQuery,
                                        RangeQuery)
 from raphtory_tpu.obs import trace as obs_trace
 from raphtory_tpu.obs.trace import NULL_SPAN, TRACER, Tracer
-from raphtory_tpu.parallel.columns import run_columns_sharded
+from raphtory_tpu.parallel.columns import (_compiled_columns,
+                                           run_columns_sharded)
 
 from test_sweep import random_log
 
@@ -268,6 +269,9 @@ def test_mesh_range_job_writes_the_fold_stages_where_it_folds(
 
 
 def test_column_sharded_dispatch_splits_comm_exchange(traced):
+    # the program is built by a key's first call only: make this one it,
+    # whatever ran before
+    _compiled_columns.cache_clear()
     log = _log(41, n_events=900, n_ids=50)
     hb = HopBatchedPageRank(log, tol=0, max_steps=5)
     hops = [400, 700, 999]
@@ -292,6 +296,16 @@ def test_column_sharded_dispatch_splits_comm_exchange(traced):
     # the wait for the chips stays outside the exchange
     (wait,) = _named(spans, "comm.block_wait")
     assert wait["ts"] >= xchg["ts"] + xchg["dur"] - 1.0
+    # a second call of the key finds its program: the exchange still
+    # holds the puts, and no build event
+    with TRACER.span("job") as again:
+        run_columns_sharded(hb.tables, *cols, hops, [1000, 300],
+                            jax.devices()[:4], tol=0, max_steps=5)
+    spans = [e for e in TRACER.for_trace(again.trace) if e["ph"] == "X"]
+    (xchg,) = _named(spans, "comm.exchange")
+    (put,) = _named(_children(spans, xchg), *COMM_STAGES)
+    assert _inside(xchg, put)
+    assert not [e for e in spans if e["name"].startswith("xla.")]
 
 
 # ---------------------------------------------------------- tracing off
