@@ -222,7 +222,10 @@ def prefetch_map(fold_fns, body, *, depth: int | None = None,
 _LOG_DERIVED = ("log", "include_occurrences", "pad", "track_rows",
                 "_t", "_k", "_s", "_d", "uv", "_ok", "_sd_all", "_dd_all",
                 "_t_sorted", "_preseed_asked", "_preseeded")
-#: fold-state arrays mutated IN PLACE by _advance — checkpoint/fork copy
+#: fold-state arrays mutated IN PLACE by _advance — a checkpoint copies
+#: them; a fork SHARES them read-only (``flags.writeable`` off: the one
+#: record of who owns an array) and a builder takes its own copy of what
+#: it does not own before its first in-place write (``_own_state``)
 _STATE_COPIED = ("v_lat", "v_alive", "v_first", "v_seen",
                  "e_lat", "e_alive", "e_first", "e_seen")
 #: fold-state arrays only ever REBOUND by _advance (np.insert/concatenate
@@ -239,6 +242,20 @@ _STATE_SHARED = ("dh_v", "dh_t", "_ea_rows", "_va_rows")
 #: for them — ``fork(cp)`` takes them from the builder it is called on
 _PAIR_TABLES = ("e_enc", "e_enc_dst")
 
+#: every ``SweepBuilder.fork`` of the process and the deferred copies
+#: they led to (``fork_status``): a fork that never writes never copies
+_FORK_COUNTS = {"forks": 0, "fork_copies": 0, "fork_copied_bytes": 0}
+_FORK_LOCK = threading.Lock()
+
+
+def fork_status() -> dict:
+    """``forks`` taken since start, ``fork_copies`` — the builders that
+    went on to copy the fold state they shared (a fork at its first
+    write, or a source that advanced after a fork of it) — and the
+    ``fork_copied_bytes`` those copies moved."""
+    with _FORK_LOCK:
+        return dict(_FORK_COUNTS)
+
 
 class FoldCheckpoint:
     """Immutable snapshot of a ``SweepBuilder``'s fold state at ``t_prev``
@@ -252,7 +269,9 @@ class FoldCheckpoint:
     rebind-only delete history and row lists and — only when the
     builder's pairs were not preseeded — the pair tables
     (``_PAIR_TABLES``). ``nbytes`` is the bytes of exactly those arrays:
-    the fold cache charges what holding the checkpoint keeps alive."""
+    the fold cache charges what holding the checkpoint keeps alive.
+    ``fork(cp)`` copies none of them: the forks share the checkpoint's
+    arrays, made read-only at the first fork, until each writes."""
 
     __slots__ = ("t_prev", "state", "config", "nbytes")
 
@@ -447,7 +466,17 @@ class SweepBuilder:
         folds its own hop window.
         Equivalence holds because the fold state at T is a function of
         (log, T) alone, not of the hop sequence that reached it (the
-        ``view_at ≡ build_view`` contract, tested per hop batching)."""
+        ``view_at ≡ build_view`` contract, tested per hop batching).
+
+        The copy is taken when it is first needed, not here: the fork
+        binds the source's ``_STATE_COPIED`` arrays (the checkpoint's, or
+        this builder's own) and the source's are made read-only, so
+        neither side owns them any more and each copies what it still
+        borrows before its first in-place write (``_own_state``) — a
+        fork that never advances never copies. Marking the source is
+        idempotent: forks may be taken from one source on several
+        threads at once, as long as the source does not advance
+        meanwhile."""
         if cp is not None and cp.config != self._config():
             raise ValueError(
                 "checkpoint was taken from an incompatible SweepBuilder "
@@ -459,8 +488,9 @@ class SweepBuilder:
             setattr(sw, k, getattr(self, k))
         src = cp.state if cp is not None else None
         for k in _STATE_COPIED:
-            setattr(sw, k, (src[k] if src is not None
-                            else getattr(self, k)).copy())
+            a = src[k] if src is not None else getattr(self, k)
+            a.flags.writeable = False
+            setattr(sw, k, a)
         for k in _STATE_SHARED + _PAIR_TABLES:
             # rebind-only arrays: the fork's first rebind leaves the
             # source (live builder or cached checkpoint) untouched. A
@@ -469,12 +499,36 @@ class SweepBuilder:
                     else getattr(self, k))
         sw.t_prev = cp.t_prev if cp is not None else self.t_prev
         sw.last_delta = None
+        with _FORK_LOCK:
+            _FORK_COUNTS["forks"] += 1
         return sw
 
     def fork_nbytes(self) -> int:
-        """Bytes a ``fork`` of this builder copies: the fold-state arrays
-        ``_advance`` mutates in place (18 B an id + 18 B a pair)."""
+        """Bytes of the fold-state arrays ``_advance`` mutates in place
+        (18 B an id + 18 B a pair): what a ``fork`` of this builder
+        shares with it, and what the fork's first write copies."""
         return int(sum(getattr(self, k).nbytes for k in _STATE_COPIED))
+
+    def _own_state(self) -> None:
+        """Take this builder's own copy of every ``_STATE_COPIED`` array
+        it still shares with a fork, a source or a checkpoint (the
+        read-only ones) — before an in-place write. The copy is a
+        ``fold.seed`` span with ``deferred=true`` on the thread that pays
+        it: the seconds a fork's copy cost when ``fork`` made it."""
+        borrowed = [k for k in _STATE_COPIED
+                    if not getattr(self, k).flags.writeable]
+        if not borrowed:
+            return
+        with _span("fold.seed", deferred=True) as sp:
+            nbytes = 0
+            for k in borrowed:
+                own = getattr(self, k).copy()
+                setattr(self, k, own)
+                nbytes += own.nbytes
+            sp.set(nbytes=nbytes)
+        with _FORK_LOCK:
+            _FORK_COUNTS["fork_copies"] += 1
+            _FORK_COUNTS["fork_copied_bytes"] += nbytes
 
     # ---- incremental re-pin (live epoch serving) ----
 
@@ -721,22 +775,30 @@ class SweepBuilder:
         """Fold the log's rows in ``(t_prev, time]`` into the running
         state — under one ``fold.advance`` span whoever calls (a View's, a
         Range unit's, a Live epoch's or the mesh route's fold; the bulk
-        advance to a checkpoint boundary too)."""
+        advance to a checkpoint boundary too). A builder that still
+        shares fold state copies it first (``_own_state``: a span of its
+        own BEFORE this one, never inside it), unless no row is due."""
+        rows = self._rows_through(time)
+        if len(rows):
+            self._own_state()
         with _span("fold.advance", time=int(time)) as sp:
-            sp.set(rows=self._fold_rows(time))
+            sp.set(rows=self._fold_rows(time, rows))
 
-    def _fold_rows(self, time: int) -> int:
-        """``_advance``'s work; returns how many log rows it folded."""
-        t_prev = self.t_prev if self.t_prev is not None else np.iinfo(np.int64).min
+    def _rows_through(self, time: int) -> np.ndarray:
+        """The log rows with time in ``(t_prev, time]``, ascending."""
         if self._t_sorted:
-            lo = 0 if t_prev == np.iinfo(np.int64).min \
-                else int(np.searchsorted(self._t, t_prev, side="right"))
+            lo = 0 if self.t_prev is None \
+                else int(np.searchsorted(self._t, self.t_prev, side="right"))
             hi = int(np.searchsorted(self._t, time, side="right"))
-            rows = np.arange(lo, hi)
-        else:
-            sel = (self._t <= time) if t_prev == np.iinfo(np.int64).min \
-                else ((self._t > t_prev) & (self._t <= time))
-            rows = np.flatnonzero(sel)
+            return np.arange(lo, hi)
+        sel = self._t <= time
+        if self.t_prev is not None:
+            sel &= self._t > self.t_prev
+        return np.flatnonzero(sel)
+
+    def _fold_rows(self, time: int, rows: np.ndarray) -> int:
+        """``_advance``'s work on ``rows`` (``_rows_through(time)``);
+        returns how many log rows it folded."""
         self.t_prev = time
         if len(rows) == 0:
             self.last_delta = _EMPTY_DELTA

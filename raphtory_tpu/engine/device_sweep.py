@@ -47,7 +47,7 @@ import numpy as np
 
 from ..core.events import EDGE_ADD, EDGE_DELETE, EventLog
 from ..core.snapshot import INT64_MIN, _pad_bucket
-from ..core.sweep import _ENC_MASK, _ENC_SHIFT, SweepBuilder
+from ..core.sweep import _ENC_MASK, _ENC_SHIFT, SweepBuilder, fork_status
 from ..native import lib as _native
 from ..obs import ledger as _ledger
 from ..obs.trace import TRACER
@@ -356,8 +356,11 @@ def log_index(log: EventLog):
                 _LOG_INDEX_COUNTS["grown"] += 1
                 status = "extended"
             _LOG_INDEX_COUNTS[status] += 1
-            with TRACER.span("index.fork",
-                             nbytes=idx.prototype.fork_nbytes()):
+            # the fork shares the prototype's fold state and copies it
+            # at its first write, if it ever writes (a ``fold.seed`` span
+            # with ``deferred=true``): nothing is copied here
+            with TRACER.span("index.fork", nbytes=0,
+                             shared=idx.prototype.fork_nbytes()):
                 return idx.prototype.fork(), idx.tables, status
 
 
@@ -465,13 +468,18 @@ def log_index_status() -> dict:
     """The ``log_index`` block of ``/statusz``: lookups by outcome since
     start (``grown``: the lookups among ``extends`` whose suffix brought
     new ids or pairs, so the index's dictionaries grew where they were
-    rebuilt, a miss, until PR 45), and the host bytes the live indexes
-    hold."""
+    rebuilt, a miss, until PR 45), the host bytes the live indexes
+    hold, and what became of the forks (``core/sweep.fork_status``):
+    every ``SweepBuilder.fork`` of the process — an engine's from the
+    index, a fold unit's from a checkpoint or a live builder — shares
+    the fold state it starts from, and ``fork_copies`` of the ``forks``
+    went on to copy it (``fork_copied_bytes``) because they wrote."""
     with _LOG_INDEX_LOCK:
         c = _LOG_INDEX_COUNTS
         return {"hits": c["hit"], "extends": c["extended"],
                 "grown": c["grown"], "misses": c["miss"],
-                "bytes": sum(i.nbytes for i in _LOG_INDEXES.values())}
+                "bytes": sum(i.nbytes for i in _LOG_INDEXES.values()),
+                **fork_status()}
 
 
 def normalize_windows(windows) -> list[int]:

@@ -1582,8 +1582,10 @@ class _HopBatched:
         is ahead of the live builder, else the live state, then one bulk
         advance — recorded back as a checkpoint for the next request.
         The lookup and the fork are one ``fold.seed`` span (``nbytes``:
-        what the fork copied), the advance the ``fold.checkpoint`` beside
-        it."""
+        what the fork copied, 0 — it shares the ``shared`` bytes of fold
+        state it starts from and copies them at its first write, a
+        second ``fold.seed`` span with ``deferred=true``), the advance
+        the ``fold.checkpoint`` beside it."""
         with TRACER.span("fold.seed") as ssp:
             cp = cache.nearest_checkpoint(fp, cfg, boundary) \
                 if cache is not None and boundary is not None else None
@@ -1595,7 +1597,7 @@ class _HopBatched:
                 # "start": neither the cache nor the live builder holds a
                 # state, so this unit advances from the log's first event
                 seed = "start" if sw.t_prev is None else "live"
-            ssp.set(seed=seed, nbytes=sw.fork_nbytes())
+            ssp.set(seed=seed, nbytes=0, shared=sw.fork_nbytes())
         if boundary is None:
             return sw
         if sw.t_prev is None or sw.t_prev < boundary:

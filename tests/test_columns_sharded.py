@@ -6,7 +6,8 @@ import pytest
 
 import jax
 
-from test_stage_spans import _log, _named, _pagerank, _spans
+from test_stage_spans import (_log, _named, _pagerank, _spans,
+                              _unit_seeds)
 from test_sweep import random_log
 
 from raphtory_tpu.engine.hopbatch import HopBatchedPageRank
@@ -231,7 +232,7 @@ def test_second_mesh_range_job_folds_from_the_first_ones_checkpoint(
     windows = (1000, 300)
     first = _spans(mgr.submit(_pagerank(), RangeQuery(
         start=300, end=600, jump=100, windows=windows)))
-    assert {s["args"]["seed"] for s in _named(first, "fold.seed")} \
+    assert {s["args"]["seed"] for s in _unit_seeds(first)} \
         == {"start"}                        # nothing cached yet
     q = RangeQuery(start=600, end=900, jump=100, windows=windows)
     job = mgr.submit(_pagerank(), q)
@@ -242,9 +243,11 @@ def test_second_mesh_range_job_folds_from_the_first_ones_checkpoint(
     assert {f["args"]["mode"] for f in folds} == {"parallel"}
     assert all(f["tid"] != root["tid"] for f in folds)
     assert sum(f["args"]["hops"] for f in folds) == 4
-    seeds = _named(spans, "fold.seed")
+    seeds = _unit_seeds(spans)
     assert len(seeds) == len(folds)
     assert {s["args"]["seed"] for s in seeds} == {"checkpoint"}
+    # each unit copies the checkpoint it shares once, when it first folds
+    assert len(_named(spans, "fold.seed")) == 2 * len(folds)
     cps = _named(spans, "fold.checkpoint")
     assert cps and all(c["args"]["seed"] == "checkpoint"
                        and c["args"]["seeded_from"] >= 300
@@ -351,7 +354,11 @@ def test_mesh_weighted_sssp_range_job_still_folds_serially(
     (fold,) = _named(spans, "hop.fold")
     assert fold["tid"] == root["tid"] and fold["args"]["hops"] == 3
     assert fold["args"]["engine"] == "HopBatchedSSSP"
-    assert not _named(spans, "fold.seed")
+    # no unit was seeded: the one ``fold.seed`` is the engine's builder
+    # taking its own copy of the index's fold state before its first write
+    assert not _unit_seeds(spans)
+    (own,) = _named(spans, "fold.seed")
+    assert own["args"]["deferred"] is True and own["tid"] == root["tid"]
     assert _named(spans, "comm.exchange")
     assert len(job.results) == len(ref.results) == 3 * 2
     for vrow in ref.results:

@@ -321,7 +321,8 @@ def test_statusz_log_index_counts_match():
     mgr = AnalysisManager(TemporalGraph(log))
     q = RangeQuery(start=300, end=600, jump=100, windows=(500,))
     was = _statusz(mgr)["log_index"]
-    assert set(was) == {"hits", "extends", "grown", "misses", "bytes"}
+    assert set(was) == {"hits", "extends", "grown", "misses", "bytes",
+                        "forks", "fork_copies", "fork_copied_bytes"}
     _range_rows(mgr, q)                 # miss
     _range_rows(mgr, q)                 # hit
     log.add_vertex(900, int(log.column("src")[0]))

@@ -80,6 +80,31 @@ def _descends(spans, child, ancestor):
     return child is not None
 
 
+def _unit_seeds(spans):
+    """The ``fold.seed`` spans ``hopbatch._seed_fork`` writes — a fold
+    unit's checkpoint lookup and its fork — without the deferred copies
+    that share the name."""
+    return [s for s in _named(spans, "fold.seed")
+            if "deferred" not in s["args"]]
+
+
+def _deferred_seeds(spans, fold):
+    """The ``fold.seed deferred=true`` spans under ``fold``: the copies of
+    shared fold state its builder took before a first write. Each lies on
+    the fold's thread, in its trace, and never inside a ``fold.advance``
+    (``benchmark/layers.span_share`` sums whole durations: nested, the
+    copy would count in two shares)."""
+    by_sid = {s["sid"]: s for s in spans}
+    got = [s for s in _named(spans, "fold.seed")
+           if s["args"].get("deferred") and _descends(spans, s, fold)]
+    for s in got:
+        assert s["args"]["deferred"] is True and s["args"]["nbytes"] > 0
+        assert s["tid"] == fold["tid"] and s["trace"] == fold["trace"]
+        assert by_sid[s["parent"]]["name"] in ("hop.fold",
+                                               "fold.checkpoint")
+    return got
+
+
 # --------------------------------------------------------- engine.build
 
 
@@ -103,9 +128,9 @@ def test_live_rebase_epoch_takes_its_engine_build_apart(traced):
     assert by["index.ids"]["args"]["events"] == log.n
     assert 0 < by["index.ids"]["args"]["ids"] <= build["args"]["n_pad"]
     assert 0 < by["index.pairs"]["args"]["pairs"] <= build["args"]["m_pad"]
-    # a fork copies 18 B an id and 18 B a pair
-    assert by["index.fork"]["args"]["nbytes"] == 18 * (
-        by["index.ids"]["args"]["ids"] + by["index.pairs"]["args"]["pairs"])
+    # a fork shares 18 B an id and 18 B a pair, and copies none of them
+    assert by["index.fork"]["args"] == {"nbytes": 0, "shared": 18 * (
+        by["index.ids"]["args"]["ids"] + by["index.pairs"]["args"]["pairs"])}
     # the children's seconds are the parent's, less its own
     assert sum(s["dur"] for s in stages) <= build["dur"]
     assert build["self"] == pytest.approx(
@@ -117,6 +142,12 @@ def test_live_rebase_epoch_takes_its_engine_build_apart(traced):
     (fold,) = _named(spans, "hop.fold")
     adv, = _named(_children(spans, fold), "fold.advance")
     assert adv["args"]["rows"] == log.n
+    # the engine's builder writes here for the first time: the copy its
+    # fork put off is a span of its own, before the advance and beside it
+    (own,) = _deferred_seeds(spans, fold)
+    assert own["parent"] == fold["sid"] and _inside(fold, own)
+    assert own["args"]["nbytes"] == by["index.fork"]["args"]["shared"]
+    assert own["ts"] + own["dur"] <= adv["ts"] + 1.0
     pay, = _named(_children(spans, fold), "fold.payload")
     assert pay["args"]["base"] is True and pay["args"]["bytes"] > 0
 
@@ -137,6 +168,9 @@ def test_second_engine_over_an_unchanged_log_only_looks_up_and_forks(
     stages = sorted(_named(second, *INDEX_STAGES), key=lambda s: s["ts"])
     assert [s["name"] for s in stages] == ["index.lookup", "index.fork"]
     assert all(_inside(build, s) for s in stages)
+    # on a hit the fork is no longer the build: it copies nothing
+    assert stages[1]["args"]["nbytes"] == 0
+    assert stages[1]["args"]["shared"] > 0
 
 
 # ------------------------------------------------------------- hop.fold
@@ -163,9 +197,14 @@ def test_range_on_two_fold_workers_writes_the_fold_stages(traced,
         kids = _children(spans, fold)
         assert {k["name"] for k in kids} <= {
             "fold.seed", "fold.checkpoint", "fold.advance", "fold.payload"}
-        (seed,) = _named(kids, "fold.seed")
+        (seed,) = _unit_seeds(kids)
         assert seed["args"]["seed"] in ("start", "live", "checkpoint")
-        assert seed["args"]["nbytes"] > 0
+        # the fork copies nothing where it is made; the unit's builder
+        # copies what it shares once, on this worker, when it first folds
+        assert seed["args"]["nbytes"] == 0 and seed["args"]["shared"] > 0
+        (own,) = _deferred_seeds(spans, fold)
+        assert own["args"]["nbytes"] == seed["args"]["shared"]
+        assert own["ts"] >= seed["ts"] + seed["dur"] - 1.0
         # one advance and one payload a hop, each inside its fold
         adv = _named(kids, "fold.advance")
         pay = _named(kids, "fold.payload")
@@ -205,6 +244,11 @@ def test_inline_column_fold_writes_an_advance_and_a_payload_a_hop(
     assert fold["args"]["hops"] == 3 and "mode" not in fold["args"]
     kids = _children(spans, fold)
     assert all(_inside(fold, k) for k in kids)
+    # the engine's own builder folds here: the copy its fork from the
+    # index put off comes first, then an advance and a payload a hop
+    assert _deferred_seeds(spans, fold) == kids[:1]
+    assert kids[0]["args"]["nbytes"] == hb.sw.fork_nbytes()
+    kids = kids[1:]
     assert [k["name"] for k in kids] == ["fold.advance", "fold.payload"] * 3
     assert [k["args"]["time"] for k in kids[::2]] == hops
     assert [k["args"]["base"] for k in kids[1::2]] == [True, False, False]
@@ -243,14 +287,17 @@ def test_mesh_range_job_writes_the_fold_stages_where_it_folds(
             == [True] + [False] * (len(pay) - 1)
         if workers == 1:
             assert fold["tid"] == job["tid"] and "mode" not in fold["args"]
-            assert {k["name"] for k in kids} == {"fold.advance",
-                                                 "fold.payload"}
+            assert {k["name"] for k in kids} == {
+                "fold.seed", "fold.advance", "fold.payload"}
+            assert _deferred_seeds(spans, fold) == _named(kids, "fold.seed")
         else:
             assert fold["tid"] != job["tid"]
             assert fold["args"]["mode"] == "parallel"
             assert fold["args"]["worker"].startswith("sweep-fold")
-            (seed,) = _named(kids, "fold.seed")
-            assert seed["args"]["nbytes"] > 0
+            (seed,) = _unit_seeds(kids)
+            assert seed["args"]["nbytes"] == 0
+            (own,) = _deferred_seeds(spans, fold)
+            assert own["args"]["nbytes"] == seed["args"]["shared"] > 0
             for cp in _named(kids, "fold.checkpoint"):
                 (bulk,) = _named(_children(spans, cp), "fold.advance")
                 assert bulk["args"]["time"] == cp["args"]["time"]
