@@ -42,6 +42,9 @@ class LabelPropagation(VertexProgram):
     needs_vids = False
     needs_vertex_times = False
     needs_edge_times = False
+    #: ``exchange`` is ``segment_mode``: a route that counts the rows it
+    #: hands to the sort (the ledger's ``device.mode_rows``) reads this
+    exchange_is_mode = True
 
     def init(self, ctx: Context):
         return jnp.where(ctx.v_mask, ctx.global_index(), _I32_MAX)

@@ -1307,6 +1307,51 @@ class FoldCache:
             self._bytes = 0
 
 
+def seeded_fork(live, boundary, cache, fp, cfg):
+    """Fork the builder ``live`` at ``boundary`` (exclusive upper time
+    of every earlier unit's hops): nearest cached checkpoint when one
+    is ahead of the live builder, else the live state, then one bulk
+    advance — recorded back as a checkpoint for the next request.
+    The lookup and the fork are one ``fold.seed`` span (``nbytes``:
+    what the fork copied, 0 — it shares the ``shared`` bytes of fold
+    state it starts from and copies them at its first write, a
+    second ``fold.seed`` span with ``deferred=true``), the advance
+    the ``fold.checkpoint`` beside it. One seeding rule for every
+    engine that folds a Range: the columnar engines' fold units
+    (``engine/hopbatch._HopBatched._seed_fork``) and the vertex-sharded
+    mesh sweep's first hop (``parallel/sweep.ShardedSweep``)."""
+    with _span("fold.seed") as ssp:
+        cp = cache.nearest_checkpoint(fp, cfg, boundary) \
+            if cache is not None and boundary is not None else None
+        t0 = live.t_prev
+        if cp is not None and (t0 is None or cp.t_prev > t0):
+            sw, seed = live.fork(cp), "checkpoint"
+        else:
+            sw = live.fork()
+            # "start": neither the cache nor the live builder holds a
+            # state, so this unit advances from the log's first event
+            seed = "start" if sw.t_prev is None else "live"
+        ssp.set(seed=seed, nbytes=0, shared=sw.fork_nbytes())
+    if boundary is None:
+        return sw
+    if sw.t_prev is None or sw.t_prev < boundary:
+        with _span("fold.checkpoint", time=int(boundary),
+                   seeded_from=(-1 if sw.t_prev is None
+                                else int(sw.t_prev)),
+                   seed=seed) as sp:
+            sw._advance(boundary)
+            if cache is not None:
+                # inside the span, so its args say what became of
+                # the state this advance reached: refused for size
+                # (stored=False), or stored at nbytes so near the
+                # bound that the next insert evicts it — either way
+                # the NEXT request's units read seed="start" again
+                cp = sw.checkpoint()
+                stored = cache.put_checkpoint(fp, cp)
+                sp.set(stored=stored, nbytes=cp.nbytes)
+    return sw
+
+
 _FOLD_CACHE = None
 _FOLD_CACHE_LOCK = threading.Lock()
 

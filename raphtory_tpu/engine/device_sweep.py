@@ -257,7 +257,7 @@ class LogIndex:
     here is read-only to its users: a fork shares the log-derived arrays
     by reference and copies the fold state (``SweepBuilder.fork``)."""
 
-    __slots__ = ("prototype", "tables", "triangles")
+    __slots__ = ("prototype", "tables", "triangles", "partitions")
 
     def __init__(self, log: EventLog):
         # fold state only (the engines never emit GraphViews, shells are
@@ -270,6 +270,10 @@ class LogIndex:
         #: built by the first engine that intersects neighbour sets
         #: (``log_triangles``), never for another program
         self.triangles = None
+        #: the pair table's static partitions for the vertex-sharded mesh
+        #: route (``parallel/sweep.StaticPartition``), by shard count:
+        #: built by the first mesh Range that asks (``log_partition``)
+        self.partitions: dict = {}
 
     @property
     def nbytes(self) -> int:
@@ -281,7 +285,8 @@ class LogIndex:
                                      *vars(self.tables).values())
                   if isinstance(a, np.ndarray)}
         return int(sum(a.nbytes for a in arrays.values())) + (
-            self.triangles.nbytes if self.triangles is not None else 0)
+            self.triangles.nbytes if self.triangles is not None else 0) \
+            + sum(p.nbytes for p in self.partitions.values())
 
     def adopt(self, log: EventLog) -> str:
         """Bring the index up to ``log``'s current pin, exactly:
@@ -304,6 +309,7 @@ class LogIndex:
             with TRACER.span("index.tables", grow=True):
                 self.tables = GlobalTables(sw)
             self.triangles = None
+            self.partitions = {}
         elif status != "extended" \
                 or not self.tables.holds_times(sw._t[n_old:]):
             return "miss"   # the prototype may be rebound: discard it
@@ -391,6 +397,29 @@ def log_triangles(log: EventLog, tables: GlobalTables):
         if idx is not None:
             idx.triangles = tt
         return tt, "built"
+
+
+def log_partition(log: EventLog, tables: GlobalTables, n_shards: int,
+                  build):
+    """``(partition, status)``: ``build(tables, n_shards)`` (the
+    vertex-sharded route's ``parallel/sweep.StaticPartition``: the range
+    partition of the pair table over ``n_shards`` and its halo layouts)
+    of ``tables``, the pair table an engine got from ``log_index(log)``:
+    kept on the log's index by shard count (``"held"``), built here the
+    first time a mesh Range asks (``"built"``). It goes with the pairs it
+    was cut from, as the triangle table does (``log_triangles``); an
+    uncached index (a frozen log's) gets one of its own every time.
+    Holds the index lock for the build, as an index miss does."""
+    with _LOG_INDEX_LOCK:
+        idx = _LOG_INDEXES.get(log)
+        if idx is not None and idx.tables is not tables:
+            idx = None      # the engine's tables outlived their index
+        if idx is not None and n_shards in idx.partitions:
+            return idx.partitions[n_shards], "held"
+        part = build(tables, n_shards)
+        if idx is not None:
+            idx.partitions[n_shards] = part
+        return part, "built"
 
 
 #: per-log cache of the device copy of the table above, as ``_DEVICE_EDGES``

@@ -37,7 +37,7 @@ import numpy as np
 import logging
 
 from ..core.events import EventLog
-from ..core.sweep import (fold_cache, fold_pool, fold_workers,
+from ..core.sweep import (fold_cache, fold_pool, fold_workers, seeded_fork,
                           log_fingerprint, prefetch_map)
 from ..obs import ledger as _ledger
 from ..obs.trace import TRACER
@@ -1577,45 +1577,8 @@ class _HopBatched:
         return payloads, cap
 
     def _seed_fork(self, boundary, cache, fp, cfg):
-        """Fork the sweep's builder at ``boundary`` (exclusive upper time
-        of every earlier unit's hops): nearest cached checkpoint when one
-        is ahead of the live builder, else the live state, then one bulk
-        advance — recorded back as a checkpoint for the next request.
-        The lookup and the fork are one ``fold.seed`` span (``nbytes``:
-        what the fork copied, 0 — it shares the ``shared`` bytes of fold
-        state it starts from and copies them at its first write, a
-        second ``fold.seed`` span with ``deferred=true``), the advance
-        the ``fold.checkpoint`` beside it."""
-        with TRACER.span("fold.seed") as ssp:
-            cp = cache.nearest_checkpoint(fp, cfg, boundary) \
-                if cache is not None and boundary is not None else None
-            t0 = self.sw.t_prev
-            if cp is not None and (t0 is None or cp.t_prev > t0):
-                sw, seed = self.sw.fork(cp), "checkpoint"
-            else:
-                sw = self.sw.fork()
-                # "start": neither the cache nor the live builder holds a
-                # state, so this unit advances from the log's first event
-                seed = "start" if sw.t_prev is None else "live"
-            ssp.set(seed=seed, nbytes=0, shared=sw.fork_nbytes())
-        if boundary is None:
-            return sw
-        if sw.t_prev is None or sw.t_prev < boundary:
-            with TRACER.span("fold.checkpoint", time=int(boundary),
-                                seeded_from=(-1 if sw.t_prev is None
-                                             else int(sw.t_prev)),
-                                seed=seed) as sp:
-                sw._advance(boundary)
-                if cache is not None:
-                    # inside the span, so its args say what became of
-                    # the state this advance reached: refused for size
-                    # (stored=False), or stored at nbytes so near the
-                    # bound that the next insert evicts it — either way
-                    # the NEXT request's units read seed="start" again
-                    cp = sw.checkpoint()
-                    stored = cache.put_checkpoint(fp, cp)
-                    sp.set(stored=stored, nbytes=cp.nbytes)
-        return sw
+        """``core/sweep.seeded_fork`` of this engine's builder."""
+        return seeded_fork(self.sw, boundary, cache, fp, cfg)
 
     @staticmethod
     def _merge_delta_parts(parts):

@@ -39,6 +39,12 @@ import logging
 _jobs_log = logging.getLogger(__name__)
 
 
+def _wait_steps(steps) -> int:
+    """A hop's wait for the devices: the superstep count of its dispatch,
+    read under ``superstep.block``."""
+    return _block_steps(lambda: (None, steps))[1]
+
+
 def declinable(e: BaseException) -> bool:
     """May a device route that raised ``e`` decline to the next rung?
 
@@ -547,7 +553,25 @@ class Job:
             return sweep.run(self.program, mesh=self.mesh, window=q.window,
                              windows=windows, block=False)
 
-        self._range_amortised(q, sweep.advance, run, sweep.reduce_view)
+        rows_a_round = sweep.mode_rows(
+            self.program, self.mesh,
+            len(q.windows) if q.windows is not None else 1)
+
+        def wait(steps) -> int:
+            # every hop is dispatched with block=False, so the route's
+            # wait for the chips is here, under the name sharded.run
+            # gives its own; with the superstep count it reads, the rows
+            # the hop's rounds handed to the mode's sort (0 for a program
+            # whose exchange is no histogram)
+            with TRACER.span("comm.block_wait",
+                             process=TRACER.process_index) as sp:
+                steps = int(steps)
+                sp.set(steps=steps)
+            self.ledger.count_mode_rows(max(steps, 0) * rows_a_round)
+            return steps
+
+        self._range_amortised(q, sweep.advance, run, sweep.reduce_view,
+                              wait)
         return True
 
     def _run_columnar_only(self, q) -> None:
@@ -849,10 +873,13 @@ class Job:
         self._range_amortised(q, sweep.advance, run, shell.freeze)
         return True
 
-    def _range_amortised(self, q: RangeQuery, advance, run, freeze_rv) -> None:
+    def _range_amortised(self, q: RangeQuery, advance, run, freeze_rv,
+                         wait=_wait_steps) -> None:
         """The shared amortised-sweep hop loop: advance the fold, dispatch
         async, emit the PREVIOUS hop while this one computes (hop i+1's host
-        fold overlaps hop i's device supersteps).
+        fold overlaps hop i's device supersteps). ``wait(steps) -> int`` is
+        a hop's wait for the devices: it reads the superstep count the
+        dispatch returned (``_wait_steps``: under ``superstep.block``).
 
         Degraded serving (docs/RESILIENCE.md): a deadline that expires or
         a transient failure that exhausts its retry budget MID-sweep stops
@@ -891,13 +918,13 @@ class Job:
                 raise
             t_disp = _time.perf_counter()
             if pending is not None:
-                self._emit_mesh(*pending)
+                self._emit_mesh(*pending, wait)
                 covered = pending[0]
             pending = (t, q, rv, result, steps, t0, t_disp)
             t += q.jump
         if pending is not None:
             try:
-                self._emit_mesh(*pending)
+                self._emit_mesh(*pending, wait)
                 covered = pending[0]
             except Exception as e:
                 # the tail hop's buffers may be poisoned by the same
@@ -922,7 +949,7 @@ class Job:
         except Exception:   # telemetry must not fail a served answer
             pass
 
-    def _emit_mesh(self, t, q, rv, result, steps, t0, t_disp) -> None:
+    def _emit_mesh(self, t, q, rv, result, steps, t0, t_disp, wait) -> None:
         import jax
         import numpy as np
 
@@ -933,7 +960,7 @@ class Job:
         # dispatch-window + blocking tail only.
         t0 = t0 + (_time.perf_counter() - t_disp)
         b0 = _time.perf_counter()
-        _, steps = _block_steps(lambda: (None, steps))
+        steps = wait(steps)
         self.ledger.add_phase("device_wait", _time.perf_counter() - b0)
         METRICS.supersteps.inc(max(steps, 0))
         self.ledger.count_supersteps(steps)

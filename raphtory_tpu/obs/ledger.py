@@ -577,7 +577,7 @@ def built(engine) -> dict:
     t = engine.tables
     out = {"engine": type(engine).__name__, "n_pad": int(t.n_pad),
            "m_pad": int(t.m_pad), "index": engine.index_status}
-    for attr in ("triangles", "features"):
+    for attr in ("triangles", "features", "partition"):
         if getattr(engine, attr + "_status", None):
             out[attr] = getattr(engine, attr + "_status")
     return out

@@ -86,6 +86,7 @@ class CollectiveStats:
         self._lock = threading.Lock()
         self._routes: dict[tuple, dict] = {}
         self._skew: dict | None = None
+        self._layout: dict | None = None
         self._skew_builds = 0
         self._skew_refreshes = 0
         # route-chooser evidence: measured frontier density per
@@ -97,11 +98,18 @@ class CollectiveStats:
         self._route_log: deque = deque(maxlen=64)
         self._route_counts: dict[tuple, int] = {}
 
-    def note_partition(self, skew: dict) -> None:
+    def note_partition(self, skew: dict,
+                       layout: dict | None = None) -> None:
         """Record the latest partition build's per-shard skew histogram
-        (built at ``partition_view`` time — rebuilds overwrite)."""
+        (built at ``partition_view`` time — rebuilds overwrite) and, for a
+        log's static partition, its ``layout``: ``shards``, the pair
+        ``rows`` of both directions, the ``pad_rows`` the blocks hold
+        them in (every shard padded to the fullest one's next power of
+        two: ``pad_factor`` = pad_rows / rows is what a superstep pays
+        for the skew) and the ``halo_rows`` of the halo route."""
         with self._lock:
             self._skew = skew
+            self._layout = layout
             self._skew_builds += 1
 
     def note_exchange(self, route: str, direction: str, *, rows: int,
@@ -181,6 +189,7 @@ class CollectiveStats:
             routes = {f"{r}/{d}": dict(v)
                       for (r, d), v in sorted(self._routes.items())}
             skew = dict(self._skew) if self._skew else None
+            layout = dict(self._layout) if self._layout else None
             builds = self._skew_builds
             refreshes = self._skew_refreshes
             density = {k: round(sum(d for d, _ in dq) / len(dq), 6)
@@ -194,7 +203,8 @@ class CollectiveStats:
             v["seconds"] = round(v["seconds"], 6)
             v["barrier_wait_seconds"] = round(
                 v["barrier_wait_seconds"], 6)
-        return {"routes": routes, "skew": skew, "skew_builds": builds,
+        return {"routes": routes, "skew": skew, "partition": layout,
+                "skew_builds": builds,
                 "skew_refreshes": refreshes,
                 "frontier_density": density, "route_table": table}
 
@@ -202,6 +212,7 @@ class CollectiveStats:
         with self._lock:
             self._routes.clear()
             self._skew = None
+            self._layout = None
             self._skew_builds = 0
             self._skew_refreshes = 0
             self._frontier.clear()
@@ -231,12 +242,14 @@ def shard_skew(**kinds) -> dict:
     return out
 
 
-def note_partition_skew(skew: dict) -> None:
+def note_partition_skew(skew: dict, layout: dict | None = None) -> None:
     """Publish one partition build's skew histogram: COLLECTIVES (the
-    /statusz / /clusterz surface), the prometheus gauges/histograms, and
-    a flight-recorder instant — shared by ``partition_view`` and the
-    static ``ShardedSweep`` build."""
-    COLLECTIVES.note_partition(skew)
+    /statusz / /clusterz surface; ``layout``: a static partition's padded
+    rows, ``CollectiveStats.note_partition``), the prometheus
+    gauges/histograms, and a flight-recorder instant — shared by
+    ``partition_view`` and the build of a log's static partition
+    (``parallel/sweep.StaticPartition``)."""
+    COLLECTIVES.note_partition(skew, layout)
     m = _metrics()
     if m is not None:
         for kind, s in skew.items():
@@ -491,6 +504,12 @@ class ShardedView:
     #: per-shard degree/halo row-count histogram built at partition time
     #: (``shard_skew`` output) — the power-law imbalance evidence
     skew: dict | None = None
+    #: device copies of the blocks no hop of a sweep changes, by (mesh,
+    #: name): a static partition's own cache
+    #: (``parallel/sweep.StaticPartition.resident``), filled by ``run`` at
+    #: the first dispatch on a mesh; None for a per-view partition, whose
+    #: every block is the view's
+    resident: dict | None = None
 
     def halo_rows(self, direction: str) -> int:
         """Rows exchanged per device per superstep on the halo path (vs
@@ -1082,19 +1101,44 @@ def run(program: VertexProgram, view: GraphView, mesh: Mesh, *,
     # (data-replicated ingestion — the reference replays every update to
     # every PM's router the same way), so each input becomes a GLOBAL
     # jax.Array by slicing out this process's addressable shards. On one
-    # process this degrades to a plain device put.
+    # process each input is put where the program reads it, a shard a
+    # device, never whole on the default device first.
+    put = {"arrays": 0, "bytes": 0, "resident_bytes": 0}
+
     def dev(x, spec):
-        if not multi:
-            return jnp.asarray(x)
         x = np.asarray(x)
+        put["arrays"] += 1
+        put["bytes"] += x.nbytes
         sh = jax.sharding.NamedSharding(mesh, spec)
+        if not multi:
+            return jax.device_put(x, sh)
         return jax.make_array_from_callback(x.shape, sh, lambda idx: x[idx])
 
+    held = sv.resident
+
+    def static(name, block):
+        """A ``[S, ...]`` block no hop of a sweep changes (an array, or a
+        function that makes it): the partition's device copy on this
+        mesh, put by the first dispatch that finds none."""
+        a = held.get((mesh, name)) if held is not None else None
+        if a is None:
+            b0 = put["bytes"]
+            a = dev(block() if callable(block) else block, v)
+            if held is not None:
+                held[(mesh, name)] = a
+                put["resident_bytes"] += put["bytes"] - b0
+        return a
+
+    def edge_times(block, m_loc):
+        """A block of edge times: the sweep's, patched this hop — or, for
+        a program that reads none (``needs_edge_times`` False: the runner
+        drops the argument), one resident block of the shape."""
+        if held is None or program.needs_edge_times:
+            return dev(block, v)
+        return static(f"no_edge_times.{m_loc}", lambda: np.full(
+            (S, m_loc), INT64_MIN, np.int64))
+
     kv, v, rep = P(W_AXIS, V_AXIS), P(V_AXIS), P()
-    halo = {}
-    if comm == "halo":
-        halo = {"d_src_h": dev(sv.d_src_h, v), "d_send": dev(sv.d_send, v),
-                "s_dst_h": dev(sv.s_dst_h, v), "s_send": dev(sv.s_send, v)}
 
     # Collective telemetry: what THIS dispatch moves across shards per
     # superstep. halo ships each device its referenced remote slot pages
@@ -1114,24 +1158,42 @@ def run(program: VertexProgram, view: GraphView, mesh: Mesh, *,
                      direction=program.direction, process=proc,
                      shards=S, windows=k_pad,
                      rows_per_superstep=rows_step) as csp:
-        result, steps = runner(
-            dev(v_masks, kv), dev(sv.vids, v), dev(sv.v_latest, v),
-            dev(sv.v_first, v),
-            dev(sv.d_src_g, v), dev(sv.d_dst_l, v), dev(d_masks, kv),
-            dev(sv.d_time, v), dev(sv.d_first, v),
-            dev(sv.s_dst_g, v), dev(sv.s_src_l, v), dev(s_masks, kv),
-            dev(sv.s_time, v), dev(sv.s_first, v),
-            halo,
-            {kk: dev(vv, v) for kk, vv in sv.d_props.items()},
-            {kk: dev(vv, v) for kk, vv in sv.s_props.items()},
-            {kk: dev(
-                np.asarray(view.vertex_prop(kk),
-                           np.float32).reshape(S, sv.n_loc),
-                v)
-             for kk in program.vertex_props},
-            dev(np.asarray(view.time, np.int64), rep),
-            dev(np.asarray(wlist_p, np.int64), P(W_AXIS)),
-        )
+        # the dispatch of the puts: what this hop ships (``bytes``: the
+        # window masks and whatever else the hop's fold changed) and, at a
+        # static partition's first dispatch on this mesh, the blocks that
+        # stay (``resident_bytes``). device_put returns before the bytes
+        # have moved: the transfer falls where the host next waits
+        with TRACER.span("comm.put") as psp:
+            halo = {}
+            if comm == "halo":
+                halo = {name: static(name, getattr(sv, name)) for name in (
+                    "d_src_h", "d_send", "s_dst_h", "s_send")}
+            st = {name: static(name, getattr(sv, name)) for name in (
+                "vids", "d_src_g", "d_dst_l", "s_dst_g", "s_src_l")}
+            args = (
+                dev(v_masks, kv), st["vids"], dev(sv.v_latest, v),
+                dev(sv.v_first, v),
+                st["d_src_g"], st["d_dst_l"], dev(d_masks, kv),
+                edge_times(sv.d_time, sv.m_loc_d),
+                edge_times(sv.d_first, sv.m_loc_d),
+                st["s_dst_g"], st["s_src_l"], dev(s_masks, kv),
+                edge_times(sv.s_time, sv.m_loc_s),
+                edge_times(sv.s_first, sv.m_loc_s),
+                halo,
+                {kk: dev(vv, v) for kk, vv in sv.d_props.items()},
+                {kk: dev(vv, v) for kk, vv in sv.s_props.items()},
+                {kk: dev(
+                    np.asarray(view.vertex_prop(kk),
+                               np.float32).reshape(S, sv.n_loc),
+                    v)
+                 for kk in program.vertex_props},
+                dev(np.asarray(view.time, np.int64), rep),
+                dev(np.asarray(wlist_p, np.int64), P(W_AXIS)),
+            )
+            psp.set(arrays=put["arrays"],
+                    bytes=put["bytes"] - put["resident_bytes"],
+                    resident_bytes=put["resident_bytes"])
+        result, steps = runner(*args)
         t_disp = _time.perf_counter()
         row_bytes = sum(
             np.dtype(a.dtype).itemsize
