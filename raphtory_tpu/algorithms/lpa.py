@@ -42,8 +42,10 @@ class LabelPropagation(VertexProgram):
     needs_vids = False
     needs_vertex_times = False
     needs_edge_times = False
-    #: ``exchange`` is ``segment_mode``: a route that counts the rows it
-    #: hands to the sort (the ledger's ``device.mode_rows``) reads this
+    #: ``exchange`` is ``segment_mode`` and takes its ``counts``: the
+    #: engines compute them once a dispatch (``engine/program``), and a
+    #: route that counts the rows it hands to the sort (the ledger's
+    #: ``device.mode_rows``) reads this
     exchange_is_mode = True
 
     def init(self, ctx: Context):
@@ -52,9 +54,10 @@ class LabelPropagation(VertexProgram):
     def message(self, src_state, edge: Edges):
         return src_state
 
-    def exchange(self, payload, seg_ids, num_segments, mask):
+    def exchange(self, payload, seg_ids, num_segments, mask, counts=None):
         # mode of the inbox per destination; -1 marks "no messages"
-        return segment_mode(payload, seg_ids, num_segments, mask, default=-1)
+        return segment_mode(payload, seg_ids, num_segments, mask, default=-1,
+                            counts=counts)
 
     def update(self, state, agg, ctx: Context):
         new = jnp.where((agg >= 0) & ctx.v_mask, agg, state)

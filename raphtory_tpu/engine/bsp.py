@@ -26,9 +26,10 @@ from ..core.snapshot import GraphView
 from ..obs import ledger as _ledger
 from ..obs.trace import TRACER, block_steps
 from ..ops.gather import gather_pack, packed_elements, row_and_slot
-from ..ops.segment import segment_combine, segment_ends_pos
+from ..ops.segment import (segment_combine, segment_counts,
+                           segment_counts_at, segment_ends_pos)
 from .program import (Context, Edges, VertexProgram, check_custom_direction,
-                      custom_exchange)
+                      custom_exchange, takes_mode_counts)
 
 _elem = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
 
@@ -132,6 +133,16 @@ def make_mask_runner(program: VertexProgram, n: int, m: int, k: int):
         # where those lie depends on flat_dst alone — once, before the
         # superstep loop, the way ``counts`` reaches ``segment_mode``
         ends, pos = segment_ends_pos(flat_dst, k * n)
+        # and so ``counts`` does reach a mode exchange: flat_dst's off the
+        # plan, flat_src's (not sorted) by ONE scatter a dispatch
+        mode_counts = None
+        if takes_mode_counts(program):
+            by_part = []    # in ``step_all``'s order of the parts
+            if program.direction in ("out", "both"):
+                by_part.append(segment_counts_at(ends, pos))
+            if program.direction in ("in", "both"):
+                by_part.append(segment_counts(flat_src, k * n))
+            mode_counts = sum(by_part)
         # a state leaf of one element a vertex is gathered P vertices a
         # row, never element by element (ops/gather): which row and slot a
         # pair reads depends on its id alone — once, here, like the plan
@@ -212,7 +223,7 @@ def make_mask_runner(program: VertexProgram, n: int, m: int, k: int):
             if custom:
                 agg = jax.tree_util.tree_map(
                     lambda a: a.reshape((k, n) + a.shape[1:]),
-                    custom_exchange(program, parts, k * n))
+                    custom_exchange(program, parts, k * n, mode_counts))
 
             def upd_k(kk, stk, aggk):
                 new, votes = program.update(stk, aggk, mk_ctx(kk, step))

@@ -160,6 +160,15 @@ class VertexProgram:
     # (``custom_exchange``). False: the pair is refused — two custom
     # aggregates have no merge.
     exchange_joint: bool = False
+    # combiner='custom': True declares that ``exchange`` is
+    # ``ops.segment.segment_mode`` of the payload and takes its
+    # ``counts=`` — the rows of every segment, a function of the segment
+    # ids alone, which the engines compute ONCE a dispatch, outside the
+    # superstep loop, and hand to every round's ``exchange``
+    # (``custom_exchange``). A route that counts the rows it hands to the
+    # sort (the ledger's ``device.mode_rows``) reads the flag too. False:
+    # ``exchange`` is called with its four arguments and no more.
+    exchange_is_mode: bool = False
     # True for a program the job layer serves on the columnar engine or
     # fails by the route's name (``jobs/manager.Job._run_columnar_only``),
     # never through ``bsp``: an algorithm that is no message along an
@@ -200,7 +209,18 @@ class VertexProgram:
         use static-shape segment ops (``segment_combine``, ``segment_mode``)
         only. Direction 'both' needs ``exchange_joint`` (merging two
         custom aggregations is not well-defined; one aggregation of both
-        directions' payloads is)."""
+        directions' payloads is).
+
+        A program that declares ``exchange_is_mode`` takes one keyword
+        more, ``counts=None``: ``i32[num_segments]``, how many of the
+        ``m`` rows carry each segment id — EVERY row, masked rows and the
+        padding rows at a block's end included, so it depends on
+        ``seg_ids`` alone and not on the round. The engines compute it
+        once a dispatch, before the superstep loop (off the sorted ids'
+        plan where they hold one: no scatter over the rows), and the
+        program passes it on to ``segment_mode(..., counts=counts)``,
+        which would otherwise count the rows anew every round. A program
+        without the flag is never handed it."""
         raise NotImplementedError
 
     def update(self, state: Any, agg: Any, ctx: Context):
@@ -230,12 +250,15 @@ def check_custom_direction(program: VertexProgram) -> None:
             "declares exchange_joint)")
 
 
-def custom_exchange(program: VertexProgram, parts, num_segments: int):
+def custom_exchange(program: VertexProgram, parts, num_segments: int,
+                    counts=None):
     """``program.exchange`` over ``parts`` = one ``(payload, seg_ids,
     mask)`` per direction the program listens on. Two directions
     (``exchange_joint``) are ONE exchange over both payloads together —
     a histogram of in- and out-neighbours' messages, not two aggregates
-    to be merged."""
+    to be merged. ``counts`` (``takes_mode_counts``) is the caller's,
+    computed before its superstep loop; only a program that declares
+    ``exchange_is_mode`` is handed it."""
     if len(parts) == 1:
         payload, ids, mask = parts[0]
     else:
@@ -244,4 +267,15 @@ def custom_exchange(program: VertexProgram, parts, num_segments: int):
 
         payload = jax.tree_util.tree_map(cat, *(p[0] for p in parts))
         ids, mask = cat(*(p[1] for p in parts)), cat(*(p[2] for p in parts))
-    return program.exchange(payload, ids, num_segments, mask)
+    if counts is None or not program.exchange_is_mode:
+        return program.exchange(payload, ids, num_segments, mask)
+    return program.exchange(payload, ids, num_segments, mask, counts=counts)
+
+
+def takes_mode_counts(program: VertexProgram) -> bool:
+    """Whether an engine computes ``counts`` for ``custom_exchange``
+    before its superstep loop: one count of the rows per direction the
+    program listens on, added — the ids of the concatenated payloads are
+    the parts' ids one after another, so a segment's rows there are the
+    sum of its rows in the parts."""
+    return program.combiner == "custom" and program.exchange_is_mode

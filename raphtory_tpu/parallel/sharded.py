@@ -51,9 +51,11 @@ from ..analysis.sanitizer import mesh_active
 from ..core.snapshot import GraphView, INT64_MIN
 from ..engine.bsp import _elem, _merge_aggs
 from ..engine.program import (Context, Edges, VertexProgram,
-                              check_custom_direction, custom_exchange)
+                              check_custom_direction, custom_exchange,
+                              takes_mode_counts)
 from ..obs.trace import TRACER
-from ..ops.segment import segment_combine, segment_ends_pos
+from ..ops.segment import (segment_combine, segment_counts_at,
+                           segment_ends_pos)
 
 V_AXIS = "vertices"
 W_AXIS = "windows"
@@ -758,6 +760,17 @@ def _sharded_runner(program: VertexProgram, mesh: Mesh, n_loc: int,
         # on the ids alone: once, before the superstep loop
         plan_d = segment_ends_pos(fl_d_dst, k_loc * n_loc)
         plan_s = segment_ends_pos(fl_s_src, k_loc * n_loc)
+        # so do the rows of every segment, which the sort of a mode
+        # exchange places its segments by: read off the plans of the
+        # directions the program listens on, never counted in a round
+        mode_counts = None
+        if takes_mode_counts(program):
+            by_part = []    # in ``step_all``'s order of the parts
+            if program.direction in ("out", "both"):
+                by_part.append(segment_counts_at(*plan_d))
+            if program.direction in ("in", "both"):
+                by_part.append(segment_counts_at(*plan_s))
+            mode_counts = sum(by_part)
 
         def combine_flat(tree_flat, ids, msk, plan):
             def leaf(x):
@@ -840,7 +853,8 @@ def _sharded_runner(program: VertexProgram, mesh: Mesh, n_loc: int,
                 # out-edges (s_*): both payloads meet in one exchange
                 agg = jax.tree_util.tree_map(
                     lambda a: a.reshape((k_loc, n_loc) + a.shape[1:]),
-                    custom_exchange(program, parts, k_loc * n_loc))
+                    custom_exchange(program, parts, k_loc * n_loc,
+                                    mode_counts))
 
             def upd_k(kk, stk, aggk):
                 new_st, votes = program.update(stk, aggk, mk_ctx(kk, step))
@@ -1158,6 +1172,10 @@ def run(program: VertexProgram, view: GraphView, mesh: Mesh, *,
                      direction=program.direction, process=proc,
                      shards=S, windows=k_pad,
                      rows_per_superstep=rows_step) as csp:
+        if takes_mode_counts(program):
+            # the runner reads a mode exchange's row counts off its plans,
+            # once a dispatch (``_sharded_runner``): no round counts rows
+            csp.set(mode_counts="plan")
         # the dispatch of the puts: what this hop ships (``bytes``: the
         # window masks and whatever else the hop's fold changed) and, at a
         # static partition's first dispatch on this mesh, the blocks that

@@ -286,7 +286,7 @@ class ShardedSweep:
         shards: the padded block rows of every direction the program
         listens on, once a window (the window axis pads ``k`` to its
         size). 0 for a program whose exchange is no mode."""
-        if not getattr(program, "exchange_is_mode", False):
+        if not program.exchange_is_mode:
             return 0
         W = mesh.shape.get(sharded.W_AXIS, 1)
         rows = (self.sv.m_loc_d if program.direction in ("out", "both")

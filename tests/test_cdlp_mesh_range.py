@@ -144,6 +144,9 @@ def test_served_mesh_range_of_cdlp(comm, mesh, traced, monkeypatch):
         routes = {s["args"]["route"] for s in _named(spans, "comm.exchange")}
         assert routes == {comm}
         assert len(_named(spans, "comm.exchange")) == 3     # one a hop
+        # the rows of CDLP's segments come off the plans, once a dispatch
+        assert {s["args"].get("mode_counts")
+                for s in _named(spans, "comm.exchange")} == {"plan"}
         waits = _named(spans, "comm.block_wait")   # the one span of a wait
         assert [w["args"]["steps"] for w in waits] == [10, 10, 10]
         assert not _named(spans, "superstep.block")

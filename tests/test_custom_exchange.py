@@ -4,14 +4,20 @@ The sum/min/max combiners cannot express a per-label histogram; the
 sort-based custom-exchange path must — against a pure-host reference with
 identical tie-breaking, on both engines."""
 
+import re
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from raphtory_tpu import EventLog, build_view
-from raphtory_tpu.algorithms import LabelPropagation
+from raphtory_tpu.algorithms import CDLP, LabelPropagation
 from raphtory_tpu.engine import bsp
-from raphtory_tpu.ops.segment import segment_mode
+from raphtory_tpu.engine.program import custom_exchange, takes_mode_counts
+from raphtory_tpu.obs.trace import TRACER
+from raphtory_tpu.ops.segment import (segment_counts, segment_counts_at,
+                                      segment_ends_pos, segment_mode)
 from raphtory_tpu.parallel import sharded
 
 
@@ -135,16 +141,206 @@ def test_lpa_windowed_matches_host_reference():
     np.testing.assert_array_equal(np.asarray(got)[vm], want[vm])
 
 
-@pytest.mark.parametrize("comm", ["halo", "all_gather"])
-def test_lpa_sharded_matches_single(comm):
-    import jax
+class InboundLabels(LabelPropagation):
+    """Labels flow dst -> src: the histogram lies at the source, whose
+    ids ``bsp`` holds unsorted."""
 
+    direction = "in"
+
+
+class PlainExchange(LabelPropagation):
+    """A user's program: the four-argument ``exchange`` and no
+    ``exchange_is_mode`` — handed ``counts=`` it would raise."""
+
+    exchange_is_mode = False
+
+    def exchange(self, payload, seg_ids, num_segments, mask):
+        return segment_mode(payload, seg_ids, num_segments, mask, default=-1)
+
+
+@pytest.mark.parametrize("prog", [
+    LabelPropagation(max_steps=8), CDLP(max_steps=6),
+    InboundLabels(max_steps=8), PlainExchange(max_steps=8)],
+    ids=lambda p: type(p).__name__)
+@pytest.mark.parametrize("comm", ["halo", "all_gather"])
+def test_lpa_sharded_matches_single(comm, prog):
+    """Both engines hand a mode exchange its segments' row counts from
+    before their loops (``takes_mode_counts``); a program that takes none
+    runs as it always did. Either way: the host's labels, on ``bsp`` and
+    on the mesh, whole view and batched windows alike."""
     view = build_view(_lpa_log(4), 90)
-    prog = LabelPropagation(max_steps=8)
     mesh = sharded.make_mesh(8, 1, devices=jax.devices()[:8])
-    got, _ = sharded.run(prog, view, mesh, comm=comm)
+    assert takes_mode_counts(prog) == (type(prog) is not PlainExchange)
+    was = TRACER.enabled
+    TRACER.enable()
+    try:
+        with TRACER.span("job") as root:
+            got, _ = sharded.run(prog, view, mesh, comm=comm)
+        (span,) = (e for e in TRACER.for_trace(root.trace)
+                   if e["ph"] == "X" and e["name"] == "comm.exchange")
+    finally:
+        (TRACER.enable if was else TRACER.disable)()
     want, _ = bsp.run(prog, view)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    if type(prog) in (LabelPropagation, PlainExchange):
+        host = _host_lpa(view, prog.max_steps)
+        np.testing.assert_array_equal(np.asarray(want)[view.v_mask],
+                                      host[view.v_mask])
+    # the span says where the counts came from, and only where some did
+    assert span["args"].get("mode_counts") == (
+        "plan" if takes_mode_counts(prog) else None)
+    got_w, _ = sharded.run(prog, view, mesh, comm=comm, windows=[60, 25])
+    want_w, _ = bsp.run(prog, view, windows=[60, 25])
+    np.testing.assert_array_equal(np.asarray(got_w), np.asarray(want_w))
+
+
+# -------------------------------------------- counts, once a dispatch
+
+
+def _block(rng, n, rows, pad, lo=0):
+    """One direction's flat rows as the engines lay them out: sorted
+    segment ids with ``pad`` padding rows (id ``n - 1``, masked) at the
+    block's end, masked rows among the others, segments ``< lo`` and a
+    few more empty."""
+    ids = np.sort(rng.choice(np.arange(lo, n - 3), rows)).astype(np.int32)
+    ids = np.concatenate([ids, np.full(pad, n - 1, np.int32)])
+    mask = np.concatenate([rng.random(rows) < 0.7, np.zeros(pad, bool)])
+    mask[ids == lo + 2] = False                   # one wholly masked
+    vals = rng.integers(0, 7, rows + pad).astype(np.int32)
+    return jnp.asarray(vals), jnp.asarray(ids), jnp.asarray(mask)
+
+
+@pytest.mark.parametrize("parts", [1, 2])
+@pytest.mark.parametrize("prog", [LabelPropagation(), PlainExchange()],
+                         ids=lambda p: type(p).__name__)
+def test_custom_exchange_with_counts_is_bit_for_bit_the_one_without(
+        parts, prog):
+    """``counts`` off the sorted ids' plans — masked rows and padding
+    rows included, two directions' added — are ``segment_counts`` of the
+    ids ``custom_exchange`` concatenates, and the exchange with them
+    returns what it returns counting for itself. A program without the
+    flag is never handed them."""
+    rng = np.random.default_rng(11)
+    n = 29
+    blocks = [_block(rng, n, 120, 8), _block(rng, n, 90, 38, lo=4)][:parts]
+    counts = sum(segment_counts_at(*segment_ends_pos(ids, n))
+                 for _, ids, _ in blocks)
+    every = jnp.concatenate([ids for _, ids, _ in blocks])
+    np.testing.assert_array_equal(np.asarray(counts),
+                                  np.asarray(segment_counts(every, n)))
+    assert counts.dtype == segment_counts(every, n).dtype
+    assert int(counts.sum()) == len(every) and int(counts[n - 1]) >= 8
+    assert int((np.asarray(counts) == 0).sum()) >= 2
+    plain = custom_exchange(prog, blocks, n)
+    given = jax.jit(lambda b, c: custom_exchange(prog, b, n, c))(
+        blocks, counts)
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(given))
+    assert plain.dtype == given.dtype
+    vals, ids, mask = (np.concatenate([np.asarray(b[i]) for b in blocks])
+                       for i in range(3))
+    for seg in range(n):
+        rows = vals[(ids == seg) & mask]
+        want = -1 if len(rows) == 0 else int(np.argmax(np.bincount(rows)))
+        assert int(plain[seg]) == want, seg
+    if not takes_mode_counts(prog):
+        return
+    # counts that are NOT the ids' would show: the exchange reads them
+    wrong = custom_exchange(prog, blocks, n, jnp.roll(counts, 1))
+    assert not np.array_equal(np.asarray(wrong), np.asarray(plain))
+
+
+def _computations(hlo: str) -> dict[str, str]:
+    """``{name: body}`` of an HLO module's computations (the text
+    ``lowered.as_text(dialect="hlo")`` gives, before any optimisation)."""
+    return {m[1]: m[2] for m in re.finditer(
+        r"^(?:ENTRY )?%?([\w.\-]+) [^\n]*\{\n(.*?)^\}", hlo, re.M | re.S)}
+
+
+def _ops_in_loops(hlo: str) -> set[str]:
+    """Opcodes of every instruction a ``while`` body runs, through the
+    computations it calls."""
+    comps = _computations(hlo)
+    todo = [b for text in comps.values()
+            for b in re.findall(r"\bwhile\(.*?body=%?([\w.\-]+)", text)]
+    assert todo, "no while loop in the program"
+    seen, ops = set(), set()
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        ops |= set(re.findall(r"= [^=\n]*?\b([a-z][\w\-]*)\(", comps[name]))
+        todo += re.findall(
+            r"(?:to_apply|calls|body|condition|branch_computations)="
+            r"\{?%?([\w.\-]+)", comps[name])
+    return ops
+
+
+def _lower_sharded(prog, comm, S=4, K=2, n_loc=16, m_d=32, m_s=64, h=4):
+    """The vertex-sharded runner's lowered program at small shapes on the
+    virtual mesh: ``_sharded_runner``'s twenty arguments as shapes."""
+    mesh = sharded.make_mesh(S, 1, devices=jax.devices()[:S])
+    h = h if comm == "halo" else 0
+    runner = sharded._sharded_runner(prog, mesh, n_loc, m_d, m_s, K,
+                                     S * n_loc, (), comm, h, h)
+
+    def a(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    i32, i64 = jnp.int32, jnp.int64
+    halo = {} if comm != "halo" else {
+        "d_src_h": a(i32, S, m_d), "d_send": a(i32, S, S * h),
+        "s_dst_h": a(i32, S, m_s), "s_send": a(i32, S, S * h)}
+    return runner.lower(
+        a(bool, K, S, n_loc), a(i64, S, n_loc), a(i64, S, n_loc),
+        a(i64, S, n_loc),
+        a(i32, S, m_d), a(i32, S, m_d), a(bool, K, S, m_d), a(i64, S, m_d),
+        a(i64, S, m_d),
+        a(i32, S, m_s), a(i32, S, m_s), a(bool, K, S, m_s), a(i64, S, m_s),
+        a(i64, S, m_s),
+        halo, {}, {}, {}, a(i64), a(i64, K))
+
+
+def _lower_bsp(prog, n=64, m=256, k=2):
+    def a(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    i32, i64 = jnp.int32, jnp.int64
+    return jax.jit(bsp.make_mask_runner(prog, n, m, k)).lower(
+        a(bool, k, n), a(bool, k, m), a(i64, n), a(i64, n), a(i64, n),
+        a(i32, m), a(i32, m), a(i64, m), a(i64, m), a(i64), a(i64, k),
+        {}, {})
+
+
+@pytest.mark.parametrize("prog", [CDLP(max_steps=10),
+                                  LabelPropagation(max_steps=8)],
+                         ids=lambda p: type(p).__name__)
+@pytest.mark.parametrize("engine", ["all_gather", "halo", "bsp"])
+def test_no_round_of_a_mode_exchange_scatters_over_the_rows(engine, prog):
+    """The mechanism's witness. ``segment_mode`` without ``counts`` runs
+    a ``segment_sum`` of ones over every padded row — XLA's row-by-row
+    scatter-add — and did so inside the ``while`` body, every round,
+    until the engines computed the counts before the loop: the loop of a
+    lowered mode exchange now holds the sort and no scatter. The
+    vertex-sharded program, whose ids are sorted both ways, holds none at
+    all; ``bsp`` keeps its unsorted direction's, before the loop."""
+    if engine == "bsp":
+        hlo = _lower_bsp(prog).as_text(dialect="hlo")
+    else:
+        hlo = _lower_sharded(prog, engine).as_text(dialect="hlo")
+    in_loops = _ops_in_loops(hlo)
+    assert "sort" in in_loops, in_loops         # the walk reached the mode
+    assert not {op for op in in_loops if "scatter" in op}, in_loops
+    if engine != "bsp":
+        assert not re.search(r"\bscatter\(", hlo)
+
+
+def test_a_program_without_the_flag_still_counts_its_rows_every_round():
+    """...and the witness can fail: the same walk over a program that
+    takes no counts finds the scatter where the parent held it."""
+    hlo = _lower_sharded(PlainExchange(max_steps=8), "all_gather").as_text(
+        dialect="hlo")
+    assert "scatter" in _ops_in_loops(hlo)
 
 
 def test_custom_combiner_rejects_direction_both():
@@ -154,8 +350,6 @@ def test_custom_combiner_rejects_direction_both():
     view = build_view(_lpa_log(5), 90)
     with pytest.raises(ValueError, match="custom"):
         bsp.run(Bad(), view)
-    import jax
-
     mesh = sharded.make_mesh(8, 1, devices=jax.devices()[:8])
     with pytest.raises(ValueError, match="custom"):
         sharded.run(Bad(), view, mesh)
